@@ -16,8 +16,7 @@ frame total, modeled vs measured. A lever whose share grew is eating
 more of the frame than the model promised, whatever the absolute
 clock; the report states both scales so a reader can judge.
 
-JAX-free on purpose: runs in bench.py's parent orchestrator, in
-tpu_watcher post-steps and in CI over committed artifacts.
+JAX-free on purpose: runs in CI over committed artifacts.
 
 Usage:
     python benchmarks/divergence.py --attribution FILE [--modeled FILE]

@@ -59,7 +59,7 @@ def main():
 
     import jax
 
-    from scenery_insitu_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     if os.environ.get(_CHILD) == "1":
         pin_cpu_backend()

@@ -33,17 +33,8 @@ def main():
     ap.add_argument("--out", default="benchmarks/results/trace_r3")
     args = ap.parse_args()
 
-    from scenery_insitu_tpu.utils.backend import (enable_compile_cache,
-                                                  pin_cpu_backend, probe_tpu)
+    from scenery_insitu_tpu.utils.backend import enable_compile_cache
 
-    if os.environ.get("JAX_PLATFORMS") == "cpu" or probe_tpu() == 0:
-        if os.environ.get("JAX_PLATFORMS") != "cpu":
-            from scenery_insitu_tpu import obs
-
-            obs.degrade("bench.platform", "tpu", "cpu",
-                        "profile_frame: TPU probe found no devices",
-                        warn=False)
-        pin_cpu_backend()
     enable_compile_cache()
 
     import jax

@@ -17,8 +17,7 @@ Usage::
     JAX_PLATFORMS=cpu python benchmarks/serve_bench.py \
         --out benchmarks/results/serve_bench_r13_cpu.json
 
-The last stdout line is the artifact JSON (tpu_watcher.sh step 13
-captures it with run_json).
+The last stdout line is the artifact JSON.
 """
 
 from __future__ import annotations

@@ -209,7 +209,7 @@ def fmt(r: dict) -> str:
                 f"(modeled raw {mod.get('dcn_bytes_sent_per_host')})")
         return "\n   ".join(lines)
     if str(r.get("metric", "")).startswith("hier_device_ab"):
-        # flat vs hierarchical device-path A/B (watcher step 14)
+        # flat vs hierarchical device-path A/B
         lines = [f"{r['metric']}: flat {r.get('flat_ms_per_frame')} "
                  f"ms/frame ({r.get('devices')} dev, {r.get('grid')}^3)"]
         for key, h in sorted((r.get("hier") or {}).items()):
@@ -221,7 +221,7 @@ def fmt(r: dict) -> str:
             lines.append(f"  note: {r['note']}")
         return "\n   ".join(lines)
     if str(r.get("metric", "")).startswith("lod_ladder"):
-        # multi-resolution march ladder (watcher step 16)
+        # multi-resolution march ladder
         sc = r.get("scene", {})
         lines = [f"{r['metric']}: x{r.get('value')} modeled march FLOPs "
                  f"at {r.get('psnr_db')} dB (floor "
@@ -237,7 +237,7 @@ def fmt(r: dict) -> str:
                 f"{rung.get('frame_ms')} ms  [{hist_s}]")
         return "\n   ".join(lines)
     if str(r.get("metric", "")).startswith("delivery_ab"):
-        # async delivery plane A/B (watcher step 19)
+        # async delivery plane A/B
         lines = [f"{r['metric']}: exposed host x{r.get('value')} of "
                  f"serial (bit_identical={r.get('bit_identical_all')}, "
                  f"fifo={r.get('ordering_fifo_all')})"]

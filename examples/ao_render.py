@@ -24,11 +24,8 @@ def main():
     ap.add_argument("--steps", type=int, default=192)
     args = ap.parse_args()
 
-    from scenery_insitu_tpu.utils.backend import (enable_compile_cache,
-                                                  pin_cpu_backend, probe_tpu)
+    from scenery_insitu_tpu.utils.backend import enable_compile_cache
 
-    if os.environ.get("JAX_PLATFORMS") == "cpu" or probe_tpu() == 0:
-        pin_cpu_backend()
     enable_compile_cache()
 
     import numpy as np

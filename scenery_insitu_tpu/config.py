@@ -148,8 +148,7 @@ class SliceMarchConfig:
     #                kernel grid, [K] state VMEM-resident per pixel strip
     #                (one HBM round trip per march; costs a f32[S,Nj,Ni]
     #                stream buffer);
-    #   "auto"       pallas_seg on TPU (compile-probe gated, falling back
-    #                to seg), xla elsewhere.
+    #   "auto"       pallas_seg on TPU, xla elsewhere.
     fold: str = "auto"
 
     def __post_init__(self):
@@ -521,9 +520,10 @@ class SimConfig:
     particle_radius: float = 0.35
     # Advance gray_scott through the time-fused Pallas stencil on TPU
     # (sim/pallas_stencil.py — T steps per volume round trip instead of
-    # one; probe-gated, degrades to the XLA roll path off-TPU or when no
-    # schedule compiles). False pins the XLA roll formulation — the
-    # sim-fusion lever's A/B switch.
+    # one). Off-TPU, on a grid no tile of the kernel fits, and on a
+    # multi-rank mesh (z-sharded state) the XLA roll formulation runs
+    # instead, and the ledger says so (sim.fused_stencil). False pins the
+    # XLA roll formulation — the sim-fusion lever's A/B switch.
     fused_stencil: bool = True
 
 

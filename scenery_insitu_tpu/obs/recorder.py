@@ -103,10 +103,6 @@ _LEDGER_REGISTRY: Dict[str, str] = {
                         "timed out; the artifact records an error row",
     "bench.cost_analysis": "bench: XLA cost analysis unavailable; "
                            "artifact bytes fall back to the floor model",
-    "bench.platform": "bench/benchmarks: the TPU attempt gave way to the "
-                      "CPU (or virtual-mesh) fallback",
-    "bench.platform_attempt": "bench: one platform attempt failed "
-                              "(per-attempt reason in failed_attempts)",
     "bench.scan_frames": "bench: SCAN_FRAMES requested without temporal "
                          "mxu mode; eager per-frame dispatch runs",
     "composite.schedule": "tile waves requested on a single-rank mesh; "
@@ -199,16 +195,8 @@ _LEDGER_REGISTRY: Dict[str, str] = {
                             "lax field_ranges recompute runs",
     "occupancy.vtiles_clamp": "requested in-plane occupancy tiles exceed "
                               "the geometry; clamped",
-    "ops.composite_fold": "Mosaic rejected the fused composite resegment "
-                          "kernel; XLA scan composite runs",
-    "ops.count_fold": "Mosaic rejected the counting kernel; XLA counting "
-                      "scan runs",
-    "ops.march_fold": "Mosaic rejected the march fold kernel; XLA fold "
-                      "runs",
     "ops.pallas_march.block_width": "kernel block width clamped below "
                                     "the VMEM-budget request",
-    "ops.seg_fold": "Mosaic rejected a seg/fused fold kernel; the probed "
-                    "seg stack runs",
     "phase_bench.sim_fused": "phase_bench: --sim-fused needs a 1-rank "
                              "mesh; xla_roll runs",
     "scenario.tf_update": "a steered transfer function not seen before "
@@ -249,8 +237,6 @@ _LEDGER_REGISTRY: Dict[str, str] = {
                        "bounded exponential backoff",
     "stream.steering": "a malformed or oversized steering message was "
                        "dropped; the drain keeps going",
-    "sim.stencil_schedule": "Mosaic rejected every probed stencil "
-                            "schedule candidate for this grid/T",
     "topology.hier": "a hierarchical topology knob is inert on this "
                      "configuration (one host, or a mode with no "
                      "two-level composite); the flat single-level "

@@ -2,10 +2,10 @@
 classification over a phase attribution (obs/profiler.py) joined with
 the compiled step's device-cost snapshot (obs/device.py).
 
-This module is deliberately **JAX-free**: bench.py's parent orchestrator
-(which never touches a JAX backend) and benchmarks/divergence.py both
-import it, and the peak tables here are the ONE copy the whole repo
-reads (bench.py re-exports them for its MFU/HBM report fields).
+This module is deliberately **JAX-free** (benchmarks/divergence.py reads
+artifacts without a backend), and the peak tables here are the ONE copy
+the whole repo reads (bench.py re-exports them for its MFU/HBM report
+fields).
 
 The verdict model, stated so the artifact can carry its own assumptions:
 
@@ -70,18 +70,20 @@ CPU_NOMINAL_HBM_GBPS = 20.0
 COMM_PHASES = {"exchange": "ici", "dcn_hop": "dcn"}
 
 
-def kind_lookup(table, device_kind: str, platform: str,
-                default: Optional[float]):
+def kind_lookup(table, device_kind: str, platform: str):
     """Device-kind substring lookup of a peak table; None off-TPU (the
-    caller decides its non-TPU story), table default when the kind is
-    unrecognized (assume v5e-class)."""
+    caller decides its non-TPU story). A TPU kind the table does not
+    know is an error — a roofline share against a guessed peak is not a
+    measurement."""
     if platform != "tpu":
         return None
     kind = (device_kind or "").lower()
     for sub, val in table:
         if sub in kind:
             return val
-    return default
+    raise ValueError(
+        f"TPU device kind {device_kind!r} is not in the peak tables "
+        f"(obs/roofline.py); add its published peaks there")
 
 
 def peaks_for(device_kind: str, platform: str,
@@ -91,8 +93,8 @@ def peaks_for(device_kind: str, platform: str,
     device kind (stated nominal figures off-TPU), link peaks from the
     modeled-projection assumptions. Every verdict artifact embeds this
     verbatim so the numbers can be re-judged when assumptions move."""
-    tflops = kind_lookup(PEAK_TFLOPS, device_kind, platform, 197.0)
-    hbm = kind_lookup(PEAK_HBM_GBPS, device_kind, platform, 819.0)
+    tflops = kind_lookup(PEAK_TFLOPS, device_kind, platform)
+    hbm = kind_lookup(PEAK_HBM_GBPS, device_kind, platform)
     if tflops is None or hbm is None:
         return {"tflops": CPU_NOMINAL_TFLOPS,
                 "hbm_gbps": CPU_NOMINAL_HBM_GBPS,

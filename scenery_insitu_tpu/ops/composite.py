@@ -107,6 +107,15 @@ def sort_stream(flat_c: jnp.ndarray, flat_d: jnp.ndarray):
     return jnp.where(live[:, None], sc, 0.0), sd
 
 
+def resolve_backend(cfg: CompositeConfig) -> str:
+    """The merge-fold schedule ``cfg.backend`` names on this backend:
+    "auto" is the fused Pallas kernel on TPU and the XLA scan elsewhere.
+    What Mosaic says about the kernel reaches the caller."""
+    if cfg.backend != "auto":
+        return cfg.backend
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
 def resegment_stream(sc: jnp.ndarray, sd: jnp.ndarray,
                      cfg: Optional[CompositeConfig] = None,
                      gap_eps: float = 1e-4) -> VDI:
@@ -126,25 +135,7 @@ def resegment_stream(sc: jnp.ndarray, sd: jnp.ndarray,
     _, _, h, w = sc.shape
     k_out = cfg.max_output_supersegments
 
-    backend = cfg.backend
-    if backend == "auto":
-        # auto is probe-gated like every other auto-picked Pallas
-        # schedule (ADVICE r5 #4): a shape-dependent Mosaic rejection of
-        # the fused resegment kernel must degrade to the XLA scan HERE
-        # (the probe ledgers it as ops.composite_fold), not fire inside
-        # a traced frame step. An explicit backend="pallas" stays
-        # trusted-unprobed.
-        if jax.default_backend() == "tpu":
-            from scenery_insitu_tpu.ops.pallas_composite import \
-                composite_compile_ok
-            nk = sc.shape[0]
-            backend = "pallas" if composite_compile_ok(
-                nk, k_out, cfg.adaptive_iters if cfg.adaptive else 0) \
-                else "xla"
-        else:
-            backend = "xla"
-
-    if backend == "pallas":
+    if resolve_backend(cfg) == "pallas":
         # fully fused: the adaptive threshold search runs inside the kernel
         from scenery_insitu_tpu.ops.pallas_composite import resegment_sorted
         color, depth = resegment_sorted(
@@ -242,7 +233,7 @@ def modeled_exchange_traffic(n: int, k: int, height: int, width: int,
                              wave_tiles: int = 1) -> dict:
     """Modeled per-rank bytes of the sort-last exchange + composite for
     one frame — the composite counterpart of
-    ``sim.pallas_stencil.modeled_sim_traffic`` (probe-free, usable
+    ``sim.pallas_stencil.modeled_sim_traffic`` (usable
     off-TPU), consumed by ``benchmarks/composite_bench.py`` and the ring
     build's obs event.
 

@@ -201,43 +201,3 @@ def resegment_sorted(sc: jnp.ndarray, sd: jnp.ndarray,
         color = color[:, :, :h, :w]
         depth = depth[:, :, :h, :w]
     return color, depth
-
-
-# ------------------------------------------------------------ compile probe
-
-_COMPOSITE_PROBE: dict = {}
-
-
-def composite_compile_ok(nk: int, k_out: int,
-                         adaptive_iters: int = 0) -> bool:
-    """One-time Mosaic-acceptance probe for the composite resegment
-    kernel at the real (nk, k_out, adaptive_iters) — the knobs the VMEM
-    working set and the statically-unrolled threshold search scale with.
-    The block geometry is one (TILE_H, TILE_W) pixel tile whatever the
-    frame size, so the probe shape IS the kernel Mosaic sees and the
-    cache key needs no width. ``composite.backend == "auto"`` consults
-    this before picking the Pallas schedule (ops/composite.py): a
-    rejection degrades to the XLA scan on the ledger instead of firing
-    inside a traced frame step where nothing can catch it. Explicit
-    ``backend="pallas"`` stays trusted-unprobed, like an explicit
-    stencil tz (ADVICE r5 #4)."""
-    from scenery_insitu_tpu.ops.pallas_util import mosaic_probe
-
-    def compile_fn():
-        sds = jax.ShapeDtypeStruct
-
-        def f(sc, sd):
-            return resegment_sorted(sc, sd, None, k_out,
-                                    adaptive_iters=adaptive_iters,
-                                    interpret=False)
-
-        jax.jit(f).lower(
-            sds((nk, 4, TILE_H, TILE_W), jnp.float32),
-            sds((nk, 2, TILE_H, TILE_W), jnp.float32)).compile()
-
-    return mosaic_probe(
-        _COMPOSITE_PROBE,
-        (jax.default_backend(), int(nk), int(k_out), int(adaptive_iters)),
-        compile_fn, "ops.composite_fold", "pallas", "xla",
-        f"Mosaic rejected the composite resegment kernel at nk={nk} "
-        f"k_out={k_out} iters={adaptive_iters}")

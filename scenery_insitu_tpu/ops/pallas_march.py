@@ -95,15 +95,9 @@ _VMEM_STRIP_BUDGET = 14 * 1024 * 1024
 # None = budget-driven choice
 _FORCE_BLOCK_W: Optional[int] = None
 # fold_chunk's VMEM estimate treats K as at least this value, so the block
-# width is IDENTICAL for every K <= _EST_K and `fold_compile_ok` (which
-# probes at _EST_K) compiles the exact geometry production will run; with
-# a K-dependent estimate a K=32 probe would pick a NARROWER (cheaper)
-# block than a K=16 production kernel and could pass where production
-# OOMs. K > _EST_K shrinks the block further (VMEM-safe) but then the
-# probe geometry no longer matches — probe explicitly at that K.
+# width is the same for every K <= _EST_K; larger K shrinks the block.
 _EST_K = 32
-# bins floor for the counting kernel's block-width estimate (see
-# count_multi_chunk / count_compile_ok)
+# bins floor for the counting kernel's block-width estimate, likewise
 _EST_B = 32
 # phase-2 schedule experiment (benchmarks/fold_microbench.py variant
 # "pallas_gated"): skip the event-extraction math for slot rows with no
@@ -124,7 +118,7 @@ def strip_fpp(c: int, k: int, small_rows: int = _NSMALL,
     1 threshold + extra per-pixel planes + 6K state + small rows +
     optional count plane), plus the per-slice record arrays (events or
     seg (slot,v) records) and slack for phase temporaries. K floored at
-    _EST_K for probe-geometry invariance. Callers differing from the
+    _EST_K. Callers differing from the
     production fold pass their deltas explicitly instead of hand-copying
     the formula."""
     return (2 * 2 * (stream_per_slice * c + 1 + extra_planes
@@ -151,8 +145,7 @@ def _pick_block_w(w: int, bytes_per_col: int) -> int:
             f"strip needs {bytes_per_col * 128 / 2**20:.1f} MB VMEM at "
             "the 128-lane minimum block width — over the "
             f"{_VMEM_STRIP_BUDGET / 2**20:.0f} MB budget; compiling at "
-            "the floor anyway (Mosaic may reject it; the fold probe / "
-            "auto mode falls back to the XLA fold)", stacklevel=3)
+            "the floor anyway (Mosaic may refuse it)", stacklevel=3)
     return max(128, min(wb, w))
 
 
@@ -341,8 +334,8 @@ def fold_chunk(packed, rgba: jnp.ndarray, t0: jnp.ndarray, t1: jnp.ndarray,
     td = jnp.stack([t0, t1], axis=1)                       # [C, 2, H, W]
     with_count = count is not None
 
-    # the count plane is budgeted whether or not it rides along, for the
-    # same probe-geometry-invariance reason as strip_fpp's K floor
+    # the count plane is budgeted whether or not it rides along, so both
+    # variants tile alike
     wb = _pick_block_w(w, 4 * TILE_H * strip_fpp(c, kk))
     grid = (h // TILE_H, pl.cdiv(w, wb))
     row = lambda *lead: pl.BlockSpec(lead + (TILE_H, wb),
@@ -416,9 +409,8 @@ def count_multi_chunk(carry, rgba: jnp.ndarray, tvec, *,
         raise ValueError(f"height {h} not a multiple of {TILE_H}")
     tvec3 = jnp.asarray(tvec, jnp.float32).reshape(b, 1, 1)
 
-    # b floored at _EST_B so the block width (the exact kernel geometry
-    # Mosaic sees) is identical for every bins <= _EST_B and matches
-    # `count_compile_ok`'s probe — same invariance argument as _EST_K
+    # b floored at _EST_B so the block width is identical for every
+    # bins <= _EST_B
     floats_per_px = 2 * 2 * (4 * c + 2 * (max(b, _EST_B) + 4)) + 32
     wb = _pick_block_w(w, 4 * TILE_H * floats_per_px)
     row = lambda *lead: pl.BlockSpec(lead + (TILE_H, wb),
@@ -442,105 +434,3 @@ def init_count_multi_packed(bins: int, height: int, width: int):
     return (jnp.zeros((bins, height, width), jnp.int32),
             jnp.zeros((3, height, width), jnp.float32),
             jnp.ones((height, width), jnp.float32))
-
-
-# ------------------------------------------------------------ compile probe
-
-_COUNT_PROBE: dict = {}
-
-
-def count_compile_ok(bins: int = 32, chunk: int = 16,
-                     width: int = 2048) -> bool:
-    """One-time Mosaic-acceptance probe for the COUNTING kernel
-    (`count_multi_chunk`) at the real (chunk, width) geometry
-    geometry. The round-4 "auto" fold resolution requires this alongside
-    the write-fold probe before selecting a pallas schedule: the
-    histogram/temporal-seed counting march runs this kernel, and a
-    rejection must degrade to the XLA counting scan in `make_spec`, not
-    fail inside a traced frame step. Probed at max(bins, _EST_B): the
-    bins floor in the kernel's block-width estimate pins the block
-    geometry for every bins <= _EST_B to what the _EST_B probe
-    exercises (conservative direction — the probe's kernel is the
-    bigger one), and bins > _EST_B probe at their real size."""
-    key = (jax.default_backend(), int(max(bins, _EST_B)), int(chunk),
-           int(width))
-    ok = _COUNT_PROBE.get(key)
-    if ok is None:
-        try:
-            b, c, h, w = int(max(bins, _EST_B)), int(chunk), TILE_H, \
-                int(width)
-            sds = jax.ShapeDtypeStruct
-
-            def f(carry, rgba, tvec):
-                return count_multi_chunk(carry, rgba, tvec)
-
-            carry = (sds((b, h, w), jnp.int32), sds((3, h, w), jnp.float32),
-                     sds((h, w), jnp.float32))
-            jax.jit(f).lower(carry, sds((c, 4, h, w), jnp.float32),
-                             sds((b,), jnp.float32)).compile()
-            ok = True
-        except Exception as e:
-            from scenery_insitu_tpu import obs
-
-            obs.degrade(
-                "ops.count_fold", "pallas_count", "xla",
-                f"Mosaic rejected the counting kernel at bins={bins} "
-                f"chunk={chunk} width={width} ({type(e).__name__}: "
-                f"{str(e)[:200]})")
-            ok = False
-        _COUNT_PROBE[key] = ok
-    return ok
-
-
-_FOLD_PROBE: dict = {}
-
-
-def fold_compile_ok(max_k: int = 32, chunk: int = 16,
-                    width: int = 2048) -> bool:
-    """One-time probe: does Mosaic accept the fold kernel AT THIS SHAPE on
-    the current backend? Like sim/pallas_stencil._compile_ok, this
-    catches a compile rejection (typically a Mosaic resource limit —
-    shape dependent, so the probe must use the real K/chunk/width, not a
-    toy shape) HERE, where `slicer.make_spec`'s "auto" resolution can
-    fall back to the XLA fold — instead of inside a traced frame step
-    (e.g. the driver's entry() compile check) where nothing can. Strip
-    VMEM scales with (max_k, chunk) and — since `_pick_block_w` caps the
-    block width by the budget — is insensitive to width beyond the cap;
-    probing at the real width still matters because it fixes the BLOCK
-    width (and thus the exact kernel Mosaic sees), not because wider
-    frames cost more VMEM. Height never matters (one TILE_H strip per
-    grid step). Defaults are conservative upper bounds for this
-    framework's configs. Cached per (backend, shape); failures are
-    warned, not silent."""
-    key = (jax.default_backend(), int(max_k), int(chunk), int(width))
-    ok = _FOLD_PROBE.get(key)
-    if ok is None:
-        try:
-            k, c, h, w = int(max_k), int(chunk), TILE_H, int(width)
-            sds = jax.ShapeDtypeStruct
-            packed = (sds((k, 4, h, w), jnp.float32),
-                      sds((k, 2, h, w), jnp.float32),
-                      sds((_NSMALL, h, w), jnp.float32))
-
-            def f(packed, rgba, t0, t1, thr, count):
-                return fold_chunk(packed, rgba, t0, t1, thr, max_k=k,
-                                  count=count)
-
-            jax.jit(f).lower(
-                packed, sds((c, 4, h, w), jnp.float32),
-                sds((c, h, w), jnp.float32), sds((c, h, w), jnp.float32),
-                sds((h, w), jnp.float32), sds((h, w), jnp.int32)).compile()
-            ok = True
-        except Exception as e:
-            from scenery_insitu_tpu import obs
-
-            obs.degrade(
-                "ops.march_fold", "pallas", "xla",
-                f"Mosaic rejected the march fold at k={max_k} "
-                f"chunk={chunk} width={width} ({type(e).__name__}: "
-                f"{str(e)[:200]}). If this was a transient backend "
-                "error, restart the process or set fold='pallas' "
-                "explicitly.")
-            ok = False
-        _FOLD_PROBE[key] = ok
-    return ok

@@ -41,17 +41,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from scenery_insitu_tpu.ops import seg_fold as sf
 from scenery_insitu_tpu.ops import supersegments as ss
-from scenery_insitu_tpu.ops.pallas_march import _pick_block_w
+from scenery_insitu_tpu.ops.pallas_march import _pick_block_w, strip_fpp
 from scenery_insitu_tpu.ops.pallas_util import TILE_H, should_interpret
-from scenery_insitu_tpu.utils.compat import tpu_compiler_params
 
 _CNT, _PREV_RGB, _PREV_EMPTY = 0, slice(1, 4), 4
 _NSMALL = 5
-# estimate floor on K so the chosen block width (and thus the exact kernel
-# Mosaic compiles) is identical for every K <= _EST_K and matches the
-# compile probe's geometry. The floor actually applied lives in
-# pallas_march (strip_fpp uses it); alias it so the two can never diverge.
-from scenery_insitu_tpu.ops.pallas_march import _EST_K, strip_fpp  # noqa: F401
 
 
 def init_seg_packed(k: int, height: int, width: int):
@@ -385,8 +379,6 @@ def _fused_fpp(c: int, k: int) -> int:
     stream (vs 6C rgba+depth), 2 extra per-pixel planes (length, ratio),
     and 9 per-slice record floats (7 scratch + the t0/t1 temporaries the
     kernel broadcasts itself)."""
-    from scenery_insitu_tpu.ops.pallas_march import strip_fpp
-
     return strip_fpp(c, k, small_rows=_NSMALL, count_plane=False,
                      per_slice_records=9, stream_per_slice=1,
                      extra_planes=2)
@@ -520,136 +512,8 @@ def fused_stream_fold(packed, val: jnp.ndarray, length: jnp.ndarray,
         out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in packed],
         scratch_shapes=[pltpu.VMEM((c, 7, TILE_H, wb), jnp.float32)],
         input_output_aliases={6: 0, 7: 1, 8: 2},
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(val, length, ratio, threshold, sk0, sk1, *packed)
     return tuple(out)
-
-
-# ------------------------------------------------------------ compile probe
-
-_PROBE: dict = {}
-
-
-def seg_compile_ok(max_k: int = 32, chunk: int = 16,
-                   width: int = 2048) -> bool:
-    """One-time Mosaic-acceptance probe at the REAL (K, chunk, width) so
-    `slicer.make_spec`'s "auto" can fall back to the XLA seg fold instead
-    of failing inside a traced frame step. Cached per (backend, shape)."""
-    key = (jax.default_backend(), int(max_k), int(chunk), int(width))
-    ok = _PROBE.get(key)
-    if ok is None:
-        try:
-            k, c, h, w = int(max_k), int(chunk), TILE_H, int(width)
-            sds = jax.ShapeDtypeStruct
-
-            # probe BOTH kernel variants the production march can trace:
-            # the compact-depth form (what the march feeds) and the
-            # td-plane form (tests / arbitrary streams). sk0 and sk1 are
-            # DISTINCT inputs: binding both to one traced array would let
-            # the compiler CSE the t0a/t1a temporaries into one
-            # [C,TH,WB] buffer and accept a smaller kernel than the
-            # production one, which always carries two sk streams.
-            def f(pk, rgba, sk0, sk1, ln, thr):
-                return fold_chunk_packed(pk, rgba, threshold=thr,
-                                         max_k=k, sk0=sk0, sk1=sk1,
-                                         length=ln)
-
-            def g(st, rgba, t0, t1, thr):
-                return seg_fold_chunk(st, rgba, t0, t1, thr, max_k=k)
-
-            pk = (sds((k, 4, h, w), jnp.float32),
-                  sds((k, 2, h, w), jnp.float32),
-                  sds((_NSMALL, h, w), jnp.float32))
-            jax.jit(f).lower(
-                pk, sds((c, 4, h, w), jnp.float32),
-                sds((c,), jnp.float32), sds((c,), jnp.float32),
-                sds((h, w), jnp.float32),
-                sds((h, w), jnp.float32)).compile()
-            st = sf.SegFoldState(
-                out_color=sds((k, 4, h, w), jnp.float32),
-                out_start=sds((k, h, w), jnp.float32),
-                out_end=sds((k, h, w), jnp.float32),
-                cnt=sds((h, w), jnp.int32),
-                prev_rgb=sds((3, h, w), jnp.float32),
-                prev_empty=sds((h, w), jnp.bool_))
-            jax.jit(g).lower(
-                st, sds((c, 4, h, w), jnp.float32),
-                sds((c, h, w), jnp.float32), sds((c, h, w), jnp.float32),
-                sds((h, w), jnp.float32)).compile()
-            ok = True
-        except Exception as e:
-            from scenery_insitu_tpu import obs
-
-            obs.degrade(
-                "ops.seg_fold", "pallas_seg", "seg",
-                f"Mosaic rejected the seg fold at k={max_k} chunk={chunk} "
-                f"width={width} ({type(e).__name__}: {str(e)[:200]})")
-            ok = False
-        _PROBE[key] = ok
-    return ok
-
-
-_FUSED_PROBE: dict = {}
-
-
-def fused_compile_ok(max_k: int = 32, chunk: int = 16,
-                     width: int = 2048, stream: bool = False) -> bool:
-    """One-time Mosaic-acceptance probe for the shade-in-kernel folds:
-    `fused_fold_chunk` (``stream=False``, fold="pallas_fused") and
-    `fused_stream_fold` (``stream=True``, fold="fused_stream") at the
-    real (K, chunk, width) geometry. The TF constants are baked into the
-    kernel but only change scalars, not structure or VMEM, so a generic
-    ramp TF probes the same kernel Mosaic judges in production.
-    `slicer.make_spec` consults this when a fused fold is explicitly
-    requested ON TPU and degrades to the probed pallas_seg/seg stack on
-    rejection (ledgered as ops.seg_fold) — same rationale as the auto
-    probes: a resource rejection must land here, not inside a traced
-    frame step. Off-TPU the fused folds run in interpret mode and are
-    never probed."""
-    from scenery_insitu_tpu.ops.pallas_util import mosaic_probe
-
-    def compile_fn():
-        from scenery_insitu_tpu.core.transfer import TransferFunction
-
-        tf = TransferFunction.ramp(0.0, 1.0, 0.5, "grays")
-        k, c, h, w = int(max_k), int(chunk), TILE_H, int(width)
-        sds = jax.ShapeDtypeStruct
-        pk = (sds((k, 4, h, w), jnp.float32),
-              sds((k, 2, h, w), jnp.float32),
-              sds((_NSMALL, h, w), jnp.float32))
-        if stream:
-            s_total = 2 * c           # exercises the multi-chunk grid
-
-            def f(pk, val, ln, ratio, sk0, sk1, thr):
-                return fused_stream_fold(pk, val, ln, ratio, sk0,
-                                         sk1, thr, max_k=k, chunk=c,
-                                         tf=tf, interpret=False)
-
-            jax.jit(f).lower(
-                pk, sds((s_total, h, w), jnp.float32),
-                sds((h, w), jnp.float32), sds((h, w), jnp.float32),
-                sds((s_total,), jnp.float32),
-                sds((s_total,), jnp.float32),
-                sds((h, w), jnp.float32)).compile()
-        else:
-            def f(pk, val, ln, ratio, sk0, sk1, thr):
-                return fused_fold_chunk(pk, val, ln, ratio, sk0,
-                                        sk1, thr, max_k=k, tf=tf,
-                                        interpret=False)
-
-            jax.jit(f).lower(
-                pk, sds((c, h, w), jnp.float32),
-                sds((h, w), jnp.float32), sds((h, w), jnp.float32),
-                sds((c,), jnp.float32), sds((c,), jnp.float32),
-                sds((h, w), jnp.float32)).compile()
-
-    return mosaic_probe(
-        _FUSED_PROBE,
-        (jax.default_backend(), int(max_k), int(chunk), int(width),
-         bool(stream)),
-        compile_fn, "ops.seg_fold",
-        "fused_stream" if stream else "pallas_fused", "seg",
-        f"Mosaic rejected the fused fold at k={max_k} chunk={chunk} "
-        f"width={width} stream={stream}")

@@ -12,28 +12,3 @@ TILE_W = 128
 def should_interpret() -> bool:
     """Run kernels in interpret mode off-TPU (tests, the virtual mesh)."""
     return jax.default_backend() != "tpu"
-
-
-def mosaic_probe(cache: dict, key: tuple, compile_fn,
-                 component: str, from_: str, to: str, detail: str) -> bool:
-    """Shared skeleton of the one-time Mosaic compile probes: run
-    ``compile_fn`` (a closure lowering+compiling the REAL kernel
-    geometry) once per ``key``, cache the verdict in ``cache``, and on
-    rejection mint one ``obs.degrade(component, from_, to, ...)`` ledger
-    entry carrying ``detail`` plus the truncated backend error. Keeps the
-    probe family (composite/fused folds) in sync on the except-breadth,
-    message truncation and caching semantics instead of hand-copying the
-    try/except per kernel."""
-    ok = cache.get(key)
-    if ok is None:
-        try:
-            compile_fn()
-            ok = True
-        except Exception as e:
-            from scenery_insitu_tpu import obs
-
-            obs.degrade(component, from_, to,
-                        f"{detail} ({type(e).__name__}: {str(e)[:200]})")
-            ok = False
-        cache[key] = ok
-    return ok

@@ -157,54 +157,19 @@ def make_spec(cam: Camera, vol_shape: Tuple[int, int, int],
     nj = rnd(dims_xyz[v_axis])
     fold = cfg.fold
     if fold == "auto":
-        # On TPU the default is the round-4 segmented-scan fold: the
-        # Pallas VMEM twin when a one-time Mosaic compile probe AT THIS
-        # SPEC'S frame width accepts it (the probe fixes the budget-capped
-        # BLOCK width and thus the exact kernel Mosaic sees; K probed at a
-        # conservative 32 — VDIConfig's K is not known here), else the
-        # pure-XLA seg schedule — still chunk-granular state traffic, no
-        # Mosaic exposure. On CPU the sequential machine wins (state
-        # lives in cache, and seg's K-masked reductions are real extra
-        # compute on a scalar core — measured 3x slower at 64x96^2), so
-        # tests and the virtual mesh keep "xla".
-        # BOTH kernels a pallas_seg spec can run must pass the probe: the
-        # write fold (pallas_seg.seg_fold_chunk) and the counting kernel
-        # the histogram/temporal-seed march uses (pm.count_multi_chunk) —
-        # a spec whose write kernel compiles but whose counting kernel is
-        # rejected would still fail inside initial_threshold(). EVERY
-        # kernel GEOMETRY must pass too: the occupancy-skip branch of
-        # slice_march feeds a 1-slice chunk (slicer.skip), compiling a
-        # second c=1 variant of each kernel inside the traced step, so
-        # probe that geometry alongside cfg.chunk (cheap, cached) — but
-        # only when the skip path is reachable (skip_empty): with
-        # skipping off the c=1 kernels are never built, and a c=1
-        # rejection must not demote a config that would never trace it.
-        if jax.default_backend() == "tpu":
-            c1_ok = (not cfg.skip_empty
-                     or (psg.seg_compile_ok(32, 1, ni)
-                         and pm.count_compile_ok(32, 1, ni)))
-            fold = ("pallas_seg" if psg.seg_compile_ok(32, cfg.chunk, ni)
-                    and pm.count_compile_ok(32, cfg.chunk, ni)
-                    and c1_ok else "seg")
-        else:
-            fold = "xla"
+        # On TPU the default is the round-4 segmented-scan fold's Pallas
+        # VMEM twin (its counting march runs pm.count_multi_chunk); what
+        # Mosaic says about either kernel reaches the caller. On CPU the
+        # sequential machine wins (state lives in cache, and seg's
+        # K-masked reductions are real extra compute on a scalar core —
+        # measured 3x slower at 64x96^2), so tests and the virtual mesh
+        # keep "xla".
+        fold = "pallas_seg" if jax.default_backend() == "tpu" else "xla"
     if fold not in ("xla", "pallas", "seg", "pallas_seg", "pallas_fused",
                     "fused_stream"):
         raise ValueError(f"unknown fold schedule {fold!r} (expected 'auto', "
                          "'xla', 'pallas', 'seg', 'pallas_seg', "
                          "'pallas_fused' or 'fused_stream')")
-    if fold in ("pallas_fused", "fused_stream") \
-            and jax.default_backend() == "tpu" \
-            and not psg.fused_compile_ok(32, cfg.chunk, ni,
-                                         stream=(fold == "fused_stream")):
-        # an explicitly requested fused fold that Mosaic rejects AT THIS
-        # GEOMETRY must degrade here (the probe ledgered it as
-        # ops.seg_fold), not compile-crash inside a traced frame step;
-        # fall back to the same probed stack the auto resolution uses.
-        # Off-TPU the fused folds run in interpret mode — never probed,
-        # never degraded.
-        fold = ("pallas_seg" if psg.seg_compile_ok(32, cfg.chunk, ni)
-                and pm.count_compile_ok(32, cfg.chunk, ni) else "seg")
     # resolve the benched auto default (-1): in-plane tiling pays on the
     # TPU march (the A/B in benchmarks/occupancy_bench.py — sparse
     # fields skip most cells) but adds nt lax.cond branches per chunk,

@@ -59,7 +59,7 @@ from scenery_insitu_tpu.ops.composite import (composite_plain,
                                               sort_stream)
 from scenery_insitu_tpu.parallel.mesh import halo_exchange_z
 from scenery_insitu_tpu.parallel.topology import Topology
-from scenery_insitu_tpu.utils.compat import shard_map
+from jax import shard_map
 
 GAP_EPS = 1e-4
 

@@ -57,8 +57,7 @@ def halo_exchange_z(local: jnp.ndarray, axis_name: str = DEFAULT_AXIS,
     not exceed the slab depth — deeper halos would need multi-hop
     exchanges; use fewer ranks or a smaller radius instead.
     """
-    from scenery_insitu_tpu.utils.compat import axis_size
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     dn = local.shape[0]
     if h > dn:
@@ -119,8 +118,7 @@ def _reslab_rows(local: jnp.ndarray, g_all, live_all,
     first — the steal planner's move cap keeps production maps local)."""
     import numpy as np
 
-    from scenery_insitu_tpu.utils.compat import axis_size
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     dn = local.shape[0]
     g_all = np.asarray(g_all, np.int64)
     live_all = np.asarray(live_all, bool)
@@ -172,8 +170,7 @@ def reslab_z(local: jnp.ndarray, plan, axis_name: str = DEFAULT_AXIS,
     (row-for-row; tests assert equality)."""
     import numpy as np
 
-    from scenery_insitu_tpu.utils.compat import axis_size
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     plan = validate_plan(plan, n, h=h)
     dn = local.shape[0]
     d = dn * n
@@ -206,8 +203,7 @@ def reslab_bricks(local: jnp.ndarray, bmap, axis_name: str = DEFAULT_AXIS,
     ppermute routing on the flattened ladder."""
     import numpy as np
 
-    from scenery_insitu_tpu.utils.compat import axis_size
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if bmap.n_ranks != n:
         raise ValueError(f"brick map built for {bmap.n_ranks} ranks on a "
                          f"{n}-rank mesh")
@@ -253,8 +249,7 @@ def reslab_bricks_lod(local: jnp.ndarray, bmap,
     (same ladder, same routing, no pooling)."""
     import numpy as np
 
-    from scenery_insitu_tpu.utils.compat import axis_size
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if bmap.n_ranks != n:
         raise ValueError(f"brick map built for {bmap.n_ranks} ranks on a "
                          f"{n}-rank mesh")

@@ -24,7 +24,7 @@ from scenery_insitu_tpu.core.camera import Camera
 from scenery_insitu_tpu.ops.composite import composite_depth_min
 from scenery_insitu_tpu.ops.splat import (SplatOutput, speed_colors,
                                           splat_particles)
-from scenery_insitu_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def sort_first_splat(pos, vel, axis: str, width: int, height: int,

@@ -102,7 +102,7 @@ from scenery_insitu_tpu.parallel.mesh import (halo_exchange_z,
                                               reslab_bricks_lod, reslab_z)
 from scenery_insitu_tpu.parallel.topology import resolve_mesh_topology
 
-from scenery_insitu_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def _plan_rank_band(plan: tuple, axis_name: str):

@@ -153,24 +153,66 @@ def advance_camera_and_index(sess) -> None:
     sess.frame_index += 1
 
 
+def _sharded_sim(mesh) -> bool:
+    """Does the sim state live z-sharded on ``mesh``? On a multi-rank mesh
+    it does: the jitted advance keeps the sharding (stencil rolls lower
+    to halo collectives), so the render step's z-sharded input is the
+    state's own field, not a per-frame scatter from the first device."""
+    return mesh is not None and mesh.devices.size > 1
+
+
+def _place_sim_state(state, mesh, axis=None):
+    """Place a volume-sim state pytree on a multi-rank mesh: fields
+    (f32[D, H, W], f32[3, D, H, W]) z-sharded like `shard_volume` shards
+    the rendered field, everything smaller (parameters, tracers)
+    replicated — the shardings the jitted advance hands back, so frame 1
+    does not recompile it for a changed input placement."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    axis = axis or mesh.axis_names[0]
+
+    def place(x):
+        spec = (P(*([None] * (x.ndim - 3) + [axis, None, None]))
+                if x.ndim >= 3 else P())
+        return jax.device_put(x, NamedSharding(mesh, spec))
+
+    return jax.tree_util.tree_map(place, state)
+
+
 class VolumeSimAdapter:
     """Uniform facade over the built-in volume sims (kind -> state/advance/
-    field)."""
+    field). ``mesh``/``axis``: the session's mesh and flat rank axis (see
+    `_sharded_sim`)."""
 
-    def __init__(self, cfg: FrameworkConfig, seed: int = 0):
+    def __init__(self, cfg: FrameworkConfig, seed: int = 0, mesh=None,
+                 axis=None):
         kind = cfg.sim.kind
         self.kind = kind
+        sharded = _sharded_sim(mesh)
         if kind == "gray_scott":
-            self.state = gs.GrayScott.from_config(cfg.sim, seed=seed)
+            st = gs.GrayScott.from_config(cfg.sim, seed=seed)
+            fused = cfg.sim.fused_stencil
+            if sharded:
+                st = _place_sim_state(st, mesh, axis)
+                if fused:
+                    _obs.degrade(
+                        "sim.fused_stencil", "pallas", "xla_roll",
+                        f"sim state is z-sharded over {mesh.devices.size} "
+                        "ranks; the fused kernel's periodic wrap is per "
+                        "buffer", warn=False)
+                    fused = False
+            self.state = st
             # fused_stencil routes through the time-fused Pallas kernel
             # on TPU (T steps per HBM round trip of u, v); off-TPU or
             # with the flag off it is exactly the XLA roll path
-            adv = (gs.multi_step_fast if cfg.sim.fused_stencil
-                   else gs.multi_step)
+            adv = gs.multi_step_fast if fused else gs.multi_step
             self._advance = lambda s, n: adv(s, n)
         elif kind == "vortex":
-            self.state = vx.VortexFlow.init_ring(tuple(cfg.sim.grid),
-                                                 vx.VortexParams.create(dt=cfg.sim.dt))
+            st = vx.VortexFlow.init_ring(tuple(cfg.sim.grid),
+                                         vx.VortexParams.create(dt=cfg.sim.dt))
+            if sharded:
+                st = _place_sim_state(st, mesh, axis)
+            self.state = st
             self._advance = lambda s, n: vx.multi_step(s, n)
         else:
             raise ValueError(f"unknown volume sim kind {cfg.sim.kind!r}")
@@ -226,15 +268,20 @@ class ParticleSimAdapter:
 
 class HybridSimAdapter:
     """Vortex flow + passive tracers for the hybrid session mode
-    (BASELINE.md Config 5)."""
+    (BASELINE.md Config 5). ``mesh``/``axis``: see `_sharded_sim` — the
+    flow is z-sharded, the tracers stay whole."""
 
-    def __init__(self, cfg: FrameworkConfig, seed: int = 0):
+    def __init__(self, cfg: FrameworkConfig, seed: int = 0, mesh=None,
+                 axis=None):
         grid = tuple(cfg.sim.grid)
         self.kind = "hybrid"
         self.flow = vx.VortexFlow.init_ring(
             grid, vx.VortexParams.create(dt=cfg.sim.dt))
         self.tracers = vx.seed_tracers(grid, cfg.sim.num_particles,
                                        seed=seed)
+        if _sharded_sim(mesh):
+            self.flow, self.tracers = _place_sim_state(
+                (self.flow, self.tracers), mesh, axis)
 
         @jax.jit
         def _adv(u, pos, n):
@@ -328,9 +375,11 @@ class InSituSession:
         elif self.cfg.sim.kind in ("lennard_jones", "sho"):
             self.sim = ParticleSimAdapter(self.cfg)
         elif self.cfg.sim.kind == "hybrid":
-            self.sim = HybridSimAdapter(self.cfg)
+            self.sim = HybridSimAdapter(self.cfg, mesh=self.mesh,
+                                        axis=self._flat_axis)
         else:
-            self.sim = VolumeSimAdapter(self.cfg)
+            self.sim = VolumeSimAdapter(self.cfg, mesh=self.mesh,
+                                        axis=self._flat_axis)
         self.tf = tf or for_dataset(
             self.cfg.sim.kind if self.cfg.runtime.dataset == "procedural"
             else self.cfg.runtime.dataset)
@@ -578,6 +627,12 @@ class InSituSession:
 
     # ------------------------------------------------------------- frames
 
+    def _shard(self, field: jnp.ndarray) -> jnp.ndarray:
+        """The field as the render steps take it: z-sharded over the
+        flat rank axis. A placement no-op for the built-in volume sims,
+        whose state already lives there (`_sharded_sim`)."""
+        return shard_volume(field, self.mesh, self._flat_axis)
+
     def render_frame(self):
         """Advance the sim and dispatch one render step (device arrays)."""
         drain_steering(self)
@@ -599,7 +654,7 @@ class InSituSession:
                 out, meta = self._hybrid_dispatch()
                 meta = meta._replace(index=jnp.int32(self.frame_index))
             else:
-                field = shard_volume(self.sim.field, self.mesh)
+                field = self._shard(self.sim.field)
                 if self._step is not None:
                     out = self._step(field, self._origin, self._spacing,
                                      self.camera)
@@ -739,15 +794,9 @@ class InSituSession:
         """Kick off the device->host transfer of every buffer in ``out``
         without blocking (``copy_to_host_async``): by the time the
         depth-k pipeline retires this frame, the bytes are already on
-        the host and ``np.asarray`` is a cheap wrap, not a sync.
-        Best-effort — a backend without the method just pays the sync in
-        ``_fetch`` like before."""
-        try:
-            for leaf in jax.tree_util.tree_leaves(out):
-                if hasattr(leaf, "copy_to_host_async"):
-                    leaf.copy_to_host_async()
-        except Exception:
-            pass
+        the host and ``np.asarray`` is a cheap wrap, not a sync."""
+        for leaf in jax.tree_util.tree_leaves(out):
+            leaf.copy_to_host_async()
 
     def _sync_nofetch(self, index: int, out) -> None:
         """Retire a pipelined frame nobody consumes: drop its metadata
@@ -856,7 +905,7 @@ class InSituSession:
         from jax.sharding import PartitionSpec as P
 
         from scenery_insitu_tpu.ops import occupancy as _occ
-        from scenery_insitu_tpu.utils.compat import shard_map
+        from jax import shard_map
 
         if self._profile_fn is None:
             axis = self._flat_axis
@@ -871,7 +920,7 @@ class InSituSession:
             self._profile_fn = jax.jit(shard_map(
                 prof, mesh=self.mesh, in_specs=P(axis, None, None),
                 out_specs=P(axis), check_vma=False))
-        field = shard_volume(self.sim.field, self.mesh)
+        field = self._shard(self.sim.field)
         return np.asarray(self._profile_fn(field))
 
     def _replan_ranges(self):
@@ -883,7 +932,7 @@ class InSituSession:
         from jax.sharding import PartitionSpec as P
 
         from scenery_insitu_tpu.ops import occupancy as _occ
-        from scenery_insitu_tpu.utils.compat import shard_map
+        from jax import shard_map
 
         if self._ranges_fn is None:
             axis = self._flat_axis
@@ -897,7 +946,7 @@ class InSituSession:
             self._ranges_fn = jax.jit(shard_map(
                 rng, mesh=self.mesh, in_specs=P(axis, None, None),
                 out_specs=(P(axis), P(axis)), check_vma=False))
-        field = shard_volume(self.sim.field, self.mesh)
+        field = self._shard(self.sim.field)
         lo, hi = self._ranges_fn(field)
         return np.asarray(lo), np.asarray(hi)
 
@@ -1163,17 +1212,7 @@ class InSituSession:
                         topology=self.cfg.topology)
                     seed = None
             steps_per_frame = self.cfg.sim.steps_per_frame
-            mesh_n = self._n_ranks
-            if mesh_n > 1 and self.sim.kind == "gray_scott":
-                # inside the scanned executable GSPMD propagates the
-                # render step's z-sharding back into the sim advance, and
-                # the fused Pallas stencil's periodic wrap is per-buffer
-                # (sim/pallas_stencil.py docstring) — pin the roll
-                # formulation, whose rolls XLA lowers to ICI halo
-                # exchanges, whenever the mesh can actually shard
-                advance = lambda s: gs.multi_step(s, steps_per_frame)
-            else:
-                advance = lambda s: self.sim._advance(s, steps_per_frame)
+            advance = lambda s: self.sim._advance(s, steps_per_frame)
             entry = (frame_scan(step, advance, block,
                                 temporal=self._temporal), seed)
             self._scan_steps[key] = entry
@@ -1286,7 +1325,7 @@ class InSituSession:
                 if self._temporal:
                     thr = self._mxu_thr.get(regime)
                     if thr is None:
-                        field = shard_volume(self.sim.field, self.mesh)
+                        field = self._shard(self.sim.field)
                         thr = seed(field, self._origin, self._spacing,
                                    self.camera)
                     (st, cam, thr2), outs = runner(*args, thr)
@@ -1392,7 +1431,7 @@ class InSituSession:
                     if self.mode == "hybrid":
                         out, _ = self._hybrid_dispatch()
                     else:
-                        field = shard_volume(self.sim.field, self.mesh)
+                        field = self._shard(self.sim.field)
                         if self.mode == "plain":
                             out = self._plain_mxu_dispatch(field)
                         else:
@@ -1460,7 +1499,7 @@ class InSituSession:
         vel = _vx.tracer_velocities(self.sim.flow.u, self.sim.tracers)
         world = _vx.tracers_to_world(self.sim.tracers, self._origin,
                                      self._spacing)
-        sfield = shard_volume(field, self.mesh)
+        sfield = self._shard(field)
         args = (sfield, self._origin, self._spacing,
                 shard_particles(world, self.mesh),
                 shard_particles(vel, self.mesh), self.camera)
@@ -1640,7 +1679,7 @@ class InSituSession:
 
         snaps = {}
         if self.mode in ("vdi", "plain"):
-            field = shard_volume(self.sim.field, self.mesh)
+            field = self._shard(self.sim.field)
             args = (field, self._origin, self._spacing, self.camera)
             if self._step is not None:
                 snaps["gather" if self.mode == "vdi" else "plain"] = \

@@ -100,10 +100,12 @@ def multi_step(state: GrayScott, n: int) -> GrayScott:
 
 def multi_step_fast(state: GrayScott, n: int) -> GrayScott:
     """Single-device fast path: the fused Pallas stencil kernel on TPU
-    (sim/pallas_stencil.py, ~10x the roll formulation), falling back to
-    `multi_step` on other backends or VMEM-oversized grids. NOT for sharded
-    state — the Pallas kernel's periodic wrap is per-buffer, so use
-    `multi_step` (whose rolls XLA lowers to ICI halo exchanges) there."""
+    (sim/pallas_stencil.py), giving way to `multi_step` — on the ledger —
+    on other backends and on grids no tile of the kernel fits. A Mosaic
+    refusal of the chosen tile is not caught: it reaches the caller. NOT
+    for sharded state — the Pallas kernel's periodic wrap is per-buffer,
+    so use `multi_step` (whose rolls XLA lowers to ICI halo exchanges)
+    there."""
     from scenery_insitu_tpu import obs
     from scenery_insitu_tpu.sim import pallas_stencil as ps
 
@@ -118,8 +120,9 @@ def multi_step_fast(state: GrayScott, n: int) -> GrayScott:
         return multi_step(state, n)
     if not ps.fused_supported(state.u.shape):
         obs.degrade("sim.fused_stencil", "pallas", "xla_roll",
-                    f"no fused-stencil schedule fits grid "
-                    f"{tuple(state.u.shape)} in the VMEM budget",
+                    f"no fused-stencil tile fits grid "
+                    f"{tuple(state.u.shape)} (needs W % 128 == 0, "
+                    f"H % 8 == 0 and a tile under the VMEM limit)",
                     warn=False)
         return multi_step(state, n)
     p = state.params
@@ -134,12 +137,12 @@ def multi_step_fast_ranges(state: GrayScott, n: int, bricks=None,
     rendered field (ops/occupancy.FieldRanges) — the sim-fused update of
     the frame's occupancy pyramid. The fused Pallas path emits the
     ranges as a kernel epilogue (near-free: the slab is already in
-    VMEM); every degraded path (off-TPU, VMEM-oversized grid, Mosaic
-    rejection of the epilogue variant, or ``fused=False`` pinning the
-    XLA roll formulation) falls back to ONE lax reduction over the final
-    field in data layout (`occupancy.field_ranges` — still cheaper than
-    the legacy permute+reduce occupancy pass, and recorded on the
-    fallback ledger unless the roll path was explicitly configured).
+    VMEM); every other path (off-TPU, a grid no tile fits, or
+    ``fused=False`` pinning the XLA roll formulation) runs ONE lax
+    reduction over the final field in data layout
+    (`occupancy.field_ranges` — still cheaper than the legacy
+    permute+reduce occupancy pass, and recorded on the fallback ledger
+    unless the roll path was explicitly configured).
 
     ``bricks = (nzb, nyb)`` is the brick GRID (defaults to
     `occupancy.default_bricks`). Returns ``(state', FieldRanges)``."""
@@ -149,8 +152,7 @@ def multi_step_fast_ranges(state: GrayScott, n: int, bricks=None,
 
     nzb, nyb = bricks or occ.default_bricks(state.v.shape)
     if (fused and jax.default_backend() == "tpu"
-            and ps.fused_supported(state.u.shape)
-            and ps.ranges_supported(state.u.shape)):
+            and ps.fused_supported(state.u.shape)):
         p = state.params
         pvec = jnp.stack([p.f, p.k, p.du, p.dv, p.dt])
         u, v, lo, hi = ps.multi_step_pallas_ranges(state.u, state.v,

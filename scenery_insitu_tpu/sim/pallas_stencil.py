@@ -1,181 +1,272 @@
 """Pallas TPU kernel for the Gray-Scott reaction-diffusion step.
 
 The XLA formulation (sim/grayscott.py) builds the 6-point Laplacian from
-``jnp.roll`` — twelve materialized full-volume copies per step, ~3 ms at
-256³ on a v5e (≈8× above memory-bound). This kernel fuses ``T`` whole
-steps into a single pass: each grid step holds a ``[Tz + 2T, H, W]`` slab
-of u and v in VMEM (the slab plus a T-slice halo on each z side, taken
-from neighbor views of the same HBM arrays with periodic wrap in the
-BlockSpec index_map), advances it T times entirely in VMEM — in-plane
-neighbors by register shifts, z-halo validity shrinking by one slice per
-step so the central Tz slices are exact — and writes the updated slab
-once. Per T steps the volume is read ``(Tz+2T)/Tz`` ≈ 1.25× and written
-1×, so HBM traffic per step drops by ~T× over the single-step kernel at
-the cost of ``2T/Tz`` redundant stencil work.
+``jnp.roll`` — twelve materialized full-volume copies per step. This
+kernel fuses ``T`` whole steps into one pass over (z × h) tiles: each
+grid step assembles a ``[tz + 2T, th + 16, W]`` padded block of u and v
+in VMEM scratch (the tile plus a T-slice z halo and an 8-row h halo on
+each side, taken from neighbor views of the same HBM arrays with
+periodic wrap in the BlockSpec index_map), advances it T times entirely
+in VMEM — one z-plane at a time, ping-ponging between two scratch
+copies; z neighbors are the adjacent planes, h and w neighbors are
+sublane/lane rotates of the plane — and writes the central tile once.
+Halo validity shrinks by one slice/row per step, so after T steps the
+central ``tz × th`` tile is exact. Per T steps the volume is read
+``(tz+2T)(th+16)/(tz·th)`` times and written once.
+
+The h halo is 8 rows whatever T is: Mosaic requires the second-minor
+block dimension to be a multiple of the 8-sublane tile (a ``(tz, T, W)``
+halo view is refused by the lowering), and whole tiles keep every
+in-kernel copy aligned. ``th == H`` is the z-slab special case (the h
+"halo" is the periodic wrap itself). W stays whole: it is the lane axis
+and truly periodic, so the rotate is exact.
+
+The plane loop keeps the compiled program small (one plane of straight-
+line vector code per step instead of the whole block unrolled) and the
+VMEM footprint explicit: scratch + double-buffered blocks are counted by
+`_vmem_bytes` and requested through ``vmem_limit_bytes``; nothing rides
+on Mosaic's 16 MiB default scoped limit.
 
 Used by the single-device fast path only: the *sharded* simulation keeps
 the roll formulation, where XLA lowers the rolls across a z-sharded mesh
 to ICI halo collectives (see sim/grayscott.py docstring) — a Pallas kernel
 with per-shard periodic wrap would silently corrupt shard boundaries.
 
-On CPU the kernel runs in interpret mode (used by the parity test); the
+On CPU the kernel runs in interpret mode (used by the parity tests); the
 production CPU path stays on the XLA formulation.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# nominal bytes of live blocks per grid step; Mosaic double-buffers the
-# pipelined inputs/outputs, so this must stay well under the part's VMEM.
-# The figure is a HEURISTIC screen only — `fused_supported` /
-# `multi_step_pallas` verify each (shape, T) choice with a one-time
-# Mosaic compile probe and degrade to smaller T / the XLA roll path, so
-# the budget's job is merely to skip probing hopeless candidates. The
-# original 24 MB default silently pinned the 512^3 flagship to T=1
-# (full 2 GB/step HBM traffic, ~20 GB of the measured 29 GB frame);
-# 96 MB admits T=2/tz=4 (40 MB nominal) and lets the probe — not the
-# heuristic — decide what this part's 128 MB VMEM really accepts.
-_VMEM_BUDGET = int(os.environ.get("SITPU_STENCIL_VMEM_MB", "96")) \
-    * 1024 * 1024
-
-# (shape, t_steps) -> did Mosaic accept the fused kernel?
-_PROBE_CACHE: dict = {}
+# h halo rows on each side: one f32 sublane tile (see module docstring)
+_HALO_H = 8
+# steps fused per pass; the h halo bounds it (validity shrinks 1 row/step)
+_FUSE_T = 4
+# Scoped VMEM requested for the kernel, of the 128 MiB a v5e core has
+# (Mosaic's default scoped limit is 16 MiB). `_vmem_bytes` screens tiles
+# against it.
+_VMEM_LIMIT = 100 * 1024 * 1024
 
 
-def _compile_ok(shape, t_steps: int, tz: int = 0,
-                with_ranges: bool = False) -> bool:
-    """One-time probe: does the fused kernel at this (shape, T, tz)
-    actually compile on the current TPU? A VMEM budget miss surfaces as a
-    Mosaic resource-exhausted error at compile time — catch it HERE,
-    where a fallback exists, not inside a traced frame step where it
-    cannot be caught. Cached per process (and cheap on repeats via the
-    persistent JAX compile cache). ``with_ranges`` probes the
-    occupancy-ranges epilogue variant — a distinct kernel Mosaic may
-    judge differently."""
-    key = (tuple(shape), int(t_steps), int(tz), bool(with_ranges))
-    ok = _PROBE_CACHE.get(key)
-    if ok is None:
-        try:
-            s = jax.ShapeDtypeStruct(tuple(shape), jnp.float32)
-            p = jax.ShapeDtypeStruct((5,), jnp.float32)
-            step_pallas.lower(s, s, p, t_steps=t_steps, tz=tz,
-                              with_ranges=with_ranges).compile()
-            ok = True
-        except Exception:
-            ok = False
-        _PROBE_CACHE[key] = ok
-    return ok
+def _vmem_bytes(tz: int, th: int, t: int, w: int) -> int:
+    """VMEM the fused kernel holds at tile (tz, th): the ping-pong scratch
+    copies of padded u and v, the double-buffered input views (center,
+    z halos, h halos, corners) and output tiles, plus a margin of 24
+    planes for the per-plane update's temporaries. Without the margin
+    this is Mosaic's own accounting (libtpu 0.0.34 compiling for v5e, at
+    512^3): T=4 (32, 64) is 66.0 MiB here and 66.02 MiB allocated there;
+    T=1 (32, 128) is 89.4 MiB here and 89.38 MiB there."""
+    hh = _HALO_H
+    thp = th + 2 * hh
+    scratch = (4 if t > 1 else 2) * (tz + 2 * t) * thp
+    blocks = 2 * 2 * (tz * th + 2 * t * th + 2 * tz * hh + 4 * t * hh)
+    outs = 2 * 2 * tz * th
+    temps = 24 * thp
+    return (scratch + blocks + outs + temps) * w * 4
 
 
-def fused_supported(shape, t_steps: int = 1) -> bool:
-    """Can a fused kernel (1D z-slab or 2D z×h tile) run this grid on the
-    current backend? True iff some tile fits the nominal budget AND (on
-    TPU) Mosaic accepts one of the capped-walk candidates. The gate
-    `sim.grayscott.multi_step_fast` consults this before choosing the
-    Pallas path; `_best_schedule` then picks the cheapest compiling
-    schedule."""
-    on_tpu = jax.default_backend() == "tpu"
-    if not (tz_candidates(shape, t_steps)
-            or tile2d_candidates(shape, t_steps)):
-        return False
-    if not on_tpu:
-        return True          # interpret mode has no VMEM to exhaust
-    return _best_schedule(shape, t_steps, True) is not None
+def _read_amp(tz: int, th: int, t: int) -> float:
+    """Volume reads per T-step pass, in units of the volume."""
+    return (tz + 2 * t) * (th + 2 * _HALO_H) / (tz * th)
 
 
-def _roll(x: jnp.ndarray, shift: int, axis: int) -> jnp.ndarray:
-    """Periodic shift via the Mosaic rotate primitive (a slice+concat
-    formulation forces unaligned sublane/lane relayouts and is ~20x
-    slower)."""
-    return pltpu.roll(x, shift % x.shape[axis], axis)
-
-
-def _kernel(t_steps, with_ranges, p_ref, u_ref, v_ref, uzm_ref, uzp_ref,
-            vzm_ref, vzp_ref, uo_ref, vo_ref, *rng_refs):
-    f, k, du, dv, dt = (p_ref[i] for i in range(5))
-    t = t_steps
-    u = jnp.concatenate([uzm_ref[...], u_ref[...], uzp_ref[...]], axis=0)
-    v = jnp.concatenate([vzm_ref[...], v_ref[...], vzp_ref[...]], axis=0)
-
-    def lap(x):
-        # z neighbors by shift with edge replication: the outermost slice
-        # of the halo goes stale anyway (validity shrinks 1 slice per
-        # step from each end; after T steps the central Tz are exact)
-        zm = jnp.concatenate([x[:1], x[:-1]], axis=0)
-        zp = jnp.concatenate([x[1:], x[-1:]], axis=0)
-        return (zm + zp
-                + _roll(x, 1, 1) + _roll(x, -1, 1)
-                + _roll(x, 1, 2) + _roll(x, -1, 2) - 6.0 * x)
-
-    for _ in range(t):
-        uvv = u * v * v
-        u, v = (u + dt * (du * lap(u) - uvv + f * (1.0 - u)),
-                v + dt * (dv * lap(v) + uvv - (f + k) * v))
-
-    uo_ref[...] = u[t:u.shape[0] - t]
-    vout = v[t:v.shape[0] - t]
-    vo_ref[...] = vout
-    if with_ranges:
-        # occupancy epilogue: per-block min/max of the RENDERED field (v)
-        # ride out of the pass as (1, 1) SMEM reductions — the slab is
-        # already in VMEM, so the ranges cost no extra HBM traffic
-        vlo_ref, vhi_ref = rng_refs
-        vlo_ref[0, 0] = jnp.min(vout)
-        vhi_ref[0, 0] = jnp.max(vout)
+def _tiles(shape, t: int):
+    """Every (traffic, tz, th) tile that satisfies the lattice
+    (T | tz | D, 8 | th | H) and fits `_VMEM_LIMIT`, cheapest first by
+    modeled HBM traffic per step."""
+    d, h, w = shape
+    out = []
+    for tz in (64, 32, 16, 8, 4, 2, 1):
+        if d % tz or tz % t:
+            continue
+        for th in sorted({h, 256, 128, 64, 32, 16, 8}, reverse=True):
+            if th > h or h % th or th % _HALO_H:
+                continue
+            if _vmem_bytes(tz, th, t, w) > _VMEM_LIMIT:
+                continue
+            out.append(((_read_amp(tz, th, t) + 1.0) / t, tz, th))
+    out.sort()
+    return out
 
 
 def tz_candidates(shape, t_steps: int = 1) -> tuple:
-    """z-slab sizes for a T-step fused call fitting the VMEM budget and
-    the divisibility constraints, largest first: tz | D so the grid tiles
-    exactly, and T | tz so the T-slice halos are expressible as whole
-    (T, H, W) blocks. The budget is a screen; the Mosaic compile probe
-    (`_compile_ok`) is the authority, so `multi_step_pallas` walks this
-    list until one compiles instead of betting everything on the
-    nominal-largest choice."""
-    d, h, w = shape
-    plane = h * w * 4
-    out = []
-    for tz in (32, 16, 8, 4, 2, 1):
-        if d % tz or tz % t_steps:
-            continue
-        # live VMEM: ~4 arrays (u, v and temporaries) of the haloed slab
-        # plus the two output slabs
-        if (4 * (tz + 2 * t_steps) + 2 * tz) * plane <= _VMEM_BUDGET:
-            out.append(tz)
-    return tuple(out)
+    """z-slab sizes (th == H) that fit, cheapest first."""
+    h = shape[1]
+    return tuple(tz for _, tz, th in _tiles(shape, t_steps) if th == h)
 
 
 def pick_tz(shape, t_steps: int = 1) -> int:
-    """Largest nominally-fitting z-slab size (0 = none fits)."""
+    """Best fitting z-slab size (0 = none fits)."""
     cands = tz_candidates(shape, t_steps)
     return cands[0] if cands else 0
 
 
-def _probe_pick(shape, t: int, cands, probe, interpret: bool):
-    """Auto-pick walk shared by step_pallas/step_pallas2d: on TPU each
-    budget-screened candidate must pass its Mosaic compile probe before
-    being chosen (the screen is a heuristic; Mosaic is the authority —
-    an unprobed auto-pick could hand a direct caller a compile-time
-    resource error the production path would have degraded around)."""
-    if not cands:
+def tile2d_candidates(shape, t_steps: int = 1) -> tuple:
+    """(tz, th) tiles with th < H that fit, cheapest first."""
+    h = shape[1]
+    return tuple((tz, th) for _, tz, th in _tiles(shape, t_steps)
+                 if th < h)
+
+
+def _best_schedule(shape, t: int):
+    """The cheapest fitting tile for a T-step pass by modeled HBM traffic:
+    ("2d", tz, th), ("1d", tz, H) when the whole-H slab wins, or None when
+    no tile satisfies the lattice and the VMEM limit. Deterministic in
+    the shape — what Mosaic then says about it reaches the caller."""
+    tiles = _tiles(shape, t)
+    if not tiles:
+        return None
+    _, tz, th = tiles[0]
+    return ("1d" if th == shape[1] else "2d"), tz, th
+
+
+def fused_supported(shape, t_steps: int = 1) -> bool:
+    """Does some tile of the fused kernel fit this grid? W must fill whole
+    128-lane tiles for the periodic lane rotate."""
+    return shape[2] % 128 == 0 and bool(_tiles(shape, t_steps))
+
+
+def _kernel(t, tz, th, with_ranges, p_ref,
+            uc, un, us, uw, ue, unw, une, usw, use_,
+            vc, vn, vs, vw, ve, vnw, vne, vsw, vse,
+            uo_ref, vo_ref, *rest):
+    if with_ranges:
+        vlo_ref, vhi_ref, *rest = rest
+    # (u, v) scratch pairs: one for T == 1, two to ping-pong between
+    pairs = [rest[i:i + 2] for i in range(0, len(rest), 2)]
+    f, k, du, dv, dt = (p_ref[i] for i in range(5))
+    hh = _HALO_H
+    thp = th + 2 * hh
+    tzp = tz + 2 * t
+    w = uc.shape[-1]
+
+    def assemble(dst, c, n, s, w_, e, nw, ne, sw, se):
+        def rows(z, west, mid, east):
+            dst[z, 0:hh] = west
+            dst[z, hh:hh + th] = mid
+            dst[z, hh + th:thp] = east
+
+        def center(i, _):
+            rows(t + i, w_[i], c[i], e[i])
+            return 0
+
+        jax.lax.fori_loop(0, tz, center, 0)
+        for i in range(t):
+            rows(i, nw[i], n[i], ne[i])
+            rows(t + tz + i, sw[i], s[i], se[i])
+
+    assemble(pairs[0][0], uc, un, us, uw, ue, unw, une, usw, use_)
+    assemble(pairs[0][1], vc, vn, vs, vw, ve, vnw, vne, vsw, vse)
+
+    def lap(src, i, x):
+        # h and w neighbors by rotate: w is truly periodic; the h wrap
+        # only pollutes the outermost halo rows, which the shrinking
+        # validity discards anyway
+        return (src[i - 1] + src[i + 1]
+                + pltpu.roll(x, 1, 0) + pltpu.roll(x, thp - 1, 0)
+                + pltpu.roll(x, 1, 1) + pltpu.roll(x, w - 1, 1) - 6.0 * x)
+
+    def update(su, sv, i):
+        u = su[i]
+        v = sv[i]
+        uvv = u * v * v
+        return (u + dt * (du * lap(su, i, u) - uvv + f * (1.0 - u)),
+                v + dt * (dv * lap(sv, i, v) + uvv - (f + k) * v))
+
+    for s in range(t - 1):
+        su, sv = pairs[s % 2]
+        nu, nv = pairs[(s + 1) % 2]
+
+        def plane(i, _, su=su, sv=sv, nu=nu, nv=nv):
+            nu[i], nv[i] = update(su, sv, i)
+            return 0
+
+        jax.lax.fori_loop(s + 1, tzp - 1 - s, plane, 0)
+
+    su, sv = pairs[(t - 1) % 2]
+
+    def last(i, carry):
+        un_, vn_ = update(su, sv, i)
+        vout = vn_[hh:hh + th]
+        uo_ref[i - t] = un_[hh:hh + th]
+        vo_ref[i - t] = vout
+        if not with_ranges:
+            return carry
+        return (jnp.minimum(carry[0], jnp.min(vout)),
+                jnp.maximum(carry[1], jnp.max(vout)))
+
+    init = ((jnp.float32(jnp.inf), jnp.float32(-jnp.inf)) if with_ranges
+            else 0)
+    rng = jax.lax.fori_loop(t, t + tz, last, init)
+    if with_ranges:
+        # occupancy epilogue: per-tile min/max of the RENDERED field (v)
+        # ride out of the pass — the tile is already in VMEM, so the
+        # ranges cost no extra HBM traffic
+        i, j = pl.program_id(0), pl.program_id(1)
+        vlo_ref[i, j] = rng[0]
+        vhi_ref[i, j] = rng[1]
+
+
+def _fused_call(u, v, params_vec, t: int, tz: int, th: int,
+                interpret: bool, with_ranges: bool):
+    d, h, w = u.shape
+    hh = _HALO_H
+    if t > hh:
+        raise ValueError(f"t_steps={t} exceeds the {hh}-row h halo")
+    # a tile off the lattice makes grid=(d//tz, h//th) floor-divide and
+    # silently leaves part of the output unwritten
+    if d % tz or tz % t or h % th or th % hh:
         raise ValueError(
-            f"grid {shape} does not fit the VMEM budget at T={t}")
-    if jax.default_backend() == "tpu" and not interpret:
-        for c in cands:
-            if probe(c):
-                return c
-        raise ValueError(
-            f"Mosaic rejected every fused-stencil candidate for grid "
-            f"{shape} at T={t} — use multi_step_pallas (degrades to "
-            f"smaller T / the XLA roll path)")
-    return cands[0]
+            f"tile (tz={tz}, th={th}) violates T | tz | D and {hh} | th | H "
+            f"for grid {u.shape} at T={t}")
+    nzb, nhb = d // tz, h // th
+    nz_t, nh_8 = d // t, h // hh      # array length in halo-block units
+    rz, rh = tz // t, th // hh
+
+    zm = lambda i: (i * rz - 1) % nz_t
+    zp = lambda i: (i + 1) * rz % nz_t
+    hm = lambda j: (j * rh - 1) % nh_8
+    hp = lambda j: (j + 1) * rh % nh_8
+    c_ = pl.BlockSpec((tz, th, w), lambda i, j: (i, j, 0))
+    # halo views in halo-block units (periodic wrap by modular index)
+    n_ = pl.BlockSpec((t, th, w), lambda i, j: (zm(i), j, 0))
+    s_ = pl.BlockSpec((t, th, w), lambda i, j: (zp(i), j, 0))
+    w_ = pl.BlockSpec((tz, hh, w), lambda i, j: (i, hm(j), 0))
+    e_ = pl.BlockSpec((tz, hh, w), lambda i, j: (i, hp(j), 0))
+    nw = pl.BlockSpec((t, hh, w), lambda i, j: (zm(i), hm(j), 0))
+    ne = pl.BlockSpec((t, hh, w), lambda i, j: (zm(i), hp(j), 0))
+    sw = pl.BlockSpec((t, hh, w), lambda i, j: (zp(i), hm(j), 0))
+    se = pl.BlockSpec((t, hh, w), lambda i, j: (zp(i), hp(j), 0))
+    specs = [c_, n_, s_, w_, e_, nw, ne, sw, se]
+
+    out_specs = [c_, c_]
+    out_shape = [jax.ShapeDtypeStruct((d, h, w), jnp.float32)] * 2
+    if with_ranges:
+        # whole [nzb, nhb] arrays resident in SMEM across the (sequential)
+        # grid — Mosaic refuses (1, 1) blocks of an SMEM array
+        rng = pl.BlockSpec(memory_space=pltpu.SMEM)
+        out_specs += [rng, rng]
+        out_shape += [jax.ShapeDtypeStruct((nzb, nhb), jnp.float32)] * 2
+    pad = pltpu.VMEM((tz + 2 * t, th + 2 * hh, w), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_kernel, t, tz, th, with_ranges),
+        grid=(nzb, nhb),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + specs + specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pad] * (4 if t > 1 else 2),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name=f"gray_scott_fused_t{t}",
+    )(params_vec, *([u] * 9), *([v] * 9))
 
 
 @functools.partial(jax.jit, static_argnames=("t_steps", "interpret", "tz",
@@ -183,74 +274,76 @@ def _probe_pick(shape, t: int, cands, probe, interpret: bool):
 def step_pallas(u: jnp.ndarray, v: jnp.ndarray, params_vec: jnp.ndarray,
                 t_steps: int = 1, interpret: bool = False, tz: int = 0,
                 with_ranges: bool = False):
-    """Advance ``t_steps`` Gray-Scott steps in one fused kernel pass.
-    ``params_vec = [f, k, du, dv, dt]`` (f32[5]). Requires
-    ``pick_tz(u.shape, t_steps) > 0``.
+    """Advance ``t_steps`` Gray-Scott steps in one fused pass over whole-H
+    z slabs. ``params_vec = [f, k, du, dv, dt]`` (f32[5]).
 
-    Auto-pick contract (ADVICE r5 #4): ``tz=0`` walks the
-    budget-screened `tz_candidates` and, on TPU, takes the first one the
-    MOSAIC COMPILE PROBE accepts — the 96 MB ``_VMEM_BUDGET`` screen is a
-    heuristic and must never be the last word, or a direct call would
-    compile-crash inside a traced step where nothing can catch it. If no
-    candidate compiles this raises ``ValueError`` at trace time (use
-    `multi_step_pallas`, which degrades to smaller T / the XLA roll
-    path, for a never-raises schedule). An EXPLICIT ``tz`` is taken on
-    trust after the ``t_steps | tz | D`` shape check: it is NOT probed,
-    so Mosaic resource errors surface to the caller at compile time —
-    pass probe-validated values (`_best_schedule`) when that matters.
+    ``tz=0`` takes `pick_tz`; an explicit ``tz`` must satisfy
+    ``t_steps | tz | D``. Either way the tile goes to Mosaic as is: a
+    compiler refusal reaches the caller at compile time.
 
     ``with_ranges=True`` appends the occupancy epilogue (ops/occupancy):
     the return becomes ``(u', v', vlo, vhi)`` with per-z-slab min/max of
     the updated v field shaped ``[d // tz, 1]`` — DATA-layout brick
     ranges at the kernel's own granularity, normalized downstream by
     `occupancy.remap_ranges`."""
-    d, h, w = u.shape
     t = t_steps
-    if tz:
-        # explicit tz: enforce the tz_candidates constraints instead of
-        # silently leaving output tiles unwritten (grid floor-division)
-        if d % tz or tz % t:
+    tz = tz or pick_tz(u.shape, t)
+    if not tz:
+        raise ValueError(
+            f"no z slab of grid {u.shape} fits the VMEM limit at T={t}")
+    return _fused_call(u, v, params_vec, t, tz, u.shape[1], interpret,
+                       with_ranges)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("t_steps", "interpret", "tz", "th",
+                                    "with_ranges"))
+def step_pallas2d(u, v, params_vec, t_steps: int = 1,
+                  interpret: bool = False, tz: int = 0, th: int = 0,
+                  with_ranges: bool = False):
+    """Advance ``t_steps`` steps in one 2D-blocked (z × h) fused pass.
+
+    ``(0, 0)`` takes the cheapest `tile2d_candidates` tile; an explicit
+    ``(tz, th)`` must satisfy ``T | tz | D`` and ``8 | th | H``. The tile
+    goes to Mosaic as is (see `step_pallas`).
+
+    ``with_ranges=True`` appends the occupancy epilogue: the return
+    becomes ``(u', v', vlo, vhi)`` with per-(z, y)-tile min/max of the
+    updated v shaped ``[d // tz, h // th]`` (see `step_pallas`)."""
+    t = t_steps
+    if bool(tz) != bool(th):
+        raise ValueError("pass both tz and th (or neither)")
+    if not tz:
+        cands = tile2d_candidates(u.shape, t)
+        if not cands:
             raise ValueError(
-                f"explicit tz={tz} violates T | tz | D for grid {u.shape} "
-                f"at T={t} (need d % tz == 0 and tz % t_steps == 0)")
-    else:
-        tz = _probe_pick(u.shape, t, tz_candidates(u.shape, t),
-                         lambda tz_: _compile_ok(u.shape, t, tz_,
-                                                 with_ranges),
-                         interpret)
-    nb = d // tz
-    nb_t = d // t                 # array length in halo-block units
+                f"no (tz, th) tile of grid {u.shape} fits the VMEM limit "
+                f"at T={t}")
+        tz, th = cands[0]
+    return _fused_call(u, v, params_vec, t, tz, th, interpret, with_ranges)
 
-    slab = pl.BlockSpec((tz, h, w), lambda i: (i, 0, 0))
-    # T-slice halo views of the same arrays; index_map is in units of the
-    # (T, H, W) block shape, so periodic wrap is exact (T | tz makes the
-    # offsets whole blocks)
-    r = tz // t
-    zm = pl.BlockSpec((t, h, w), lambda i: ((i * r - 1) % nb_t, 0, 0))
-    zp = pl.BlockSpec((t, h, w), lambda i: ((i + 1) * r % nb_t, 0, 0))
 
-    out_specs = [slab, slab]
-    out_shape = [jax.ShapeDtypeStruct((d, h, w), jnp.float32)] * 2
-    if with_ranges:
-        rng = pl.BlockSpec((1, 1), lambda i: (i, 0),
-                           memory_space=pltpu.SMEM)
-        out_specs += [rng, rng]
-        out_shape += [jax.ShapeDtypeStruct((nb, 1), jnp.float32)] * 2
-
-    return pl.pallas_call(
-        functools.partial(_kernel, t, with_ranges),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  slab, slab, zm, zp, zm, zp],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(params_vec, u, v, u, u, v, v)
+def schedule(shape, n: int) -> tuple:
+    """The passes ``n`` steps decompose into on ``shape``, greedily by
+    fusion factor (n=10 runs two T=4 passes and one T=2 pass instead of
+    degrading the whole loop to a smaller T):
+    ``((kind, T, tz, th, reps), ...)``, plus the steps no fused tile
+    covers (0 whenever `fused_supported`)."""
+    out = []
+    remaining = n
+    for t in range(min(_FUSE_T, n), 0, -1):
+        reps = remaining // t
+        sched = _best_schedule(shape, t) if reps else None
+        if sched is None:
+            continue
+        out.append((sched[0], t, sched[1], sched[2], reps))
+        remaining -= reps * t
+    return tuple(out), remaining
 
 
 def _multi_step_impl(u, v, params_vec, n: int, interpret: bool,
                      ranges_to):
-    """Greedy multi-T schedule walk shared by `multi_step_pallas` and
+    """The `schedule` walk shared by `multi_step_pallas` and
     `multi_step_pallas_ranges`. ``ranges_to = (nzb, nyb)`` threads the
     occupancy epilogue through every pass: each kernel's native-
     granularity v ranges are normalized onto the fixed (nzb, nyb) brick
@@ -273,46 +366,25 @@ def _multi_step_impl(u, v, params_vec, n: int, interpret: bool,
              jnp.full((nzb, nyb), -jnp.inf, jnp.float32))
     else:
         s = (u, v)
-    remaining = n
-    on_tpu = jax.default_backend() == "tpu" and not interpret
-    for t in range(min(_FUSE_T, n), 0, -1):
-        reps = remaining // t
-        if reps == 0:
-            continue
-        sched = _best_schedule(u.shape, t, on_tpu, with_ranges)
-        if sched is None:
-            continue         # Mosaic rejected this T: degrade, don't die
-        kind, tz, th = sched
-
-        def one(s, t=t, kind=kind, tz=tz, th=th):
-            if kind == "2d":
-                out = step_pallas2d(s[0], s[1], params_vec, t,
-                                    interpret=interpret, tz=tz, th=th,
-                                    with_ranges=with_ranges)
-            else:
-                out = step_pallas(s[0], s[1], params_vec, t,
-                                  interpret=interpret, tz=tz,
-                                  with_ranges=with_ranges)
+    passes, remaining = schedule(u.shape, n)
+    if remaining:   # fused_supported(shape) is False: caller should gate
+        raise ValueError(f"no fused-stencil tile fits grid {u.shape}")
+    for _, t, tz, th, reps in passes:
+        def one(s, t=t, tz=tz, th=th):
+            out = _fused_call(s[0], s[1], params_vec, t, tz, th, interpret,
+                              with_ranges)
             if not with_ranges:
-                return out
+                return tuple(out)
             un, vn, lo, hi = out
             return (un, vn) + remap_ranges(lo, hi, ranges_to)
 
         s = jax.lax.fori_loop(0, reps, lambda _, s: one(s), s)
-        remaining -= reps * t
-        if remaining == 0:
-            break
-    if remaining:   # pick_tz(shape, 1) == 0: caller should have gated
-        raise ValueError(f"grid {u.shape} does not fit the VMEM budget")
     return s
 
 
 @functools.partial(jax.jit, static_argnames=("n", "interpret"))
 def multi_step_pallas(u, v, params_vec, n: int, interpret: bool = False):
-    """n Gray-Scott steps, fused ``_FUSE_T`` at a time; the remainder runs
-    at progressively smaller fusion factors (greedy decomposition, so e.g.
-    n=5 runs one T=4 pass + one T=1 pass instead of silently degrading the
-    whole loop to T=1)."""
+    """n Gray-Scott steps in the passes `schedule` gives."""
     return _multi_step_impl(u, v, params_vec, n, interpret, None)
 
 
@@ -325,276 +397,20 @@ def multi_step_pallas_ranges(u, v, params_vec, n: int, nzb: int, nyb: int,
     FINAL v field on the (nzb, nyb) data-layout brick grid
     (ops/occupancy.FieldRanges arrays) — the per-frame empty-space
     structure rides out of the sim pass instead of costing a volume
-    sweep. Gate availability with `ranges_supported` (the epilogue
-    variant is a distinct kernel Mosaic may reject independently)."""
+    sweep."""
     return _multi_step_impl(u, v, params_vec, n, interpret, (nzb, nyb))
 
 
-def ranges_supported(shape, t_steps: int = 1) -> bool:
-    """Can the occupancy-ranges epilogue ride the fused stencil on this
-    grid/backend? Checks the T=1 schedule (the greedy decomposition's
-    catch-all, so `multi_step_pallas_ranges` cannot hit an uncovered
-    remainder when it holds)."""
-    on_tpu = jax.default_backend() == "tpu"
-    if not (tz_candidates(shape, t_steps)
-            or tile2d_candidates(shape, t_steps)):
-        return False
-    if not on_tpu:
-        return True          # interpret mode compiles anything
-    return _best_schedule(shape, 1, True, with_ranges=True) is not None
-
-
-_FUSE_T = 4
-
-
-# ------------------------------------------------- 2D-blocked (z x h) fusion
-#
-# At 512^3 a full (H, W) plane is 1 MB, so the z-only slab above cannot
-# afford a useful T at any tz — the kernel's ~6 live haloed-slab copies
-# exhaust VMEM (the round-5 flagship ran the sim at T=1: a full 2 GB of
-# HBM traffic per step, ~20 GB of the measured 29 GB frame). Blocking z
-# AND h shrinks the live set quadratically while the halo overhead stays
-# linear in T, so T=4 fits 512^3 comfortably: per T steps the volume is
-# read ((tz+2T)(th+2T))/(tz·th) ≈ 1.6x and written once — ~3x less HBM
-# traffic per step than the best 1D schedule at this scale.
-#
-# Geometry: the T-step dependency cone of the 6-point Laplacian is an L1
-# ball, covered by a square halo of width T in (z, h). Each field reads
-# 9 views of the same HBM array (center + 4 edges + 4 corners, periodic
-# wrap via index_map arithmetic in block units — requiring T | tz | D
-# and T | th | H); in-kernel, rows of blocks are concatenated into one
-# (tz+2T, th+2T, W) padded array. z and h neighbors use edge-replicated
-# shifts (the replicated rim is exactly the region whose validity the
-# per-step shrink discards); w neighbors keep the Mosaic rotate because
-# w is the full, truly-periodic lane axis.
-
-
-def _kernel2d(t_steps, with_ranges, p_ref,
-              uc, un, us, uw, ue, unw, une, usw, use_,
-              vc, vn, vs, vw, ve, vnw, vne, vsw, vse,
-              uo_ref, vo_ref, *rng_refs):
-    f, k, du, dv, dt = (p_ref[i] for i in range(5))
-    t = t_steps
-
-    def pad(n, w_, c, e, nw, ne, s, sw, se):
-        top = jnp.concatenate([nw[...], n[...], ne[...]], axis=1)
-        mid = jnp.concatenate([w_[...], c[...], e[...]], axis=1)
-        bot = jnp.concatenate([sw[...], s[...], se[...]], axis=1)
-        return jnp.concatenate([top, mid, bot], axis=0)
-
-    u = pad(un, uw, uc, ue, unw, une, us, usw, use_)
-    v = pad(vn, vw, vc, ve, vnw, vne, vs, vsw, vse)
-
-    def lap(x):
-        zm = jnp.concatenate([x[:1], x[:-1]], axis=0)
-        zp = jnp.concatenate([x[1:], x[-1:]], axis=0)
-        hm = jnp.concatenate([x[:, :1], x[:, :-1]], axis=1)
-        hp = jnp.concatenate([x[:, 1:], x[:, -1:]], axis=1)
-        return (zm + zp + hm + hp
-                + _roll(x, 1, 2) + _roll(x, -1, 2) - 6.0 * x)
-
-    for _ in range(t):
-        uvv = u * v * v
-        u, v = (u + dt * (du * lap(u) - uvv + f * (1.0 - u)),
-                v + dt * (dv * lap(v) + uvv - (f + k) * v))
-
-    uo_ref[...] = u[t:u.shape[0] - t, t:u.shape[1] - t]
-    vout = v[t:v.shape[0] - t, t:v.shape[1] - t]
-    vo_ref[...] = vout
-    if with_ranges:
-        # occupancy epilogue (see _kernel): per-(tz, th)-block min/max
-        # of the updated field, free of extra HBM traffic
-        vlo_ref, vhi_ref = rng_refs
-        vlo_ref[0, 0] = jnp.min(vout)
-        vhi_ref[0, 0] = jnp.max(vout)
-
-
-def tile2d_candidates(shape, t_steps: int = 1) -> tuple:
-    """(tz, th) tiles for the 2D-blocked T-step kernel fitting the VMEM
-    screen, best-first by modeled HBM traffic per step. Constraints:
-    T | tz | D, T | th | H (halo/corner views are whole blocks of the
-    halo shapes), and w stays whole (the periodic lane axis)."""
-    d, h, w = shape
-    t = t_steps
-    cands = []
-    for tz in (32, 16, 8, 4):
-        if d % tz or tz % t:
-            continue
-        for th in (256, 128, 64, 32):
-            if h % th or th % t:
-                continue
-            # ~6 live copies of the padded block (u, v, laplacian
-            # temporaries) + the two output blocks
-            live = (6 * (tz + 2 * t) * (th + 2 * t) + 2 * tz * th) * w * 4
-            if live > _VMEM_BUDGET:
-                continue
-            # HBM traffic per step per field, in units of volume bytes:
-            # (read amplification + 1 write) / T
-            traffic = ((tz + 2 * t) * (th + 2 * t) / (tz * th) + 1.0) / t
-            cands.append((traffic, tz, th))
-    cands.sort()
-    return tuple((tz, th) for _, tz, th in cands)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("t_steps", "interpret", "tz", "th",
-                                    "with_ranges"))
-def step_pallas2d(u, v, params_vec, t_steps: int = 1,
-                  interpret: bool = False, tz: int = 0, th: int = 0,
-                  with_ranges: bool = False):
-    """Advance ``t_steps`` steps in one 2D-blocked fused pass.
-
-    Same auto-pick contract as `step_pallas` (ADVICE r5 #4): ``(0, 0)``
-    walks `tile2d_candidates` best-first and, on TPU, returns the first
-    tile the Mosaic compile probe accepts — the VMEM budget is only a
-    screen — raising ``ValueError`` at trace time when none compiles
-    (`multi_step_pallas` is the degrading wrapper). An explicit
-    ``(tz, th)`` must satisfy ``T | tz | D`` and ``T | th | H`` (the
-    `tile2d_candidates` lattice) and is then taken on trust — unprobed,
-    so Mosaic errors surface at compile time; route through
-    `_best_schedule` for probe-validated tiles.
-
-    ``with_ranges=True`` appends the occupancy epilogue: the return
-    becomes ``(u', v', vlo, vhi)`` with per-(z, y)-block min/max of the
-    updated v shaped ``[d // tz, h // th]`` (see `step_pallas`)."""
-    d, h, w = u.shape
-    t = t_steps
-    if tz or th:
-        # explicit tile: a value off the T | tz | D / T | th | H lattice
-        # makes grid=(d//tz, h//th) floor-divide and silently leaves part
-        # of the output unwritten — reject it loudly instead
-        if not (tz and th):
-            raise ValueError("pass both tz and th (or neither)")
-        if d % tz or h % th or tz % t or th % t:
-            raise ValueError(
-                f"explicit (tz={tz}, th={th}) violates T | tz | D and "
-                f"T | th | H for grid {u.shape} at T={t} (need d % tz == "
-                f"0, h % th == 0, tz % t_steps == 0, th % t_steps == 0)")
-    else:
-        tz, th = _probe_pick(
-            u.shape, t, tile2d_candidates(u.shape, t),
-            lambda c: _compile2d_ok(u.shape, t, c[0], c[1], with_ranges),
-            interpret)
-    nzb, nhb = d // tz, h // th
-    nz_t, nh_t = d // t, h // t    # array length in halo-block units
-    rz, rh = tz // t, th // t
-
-    c_ = pl.BlockSpec((tz, th, w), lambda i, j: (i, j, 0))
-    # edge views in halo-block units (periodic wrap by modular index)
-    n_ = pl.BlockSpec((t, th, w), lambda i, j: ((i * rz - 1) % nz_t, j, 0))
-    s_ = pl.BlockSpec((t, th, w), lambda i, j: ((i + 1) * rz % nz_t, j, 0))
-    w_ = pl.BlockSpec((tz, t, w), lambda i, j: (i, (j * rh - 1) % nh_t, 0))
-    e_ = pl.BlockSpec((tz, t, w), lambda i, j: (i, (j + 1) * rh % nh_t, 0))
-    nw = pl.BlockSpec((t, t, w),
-                      lambda i, j: ((i * rz - 1) % nz_t,
-                                    (j * rh - 1) % nh_t, 0))
-    ne = pl.BlockSpec((t, t, w),
-                      lambda i, j: ((i * rz - 1) % nz_t,
-                                    (j + 1) * rh % nh_t, 0))
-    sw = pl.BlockSpec((t, t, w),
-                      lambda i, j: ((i + 1) * rz % nz_t,
-                                    (j * rh - 1) % nh_t, 0))
-    se = pl.BlockSpec((t, t, w),
-                      lambda i, j: ((i + 1) * rz % nz_t,
-                                    (j + 1) * rh % nh_t, 0))
-
-    specs = [c_, n_, s_, w_, e_, nw, ne, sw, se]
-    out_specs = [c_, c_]
-    out_shape = [jax.ShapeDtypeStruct((d, h, w), jnp.float32)] * 2
-    if with_ranges:
-        rng = pl.BlockSpec((1, 1), lambda i, j: (i, j),
-                           memory_space=pltpu.SMEM)
-        out_specs += [rng, rng]
-        out_shape += [jax.ShapeDtypeStruct((nzb, nhb), jnp.float32)] * 2
-    return pl.pallas_call(
-        functools.partial(_kernel2d, t, with_ranges),
-        grid=(nzb, nhb),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + specs + specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(params_vec, *([u] * 9), *([v] * 9))
-
-
-def _compile2d_ok(shape, t_steps: int, tz: int, th: int,
-                  with_ranges: bool = False) -> bool:
-    """Mosaic probe for the 2D kernel at (shape, T, tz, th); cached."""
-    key = ("2d", tuple(shape), int(t_steps), int(tz), int(th),
-           bool(with_ranges))
-    ok = _PROBE_CACHE.get(key)
-    if ok is None:
-        try:
-            s = jax.ShapeDtypeStruct(tuple(shape), jnp.float32)
-            p = jax.ShapeDtypeStruct((5,), jnp.float32)
-            step_pallas2d.lower(s, s, p, t_steps=t_steps,
-                                tz=tz, th=th,
-                                with_ranges=with_ranges).compile()
-            ok = True
-        except Exception:
-            ok = False
-        _PROBE_CACHE[key] = ok
-    return ok
-
-
 def modeled_sim_traffic(shape, n: int, fused: bool = True) -> float:
-    """Modeled HBM bytes for ``n`` Gray-Scott steps under the schedules
-    `multi_step_pallas` would pick (budget screen only — probe-free, so
-    usable off-TPU), for the bench harness's traffic-model fallback and
+    """Modeled HBM bytes for ``n`` Gray-Scott steps under the schedule
+    `multi_step_pallas` runs, for the bench harness's traffic model and
     the per-lever A/B accounting. ``fused=False`` (or any remainder no
-    fused schedule covers) charges the roll formulation's floor: one
-    read + one write of u and v per step."""
+    fused tile covers) charges the roll formulation's floor: one read +
+    one write of u and v per step."""
     d, h, w = shape
     vol_bytes = 2 * 4.0 * d * h * w          # u + v, f32
-    total = 0.0
-    remaining = n
-    if fused:
-        for t in range(min(_FUSE_T, n), 0, -1):
-            reps = remaining // t
-            if reps == 0:
-                continue
-            sched = _best_schedule(shape, t, on_tpu=False)
-            if sched is None:
-                continue
-            kind, tz, th = sched
-            amp = ((tz + 2 * t) * (th + 2 * t) / (tz * th) if kind == "2d"
-                   else (tz + 2 * t) / tz)
-            total += reps * (amp + 1.0) * vol_bytes   # per T-step pass
-            remaining -= reps * t
-            if remaining == 0:
-                break
-    total += remaining * 2.0 * vol_bytes
+    passes, remaining = schedule(shape, n) if fused else ((), n)
+    total = remaining * 2.0 * vol_bytes
+    for _, t, tz, th, reps in passes:
+        total += reps * (_read_amp(tz, th, t) + 1.0) * vol_bytes
     return total
-
-
-def _best_schedule(shape, t: int, on_tpu: bool, with_ranges: bool = False):
-    """Pick the cheapest compiling schedule for a T-step pass: 2D tiles
-    and 1D slabs compete on modeled HBM traffic per step; the Mosaic
-    probe (capped walk) has the final word. ``with_ranges`` probes the
-    occupancy-epilogue kernel variant instead. Returns ("2d", tz, th),
-    ("1d", tz, None) or None."""
-    opts = []
-    for tz, th in tile2d_candidates(shape, t)[:2]:
-        traffic = ((tz + 2 * t) * (th + 2 * t) / (tz * th) + 1.0) / t
-        opts.append((traffic, "2d", tz, th))
-    for tz in tz_candidates(shape, t)[:2]:
-        traffic = ((tz + 2 * t) / tz + 1.0) / t
-        opts.append((traffic, "1d", tz, None))
-    opts.sort(key=lambda o: o[0])
-    for _, kind, tz, th in opts[:3]:
-        if not on_tpu:
-            return kind, tz, th
-        ok = (_compile2d_ok(shape, t, tz, th, with_ranges) if kind == "2d"
-              else _compile_ok(shape, t, tz, with_ranges))
-        if ok:
-            return kind, tz, th
-    if opts:
-        from scenery_insitu_tpu import obs
-
-        # the auto-pick found budget-fitting candidates but Mosaic took
-        # none — the caller runs this T-pass on the XLA roll path;
-        # ledger-only (callers decide loudness via fused_supported)
-        obs.degrade("sim.stencil_schedule", f"fused T={t}", "xla_roll",
-                    f"Mosaic rejected all {len(opts[:3])} probed "
-                    f"schedule candidates for grid {tuple(shape)}",
-                    warn=False)
-    return None

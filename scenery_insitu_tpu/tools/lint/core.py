@@ -181,14 +181,13 @@ def dotted_name(expr: ast.AST) -> str:
 # functions that mint a ledger entry on behalf of their caller, with the
 # positional index of the literal component argument (used by both the
 # LEDGER checker and the registry round-trip discovery)
-DEGRADE_WRAPPERS = {"degrade": 0, "mosaic_probe": 3}
+DEGRADE_WRAPPERS = {"degrade": 0}
 
 
 def calls_degrade(node: ast.AST) -> bool:
     """Does ``node`` contain a ledger mint — ``obs.degrade(...)`` /
-    ``degrade(...)`` or a degrade-minting wrapper like
-    ``pallas_util.mosaic_probe`` (the fallback-ledger contract,
-    obs/recorder.py)?"""
+    ``degrade(...)`` or a degrade-minting wrapper (the fallback-ledger
+    contract, obs/recorder.py)?"""
     return any(call_name(c) in DEGRADE_WRAPPERS for c in iter_calls(node))
 
 

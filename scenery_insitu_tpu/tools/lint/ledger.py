@@ -12,16 +12,15 @@ returns an alternate result, swaps a value the ``try`` body also assigns
 the ledger, or absorbs a missing optional dependency (``ImportError``)
 must call ``obs.degrade`` on that path. Handlers that re-``raise`` are
 exempt (nothing degraded — the failure propagates), as are probe
-*predicates* (``have_*`` / ``*_compile_ok`` / ``*_supported`` ... returning
+*predicates* (``have_*`` / ``*_supported`` ... returning
 constants): the probe reports capability, its CALLER owns the fallback
 decision and the ledger entry.
 
 **R2 — unledgered feature-probe consultations.** A function that consults
 a probe predicate and is therefore making a capability-dependent choice
 must mint a ledger entry on some path — unless the probe itself does
-(the ``*_compile_ok`` probes ledger their own rejections) or the caller
-is itself a probe predicate (the obligation stays with the ultimate
-consumer).
+or the caller is itself a probe predicate (the obligation stays with
+the ultimate consumer).
 
 Both rules are heuristics with a principled escape hatch: true positives
 that are genuinely fine (e.g. reporting-only error capture that lands in

@@ -1,17 +1,10 @@
 """SITPU-PALLAS — the Mosaic kernel contracts, checked instead of recited.
 
-PR 1 and PR 6 state the contract in docstrings (``step_pallas``'s
-"auto-pick probes / explicit tz is trusted", the ``*_compile_ok``
-families); this enforces the checkable parts at every ``pl.pallas_call``
-site:
-
-**P1 — compile probe.** Mosaic acceptance is shape-dependent, so a kernel
-entry point must be reachable through a one-time compile probe (the
-``*_compile_ok`` pattern: ``.lower(...).compile()`` under try/except,
-ledgering the rejection) — otherwise a resource rejection fires inside a
-traced frame step where nothing can catch it. Checked as: the top-level
-function containing the ``pallas_call`` is itself a probe, or is
-referenced from a probe function in the same module.
+The checkable parts of the kernel contract at every ``pl.pallas_call``
+site. (Until PR 21 a third rule demanded a ``*_compile_ok`` probe with
+an XLA fallback around every kernel; a refusal of a default-path kernel
+now reaches the user with Mosaic's own message, and ``chip_smoke.py`` is
+where the kernels meet the compiler.)
 
 **P2 — tile-divisibility declared.** A grid of ``shape // tile`` silently
 leaves output tiles unwritten when the division floors; every kernel
@@ -19,21 +12,22 @@ entry must either guard (``if h % TILE_H: raise``, the explicit-tz
 checks) or pad by a computed remainder (``(-h) % TILE_H`` feeding a
 pad) — some ``%``-derived handling must be visible in the entry function.
 
-**P3 — SMEM scalar outputs are (1, 1).** Mosaic requires scalar SMEM
-blocks shaped ``(1, 1)`` (the occupancy ranges epilogue contract,
-sim/pallas_stencil.py): any ``pl.BlockSpec`` carrying
-``memory_space=pltpu.SMEM`` with an explicit block shape must have every
-dimension literally 1.
+**P3 — SMEM operands are whole.** Mosaic's lowering refuses a blocked
+SMEM operand whose last two block dimensions are not multiples of
+(8, 128) or the full extent — the ``(1, 1)`` scalar-output blocks the
+occupancy ranges epilogue once used do not compile (libtpu 0.0.34).
+Any ``pl.BlockSpec`` carrying ``memory_space=pltpu.SMEM`` must leave the
+block shape out: the whole array rides in SMEM and the kernel indexes
+it by ``pl.program_id``.
 """
 
 from __future__ import annotations
 
 import ast
-from typing import List, Set
+from typing import List
 
 from scenery_insitu_tpu.tools.lint.core import (Diagnostic, SourceFile,
                                                 dotted_name, iter_calls)
-from scenery_insitu_tpu.tools.lint.ledger import PROBE_NAME_RE
 
 CODE = "SITPU-PALLAS"
 
@@ -50,33 +44,6 @@ def _top_level_fn_of(tree: ast.Module, node: ast.AST):
                                                   or top.lineno):
             return top
     return None
-
-
-def _compiles_a_lowering(fn) -> bool:
-    """try/except around a ``....compile()`` chain — the probe shape."""
-    has_try = any(isinstance(n, ast.Try) for n in ast.walk(fn))
-    compiles = any(isinstance(c.func, ast.Attribute)
-                   and c.func.attr == "compile" for c in iter_calls(fn))
-    return has_try and compiles
-
-
-def _probe_fns(tree: ast.Module) -> List[ast.FunctionDef]:
-    out = []
-    for n in ast.walk(tree):
-        if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef)) and (
-                PROBE_NAME_RE.search(n.name) or _compiles_a_lowering(n)):
-            out.append(n)
-    return out
-
-
-def _names_referenced(fn) -> Set[str]:
-    out = set()
-    for n in ast.walk(fn):
-        if isinstance(n, ast.Name):
-            out.add(n.id)
-        elif isinstance(n, ast.Attribute):
-            out.add(n.attr)
-    return out
 
 
 def _has_mod_guard(fn) -> bool:
@@ -110,16 +77,12 @@ def _smem_blockspec_diags(src: SourceFile) -> List[Diagnostic]:
         shape = c.args[0] if c.args else kw.get("block_shape")
         if shape is None:
             continue                    # whole-operand SMEM ref (inputs)
-        if isinstance(shape, ast.Tuple):
-            ones = all(isinstance(e, ast.Constant) and e.value == 1
-                       for e in shape.elts)
-            if not ones or len(shape.elts) != 2:
-                diags.append(Diagnostic(
-                    src.path, c.lineno, CODE,
-                    "SMEM scalar block must be shaped (1, 1) — Mosaic "
-                    "rejects (or miscompiles) other scalar-output "
-                    "shapes (see sim/pallas_stencil.py ranges "
-                    "epilogue)"))
+        diags.append(Diagnostic(
+            src.path, c.lineno, CODE,
+            "blocked SMEM operand — Mosaic refuses SMEM blocks that "
+            "are not (8, 128)-tiled; pass the whole array "
+            "(BlockSpec(memory_space=pltpu.SMEM)) and index it by "
+            "program_id (see sim/pallas_stencil.py ranges epilogue)"))
     return diags
 
 
@@ -129,31 +92,18 @@ def check(sources: List[SourceFile]) -> List[Diagnostic]:
         sites = _pallas_call_sites(src.tree)
         if not sites:
             continue
-        probes = _probe_fns(src.tree)
-        probed_names: Set[str] = set()
-        for p in probes:
-            probed_names |= _names_referenced(p)
-        probe_fn_names = {p.name for p in probes}
         seen_fns = set()
         for site in sites:
             fn = _top_level_fn_of(src.tree, site)
             if fn is None:
                 diags.append(Diagnostic(
                     src.path, site.lineno, CODE,
-                    "module-level pallas_call — cannot sit behind a "
-                    "compile probe"))
+                    "module-level pallas_call — no entry function to "
+                    "declare its tile-divisibility handling"))
                 continue
             if fn.name in seen_fns:
                 continue
             seen_fns.add(fn.name)
-            if fn.name not in probe_fn_names \
-                    and fn.name not in probed_names:
-                diags.append(Diagnostic(
-                    src.path, site.lineno, CODE,
-                    f"pallas_call not behind a Mosaic compile probe: no "
-                    f"*_compile_ok probe in {src.path} references "
-                    f"{fn.name}() — a shape-dependent Mosaic rejection "
-                    f"will fire inside a traced step", fn.name))
             if not _has_mod_guard(fn):
                 diags.append(Diagnostic(
                     src.path, site.lineno, CODE,

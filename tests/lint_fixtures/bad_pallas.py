@@ -1,6 +1,6 @@
-# SITPU-PALLAS bad fixture: a kernel entry with no compile probe, no
-# divisibility handling, and a mis-shaped SMEM scalar output. Parsed by
-# the linter only — never imported or executed.
+# SITPU-PALLAS bad fixture: a kernel entry with no divisibility handling
+# and a blocked SMEM scalar output. Parsed by the linter only — never
+# imported or executed.
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -18,8 +18,8 @@ def _kernel(x_ref, o_ref, s_ref):
 def double_chunk(x):
     # no % guard / padding: h not a multiple of TILE_H floors the grid
     h, w = x.shape
-    # SMEM scalar output shaped (TILE_H, 1) instead of (1, 1)
-    smem = pl.BlockSpec((TILE_H, 1), lambda i: (i, 0),
+    # blocked SMEM scalar output: Mosaic refuses (1, 1) SMEM blocks
+    smem = pl.BlockSpec((1, 1), lambda i: (i, 0),
                         memory_space=pltpu.SMEM)
     return pl.pallas_call(
         _kernel, grid=(h // TILE_H,),

@@ -236,18 +236,18 @@ class TestPallas:
     def test_bad_flagged(self):
         diags = P.check(fixture_sources("bad_pallas.py"))
         msgs = [d.message for d in diags]
-        assert any("not behind a Mosaic compile probe" in m for m in msgs)
         assert any("tile-divisibility" in m for m in msgs)
-        assert any("SMEM scalar block" in m for m in msgs)
-        assert len(diags) == 3, [d.render() for d in diags]
+        assert any("blocked SMEM operand" in m for m in msgs)
+        assert len(diags) == 2, [d.render() for d in diags]
 
     def test_good_clean(self):
         diags = P.check(fixture_sources("good_pallas.py"))
         assert diags == [], [d.render() for d in diags]
 
-    def test_real_kernels_probed(self):
-        """Every production pallas_call sits behind a probe (the
-        fold_microbench experiment kernels are baselined, not clean)."""
+    def test_real_kernels_clean(self):
+        """Every production pallas_call declares its tile-divisibility
+        handling and keeps its SMEM operands whole (the fold_microbench
+        experiment kernels are baselined, not clean)."""
         pkg = os.path.join(ROOT, "scenery_insitu_tpu")
         paths = []
         for dirpath, _, files in os.walk(pkg):

@@ -42,7 +42,7 @@ from scenery_insitu_tpu.parallel.mesh import make_mesh, reslab_bricks_lod
 from scenery_insitu_tpu.parallel.pipeline import (distributed_vdi_step,
                                                   distributed_vdi_step_mxu,
                                                   shard_volume)
-from scenery_insitu_tpu.utils.compat import shard_map
+from jax import shard_map
 
 N = 8
 D = 32

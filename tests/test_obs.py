@@ -435,7 +435,10 @@ def test_publisher_collector_roundtrip():
             pass
         assert pub.pump(rec, force=True)
         for _ in range(100):
-            if col.poll(20):
+            # poll() also counts probes still queued from the handshake
+            # loop above — wait for the payload batch itself
+            col.poll(20)
+            if col.batches:
                 break
         assert col.batches == 1
         merged = col.merged_events()

@@ -21,7 +21,7 @@ from scenery_insitu_tpu.ops import occupancy as occ
 from scenery_insitu_tpu.ops import slicer
 from scenery_insitu_tpu.ops import supersegments as ss
 from scenery_insitu_tpu.sim import grayscott as gs
-from scenery_insitu_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def _tf():

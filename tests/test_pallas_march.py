@@ -190,20 +190,21 @@ def test_fold_parity_under_jit(vol, tf):
                                rtol=2e-6, atol=1e-6)
 
 
-def test_auto_fold_resolution_and_probe():
-    """"auto" resolves to the XLA fold off-TPU (interpret-mode pallas is
-    slow; conftest pins the cpu backend); the probe caches per
-    (backend, shape); an explicit fold choice is always honored."""
+def test_auto_fold_resolution(monkeypatch):
+    """"auto" resolves by backend NAME — the XLA fold off-TPU
+    (interpret-mode pallas is slow; conftest pins the cpu backend), the
+    pallas_seg kernel on TPU with no compile probe in between (a Mosaic
+    refusal raises at compile time) — and an explicit fold choice is
+    always honored."""
     assert jax.default_backend() == "cpu"        # conftest invariant
     cam = Camera.create((0.0, 0.4, 2.8))
     spec = slicer.make_spec(cam, (16, 16, 16), SliceMarchConfig())
     assert spec.fold == "xla"
-    pm._FOLD_PROBE.clear()
-    pm.fold_compile_ok(4, 2, 128)
-    assert ("cpu", 4, 2, 128) in pm._FOLD_PROBE  # cached by shape key
-    pm._FOLD_PROBE.clear()
     spec_p = slicer.make_spec(cam, (16, 16, 16), PALLAS)
     assert spec_p.fold == "pallas"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec_t = slicer.make_spec(cam, (16, 16, 16), SliceMarchConfig())
+    assert spec_t.fold == "pallas_seg"
 
 
 def test_skip_chunks_execute_through_pallas_fold(tf):

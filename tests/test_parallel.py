@@ -9,7 +9,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from scenery_insitu_tpu.config import CompositeConfig, RenderConfig, VDIConfig
-from scenery_insitu_tpu.utils.compat import shard_map
+from jax import shard_map
 from scenery_insitu_tpu.core.camera import Camera
 from scenery_insitu_tpu.core.transfer import TransferFunction
 from scenery_insitu_tpu.core.vdi import render_vdi_same_view

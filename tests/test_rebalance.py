@@ -39,7 +39,7 @@ from scenery_insitu_tpu.parallel.mesh import (halo_exchange_z, make_mesh,
 from scenery_insitu_tpu.parallel.pipeline import (distributed_plain_step,
                                                   distributed_vdi_step,
                                                   shard_volume)
-from scenery_insitu_tpu.utils.compat import shard_map
+from jax import shard_map
 
 N = 8
 D = 32

@@ -101,22 +101,31 @@ def test_pallas_multistep_remainder():
     np.testing.assert_allclose(np.asarray(ref.v), np.asarray(v2), atol=1e-5)
 
 
-def test_stencil_compile_probe_gates_fused_path():
-    """fused_supported must reject (without raising) kernels the backend
-    cannot compile: on CPU the Mosaic lowering of step_pallas fails, so
-    _compile_ok catches and caches False — the degrade path a real-TPU
-    VMEM budget miss takes."""
+def test_stencil_rejection_raises_on_default_path(monkeypatch):
+    """On TPU the fused stencil is the default sim path and nothing
+    stands between it and the compiler: a kernel the backend cannot
+    compile must RAISE with the compiler's message, not hand the session
+    the XLA roll path under a ledger row. Shown on CPU by claiming the
+    TPU backend — the Mosaic kernel cannot lower here."""
+    from scenery_insitu_tpu import obs
     from scenery_insitu_tpu.sim import pallas_stencil as ps
 
-    shape = (8, 8, 128)
-    assert ps.pick_tz(shape) > 0
-    ps._PROBE_CACHE.clear()
-    assert ps._compile_ok(shape, 1) is False        # swallowed, not raised
-    # cached (tz=0 = auto, ranges-epilogue variant off)
-    assert ps._PROBE_CACHE[(shape, 1, 0, False)] is False
-    # fused_supported skips the probe off-TPU (interpret mode is safe)
-    assert ps.fused_supported(shape)
-    ps._PROBE_CACHE.clear()
+    st = gs.GrayScott.init((8, 8, 128), n_seeds=1)
+    assert ps.fused_supported(st.u.shape)
+    obs.clear_ledger()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(Exception) as ei:
+        jax.block_until_ready(gs.multi_step_fast(st, 2).u)
+    assert not isinstance(ei.value, AssertionError)
+    assert not any(e["component"] == "sim.fused_stencil"
+                   for e in obs.ledger())
+    # a grid no tile of the kernel fits is a selection the code can
+    # observe, not a refusal: that one still gives way, on the ledger
+    odd = gs.GrayScott.init((8, 8, 48), n_seeds=1)
+    assert not ps.fused_supported(odd.u.shape)
+    out = gs.multi_step_fast(odd, 1)
+    assert out.u.shape == (8, 8, 48)
+    assert any(e["component"] == "sim.fused_stencil" for e in obs.ledger())
 
 
 @pytest.mark.parametrize("t_steps", [2, 4])
@@ -146,12 +155,12 @@ def test_pallas_stencil_2d_multistep_parity(t_steps):
 
 def test_best_schedule_prefers_lower_traffic():
     """_best_schedule must rank 2D tiles above the 1D slab when the
-    modeled per-step traffic is lower (the 512^3 regime), and fall back
-    to 1D when no 2D tile exists."""
+    whole-H slab does not fit VMEM (the 512^3 regime), and take the 1D
+    slab where it does."""
     from scenery_insitu_tpu.sim import pallas_stencil as ps
 
-    kind, tz, th = ps._best_schedule((512, 512, 512), 4, on_tpu=False)
-    assert kind == "2d" and tz % 4 == 0 and th % 4 == 0
-    # h=48 admits no th in (256,128,64,32): only the 1D slab remains
-    sched = ps._best_schedule((64, 48, 128), 1, on_tpu=False)
-    assert sched is not None and sched[0] == "1d"
+    kind, tz, th = ps._best_schedule((512, 512, 512), 4)
+    assert kind == "2d" and tz % 4 == 0 and th % 8 == 0
+    # a small plane fits whole: the z slab (th == H) has the least halo
+    sched = ps._best_schedule((64, 48, 128), 1)
+    assert sched is not None and sched[0] == "1d" and sched[2] == 48

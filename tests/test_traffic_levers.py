@@ -18,22 +18,6 @@ from scenery_insitu_tpu.runtime.session import InSituSession
 from scenery_insitu_tpu.sim import grayscott as gs
 
 
-# ------------------------------------------------------------ compat shim
-
-
-def test_compat_shim_surface():
-    """The one-place JAX version shim must expose the new-API surface on
-    whatever JAX is installed (the seed pinned `jax.shard_map`, absent
-    here — the tier-1 collection failure this PR removes)."""
-    from scenery_insitu_tpu.utils import compat
-
-    assert callable(compat.shard_map)
-    assert callable(compat.tpu_compiler_params)
-    p = compat.tpu_compiler_params(
-        dimension_semantics=("arbitrary",))
-    assert p.dimension_semantics == ("arbitrary",)
-
-
 # ------------------------------------------------- stencil guard rails
 
 

@@ -273,7 +273,7 @@ def test_wire_exchange_matrix_composite_step():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from scenery_insitu_tpu.parallel.pipeline import _composite_exchanged
-    from scenery_insitu_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     _, mesh, _ = _scene()
     axis = mesh.axis_names[0]

@@ -12,12 +12,27 @@ explain itself:
    XLA carries the scope through fusion into per-instruction
    ``op_name`` metadata in the compiled HLO.
 2. ``ProfileCapture`` runs N bracketed frames under
-   ``jax.profiler.trace``, parses the emitted trace-event JSON
-   (``plugins/profile/<ts>/*.trace.json.gz``), and joins each XLA op
-   event back to its scope via the compiled HLO text: instruction names
-   are module-unique and the trace events carry ``args.hlo_op`` +
-   ``args.hlo_module``. This join is backend-portable — it works on the
-   CPU trace backend today and on TPU XSpace-derived traces unchanged.
+   ``jax.profiler.trace`` and joins each XLA op event of the emitted
+   ``*.xplane.pb`` back to its scope via the compiled HLO text:
+   instruction names are module-unique, and every op event names its
+   instruction and its module (``_xplane_events``). The CPU backend
+   runs its ops on host threads, and their events carry ``hlo_op`` +
+   ``hlo_module`` stats. On a TPU the ``XLA Ops`` events of a device
+   plane are named by their whole HLO text (``%fusion.12 = ...``),
+   carry neither ``op_name`` nor module, and ``while``/``conditional``
+   events enclose their bodies' events — so the reader takes each op's
+   SELF time and the module from the enclosing ``XLA Modules`` event
+   (jax 0.9 + libtpu 0.0.34, looked at on a v5e). The
+   ``*.trace.json.gz`` that ``jax.profiler.trace`` writes beside it on
+   both backends mixes host function events in and nests device ops:
+   it is not read.
+3. A session with ``obs.enabled`` keeps the same ``{module:
+   {instruction: phase}}`` table of every step executable it dispatches
+   on its recorder (``scoped_step`` -> ``Recorder.hlo_scopes``, with the
+   instructions that only inherited their phase named in
+   ``Recorder.hlo_inherited``), so a
+   reader of a profile of the whole run (chipbench's per-phase device
+   ms) can make the join without the executables.
 
 Accounting (validated against an 8-device virtual-mesh probe):
 
@@ -45,9 +60,8 @@ benchmarks/divergence.py.
 
 from __future__ import annotations
 
+import functools
 import glob
-import gzip
-import json
 import os
 import re
 import tempfile
@@ -64,7 +78,7 @@ SCOPE_PREFIX = "sitpu_"
 # assert per-builder subsets of these appear in lowered HLO; the CI
 # attribution lane asserts the captured breakdown names come from here
 # (plus the two synthetic phases the capture itself mints).
-PHASES = ("march", "halo", "exchange", "merge", "resegment",
+PHASES = ("march", "fold", "halo", "exchange", "merge", "resegment",
           "wire_encode", "sim_step", "dcn_hop", "wave")
 
 # Synthetic phases ProfileCapture adds on top of the scope catalog.
@@ -77,14 +91,34 @@ def phase(name: str):
     return jax.named_scope(SCOPE_PREFIX + name)
 
 
+def in_phase(name: str):
+    """Decorator form of ``phase``: every call of the function runs
+    under a scope of its own (a ``jax.named_scope`` instance keeps its
+    exit state on itself, so one shared instance must neither nest nor
+    run on two threads)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with phase(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
 def scope_of(op_name: str) -> Optional[str]:
     """Extract the phase from an HLO ``op_name`` metadata path. The LAST
     ``sitpu_`` component wins so nested scopes attribute to the
-    innermost phase (wave(march) → march)."""
+    innermost phase (wave(march) → march). A Pallas kernel's ``name``
+    is a path component too: ``sitpu_<phase>_<kernel>`` is its phase."""
     found = None
     for comp in op_name.split("/"):
         if comp.startswith(SCOPE_PREFIX):
             found = comp[len(SCOPE_PREFIX):]
+    if found is None or found in PHASES:
+        return found
+    for name in sorted(PHASES, key=len, reverse=True):
+        if found.startswith(name + "_"):
+            return name
     return found
 
 
@@ -96,44 +130,180 @@ def scope_names(text: str) -> set:
 
 
 _HLO_MODULE_RE = re.compile(r"^HloModule ([^,\s]+)", re.M)
-_HLO_OP_RE = re.compile(
-    r"%?([\w\.\-]+) = [^\n]*?metadata=\{[^}]*?op_name=\"([^\"]*)\"")
+_HLO_INST_RE = re.compile(r"^\s+(?:ROOT )?%?([\w\.\-]+) = ")
+_HLO_COMP_RE = re.compile(r"^(?:ENTRY )?%?([\w\.\-]+) .*\{$")
+_HLO_OP_NAME_RE = re.compile(r"metadata=\{[^}]*?op_name=\"([^\"]*)\"")
+_HLO_CALLS_RE = re.compile(
+    r"(?:calls|body|condition|to_apply|true_computation"
+    r"|false_computation)=%?([\w\.\-]+)|branch_computations=\{([^}]*)\}")
 
 
 def parse_hlo_scopes(hlo_text: str):
-    """(module_name, {instruction_name: phase}) from compiled HLO text.
-    Instruction names are module-unique, so they key the trace join."""
+    """(module_name, {instruction_name: phase}, inherited) from compiled
+    HLO text. Instruction names are module-unique, so they key the trace
+    join. ``inherited`` is the set of instruction names whose phase is
+    not their own: a reader keeps their time apart
+    (``phases[...]["inherited_ms"]``), because it is the enclosing
+    loop's by position, not by what the source scoped.
+
+    An instruction takes the phase of its own ``op_name``. One without
+    (the copies and prefetches the compiler inserts, fusions it builds
+    from constants) takes the phase of the instruction that calls the
+    computation it stands in — the ``while`` or ``conditional`` around
+    it, transitively — where every caller agrees: what runs inside the
+    march's loop is the march's, unless it says otherwise itself."""
     m = _HLO_MODULE_RE.search(hlo_text)
     module = m.group(1) if m else None
-    ops: Dict[str, str] = {}
-    for inst, op_name in _HLO_OP_RE.findall(hlo_text):
-        sc = scope_of(op_name)
+    own: Dict[str, str] = {}
+    comp_of: Dict[str, str] = {}
+    callers: Dict[str, list] = {}
+    comp = None
+    for line in hlo_text.splitlines():
+        mi = _HLO_INST_RE.match(line)
+        if mi is None:
+            mc = _HLO_COMP_RE.match(line)
+            if mc is not None:
+                comp = mc.group(1)
+            continue
+        inst = mi.group(1)
+        comp_of[inst] = comp
+        mo = _HLO_OP_NAME_RE.search(line)
+        sc = scope_of(mo.group(1)) if mo else None
         if sc is not None:
-            ops[inst] = sc
-    return module, ops
+            own[inst] = sc
+        for one, many in _HLO_CALLS_RE.findall(line):
+            for callee in (one,) if one else many.replace("%", "").split(","):
+                callers.setdefault(callee.strip(), []).append(inst)
+
+    ops = dict(own)
+    seen: Dict[str, Optional[str]] = {}
+
+    def of_comp(name):
+        """The one phase every caller of computation ``name`` has."""
+        if name not in seen:
+            seen[name] = None           # a cycle cannot be, but be safe
+            phases = {own.get(i) or of_comp(comp_of.get(i))
+                      for i in callers.get(name, ())}
+            seen[name] = phases.pop() if len(phases) == 1 else None
+        return seen[name]
+
+    inherited = set()
+    for inst, where in comp_of.items():
+        if inst not in own:
+            sc = of_comp(where)
+            if sc is not None:
+                ops[inst] = sc
+                inherited.add(inst)
+    return module, ops, inherited
+
+
+def scoped_step(fn, rec):
+    """``fn`` (a jitted step) where ``rec`` is disabled. Where it is
+    enabled, a wrapper that after its FIRST call reads the executable's
+    compiled HLO and keeps ``{instruction: phase}`` under the module's
+    name in ``rec.hlo_scopes`` (and the names that only inherited their
+    phase in ``rec.hlo_inherited``) — the table a trace reader joins
+    device ops to ``sitpu_*`` scopes with. The call has just compiled the
+    program, so ``lower().compile()`` is answered from jit's own cache
+    and issues no compile request; nothing is read on later calls.
+    ``lower`` stays reachable (obs/device.cost_snapshot)."""
+    if not rec.enabled:
+        return fn
+    noted = []
+
+    def call(*args, **kwargs):
+        out = fn(*args, **kwargs)
+        if not noted:
+            noted.append(True)
+            try:
+                module, ops, inherited = parse_hlo_scopes(
+                    fn.lower(*args, **kwargs).compile().as_text())
+                rec.hlo_scopes.setdefault(module, {}).update(ops)
+                rec.hlo_inherited.setdefault(module, set()).update(
+                    inherited)
+            except Exception as e:      # noqa: BLE001 — observability
+                # must never take the frame down
+                _rec.degrade("obs.profiler", "hlo_scopes", "none",
+                             f"scope table unavailable: {e}", warn=False)
+        return out
+
+    call.lower = fn.lower
+    return call
+
+
+def _self_times(ops):
+    """(name, start_ns, self_ns) for nested ``(name, start_ns, dur_ns)``
+    events of one line: an op that encloses others (``while``,
+    ``conditional``) keeps only what they leave."""
+    out, stack = [], []             # stack rows: [name, start, end, self]
+    for name, start, dur in sorted(ops, key=lambda e: (e[1], -e[2])):
+        while stack and stack[-1][2] <= start:
+            top = stack.pop()
+            out.append((top[0], top[1], top[3]))
+        if stack:
+            stack[-1][3] -= min(dur, stack[-1][2] - start)
+        stack.append([name, start, start + dur, dur])
+    out.extend((name, start, self_ns) for name, start, _, self_ns in stack)
+    return out
+
+
+def _xplane_events(path: str):
+    """The op events of one ``.xplane.pb`` in the trace-event form the
+    join takes (``dur`` in us, ``args.hlo_op``, ``args.hlo_module``).
+    Host plane: events that carry an ``hlo_op`` stat (the CPU backend
+    runs its ops on host threads). ``/device:TPU:<n>`` planes: SELF
+    time of each ``XLA Ops`` event, named by the instruction its HLO
+    text defines, module from the enclosing ``XLA Modules`` event."""
+    import bisect
+
+    from jax.profiler import ProfileData
+
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:TPU:"):
+            lines = {line.name: line for line in plane.lines}
+            if "XLA Ops" not in lines:
+                continue
+            mods = sorted((ev.start_ns, ev.start_ns + ev.duration_ns,
+                           ev.name.split("(", 1)[0])
+                          for ev in (lines["XLA Modules"].events
+                                     if "XLA Modules" in lines else ()))
+            starts = [m[0] for m in mods]
+            ops = [(ev.name.split(" = ", 1)[0].strip().lstrip("%"),
+                    ev.start_ns, ev.duration_ns)
+                   for ev in lines["XLA Ops"].events]
+            for name, start, self_ns in _self_times(ops):
+                i = bisect.bisect_right(starts, start) - 1
+                module = (mods[i][2] if i >= 0 and start < mods[i][1]
+                          else None)
+                yield {"ph": "X", "name": name, "dur": self_ns / 1e3,
+                       "args": {"hlo_op": name, "hlo_module": module}}
+        elif plane.name == "/host:CPU":
+            for line in plane.lines:
+                for ev in line.events:
+                    stats = dict(ev.stats)
+                    if "hlo_op" in stats:
+                        yield {"ph": "X", "name": ev.name,
+                               "dur": ev.duration_ns / 1e3,
+                               "args": {"hlo_op": stats["hlo_op"],
+                                        "hlo_module":
+                                            stats.get("hlo_module")}}
 
 
 def _trace_events(trace_dir: str):
-    """Load the newest emitted trace under ``trace_dir`` and yield its
-    complete ("X") events. jax.profiler.trace writes
-    ``<dir>/plugins/profile/<ts>/<host>.trace.json.gz`` on every
-    backend that supports tracing (CPU included)."""
-    paths = sorted(glob.glob(os.path.join(
-        trace_dir, "plugins", "profile", "*", "*.trace.json*")))
-    if not paths:
+    """Yield the op events of the newest trace under ``trace_dir``
+    (``<dir>/plugins/profile/<ts>/*.xplane.pb``) through
+    ``_xplane_events``: op events only, self times on a TPU."""
+    runs = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*")))
+    if not runs:
         raise FileNotFoundError(
             f"no trace emitted under {trace_dir!r} (profiler backend "
             "absent?)")
-    newest_run = os.path.dirname(paths[-1])
-    for path in paths:
-        if os.path.dirname(path) != newest_run:
-            continue
-        opener = gzip.open if path.endswith(".gz") else open
-        with opener(path, "rt") as f:
-            doc = json.load(f)
-        for ev in doc.get("traceEvents", []):
-            if ev.get("ph") == "X":
-                yield ev
+    planes = sorted(glob.glob(os.path.join(runs[-1], "*.xplane.pb")))
+    if not planes:
+        raise FileNotFoundError(f"no .xplane.pb under {runs[-1]!r}")
+    for path in planes:
+        yield from _xplane_events(path)
 
 
 class ProfileCapture:
@@ -195,7 +365,7 @@ class ProfileCapture:
     # ------------------------------------------------------------------
     def _capture(self, fn, args, step):
         hlo = fn.lower(*args).compile().as_text()
-        module, op_scopes = parse_hlo_scopes(hlo)
+        module, op_scopes, inherited = parse_hlo_scopes(hlo)
 
         run = step if step is not None else (
             lambda: jax.block_until_ready(fn(*args)))
@@ -217,6 +387,7 @@ class ProfileCapture:
             hook_ms = min(hook_ms, wall_ms)   # a hook cannot exceed wall
 
         phase_us: Dict[str, float] = {}
+        inherited_us: Dict[str, float] = {}
         phase_events: Dict[str, int] = {}
         total_events = joined = 0
         for ev in _trace_events(trace_dir):
@@ -233,13 +404,20 @@ class ProfileCapture:
                 sc = "unattributed"
             else:
                 joined += 1
-            phase_us[sc] = phase_us.get(sc, 0.0) + float(
-                ev.get("dur") or 0.0)
+            dur = float(ev.get("dur") or 0.0)
+            phase_us[sc] = phase_us.get(sc, 0.0) + dur
+            if op in inherited:
+                inherited_us[sc] = inherited_us.get(sc, 0.0) + dur
             phase_events[sc] = phase_events.get(sc, 0) + 1
 
         devices = self.devices or jax.local_device_count()
+        per_frame = 1e3 * self.frames * devices
+        # inherited_ms: the part of ms whose ops have no op_name of their
+        # own and took the phase of the while/conditional around them
         phases = {
-            name: {"ms": round(us / 1e3 / (self.frames * devices), 4),
+            name: {"ms": round(us / per_frame, 4),
+                   "inherited_ms": round(
+                       inherited_us.get(name, 0.0) / per_frame, 4),
                    "events": phase_events.get(name, 0)}
             for name, us in sorted(phase_us.items())}
         device_ms = sum(p["ms"] for p in phases.values())
@@ -258,10 +436,12 @@ class ProfileCapture:
             scale = device_budget / device_ms
             for p in phases.values():
                 p["ms"] = round(p["ms"] * scale, 4)
+                p["inherited_ms"] = round(p["inherited_ms"] * scale, 4)
             device_ms = sum(p["ms"] for p in phases.values())
             normalized = True
         host_ms = hook_ms + max(0.0, wall_ms - hook_ms - device_ms)
-        phases["host"] = {"ms": round(host_ms, 4), "events": 0}
+        phases["host"] = {"ms": round(host_ms, 4), "inherited_ms": 0.0,
+                          "events": 0}
 
         attr = {
             "type": "phase_attribution",
@@ -288,39 +468,6 @@ class ProfileCapture:
 
 
 # ------------------------------------------------- fleet-trace export
-
-def attribution_chrome_events(attr: Dict[str, Any],
-                              pid: int = 9000) -> list:
-    """Render one attribution as extra Perfetto tracks: a synthetic
-    "device phases" process whose complete events lay the per-phase ms
-    out sequentially (one representative frame). Append these to a
-    Recorder ``chrome_trace_events()`` list or an exported trace file
-    (``append_to_chrome_trace``)."""
-    out = [{"ph": "M", "name": "process_name", "pid": pid, "tid": 0,
-            "args": {"name": "device phases (attributed)"}}]
-    ts = 0.0
-    for name, p in (attr.get("phases") or {}).items():
-        dur = float(p.get("ms") or 0.0) * 1e3    # µs
-        out.append({"ph": "X", "name": name, "pid": pid, "tid": 0,
-                    "ts": round(ts, 1), "dur": round(dur, 1),
-                    "cat": "device_phase",
-                    "args": {"ms": p.get("ms"),
-                             "events": p.get("events")}})
-        ts += dur
-    return out
-
-
-def append_to_chrome_trace(attr: Dict[str, Any], path: str) -> str:
-    """Append the attribution tracks to an existing exported fleet
-    trace (Recorder.export_chrome_trace format)."""
-    with open(path) as f:
-        doc = json.load(f)
-    doc.setdefault("traceEvents", []).extend(
-        attribution_chrome_events(attr))
-    with open(path, "w") as f:
-        json.dump(doc, f)
-    return path
-
 
 def publish_attribution(attr: Dict[str, Any], rec=None,
                         frame: Optional[int] = None) -> None:

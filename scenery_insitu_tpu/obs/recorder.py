@@ -18,7 +18,10 @@ Three layers:
   markers) — the timers are one sink among several, and ``sess.timers``
   keeps working unchanged. A DISABLED recorder degrades to exactly the
   PR-1 behavior: spans still feed the timers but record no events and
-  write no sinks (near-zero extra cost, no growing state).
+  write no sinks (near-zero extra cost, no growing state). An ENABLED
+  span is also a ``jax.profiler.TraceAnnotation`` (name, ``frame``,
+  scalar attrs): a profile taken of the run holds the spans on its
+  ``/host:CPU`` plane, on the device ops' clock.
 - the module-level **fallback ledger** (`degrade`/`ledger`): process-
   global so probe-time degradations (Mosaic rejections fire inside
   cached compile probes, possibly before any session exists) are never
@@ -154,9 +157,10 @@ _LEDGER_REGISTRY: Dict[str, str] = {
                            "trace/metrics paths",
     "obs.profiler": "a ProfileCapture could not produce a phase "
                     "attribution (trace backend absent, no trace "
-                    "emitted, or the HLO/trace join failed); the step "
-                    "keeps running unprofiled (docs/OBSERVABILITY.md "
-                    "'Phase attribution')",
+                    "emitted, or the HLO/trace join failed), a step's "
+                    "scope table could not be read, or jax.profiler is "
+                    "absent and spans are not annotated; the step keeps "
+                    "running (docs/OBSERVABILITY.md 'Phase attribution')",
     "slo.breach": "the live SLO engine saw a rolling-window quantile "
                   "cross its configured budget (metric and quantile in "
                   "the reason); the run keeps going, the breach is the "
@@ -401,12 +405,39 @@ def clear_ledger() -> None:
 
 # ----------------------------------------------------------------- spans
 
+_ANNOTATION = False     # unresolved; then jax's TraceAnnotation, or None
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, looked up the first time an
+    ENABLED recorder opens a span (this module stays importable without
+    jax: no jax, no annotations, spans unchanged)."""
+    global _ANNOTATION
+    if _ANNOTATION is False:
+        try:
+            from jax.profiler import TraceAnnotation as _ANNOTATION
+        except ImportError as e:
+            _ANNOTATION = None
+            degrade("obs.profiler", "trace_annotation", "none",
+                    f"jax.profiler unavailable, spans stay off the "
+                    f"profiler's trace: {e}", warn=False)
+    return _ANNOTATION
+
+
+_SCALAR = (bool, int, float, str)
+
+
 class _Span:
     """One timed region. Always feeds the recorder's Timers (so the PR-1
     PhaseStats/windowed dumps are unchanged); records a structured event
-    only when the recorder is enabled."""
+    only when the recorder is enabled. An enabled span is also a
+    ``jax.profiler.TraceAnnotation`` of the same name, with ``frame`` and
+    the scalar attrs as its stats: it lands on the ``/host:CPU`` plane of
+    whatever profile is being taken, on the device ops' clock, and costs
+    one flag test when none is."""
 
-    __slots__ = ("rec", "name", "frame", "attrs", "t0", "depth", "parent")
+    __slots__ = ("rec", "name", "frame", "attrs", "t0", "depth", "parent",
+                 "ann")
 
     def __init__(self, rec: "Recorder", name: str,
                  frame: Optional[int], attrs: Optional[dict]):
@@ -422,6 +453,15 @@ class _Span:
             self.depth = len(stack)
             self.parent = stack[-1] if stack else None
             stack.append(self.name)
+            ann = _annotation()
+            if ann is not None:
+                stats = {k: v for k, v in (self.attrs or {}).items()
+                         if isinstance(v, _SCALAR)}
+                if self.frame is not None:
+                    stats["frame"] = self.frame
+                ann = ann(self.name, **stats)
+                ann.__enter__()
+            self.ann = ann
         self.t0 = time.perf_counter()
         return self
 
@@ -431,6 +471,8 @@ class _Span:
         dt = t1 - self.t0
         rec.timers.record(self.name, dt)
         if rec.enabled:
+            if self.ann is not None:
+                self.ann.__exit__(*exc)
             rec._stack.pop()
             ev = {"type": "span", "name": self.name,
                   "rank": rec.rank,
@@ -467,6 +509,13 @@ class Recorder:
         self.epoch_unix = time.time()
         self.events: List[dict] = []
         self.counters: Dict[str, float] = {}
+        # {hlo module: {instruction: phase}} of every step executable an
+        # enabled session dispatched (obs/profiler.scoped_step): what a
+        # reader of the run's profile joins device ops to scopes with;
+        # hlo_inherited names, per module, the instructions among them
+        # that have no scope of their own and took the enclosing loop's
+        self.hlo_scopes: Dict[str, Dict[str, str]] = {}
+        self.hlo_inherited: Dict[str, set] = {}
         self.max_events = max_events
         # spans now open/close on the delivery worker threads too
         # (runtime/delivery.py): the open-span stack is per-thread so a

@@ -195,6 +195,7 @@ def resegment_sorted(sc: jnp.ndarray, sd: jnp.ndarray,
             pltpu.VMEM((TILE_H, TILE_W), jnp.int32),        # next free slot
         ],
         interpret=interpret,
+        name="sitpu_resegment_sorted",
     )(sc, sd, threshold)
 
     if ph or pw:

@@ -361,6 +361,7 @@ def fold_chunk(packed, rgba: jnp.ndarray, t0: jnp.ndarray, t1: jnp.ndarray,
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shapes, input_output_aliases=aliases,
         interpret=interpret,
+        name="sitpu_fold_push",
     )(*operands)
     if with_count:
         return tuple(out[:_STATE_FIELDS]), out[_STATE_FIELDS]
@@ -426,6 +427,7 @@ def count_multi_chunk(carry, rgba: jnp.ndarray, tvec, *,
                    jax.ShapeDtypeStruct((h, w), jnp.float32)],
         input_output_aliases={2: 0, 3: 1, 4: 2},
         interpret=interpret,
+        name="sitpu_fold_count",
     )(rgba, tvec3, count, prev, fe)
     return tuple(out)
 

@@ -239,6 +239,7 @@ def fold_chunk_packed(packed, rgba: jnp.ndarray, t0=None, t1=None,
             scratch_shapes=[pltpu.VMEM((c, 5, TILE_H, wb), jnp.float32)],
             input_output_aliases={5: 0, 6: 1, 7: 2},
             interpret=interpret,
+            name="sitpu_fold_seg_compact",
         )(rgba, length, threshold, sk0, sk1, *packed)
         return tuple(out)
 
@@ -252,6 +253,7 @@ def fold_chunk_packed(packed, rgba: jnp.ndarray, t0=None, t1=None,
         scratch_shapes=[pltpu.VMEM((c, 5, TILE_H, wb), jnp.float32)],
         input_output_aliases={3: 0, 4: 1, 5: 2},
         interpret=interpret,
+        name="sitpu_fold_seg",
     )(rgba, td, threshold, *packed)
     return tuple(out)
 
@@ -425,6 +427,7 @@ def fused_fold_chunk(packed, val: jnp.ndarray, length: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((c, 7, TILE_H, wb), jnp.float32)],
         input_output_aliases={6: 0, 7: 1, 8: 2},
         interpret=interpret,
+        name="sitpu_fold_fused",
     )(val, length, ratio, threshold, sk0, sk1, *packed)
     return tuple(out)
 
@@ -515,5 +518,6 @@ def fused_stream_fold(packed, val: jnp.ndarray, length: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="sitpu_fold_fused_stream",
     )(val, length, ratio, threshold, sk0, sk1, *packed)
     return tuple(out)

@@ -58,6 +58,8 @@ from scenery_insitu_tpu.core.camera import Camera, frustum, look_at
 from scenery_insitu_tpu.core.transfer import TransferFunction
 from scenery_insitu_tpu.core.vdi import VDI, VDIMetadata
 from scenery_insitu_tpu.core.volume import Volume
+from scenery_insitu_tpu.obs.profiler import in_phase as _in_phase
+from scenery_insitu_tpu.obs.profiler import phase as _phase
 from scenery_insitu_tpu.ops import pallas_march as pm
 from scenery_insitu_tpu.ops import pallas_seg as psg
 from scenery_insitu_tpu.ops import seg_fold as sf
@@ -570,9 +572,10 @@ def _fused_stream_vdi_march(vol, tf, axcam, spec, threshold, k, occ,
                               v_bounds, step_scale=step_scale,
                               occupancy=occ, raw=True, raw_full_skip=True,
                               volp=volp, w_bounds=w_bounds)
-    packed = psg.fused_stream_fold(
-        psg.init_seg_packed(k, spec.nj, spec.ni), buf, length, ratio,
-        skb, skb + ds, threshold, max_k=k, chunk=c, tf=tf)
+    with _phase("fold"):
+        packed = psg.fused_stream_fold(
+            psg.init_seg_packed(k, spec.nj, spec.ni), buf, length, ratio,
+            skb, skb + ds, threshold, max_k=k, chunk=c, tf=tf)
     return psg.unpack_seg_state(packed)
 
 
@@ -670,6 +673,10 @@ def slice_march(vol: Volume, tf: TransferFunction, axcam: AxisCamera,
     if raw and pre_shaded:
         raise ValueError("raw slice_march feeds a transfer-function "
                          "kernel; pre-shaded volumes have no TF")
+    # the trace's phases: what the consumer does with a chunk is `fold`
+    # (innermost scope wins); the resampling and shading around it stay
+    # in the caller's scope — `march` in every VDI generator and builder
+    consume = _in_phase("fold")(consume)
     occ_tiles = None
     if isinstance(occupancy, tuple):
         occupancy, occ_tiles = occupancy
@@ -1048,6 +1055,7 @@ def raycast_mxu(vol: Volume, tf: TransferFunction, cam: Camera,
 # ----------------------------------------------------------- VDI generation
 
 
+@_in_phase("march")
 def generate_vdi_mxu(vol: Volume, tf: TransferFunction, cam: Camera,
                      spec: AxisSpec, cfg: Optional[VDIConfig] = None,
                      frame_index: int = 0,
@@ -1130,7 +1138,8 @@ def generate_vdi_mxu(vol: Volume, tf: TransferFunction, cam: Camera,
             return pm.fold_chunk(packed, rgba, t0, t1, threshold, max_k=k)
 
         packed = march(consume, pm.init_packed(k, nj, ni))
-        color, depth = ss.finalize(pm.unpack_state(packed))
+        with _phase("fold"):
+            color, depth = ss.finalize(pm.unpack_state(packed))
     elif spec.fold == "pallas_seg":
         # packed-carry: the [K,...] state keeps one layout across the
         # whole scan so the kernel's input_output_aliases update it in
@@ -1150,7 +1159,8 @@ def generate_vdi_mxu(vol: Volume, tf: TransferFunction, cam: Camera,
                              occupancy=occ,
                              shaded_compact=True, volp=volp,
                              w_bounds=w_bounds)
-        color, depth = sf.seg_finalize(psg.unpack_seg_state(packed))
+        with _phase("fold"):
+            color, depth = sf.seg_finalize(psg.unpack_seg_state(packed))
     elif spec.fold in ("pallas_fused", "fused_stream"):
         # shade-in-kernel: the march feeds the raw resampled value plane
         # and the kernel applies TF + opacity correction + depths itself
@@ -1163,13 +1173,15 @@ def generate_vdi_mxu(vol: Volume, tf: TransferFunction, cam: Camera,
         state = marcher(vol, tf, axcam, spec, threshold, k, occ,
                         u_bounds, v_bounds, step_scale=step_scale,
                         volp=volp, w_bounds=w_bounds)
-        color, depth = sf.seg_finalize(state)
+        with _phase("fold"):
+            color, depth = sf.seg_finalize(state)
     elif spec.fold == "seg":
         def consume(st, rgba, t0, t1):
             return sf.seg_fold_chunk(st, rgba, t0, t1, threshold, max_k=k)
 
         state = march(consume, sf.init_seg_state(k, nj, ni))
-        color, depth = sf.seg_finalize(state)
+        with _phase("fold"):
+            color, depth = sf.seg_finalize(state)
     else:
         def consume(st, rgba, t0, t1):
             for i in range(rgba.shape[0]):
@@ -1177,7 +1189,8 @@ def generate_vdi_mxu(vol: Volume, tf: TransferFunction, cam: Camera,
             return st
 
         state = march(consume, ss.init_state(k, nj, ni))
-        color, depth = ss.finalize(state)
+        with _phase("fold"):
+            color, depth = ss.finalize(state)
 
     meta = _vdi_meta(vol, axcam, ni, nj, frame_index, step_scale)
     return VDI(color, depth), meta, axcam
@@ -1222,6 +1235,7 @@ def _histogram_threshold(march, cfg: VDIConfig, k: int, nj: int, ni: int,
     return ss.pick_threshold(counts, tvec, k)
 
 
+@_in_phase("march")
 def initial_threshold(vol: Volume, tf: TransferFunction, cam: Camera,
                       spec: AxisSpec, cfg: Optional[VDIConfig] = None,
                       box_min: Optional[jnp.ndarray] = None,
@@ -1253,6 +1267,7 @@ def initial_threshold(vol: Volume, tf: TransferFunction, cam: Camera,
     return ss.init_threshold_state(thr, cfg.thr_min, cfg.thr_max)
 
 
+@_in_phase("march")
 def generate_vdi_mxu_temporal(vol: Volume, tf: TransferFunction,
                               cam: Camera, spec: AxisSpec,
                               threshold: ss.ThresholdState,
@@ -1313,7 +1328,8 @@ def generate_vdi_mxu_temporal(vol: Volume, tf: TransferFunction,
             (pm.init_packed(k, nj, ni), jnp.zeros((nj, ni), jnp.int32)),
             u_bounds, v_bounds, step_scale=step_scale, occupancy=occ,
             volp=volp, w_bounds=w_bounds)
-        color, depth = ss.finalize(pm.unpack_state(packed))
+        with _phase("fold"):
+            color, depth = ss.finalize(pm.unpack_state(packed))
     elif spec.fold in ("seg", "pallas_seg", "pallas_fused",
                        "fused_stream"):
         # the segmented-scan fold's own running start count IS the true
@@ -1350,7 +1366,8 @@ def generate_vdi_mxu_temporal(vol: Volume, tf: TransferFunction,
                                 u_bounds, v_bounds,
                                 step_scale=step_scale, occupancy=occ,
                                 volp=volp, w_bounds=w_bounds)
-        color, depth = sf.seg_finalize(state)
+        with _phase("fold"):
+            color, depth = sf.seg_finalize(state)
         count = state.cnt
     else:
         def consume(carry, rgba, t0, t1):
@@ -1365,10 +1382,12 @@ def generate_vdi_mxu_temporal(vol: Volume, tf: TransferFunction,
             (ss.init_state(k, nj, ni), ss.init_count(nj, ni)),
             u_bounds, v_bounds, step_scale=step_scale, occupancy=occ,
             volp=volp, w_bounds=w_bounds)
-        color, depth = ss.finalize(state)
+        with _phase("fold"):
+            color, depth = ss.finalize(state)
         count = cstate.count
-    next_thr = ss.update_threshold(threshold, count, kt,
-                                   cfg.adaptive_delta, cfg.thr_min,
-                                   cfg.thr_max, cfg.temporal_track)
+    with _phase("fold"):        # the controller reads the fold's count
+        next_thr = ss.update_threshold(threshold, count, kt,
+                                       cfg.adaptive_delta, cfg.thr_min,
+                                       cfg.thr_max, cfg.temporal_track)
     meta = _vdi_meta(vol, axcam, ni, nj, frame_index, step_scale)
     return VDI(color, depth), meta, axcam, next_thr

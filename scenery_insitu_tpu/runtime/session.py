@@ -33,6 +33,7 @@ from scenery_insitu_tpu.config import FrameworkConfig
 from scenery_insitu_tpu.core.camera import Camera, orbit
 from scenery_insitu_tpu.core.transfer import TransferFunction, for_dataset
 from scenery_insitu_tpu.core.vdi import VDI
+from scenery_insitu_tpu.obs.profiler import scoped_step
 from scenery_insitu_tpu.parallel.topology import (make_topology_mesh,
                                                   resolve_mesh_topology)
 from scenery_insitu_tpu.parallel.pipeline import (distributed_plain_step,
@@ -56,7 +57,8 @@ def steer_session(sess, msg: dict) -> None:
     failing ``fault.max_sink_failures`` consecutive times is quarantined
     on the ``session.sink`` ledger."""
     from scenery_insitu_tpu.runtime.streaming import apply_steering
-    sess.camera, other = apply_steering(sess.camera, msg)
+    sess.camera, other = apply_steering(sess.camera, msg,
+                                        frame=sess.frame_index)
     for kind_msg in other.values():
         sess._sink_guard.run(sess.on_steer, kind_msg,
                              kind="on_steer callback")
@@ -490,9 +492,9 @@ class InSituSession:
             from scenery_insitu_tpu.parallel.particles import (
                 distributed_particle_step)
             self.mode = "particles"
-            self._step = distributed_particle_step(
+            self._step = scoped_step(distributed_particle_step(
                 self.mesh, r.width, r.height,
-                radius=self.cfg.sim.particle_radius)
+                radius=self.cfg.sim.particle_radius), self.obs)
         elif isinstance(self.sim, HybridSimAdapter):
             # hybrid is implemented on the slice-march engine only (the
             # particle layer shares the virtual camera's rays); the engine
@@ -503,11 +505,11 @@ class InSituSession:
         elif self.cfg.runtime.generate_vdis and self.engine == "mxu":
             self._step = None
         elif self.cfg.runtime.generate_vdis:
-            self._step = distributed_vdi_step(
+            self._step = scoped_step(distributed_vdi_step(
                 self.mesh, self.tf, r.width, r.height,
                 self.cfg.vdi, self.cfg.composite, max_steps=r.max_steps,
                 plan=self._plan, bricks=self._bricks,
-                topology=self.cfg.topology)
+                topology=self.cfg.topology), self.obs)
         elif self.engine == "mxu":
             # TPU plain mode: slice march + column exchange + nearest-first
             # composite on the intermediate grid, homography-warped to the
@@ -518,7 +520,7 @@ class InSituSession:
         else:
             self.mode = "plain"
             cc = self.cfg.composite
-            self._step = distributed_plain_step(
+            self._step = scoped_step(distributed_plain_step(
                 self.mesh, self.tf, r.width, r.height, r,
                 exchange=cc.exchange,
                 wire=cc.wire,
@@ -533,7 +535,7 @@ class InSituSession:
                 rebalance_max_moves=cc.rebalance_max_moves,
                 temporal_reuse=cc.temporal_reuse,
                 plan=self._plan, bricks=self._bricks,
-                topology=self.cfg.topology)
+                topology=self.cfg.topology), self.obs)
 
         self._temporal = (self.cfg.vdi.adaptive
                           and self.cfg.vdi.adaptive_mode == "temporal"
@@ -808,6 +810,50 @@ class InSituSession:
         with self.obs.span("fetch", frame=index, host_copy=False):
             jax.block_until_ready(out)
 
+    def _to_host(self, index: int, out):
+        """``out`` with every leaf a numpy array, inside the caller's
+        ``fetch`` span, split into what the host waits for: the frame's
+        device programs (``fetch.ready``), then the device->host copy
+        (``fetch.copy``; a cheap wrap where `_start_host_copy` already
+        landed the bytes). A leaf sharded over the mesh is copied shard
+        by shard (one ``fetch.copy`` each, attr ``shard``) and assembled
+        on the host (``fetch.concat``) — the same shard copies and slice
+        assignments ``np.asarray`` makes of it, so the bytes are the
+        same. Only a recorded run calls this: with obs off the fetch is
+        the ``np.asarray`` of each leaf, which these steps would only
+        slow down (a second wait, idle spans: 0.24 ms per frame at 128^3
+        on a v5e's host)."""
+        span = self.obs.span
+        with span("fetch.ready", frame=index):
+            jax.block_until_ready(out)
+        leaves, treedef = jax.tree_util.tree_flatten(out)
+        sharded = [isinstance(leaf, jax.Array) and leaf.is_fully_addressable
+                   and not leaf.is_fully_replicated for leaf in leaves]
+        if not any(sharded):
+            with span("fetch.copy", frame=index,
+                      bytes=sum(leaf.nbytes for leaf in leaves)):
+                host = [np.asarray(leaf) for leaf in leaves]
+            return jax.tree_util.tree_unflatten(treedef, host)
+        host = []
+        for leaf, split in zip(leaves, sharded):
+            if not split:
+                with span("fetch.copy", frame=index, bytes=leaf.nbytes):
+                    host.append(np.asarray(leaf))
+                continue
+            parts = []
+            for sh in leaf.addressable_shards:
+                if sh.replica_id == 0:
+                    with span("fetch.copy", frame=index,
+                              shard=sh.device.id, bytes=sh.data.nbytes):
+                        parts.append((sh.index, np.asarray(sh.data)))
+            with span("fetch.concat", frame=index, bytes=leaf.nbytes):
+                whole = np.empty(leaf.shape, leaf.dtype)
+                for where, part in parts:
+                    whole[where] = part
+                whole.flags.writeable = False   # as np.asarray's is
+            host.append(whole)
+        return jax.tree_util.tree_unflatten(treedef, host)
+
     def _fetch(self, index: int, out) -> dict:
         from scenery_insitu_tpu.ops.splat import SplatOutput
         meta = self._pending_meta.pop(index, None)
@@ -817,6 +863,8 @@ class InSituSession:
         tiled = bool(self.tile_sinks) \
             and self.cfg.composite.schedule == "waves"
         with self.obs.span("fetch", frame=index):
+            if self.obs.enabled:
+                out = self._to_host(index, out)     # the copy, recorded
             if isinstance(out, VDI):
                 # ONE device->host transfer; the tile delivery below and
                 # the frame payload share these buffers (a no-op wrap
@@ -1341,6 +1389,8 @@ class InSituSession:
                 metas = outs[1] if mxu else None
                 with self.obs.span("fetch", frame=start,
                                    scan_block=block):
+                    if self.obs.enabled:
+                        vdi = self._to_host(start, vdi)
                     color = np.asarray(vdi.color)
                     depth = np.asarray(vdi.depth)
                 for i in range(block):
@@ -1427,7 +1477,8 @@ class InSituSession:
                 cam = regime_camera(cam0, regime, self._slicer)
                 self.camera = cam
                 t0 = _time.perf_counter()
-                with self.obs.span("prewarm", regime=str(regime)):
+                with self.obs.span("prewarm", frame=self.frame_index,
+                                   regime=str(regime)):
                     if self.mode == "hybrid":
                         out, _ = self._hybrid_dispatch()
                     else:
@@ -1460,7 +1511,7 @@ class InSituSession:
             distributed_hybrid_step_mxu, distributed_initial_threshold_mxu)
         from scenery_insitu_tpu.sim import vortex as _vx
 
-        regime = self._slicer.choose_axis(self.camera)
+        regime = self._regime("hybrid")
         key = ("hybrid",) + regime
         if self._temporal:
             self._enter_regime(key)
@@ -1473,11 +1524,11 @@ class InSituSession:
             spec = self._slicer.make_spec(self.camera, self.sim.field.shape,
                                           self.cfg.slicer, axis_sign=regime,
                                           multiple_of=n)
-            step = distributed_hybrid_step_mxu(
+            step = scoped_step(distributed_hybrid_step_mxu(
                 self.mesh, self.tf, spec, self.cfg.vdi, self.cfg.composite,
                 radius=self.cfg.sim.particle_radius * float(self._spacing[0]),
                 stamp=5, temporal=self._temporal, plan=self._plan,
-                bricks=self._bricks, topology=self.cfg.topology)
+                bricks=self._bricks, topology=self.cfg.topology), self.obs)
             seed = (distributed_initial_threshold_mxu(
                         self.mesh, self.tf, spec, self.cfg.vdi,
                         plan=self._plan)
@@ -1520,7 +1571,7 @@ class InSituSession:
         from scenery_insitu_tpu.parallel.pipeline import (
             distributed_plain_step_mxu)
 
-        regime = self._slicer.choose_axis(self.camera)
+        regime = self._regime("plain")
         key = ("plain",) + regime
         entry = self._mxu_steps.get(key)
         if entry is None:
@@ -1532,7 +1583,7 @@ class InSituSession:
                                           self.cfg.slicer, axis_sign=regime,
                                           multiple_of=n)
             cc = self.cfg.composite
-            step = distributed_plain_step_mxu(
+            step = scoped_step(distributed_plain_step_mxu(
                 self.mesh, self.tf, spec, self.cfg.render,
                 exchange=cc.exchange,
                 wire=cc.wire,
@@ -1547,7 +1598,7 @@ class InSituSession:
                 rebalance_max_moves=cc.rebalance_max_moves,
                 temporal_reuse=cc.temporal_reuse,
                 plan=self._plan, bricks=self._bricks,
-                topology=self.cfg.topology)
+                topology=self.cfg.topology), self.obs)
             r = self.cfg.render
             slicer = self._slicer
 
@@ -1562,6 +1613,19 @@ class InSituSession:
         img, axcam = step(field, self._origin, self._spacing, self.camera)
         return warp(img, axcam, self.camera)
 
+    def _regime(self, site: str):
+        """The camera's march regime (axis, sign). `choose_axis` reads eye
+        and target on the host: where the camera lives on the device
+        (after an orbit step, after a steering message) that is a
+        device->host read which waits for the device — a span of its
+        own, so that a trace can say what it costs (and no span at all
+        in a run that records nothing: this is every frame's path)."""
+        if not self.obs.enabled:
+            return self._slicer.choose_axis(self.camera)
+        with self.obs.span("camera_readback", frame=self.frame_index,
+                           site=site):
+            return self._slicer.choose_axis(self.camera)
+
     def _mxu_step(self):
         """Jitted MXU distributed step for the camera's current march
         regime; one compilation per (axis, sign), cached (the camera may
@@ -1573,7 +1637,7 @@ class InSituSession:
             distributed_initial_threshold_mxu, distributed_vdi_step_mxu,
             distributed_vdi_step_mxu_temporal)
 
-        regime = self._slicer.choose_axis(self.camera)
+        regime = self._regime("mxu_step")
         if self._temporal or self._reuse:
             self._enter_regime(regime)
         step = self._mxu_steps.get(regime)
@@ -1591,11 +1655,11 @@ class InSituSession:
                          self.cfg.composite, plan=self._plan)
                      if self._reuse else None)
             if self._temporal:
-                inner = distributed_vdi_step_mxu_temporal(
+                inner = scoped_step(distributed_vdi_step_mxu_temporal(
                     self.mesh, self.tf, spec, self.cfg.vdi,
                     self.cfg.composite, plan=self._plan,
                     bricks=self._bricks, reuse_tol=tol,
-                    topology=self.cfg.topology)
+                    topology=self.cfg.topology), self.obs)
                 seed = distributed_initial_threshold_mxu(
                     self.mesh, self.tf, spec, self.cfg.vdi,
                     plan=self._plan, bricks=self._bricks)
@@ -1620,10 +1684,10 @@ class InSituSession:
                             field, origin, spacing, cam, thr, ru)
                     return out
             elif self._reuse:
-                inner = distributed_vdi_step_mxu(
+                inner = scoped_step(distributed_vdi_step_mxu(
                     self.mesh, self.tf, spec, self.cfg.vdi,
                     self.cfg.composite, plan=self._plan, reuse_tol=tol,
-                    topology=self.cfg.topology)
+                    topology=self.cfg.topology), self.obs)
                 # (bricks force _reuse off at _build_steps, so this
                 # branch never carries a brick map)
 
@@ -1638,10 +1702,10 @@ class InSituSession:
                         field, origin, spacing, cam, ru)
                     return out
             else:
-                step = distributed_vdi_step_mxu(
+                step = scoped_step(distributed_vdi_step_mxu(
                     self.mesh, self.tf, spec, self.cfg.vdi,
                     self.cfg.composite, plan=self._plan,
-                    bricks=self._bricks, topology=self.cfg.topology)
+                    bricks=self._bricks, topology=self.cfg.topology), self.obs)
             self._mxu_steps[regime] = step
         return step
 

@@ -956,20 +956,35 @@ def tf_from_message(msg: dict):
         colormap=msg.get("colormap", "hot"))
 
 
-def apply_steering(cam: Camera, msg: dict) -> Tuple[Camera, dict]:
+def apply_steering(cam: Camera, msg: dict,
+                   frame: Optional[int] = None) -> Tuple[Camera, dict]:
     """Apply one steering message; returns (camera, side_effects). Unknown
     types pass through in side_effects (≅ updateVis dispatch,
     DistributedVolumeRenderer.kt:747-774 — there by payload size, here by
-    the explicit type tag)."""
+    the explicit type tag). ``frame`` only labels the span."""
     import jax.numpy as jnp
 
     kind = msg.get("type")
     if kind == "camera":
+        # the defaults are read back from the camera whether or not the
+        # message carries the field; where the camera lives on the device
+        # that is a host read that waits for it — a span of its own in a
+        # recorded run, so that a trace can say what a camera message costs
+        def defaults():
+            return (msg.get("target", np.asarray(cam.target)),
+                    msg.get("up", np.asarray(cam.up)))
+
+        rec = _obs.get_recorder()
+        if rec.enabled:
+            with rec.span("camera_readback", frame=frame,
+                          site="steer_defaults"):
+                target, up = defaults()
+        else:
+            target, up = defaults()
         cam = cam._replace(
             eye=jnp.asarray(msg["eye"], jnp.float32),
-            target=jnp.asarray(msg.get("target", np.asarray(cam.target)),
-                               jnp.float32),
-            up=jnp.asarray(msg.get("up", np.asarray(cam.up)), jnp.float32))
+            target=jnp.asarray(target, jnp.float32),
+            up=jnp.asarray(up, jnp.float32))
         if "fov_y" in msg:
             cam = cam._replace(fov_y=jnp.float32(msg["fov_y"]))
         return cam, {}

@@ -575,3 +575,168 @@ def test_session_slo_breach_end_to_end():
     assert not snap["healthy"]
     assert sess.obs.counters.get("slo_breaches", 0) >= 1
     assert any(e["component"] == "slo.breach" for e in obs.ledger())
+
+
+# ------------------------------------------- one trace, one clock (ISSUE 24)
+
+_MXU = {"slicer.engine": "mxu", "vdi.adaptive_mode": "temporal"}
+_CHILDREN = {"fetch.ready": "fetch", "fetch.copy": "fetch",
+             "fetch.concat": "fetch", "camera_readback": None}
+
+
+class _Steer:
+    """An in-process steering source: one camera message per drain, eye
+    only, so that `apply_steering` reads target and up back."""
+
+    def drain(self):
+        return [{"type": "camera", "eye": [0.1, 0.6, 3.0]}]
+
+
+def _mxu_session(ranks, enabled, **kw):
+    sess = InSituSession(
+        _session_cfg(**{"obs.enabled": str(enabled).lower(), **_MXU, **kw}),
+        mesh=make_mesh(ranks), sinks=[lambda i, p: None])
+    sess.steering = _Steer()
+    return sess
+
+
+@pytest.mark.parametrize("ranks", [1, 2])
+def test_session_child_spans_nest_and_carry_frames(ranks):
+    sess = _mxu_session(ranks, True)
+    sess.run(3)
+    spans = [e for e in sess.obs.events if e["type"] == "span"]
+    assert all("frame" in s for s in spans), \
+        [s["name"] for s in spans if "frame" not in s]
+    nest = {(s["name"], s.get("parent")) for s in spans}
+    assert {("fetch.ready", "fetch"), ("fetch.copy", "fetch"),
+            ("camera_readback", "dispatch"),
+            ("camera_readback", "steer")} <= nest, nest
+    sites = {s["attrs"]["site"] for s in spans
+             if s["name"] == "camera_readback"}
+    assert sites == {"mxu_step", "steer_defaults"}
+    copies = [s for s in spans if s["name"] == "fetch.copy"]
+    assert all(s["attrs"]["bytes"] > 0 for s in copies)
+    if ranks == 1:
+        assert len(copies) == 3                 # one per frame
+    else:
+        # one per addressable shard of each of the VDI's two leaves
+        assert len(copies) == 3 * 2 * ranks
+        assert {s["attrs"]["shard"] for s in copies} == set(range(ranks))
+        assert ("fetch.concat", "fetch") in nest
+    # a child closes before its parent: the gap after it is its own
+    by_frame = {}
+    for s in spans:
+        by_frame.setdefault((s["name"], s["frame"]), s)
+    for s in spans:
+        if s["name"].startswith("fetch."):
+            par = by_frame[("fetch", s["frame"])]
+            assert par["ts"] <= s["ts"]
+            assert s["ts"] + s["dur"] <= par["ts"] + par["dur"]
+    # the step executable's scope table, taken once, for the trace join
+    table = sess.obs.hlo_scopes
+    assert "jit_step" in table
+    assert {"march", "fold"} <= set(table["jit_step"].values())
+
+
+@pytest.mark.parametrize("ranks,enabled", [(1, True), (4, True), (8, True),
+                                           (4, False)])
+def test_to_host_is_bitwise_np_asarray(ranks, enabled):
+    """The recorded fetch copies a sharded frame shard by shard and
+    assembles it on the host: the same bytes as np.asarray's."""
+    import jax
+    import numpy as np
+
+    sess = _mxu_session(ranks, enabled)
+    sess.run(1)
+    out = sess.render_frame()
+    n0 = len(sess.obs.events)
+    host = sess._to_host(1, out)
+    for got, dev in zip(jax.tree_util.tree_leaves(host),
+                        jax.tree_util.tree_leaves(out)):
+        want = np.asarray(dev)
+        assert isinstance(got, np.ndarray)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert got.tobytes() == want.tobytes()
+    names = [e["name"] for e in sess.obs.events[n0:]]
+    if not enabled:
+        assert sess.obs.events == [] and sess.obs.hlo_scopes == {}
+    elif ranks == 1:
+        assert names == ["fetch.ready", "fetch.copy"]
+    else:
+        assert names.count("fetch.copy") == 2 * ranks
+        assert names.count("fetch.concat") == 2
+
+
+@pytest.mark.parametrize("ranks", [1, 2])
+def test_obs_enabled_payload_is_bitwise_the_disabled_one(ranks):
+    payloads = []
+    for enabled in (False, True):
+        sess = _mxu_session(ranks, enabled)
+        payloads.append(sess.run(3))
+        assert bool(sess.obs.events) is enabled
+    off, on = payloads
+    for key in ("vdi_color", "vdi_depth"):
+        assert off[key].tobytes() == on[key].tobytes()
+    assert off["frame"] == on["frame"] == 2
+
+
+@pytest.fixture(scope="module")
+def profiled_annotations(tmp_path_factory):
+    """One profile of a 3-frame session taken with run(profile_dir=...)
+    and obs.enabled: {span name: [stats of each annotation of that name
+    on the /host:CPU plane]} and the recorder's own spans."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    prev = obs.get_recorder()
+    out = str(tmp_path_factory.mktemp("profile"))
+    sess = _mxu_session(1, True)
+    sess.run(1)
+    n0 = len(sess.obs.events)
+    sess.run(3, profile_dir=out)
+    spans = [e for e in sess.obs.events[n0:] if e["type"] == "span"]
+    obs.set_recorder(prev)
+    obs.clear_ledger()
+    path, = glob.glob(out + "/plugins/profile/*/*.xplane.pb")
+    names = {s["name"] for s in spans}
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in names:
+                    found.setdefault(ev.name, []).append(dict(ev.stats))
+    return found, spans
+
+
+@pytest.mark.parametrize("name", ["steer", "sim", "dispatch", "fetch",
+                                  "sinks", "fetch.ready", "fetch.copy",
+                                  "camera_readback"])
+def test_profile_holds_spans_as_annotations_with_frame(
+        profiled_annotations, name):
+    found, spans = profiled_annotations
+    mine = [s for s in spans if s["name"] == name]
+    assert mine and len(found.get(name, [])) == len(mine)
+    assert sorted(st["frame"] for st in found[name]) == \
+        sorted(s["frame"] for s in mine)
+    if name == "camera_readback":
+        assert {st["site"] for st in found[name]} == \
+            {"mxu_step", "steer_defaults"}
+
+
+def test_disabled_span_makes_no_annotation(monkeypatch):
+    from scenery_insitu_tpu.obs import recorder as rec_mod
+
+    made = []
+    monkeypatch.setattr(rec_mod, "_annotation",
+                        lambda: made.append(1) or None)
+    rec = Recorder(enabled=False)
+    with rec.span("sim", frame=0, kind="x"):
+        pass
+    assert made == [] and rec.events == []
+    rec = Recorder(enabled=True)
+    with rec.span("sim", frame=0, kind="x", arr=object()):
+        pass
+    assert made == [1] and rec.events[0]["frame"] == 0

@@ -90,9 +90,10 @@ def test_phase_scope_lands_in_compiled_hlo():
     x = jnp.ones((8, 8), jnp.float32)
     text = f.lower(x).compile().as_text()
     assert {"march", "merge"} <= scope_names(text)
-    module, ops = parse_hlo_scopes(text)
+    module, ops, inherited = parse_hlo_scopes(text)
     assert module
     assert set(ops.values()) >= {"march"}, ops
+    assert inherited <= set(ops)
 
 
 # ------------------------------------- per-builder scope presence
@@ -384,29 +385,169 @@ def test_divergence_roundtrip_from_bench_artifact(tmp_path):
     assert rep["levers"]["sim"]["measured_ms"] == 2.0
 
 
-# ------------------------------------------------ chrome-trace export
+# ------------------------------------------------ fleet-trace export
 
-def test_attribution_rides_fleet_trace(tmp_path):
-    from scenery_insitu_tpu.obs.profiler import (append_to_chrome_trace,
-                                                 publish_attribution)
+def test_publish_attribution_is_an_instant_event():
+    from scenery_insitu_tpu.obs.profiler import publish_attribution
 
     rec = obs.Recorder(enabled=True)
-    saved = obs.get_recorder()
-    obs.set_recorder(rec)
-    try:
-        attr = _attr({"march": 2.0, "exchange": 1.0})
-        publish_attribution(attr, frame=0)
-        path = str(tmp_path / "trace.json")
-        rec.export_chrome_trace(path)
-        append_to_chrome_trace(attr, path)
-    finally:
-        obs.set_recorder(saved)
-    import json
+    publish_attribution(_attr({"march": 2.0, "exchange": 1.0}), rec=rec,
+                        frame=4)
+    ev, = [e for e in rec.events if e["name"] == "phase_attribution"]
+    assert ev["type"] == "instant" and ev["frame"] == 4
+    assert ev["attrs"]["ms_march"] == 2.0
+    assert ev["attrs"]["ms_exchange"] == 1.0
 
-    doc = json.load(open(path))
-    names = [e.get("name") for e in doc["traceEvents"]]
-    assert "phase_attribution" in names
-    assert "march" in names and "exchange" in names
-    procs = [e["args"]["name"] for e in doc["traceEvents"]
-             if e.get("ph") == "M"]
-    assert "device phases (attributed)" in procs
+
+# ------------------------------- scopes that reach the chip (ISSUE 24)
+
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(step)/sitpu_march/sitpu_fold/sitpu_fold_seg_compact/pallas_call",
+     "fold"),
+    ("jit(step)/sitpu_merge/sitpu_resegment_sorted/pallas_call",
+     "resegment"),
+    ("jit(f)/sitpu_sim_step_fused/pallas_call", "sim_step"),
+    ("jit(step)/sitpu_march/sitpu_fold/reshape", "fold"),
+    ("jit(step)/sitpu_foldable/add", "foldable"),
+])
+def test_scope_of_reads_a_kernel_name_as_its_phase(op_name, want):
+    assert scope_of(op_name) == want
+
+
+@pytest.mark.parametrize("fold", ["xla", "seg", "pallas_seg"])
+def test_scopes_temporal_mxu_step_divides_march_and_fold(fold):
+    """The slicer's own scopes: resampling and shading stay `march`, what
+    the consumer does with a chunk (and the finish of its state) is
+    `fold`, whatever the fold's schedule."""
+    import dataclasses
+
+    from scenery_insitu_tpu.parallel.pipeline import (
+        distributed_initial_threshold_mxu,
+        distributed_vdi_step_mxu_temporal)
+
+    cam, vol, mesh = _cam(), _vol(), make_mesh(2)
+    spec = dataclasses.replace(_mxu_spec(cam, vol), fold=fold)
+    vcfg = dataclasses.replace(_vcfg(), adaptive_mode="temporal")
+    step = distributed_vdi_step_mxu_temporal(mesh, _tf(), spec, vcfg,
+                                             CompositeConfig())
+    seed = distributed_initial_threshold_mxu(mesh, _tf(), spec, vcfg)
+    data = shard_volume(vol.data, mesh)
+    thr = seed(data, vol.origin, vol.spacing, cam)
+    lowered = step.lower(data, vol.origin, vol.spacing, cam, thr)
+    assert {"march", "fold"} <= scope_names(
+        lowered.as_text(debug_info=True))
+    _, ops, inherited = parse_hlo_scopes(lowered.compile().as_text())
+    assert {"march", "fold"} <= {ops[i] for i in set(ops) - inherited}
+
+
+def test_scoped_step_keeps_the_table_once():
+    from scenery_insitu_tpu.obs.profiler import scoped_step
+
+    @jax.jit
+    def step(x):
+        with phase("march"):
+            y = x @ x
+        with phase("fold"):
+            return jnp.cumsum(jnp.tanh(y), 0)
+
+    x = jnp.ones((64, 64), jnp.float32)
+    assert scoped_step(step, obs.Recorder(enabled=False)) is step
+    rec = obs.Recorder(enabled=True)
+    wrapped = scoped_step(step, rec)
+    assert rec.hlo_scopes == {}
+    assert jnp.array_equal(wrapped(x), step(x))
+    table = dict(rec.hlo_scopes)
+    assert set(table) == {"jit_step"} == set(rec.hlo_inherited)
+    assert {"march", "fold"} <= set(table["jit_step"].values())
+    assert rec.hlo_inherited["jit_step"] <= set(table["jit_step"])
+    rec.hlo_scopes.clear()
+    wrapped(x)                              # read once, not per call
+    assert rec.hlo_scopes == {}
+    assert wrapped.lower(x).compile() is not None
+
+
+def test_self_times_leave_a_while_only_what_its_body_leaves():
+    from scenery_insitu_tpu.obs.profiler import _self_times
+
+    ops = [("fusion.1", 10, 10), ("while.2", 30, 20), ("fusion.3", 32, 6),
+           ("cond.4", 40, 8), ("fold.5", 41, 5), ("copy.6", 52, 6)]
+    got = {name: self_ns for name, _, self_ns in _self_times(ops)}
+    assert got == {"fusion.1": 10, "while.2": 6, "fusion.3": 6,
+                   "cond.4": 3, "fold.5": 5, "copy.6": 6}
+
+
+def test_capture_joins_from_the_xplane(tmp_path):
+    """The join runs from the .xplane.pb: op events only (what a TPU
+    needs), each phase's time split into its own and what it inherited."""
+    from scenery_insitu_tpu.obs import profiler
+
+    @jax.jit
+    def f(x):
+        with phase("march"):
+            return (x @ x).sum()
+
+    x = jnp.ones((512, 512), jnp.float32)
+    attr = ProfileCapture(frames=2, devices=1,
+                          trace_dir=str(tmp_path)).capture(f, x)
+    assert attr is not None, obs.ledger()
+    assert attr["events_joined"] > 0
+    march = attr["phases"]["march"]
+    assert march["events"] > 0
+    assert 0.0 <= march["inherited_ms"] <= march["ms"]
+    evs = list(profiler._trace_events(str(tmp_path)))
+    assert evs and all(e["ph"] == "X" and "hlo_op" in e["args"]
+                       for e in evs)
+    assert attr["events_total"] == len(evs)
+
+
+@pytest.mark.parametrize("left", ["nothing", "trace.json.gz"])
+def test_no_xplane_is_no_trace(tmp_path, left):
+    """There is one reader: a directory the profiler left no .xplane.pb
+    in has no trace, whatever else lies there."""
+    from scenery_insitu_tpu.obs import profiler
+
+    if left != "nothing":
+        run = tmp_path / "plugins" / "profile" / "2026_01_01"
+        run.mkdir(parents=True)
+        (run / f"host.{left}").write_bytes(b"")
+    with pytest.raises(FileNotFoundError):
+        list(profiler._trace_events(str(tmp_path)))
+
+
+_HLO_INHERIT = """HloModule jit_step, entry_computation_layout={()->f32[]}
+
+%body.1 (p: f32[4]) -> f32[4] {
+  %p = f32[4] parameter(0)
+  %copy.2 = f32[4] copy(%p)
+  %fusion.3 = f32[4] fusion(%copy.2), kind=kLoop, calls=%fused.9, metadata={op_name="jit(step)/sitpu_march/while/body/sitpu_fold/add"}
+  ROOT %dus.4 = f32[4] dynamic-update-slice(%fusion.3, %p)
+}
+
+%shared.5 (q: f32[4]) -> f32[4] {
+  %q = f32[4] parameter(0)
+  ROOT %copy.6 = f32[4] copy(%q)
+}
+
+ENTRY %main.7 () -> f32[] {
+  %while.8 = f32[4] while(%init), condition=%cond.0, body=%body.1, metadata={op_name="jit(step)/sitpu_march/while"}
+  %call.10 = f32[4] call(%while.8), to_apply=%shared.5, metadata={op_name="jit(step)/sitpu_merge/call"}
+  %call.11 = f32[4] call(%call.10), to_apply=%shared.5, metadata={op_name="jit(step)/sitpu_resegment/call"}
+  ROOT %copy.12 = f32[4] copy(%call.11)
+}
+"""
+
+
+@pytest.mark.parametrize("inst,want,marked", [
+    ("while.8", "march", False),        # its own op_name
+    ("fusion.3", "fold", False),        # innermost of its own path
+    ("copy.2", "march", True),          # none of its own: the while's
+    ("dus.4", "march", True),
+    ("copy.6", None, False),            # two callers that disagree
+    ("copy.12", None, False),           # the entry has no caller
+])
+def test_an_instruction_without_a_scope_inherits_and_is_marked(
+        inst, want, marked):
+    module, ops, inherited = parse_hlo_scopes(_HLO_INHERIT)
+    assert module == "jit_step"
+    assert ops.get(inst) == want
+    assert (inst in inherited) == marked

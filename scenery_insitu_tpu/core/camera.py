@@ -16,6 +16,7 @@ from __future__ import annotations
 from typing import NamedTuple, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 
 class Camera(NamedTuple):
@@ -37,6 +38,21 @@ class Camera(NamedTuple):
         f32 = lambda v: jnp.asarray(v, jnp.float32)
         return cls(f32(eye), f32(target), f32(up),
                    f32(jnp.deg2rad(fov_y_deg)), f32(near), f32(far))
+
+
+class HostPose(NamedTuple):
+    """The eye and target of ONE camera object as the host f32 values its
+    device leaves were made from. A camera that arrived as host floats (a
+    steering message) keeps them beside it, so that its holder can decide
+    on the host what depends on the pose (the march regime,
+    runtime/session.camera_regime) without reading the device copy back.
+    They belong to ``camera`` and to no other object: a holder whose
+    camera is a different object (orbited, restored, assigned by a
+    caller) has no host values for it."""
+
+    camera: Camera
+    eye: np.ndarray         # f32[3]
+    target: np.ndarray      # f32[3]
 
 
 def look_at(eye: jnp.ndarray, target: jnp.ndarray, up: jnp.ndarray) -> jnp.ndarray:

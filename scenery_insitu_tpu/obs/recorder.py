@@ -330,6 +330,8 @@ _COUNTER_REGISTRY: Dict[str, str] = {
                          "executed",
     "rebalance_steps_built": "a render step was compiled for a "
                              "rebalanced partition",
+    "regime_host": "a frame's march regime was decided from the host "
+                   "values of a steered camera (no device read)",
     "regime_switches": "the session switched between scan and eager "
                        "dispatch regimes",
     "reuse_steps_built": "a temporal-reuse render step (carried "

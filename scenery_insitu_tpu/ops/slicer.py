@@ -128,12 +128,20 @@ def resolve_engine(engine: str) -> str:
     return engine
 
 
+def march_axis(eye, target) -> Tuple[int, int]:
+    """(axis, sign) of the volume axis most aligned with target - eye, in
+    float64 on the host. A leaf that lives on the device is read back
+    here; a session whose camera arrived as host floats passes those
+    (runtime/session.camera_regime)."""
+    d = np.asarray(target, np.float64) - np.asarray(eye, np.float64)
+    axis = int(np.argmax(np.abs(d)))
+    return axis, (1 if d[axis] >= 0 else -1)
+
+
 def choose_axis(cam: Camera) -> Tuple[int, int]:
     """Pick the volume axis most aligned with the view direction (host-side,
     concrete camera). Returns (axis, sign)."""
-    d = np.asarray(cam.target, np.float64) - np.asarray(cam.eye, np.float64)
-    axis = int(np.argmax(np.abs(d)))
-    return axis, (1 if d[axis] >= 0 else -1)
+    return march_axis(cam.eye, cam.target)
 
 
 def make_spec(cam: Camera, vol_shape: Tuple[int, int, int],

@@ -53,6 +53,7 @@ class SceneSession:
         self.tf = tf or for_dataset(self.cfg.runtime.dataset)
         self.camera = camera or Camera.create(
             (0.0, 0.6, 3.0), fov_y_deg=50.0, near=0.3, far=20.0)
+        self._host_pose = None      # set by steer_session
         self.sinks: List[Sink] = list(sinks)
         # same per-callable failure isolation as InSituSession (sinks +
         # on_steer run behind the guard; see drain_steering)
@@ -262,7 +263,9 @@ class SceneSession:
         bounds + spacing (a driver that repartitions, moves grids, or
         changes resolution triggers exactly one recompile; same-extent
         timestep updates reuse the cache)."""
-        regime = self._slicer.choose_axis(self.camera)
+        from scenery_insitu_tpu.runtime.session import camera_regime
+
+        regime = camera_regime(self, "scene_step")
         gs = self.scene.grids
         sig = tuple((tuple(g.volume.data.shape), g.ghost_lo, g.ghost_hi)
                     for g in gs)

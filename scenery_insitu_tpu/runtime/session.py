@@ -52,16 +52,51 @@ def steer_session(sess, msg: dict) -> None:
     the in-process path (scenario steering hooks —
     scenery_insitu_tpu/scenarios) route through this same consumer.
 
+    A camera message stays on the host: the session keeps the message's
+    own eye and target beside the camera it now holds (``_host_pose``),
+    and `camera_regime` decides from them.
+
     on_steer callbacks run behind the session's SinkGuard: an exception
     in one callback must not kill the drain (or the run) — a callback
     failing ``fault.max_sink_failures`` consecutive times is quarantined
     on the ``session.sink`` ledger."""
-    from scenery_insitu_tpu.runtime.streaming import apply_steering
-    sess.camera, other = apply_steering(sess.camera, msg,
-                                        frame=sess.frame_index)
-    for kind_msg in other.values():
-        sess._sink_guard.run(sess.on_steer, kind_msg,
-                             kind="on_steer callback")
+    if msg.get("type") == "camera":
+        from scenery_insitu_tpu.runtime.streaming import steer_camera
+        sess._host_pose = steer_camera(sess.camera, msg, host_pose(sess),
+                                       frame=sess.frame_index)
+        sess.camera = sess._host_pose.camera
+    else:
+        sess._sink_guard.run(sess.on_steer, msg, kind="on_steer callback")
+
+
+def host_pose(sess):
+    """The host values of the eye and target of the camera ``sess`` holds
+    (core/camera.HostPose), or None where it has none for THAT object: a
+    camera whose leaves were computed on the device (the benchmark orbit,
+    a prewarm's synthetic camera), restored from a checkpoint or assigned
+    by a caller."""
+    pose = sess._host_pose
+    return pose if pose is not None and pose.camera is sess.camera else None
+
+
+def camera_regime(sess, site: str):
+    """The march regime (axis, sign) of the camera ``sess`` holds, for
+    both sessions. A camera that arrived as host floats is decided from
+    them (counter ``regime_host``): nothing is read from the device. Any
+    other camera goes through `choose_axis`, which reads eye and target
+    back — a device->host read that waits for the device where the
+    leaves are new there (after an orbit step), a span of its own so
+    that a trace can say what it costs (and no span at all in a run that
+    records nothing: this is every frame's path)."""
+    pose = host_pose(sess)
+    if pose is not None:
+        sess.obs.count("regime_host")
+        return sess._slicer.march_axis(pose.eye, pose.target)
+    if not sess.obs.enabled:
+        return sess._slicer.choose_axis(sess.camera)
+    with sess.obs.span("camera_readback", frame=sess.frame_index,
+                       site=site):
+        return sess._slicer.choose_axis(sess.camera)
 
 
 def drain_steering(sess) -> None:
@@ -387,6 +422,7 @@ class InSituSession:
             else self.cfg.runtime.dataset)
         self.camera = camera or Camera.create(
             (0.0, 0.6, 3.0), fov_y_deg=50.0, near=0.3, far=20.0)
+        self._host_pose = None      # set by steer_session
         self.sinks: List[Sink] = list(sinks)
         # session failure isolation (docs/ROBUSTNESS.md): every frame
         # sink, tile sink and on_steer callback runs behind this guard —
@@ -1511,7 +1547,7 @@ class InSituSession:
             distributed_hybrid_step_mxu, distributed_initial_threshold_mxu)
         from scenery_insitu_tpu.sim import vortex as _vx
 
-        regime = self._regime("hybrid")
+        regime = camera_regime(self, "hybrid")
         key = ("hybrid",) + regime
         if self._temporal:
             self._enter_regime(key)
@@ -1571,7 +1607,7 @@ class InSituSession:
         from scenery_insitu_tpu.parallel.pipeline import (
             distributed_plain_step_mxu)
 
-        regime = self._regime("plain")
+        regime = camera_regime(self, "plain")
         key = ("plain",) + regime
         entry = self._mxu_steps.get(key)
         if entry is None:
@@ -1613,19 +1649,6 @@ class InSituSession:
         img, axcam = step(field, self._origin, self._spacing, self.camera)
         return warp(img, axcam, self.camera)
 
-    def _regime(self, site: str):
-        """The camera's march regime (axis, sign). `choose_axis` reads eye
-        and target on the host: where the camera lives on the device
-        (after an orbit step, after a steering message) that is a
-        device->host read which waits for the device — a span of its
-        own, so that a trace can say what it costs (and no span at all
-        in a run that records nothing: this is every frame's path)."""
-        if not self.obs.enabled:
-            return self._slicer.choose_axis(self.camera)
-        with self.obs.span("camera_readback", frame=self.frame_index,
-                           site=site):
-            return self._slicer.choose_axis(self.camera)
-
     def _mxu_step(self):
         """Jitted MXU distributed step for the camera's current march
         regime; one compilation per (axis, sign), cached (the camera may
@@ -1637,7 +1660,7 @@ class InSituSession:
             distributed_initial_threshold_mxu, distributed_vdi_step_mxu,
             distributed_vdi_step_mxu_temporal)
 
-        regime = self._regime("mxu_step")
+        regime = camera_regime(self, "mxu_step")
         if self._temporal or self._reuse:
             self._enter_regime(regime)
         step = self._mxu_steps.get(regime)

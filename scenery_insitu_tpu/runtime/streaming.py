@@ -50,7 +50,7 @@ import numpy as np
 from scenery_insitu_tpu import obs as _obs
 from scenery_insitu_tpu.obs.collector import lineage, trace_ctx
 from scenery_insitu_tpu.config import DeltaConfig, FaultConfig
-from scenery_insitu_tpu.core.camera import Camera
+from scenery_insitu_tpu.core.camera import Camera, HostPose
 from scenery_insitu_tpu.core.vdi import VDI, VDIMetadata
 from scenery_insitu_tpu.io.vdi_io import compress, decompress
 from scenery_insitu_tpu.utils.retry import Backoff
@@ -956,38 +956,43 @@ def tf_from_message(msg: dict):
         colormap=msg.get("colormap", "hot"))
 
 
+def steer_camera(cam: Camera, msg: dict, pose: Optional[HostPose] = None,
+                 frame: Optional[int] = None) -> HostPose:
+    """The camera a "camera" message describes, with the host values of
+    its eye and target beside it. It is built from the message's own
+    values: they become f32 on the host and reach the device in ONE
+    transfer, and a field the message does not carry keeps the leaf
+    ``cam`` has. Only a target the message lacks has to be known on the
+    host: from ``pose`` (the host values of ``cam``, where its holder has
+    them), else by reading ``cam.target`` back, which waits for the
+    device where the leaf lives there — a span of its own in a recorded
+    run (``frame`` only labels it)."""
+    import jax
+
+    host = {k: np.asarray(msg[k], np.float32)
+            for k in ("eye", "target", "up", "fov_y") if k in msg}
+    target = host.get("target")
+    if target is None and pose is not None:
+        target = pose.target
+    elif target is None:
+        with _obs.get_recorder().span("camera_readback", frame=frame,
+                                      site="steer_defaults"):
+            target = np.asarray(cam.target)
+    return HostPose(cam._replace(**jax.device_put(host)), host["eye"],
+                    target)
+
+
 def apply_steering(cam: Camera, msg: dict,
                    frame: Optional[int] = None) -> Tuple[Camera, dict]:
     """Apply one steering message; returns (camera, side_effects). Unknown
     types pass through in side_effects (≅ updateVis dispatch,
     DistributedVolumeRenderer.kt:747-774 — there by payload size, here by
-    the explicit type tag). ``frame`` only labels the span."""
-    import jax.numpy as jnp
-
+    the explicit type tag). ``frame`` only labels the span. A session
+    keeps the steered camera's host values too (`steer_camera`, through
+    runtime/session.steer_session)."""
     kind = msg.get("type")
     if kind == "camera":
-        # the defaults are read back from the camera whether or not the
-        # message carries the field; where the camera lives on the device
-        # that is a host read that waits for it — a span of its own in a
-        # recorded run, so that a trace can say what a camera message costs
-        def defaults():
-            return (msg.get("target", np.asarray(cam.target)),
-                    msg.get("up", np.asarray(cam.up)))
-
-        rec = _obs.get_recorder()
-        if rec.enabled:
-            with rec.span("camera_readback", frame=frame,
-                          site="steer_defaults"):
-                target, up = defaults()
-        else:
-            target, up = defaults()
-        cam = cam._replace(
-            eye=jnp.asarray(msg["eye"], jnp.float32),
-            target=jnp.asarray(target, jnp.float32),
-            up=jnp.asarray(up, jnp.float32))
-        if "fov_y" in msg:
-            cam = cam._replace(fov_y=jnp.float32(msg["fov_y"]))
-        return cam, {}
+        return steer_camera(cam, msg, frame=frame).camera, {}
     return cam, {kind: msg}
 
 

@@ -585,11 +585,18 @@ _CHILDREN = {"fetch.ready": "fetch", "fetch.copy": "fetch",
 
 
 class _Steer:
-    """An in-process steering source: one camera message per drain, eye
-    only, so that `apply_steering` reads target and up back."""
+    """An in-process steering source: a camera message on every second
+    drain, eye only. With the session's orbit on, the camera such a
+    message meets was computed on the device, so `steer_camera` reads its
+    target back; so does the frame between two messages, at dispatch."""
+
+    def __init__(self):
+        self.drains = 0
 
     def drain(self):
-        return [{"type": "camera", "eye": [0.1, 0.6, 3.0]}]
+        self.drains += 1
+        return ([{"type": "camera", "eye": [0.1, 0.6, 3.0]}]
+                if self.drains % 2 else [])
 
 
 def _mxu_session(ranks, enabled, **kw):
@@ -597,6 +604,7 @@ def _mxu_session(ranks, enabled, **kw):
         _session_cfg(**{"obs.enabled": str(enabled).lower(), **_MXU, **kw}),
         mesh=make_mesh(ranks), sinks=[lambda i, p: None])
     sess.steering = _Steer()
+    sess.orbit_rate = 0.01
     return sess
 
 

@@ -300,6 +300,9 @@ _COUNTER_REGISTRY: Dict[str, str] = {
                         "stayed incomplete past its window",
     "frames_eager_dispatch": "a frame went through the eager per-frame "
                              "dispatch path",
+    "frames_fetched_sharded": "a frame sharded over the mesh was brought "
+                              "to the host shard by shard and assembled "
+                              "there (InSituSession._to_host)",
     "frames_scan_dispatch": "a frame was delivered from a compiled scan "
                             "block",
     "head_degraded_frames": "the head composited a frame with >= 1 rank "

@@ -19,9 +19,12 @@ externally by supplying a custom sim adapter (anything with
 
 from __future__ import annotations
 
+import contextlib
 import os
+import sys
 import time
 from collections import deque
+from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, List, Optional, Sequence
 
 import jax
@@ -345,6 +348,83 @@ class HybridSimAdapter:
         return self.flow.field
 
 
+def _no_span(name, **_):
+    return contextlib.nullcontext()
+
+
+def _holders(bufs: list, i: int) -> int:
+    return sys.getrefcount(bufs[i])
+
+
+# what `_holders` reads of a buffer that only its list holds
+_UNHELD = _holders([object()], 0)
+
+
+class HostFrames:
+    """Assembles frames that are sharded over the mesh into host arrays,
+    and uses an array again once nobody else holds it.
+
+    On a v5e's host the first touch of a fresh 157 MB array's pages is
+    nine tenths of a serial assembly (264 ms against 22 into pages
+    already touched), and sixteen threads copying disjoint blocks make
+    that 112 and 8 (PERF.md, PR 27; numpy releases the GIL in a
+    plain-dtype copy). A sink may keep what it was handed for as long as
+    it likes: an array is taken again only when this list holds its one
+    remaining reference, and every numpy view, slice or buffer export of
+    an array that owns its data holds one (numpy collapses a view's
+    ``base`` to the owner). A frame that a sink does keep costs one fresh
+    array, which the threads keep inside a four-rank frame's time."""
+
+    WORKERS = 16
+
+    def __init__(self):
+        self._bufs: list = []
+        self._threads = None        # started by the first sharded frame
+
+    def take(self, shape, dtype) -> np.ndarray:
+        """A writeable C-contiguous array of this shape and dtype whose
+        contents are undefined. Free arrays it does not hand out are let
+        go — the others of this size at once, those of another size when
+        it has to allocate — so the list never holds more than the frames
+        alive at one time."""
+        bufs = self._bufs
+        free = [i for i in range(len(bufs)) if _holders(bufs, i) == _UNHELD]
+        same = [i for i in free
+                if bufs[i].shape == shape and bufs[i].dtype == dtype]
+        drop = set(same[1:] if same else free)
+        self._bufs = [b for i, b in enumerate(bufs) if i not in drop]
+        if same:
+            buf = bufs[same[0]]
+            buf.flags.writeable = True
+            return buf
+        buf = np.empty(shape, dtype)
+        self._bufs.append(buf)
+        return buf
+
+    def assemble(self, shape, dtype, parts) -> np.ndarray:
+        """The read-only array of ``shape`` whose block ``index`` holds
+        ``block`` for each ``(index, block)`` of ``parts`` (disjoint, and
+        together the whole array): the slice assignments ``np.asarray``
+        makes of a sharded ``jax.Array``, each cut along its leading axis
+        so that all the threads have one."""
+        whole = self.take(shape, dtype)
+        if self._threads is None:
+            self._threads = ThreadPoolExecutor(
+                self.WORKERS, thread_name_prefix="sitpu-fetch")
+        cuts = max(1, self.WORKERS // len(parts))
+        dsts, srcs = [], []
+        for index, block in parts:
+            dst = whole[index]
+            step = -(-len(block) // cuts)
+            for k in range(0, len(block), step):
+                dsts.append(dst[k:k + step])
+                srcs.append(block[k:k + step])
+        for _ in self._threads.map(np.copyto, dsts, srcs):
+            pass                    # reading each result raises its error
+        whole.flags.writeable = False       # as np.asarray's is
+        return whole
+
+
 class InSituSession:
     def __init__(self, cfg: Optional[FrameworkConfig] = None,
                  mesh=None, camera: Optional[Camera] = None,
@@ -472,6 +552,7 @@ class InSituSession:
         self.steering = None   # optional streaming.SteeringEndpoint
         self.on_steer: List[Callable[[dict], None]] = []  # non-camera msgs
         self._pending_meta = {}  # frame index -> VDIMetadata at dispatch
+        self._host_frames = HostFrames()    # see _to_host
 
         from scenery_insitu_tpu.ops import slicer as _slicer
         self._slicer = _slicer
@@ -735,8 +816,6 @@ class InSituSession:
         lax.scan executable per launch (parallel/pipeline.frame_scan) —
         same frames, one dispatch — for supported modes; unsupported
         modes log the downgrade and run the eager loop."""
-        import contextlib
-
         if self.cfg.runtime.scan_frames > 1:
             ok, reason = self._scan_supported()
             if ok:
@@ -847,21 +926,30 @@ class InSituSession:
             jax.block_until_ready(out)
 
     def _to_host(self, index: int, out):
-        """``out`` with every leaf a numpy array, inside the caller's
-        ``fetch`` span, split into what the host waits for: the frame's
-        device programs (``fetch.ready``), then the device->host copy
-        (``fetch.copy``; a cheap wrap where `_start_host_copy` already
-        landed the bytes). A leaf sharded over the mesh is copied shard
-        by shard (one ``fetch.copy`` each, attr ``shard``) and assembled
-        on the host (``fetch.concat``) — the same shard copies and slice
-        assignments ``np.asarray`` makes of it, so the bytes are the
-        same. Only a recorded run calls this: with obs off the fetch is
-        the ``np.asarray`` of each leaf, which these steps would only
-        slow down (a second wait, idle spans: 0.24 ms per frame at 128^3
-        on a v5e's host)."""
-        span = self.obs.span
-        with span("fetch.ready", frame=index):
-            jax.block_until_ready(out)
+        """``out`` with every leaf a read-only numpy array holding the
+        bytes ``np.asarray`` would give, inside the caller's ``fetch``
+        span. The one routine for a frame sharded over the mesh, recorded
+        or not; a one-device frame comes here only in a recorded run.
+
+        A leaf sharded over the mesh is copied shard by shard (``np.asarray``
+        of a shard is a wrap where `_start_host_copy` already landed the
+        bytes) and assembled on the host by the slice assignments
+        ``np.asarray(leaf)`` would make — by `HostFrames`: into an array
+        of an earlier frame rather than a fresh one, on several threads,
+        which is what makes the assembly cheap enough that the device
+        paces a four-rank frame.
+
+        ``obs.enabled`` only decides whether spans open: ``fetch.ready``
+        (``jax.block_until_ready``: the frame's device programs; the one
+        extra device wait of a recorded run), ``fetch.copy`` (attr
+        ``bytes``; one per shard on a mesh, attr ``shard``), and
+        ``fetch.concat`` around the host assembly of each sharded leaf."""
+        if self.obs.enabled:
+            span = self.obs.span
+            with span("fetch.ready", frame=index):
+                jax.block_until_ready(out)
+        else:
+            span = _no_span
         leaves, treedef = jax.tree_util.tree_flatten(out)
         sharded = [isinstance(leaf, jax.Array) and leaf.is_fully_addressable
                    and not leaf.is_fully_replicated for leaf in leaves]
@@ -870,6 +958,7 @@ class InSituSession:
                       bytes=sum(leaf.nbytes for leaf in leaves)):
                 host = [np.asarray(leaf) for leaf in leaves]
             return jax.tree_util.tree_unflatten(treedef, host)
+        self.obs.count("frames_fetched_sharded")
         host = []
         for leaf, split in zip(leaves, sharded):
             if not split:
@@ -883,11 +972,8 @@ class InSituSession:
                               shard=sh.device.id, bytes=sh.data.nbytes):
                         parts.append((sh.index, np.asarray(sh.data)))
             with span("fetch.concat", frame=index, bytes=leaf.nbytes):
-                whole = np.empty(leaf.shape, leaf.dtype)
-                for where, part in parts:
-                    whole[where] = part
-                whole.flags.writeable = False   # as np.asarray's is
-            host.append(whole)
+                host.append(self._host_frames.assemble(
+                    leaf.shape, leaf.dtype, parts))
         return jax.tree_util.tree_unflatten(treedef, host)
 
     def _fetch(self, index: int, out) -> dict:
@@ -899,8 +985,9 @@ class InSituSession:
         tiled = bool(self.tile_sinks) \
             and self.cfg.composite.schedule == "waves"
         with self.obs.span("fetch", frame=index):
-            if self.obs.enabled:
-                out = self._to_host(index, out)     # the copy, recorded
+            if self._n_ranks > 1 or self.obs.enabled:
+                # a frame on a mesh, or a recorded run (the copy, timed)
+                out = self._to_host(index, out)
             if isinstance(out, VDI):
                 # ONE device->host transfer; the tile delivery below and
                 # the frame payload share these buffers (a no-op wrap
@@ -1425,7 +1512,7 @@ class InSituSession:
                 metas = outs[1] if mxu else None
                 with self.obs.span("fetch", frame=start,
                                    scan_block=block):
-                    if self.obs.enabled:
+                    if self._n_ranks > 1 or self.obs.enabled:
                         vdi = self._to_host(start, vdi)
                     color = np.asarray(vdi.color)
                     depth = np.asarray(vdi.depth)

@@ -4,9 +4,11 @@
   composited VDI (≅ VDICompositor.comp). The reference does a sequential
   k-way merge with per-process front pointers (VDICompositor.comp:58-91);
   on TPU we instead flatten to N*K segments per pixel, sort by start depth
-  (one vectorized ``jnp.sort`` — XLA lowers to a bitonic network, no
-  divergence), and fold the sorted stream through the shared supersegment
-  state machine for re-segmentation.
+  (``sort_stream``: ONE stable multi-operand ``lax.sort`` keyed on the
+  start depth, the five payload planes riding through it as further
+  operands — a sorting network, no divergence, and no permutation to
+  apply afterwards), and fold the sorted stream through the shared
+  supersegment state machine for re-segmentation.
 - ``merge_vdis_pairwise``: the ring-exchange counterpart (docs/PERF.md
   "Exchange modes"): two per-pixel depth-SORTED segment streams interleave
   by searchsorted-style rank selection — no bitonic sort, peak live state
@@ -99,11 +101,20 @@ def sort_stream(flat_c: jnp.ndarray, flat_d: jnp.ndarray):
     ``flat_c`` f32[M, 4, H, W], ``flat_d`` f32[M, 2, H, W] → the same
     shapes sorted by start depth per pixel (empty slots carry +inf start
     so they sort to the back) with non-live slots' colors zeroed (they
-    may carry stale payloads)."""
-    order = jnp.argsort(flat_d[:, 0], axis=0)              # [M, H, W]
-    sc = jnp.take_along_axis(flat_c, order[:, None], axis=0)
-    sd = jnp.take_along_axis(flat_d, order[:, None], axis=0)
-    live = jnp.isfinite(sd[:, 0])
+    may carry stale payloads). Equal start depths keep their input order
+    (rank order — the ring path's local sort relies on it).
+
+    The payload travels with the key: one stable ``lax.sort`` whose only
+    key is the start depth and whose other operands are R, G, B, A and
+    the end depth, so no permutation is materialised or applied (a
+    gather by a sorted index gives the same bits, but runs serially on a
+    TPU: 130 ms against 3 for [64, 4|2, 640, 160] on a v5e, PERF.md §6)."""
+    start, *rgba, end = jax.lax.sort(
+        (flat_d[:, 0], *(flat_c[:, i] for i in range(4)), flat_d[:, 1]),
+        dimension=0, is_stable=True, num_keys=1)
+    sc = jnp.stack(rgba, axis=1)
+    sd = jnp.stack([start, end], axis=1)
+    live = jnp.isfinite(start)
     return jnp.where(live[:, None], sc, 0.0), sd
 
 

@@ -1,8 +1,10 @@
 """Compositing tests: the dump->recomposite->compare loop the reference runs
 by eye (VDICompositingExample) becomes numeric golden checks here."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from scenery_insitu_tpu.config import CompositeConfig, RenderConfig, VDIConfig
 from scenery_insitu_tpu.core.camera import Camera
@@ -10,7 +12,8 @@ from scenery_insitu_tpu.core.transfer import TransferFunction
 from scenery_insitu_tpu.core.vdi import VDI, render_vdi_same_view
 from scenery_insitu_tpu.core.volume import Volume, procedural_volume
 from scenery_insitu_tpu.ops.composite import (composite_depth_min,
-                                              composite_plain, composite_vdis)
+                                              composite_plain, composite_vdis,
+                                              sort_stream)
 from scenery_insitu_tpu.ops.raycast import raycast
 from scenery_insitu_tpu.ops.vdi_gen import generate_vdi
 from scenery_insitu_tpu.utils.image import psnr
@@ -146,3 +149,66 @@ def test_n1_composite_is_identity_pad():
     b = render_vdi_same_view(slow)
     q = psnr(np.asarray(b), np.asarray(a))
     assert q > 40.0, f"PSNR {q:.1f} dB"
+
+
+def _stream(m: int, seed: int, h: int = 12, w: int = 20):
+    """A stacked stream as the exchange delivers it: ~40 % empty slots at
+    +inf carrying stale colours, whole pixels empty, and two slot rows
+    with identical start depths (rows 2 and 9, distinct payloads)."""
+    rng = np.random.default_rng(seed)
+    start = rng.uniform(0.5, 9.0, (m, h, w)).astype(np.float32)
+    start[9] = start[2]
+    start[rng.random((m, h, w)) < 0.4] = np.inf
+    start[:, :2, :5] = np.inf                         # whole pixels empty
+    end = np.where(np.isinf(start), np.inf, start + 0.07).astype(np.float32)
+    color = rng.uniform(0.01, 1.0, (m, 4, h, w)).astype(np.float32)
+    return jnp.asarray(color), jnp.asarray(np.stack([start, end], axis=1))
+
+
+@pytest.mark.parametrize("m", [16, 32, 64])
+def test_sort_stream_bit_equal_to_argsort_gather(m):
+    """`sort_stream` carries the payload through its one stable sort; the
+    result is bit-equal to sorting a permutation and gathering with it
+    (the form it replaced): same order, ties in input order, +inf depths
+    intact, stale colours of empty slots zeroed."""
+    color, depth = _stream(m, seed=m)
+
+    order = jnp.argsort(depth[:, 0], axis=0)          # stable
+    want_c = jnp.take_along_axis(color, order[:, None], axis=0)
+    want_d = jnp.take_along_axis(depth, order[:, None], axis=0)
+    want_c = jnp.where(jnp.isfinite(want_d[:, 0])[:, None], want_c, 0.0)
+
+    got_c, got_d = jax.jit(sort_stream)(color, depth)
+    np.testing.assert_array_equal(np.asarray(got_c), np.asarray(want_c))
+    np.testing.assert_array_equal(np.asarray(got_d), np.asarray(want_d))
+
+    got_c, got_d = np.asarray(got_c), np.asarray(got_d)
+    assert np.isinf(got_d[:, :, :2, :5]).all()        # empty pixels stay so
+    assert (np.moveaxis(got_c, 1, -1)[np.isinf(got_d[:, 0])] == 0.0).all()
+    # the tie: wherever rows 2 and 9 are both live they are adjacent in the
+    # output, row 2's payload first
+    c_in, d_in = np.asarray(color), np.asarray(depth)
+    both = np.isfinite(d_in[2, 0]) & np.isfinite(d_in[9, 0])
+    assert both.any()
+    first = np.argmax(got_d[:, 0] == d_in[2, 0][None], axis=0)   # [h, w]
+    ys, xs = np.nonzero(both)
+    np.testing.assert_array_equal(got_c[first[ys, xs], :, ys, xs],
+                                  c_in[2][:, ys, xs].T)
+    np.testing.assert_array_equal(got_c[first[ys, xs] + 1, :, ys, xs],
+                                  c_in[9][:, ys, xs].T)
+
+
+def test_composite_n4_lowers_without_gather():
+    """The four-rank merge applies no permutation: the lowered program of
+    `composite_vdis` for n = 4 holds one multi-operand sort (key + five
+    payload planes) and no gather."""
+    colors = jax.ShapeDtypeStruct((4, 16, 4, 16, 128), jnp.float32)
+    depths = jax.ShapeDtypeStruct((4, 16, 2, 16, 128), jnp.float32)
+    cfg = CompositeConfig(max_output_supersegments=16)
+    text = jax.jit(lambda c, d: composite_vdis(c, d, cfg)).lower(
+        colors, depths).as_text()
+    assert "gather" not in text
+    sorts = [ln for ln in text.splitlines() if '"stablehlo.sort"(' in ln]
+    assert len(sorts) == 1 and "is_stable = true" in sorts[0]
+    operands = sorts[0].split('"stablehlo.sort"(')[1].split(")")[0]
+    assert len(operands.split(",")) == 6

@@ -35,13 +35,11 @@ def main():
     ap.add_argument("--k", type=int, default=8)
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--sim-steps", type=int, default=5)
-    # HBM-traffic lever A/Bs (ISSUE 1): bf16 marched-volume copy,
-    # time-fused sim stencil, and the scanned frame loop (N frames in
-    # one executable; 0 = skip that measurement)
+    # HBM-traffic lever A/Bs (ISSUE 1): bf16 marched-volume copy and
+    # time-fused sim stencil
     ap.add_argument("--render-dtype", choices=("f32", "bf16"),
                     default="f32")
     ap.add_argument("--sim-fused", type=int, default=0)
-    ap.add_argument("--scan-frames", type=int, default=0)
     # fleet-telemetry overhead guard (ISSUE 17): A/B the per-frame cost
     # of the obs plane (span + lineage + SLO observe) and fail if the
     # enabled path costs more than --obs-budget over the disabled one
@@ -103,8 +101,7 @@ def main():
     if sim_fused and n > 1:
         # the fused Pallas stencil's periodic wrap is per-buffer, so it
         # cannot run on z-sharded state (sim/pallas_stencil.py) — the
-        # multi-rank sim lever is the roll path, same as the session's
-        # scan guard
+        # multi-rank sim lever is the roll path, as in the session
         print("[phase_bench] --sim-fused needs a 1-rank mesh (the Pallas "
               "stencil is not partitionable); using the roll path",
               file=sys.stderr)
@@ -184,35 +181,6 @@ def main():
 
     ms = {k: round(t / args.iters * 1000, 2) for k, t in phases.items()}
 
-    # scanned frame loop: sim+render frames rolled into ONE executable
-    # (the session's scan_frames path) — per-frame ms against the eager
-    # fused_total isolates the per-launch dispatch tax
-    scan_ms = None
-    if args.scan_frames > 1:
-        from scenery_insitu_tpu.parallel.pipeline import frame_scan
-
-        params = gs.GrayScottParams.create()
-        # the same advance the eager phases measured (sim_fused already
-        # downgraded to the roll path on multi-rank meshes above), so
-        # scanloop isolates the launch lever and nothing else
-        runner = frame_scan(
-            fused, lambda s: advance(s, args.sim_steps),
-            args.scan_frames)
-        state = gs.GrayScott(u, v, params)
-        # warm TWICE: the chained state's sharding/layout can differ
-        # between the fresh inputs and the runner's own outputs, and the
-        # second compilation must not land in the timed window
-        for _ in range(2):
-            (state, _, _), outs = runner(state, origin, spacing, cam,
-                                         jnp.float32(0.0))
-        jax.block_until_ready(outs[0].color)               # warm
-        t0 = time.perf_counter()
-        (state, _, _), outs = runner(state, origin, spacing, cam,
-                                     jnp.float32(0.0))
-        jax.block_until_ready(outs[0].color)
-        scan_ms = round((time.perf_counter() - t0)
-                        / args.scan_frames * 1000, 2)
-
     # obs plane A/B: the identical warm fused frame, once under a
     # disabled Recorder and once under an enabled one doing everything
     # Session.run does per frame (span + lineage instant + SLO observe).
@@ -255,9 +223,8 @@ def main():
         "fused_render_ms": ms["fused_total"],
         "overlap_gain": round(split_render / max(ms["fused_total"], 1e-9), 2),
         "levers": {"render_dtype": args.render_dtype,
-                   "sim_fused": sim_fused,    # EFFECTIVE (multi-rank
-                   "scan_frames": args.scan_frames,  # downgrades to roll)
-                   "scanloop_ms_per_frame": scan_ms},
+                   # EFFECTIVE (multi-rank downgrades to roll)
+                   "sim_fused": sim_fused},
         "obs_overhead": obs_ab,
         # device-cost truth + everything that did not run as configured
         # (same record shape bench.py embeds — see docs/OBSERVABILITY.md)

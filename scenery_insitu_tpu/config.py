@@ -539,14 +539,7 @@ class RuntimeConfig:
     benchmark_frames: int = 100
     stats_window: int = 100         # frames between timer-stat dumps
     dataset: str = "procedural"
-    # Frames rolled into ONE lax.scan-based executable per launch (0/1 =
-    # eager per-frame dispatch). Amortizes the per-launch dispatch tax
-    # (docs/PERF.md H2) at the cost of steering/camera latency: steering
-    # drains and regime changes only take effect at block boundaries.
-    # Applies to volume-sim VDI sessions; other modes fall back to the
-    # eager loop (runtime/session.py logs the downgrade).
-    scan_frames: int = 0
-    # Device->host pipeline depth of the eager loop (docs/PERF.md "Async
+    # Device->host pipeline depth of the frame loop (docs/PERF.md "Async
     # delivery"): how many dispatched frames may have their host copies
     # in flight before the loop blocks on the oldest. 1 = the historical
     # one-deep overlap (bitwise the pre-async behavior); deeper values

@@ -5,9 +5,8 @@ spans and machine-greppable ``#COMP:rank:iter:sec#`` markers
 (DistributedVolumeRenderer.kt:85-108, VDICompositingTest.kt:301);
 ``runtime/timers.py`` reproduces that. This module unifies those wall
 -clock spans with everything the timers cannot say: WHICH frame and rank
-a span belongs to, how often each executable (re)compiled, whether the
-scan or the eager loop actually dispatched, and — through the fallback
-ledger — every configured-but-degraded path of the run, as one
+a span belongs to, how often each executable (re)compiled, and — through
+the fallback ledger — every configured-but-degraded path of the run, as one
 machine-readable record.
 
 Three layers:
@@ -116,8 +115,7 @@ _LEDGER_REGISTRY: Dict[str, str] = {
                        "transfer function renders instead of a tuned one",
     "delta.reuse": "temporal fragment reuse requested where no marched "
                    "VDI fragment can be carried (gather/hybrid/plain/"
-                   "particle modes, scan blocks); every frame "
-                   "re-marches",
+                   "particle modes); every frame re-marches",
     "delivery.drain": "teardown drain of the async delivery queue timed "
                       "out; undelivered frames were abandoned so "
                       "shutdown could proceed",
@@ -207,10 +205,6 @@ _LEDGER_REGISTRY: Dict[str, str] = {
                           "rebuilt the compiled steps (a repeated TF "
                           "restores its cached steps instead — the "
                           "recompile-or-reuse contract)",
-    "session.scan_block": "a scan block fell back to eager frames "
-                          "(regime change or steering drain)",
-    "session.scan_frames": "scan_frames configured but unsupported in "
-                           "this mode; eager loop runs",
     "serve.client": "edge server: a malformed or oversized client "
                     "message was dropped; the serve loop keeps going",
     "serve.shed": "edge server admission control refused a viewer or "
@@ -269,7 +263,6 @@ _COUNTER_REGISTRY: Dict[str, str] = {
     "bricks_steps_built": "a brick-partition render step was compiled "
                           "for a (brick map, camera) combination",
     "build_steps": "the session (re)built its compiled render step set",
-    "compile_scan_block": "a temporal scan frame-block was compiled",
     "compile_step": "one render/serve executable was compiled (lowered "
                     "+ jitted)",
     "dcn_bytes_received": "bytes received over the inter-host DCN seam "
@@ -295,7 +288,6 @@ _COUNTER_REGISTRY: Dict[str, str] = {
     "delta_tiles_skipped": "an unchanged tile shipped as a SKIP record",
     "flight_dumps": "the flight recorder dumped the last obs window "
                     "after an unhandled frame-loop exception",
-    "frame_scan_builds": "a per-frame scan build was dispatched",
     "frames_abandoned": "the tile assembler abandoned a frame that "
                         "stayed incomplete past its window",
     "frames_eager_dispatch": "a frame went through the eager per-frame "
@@ -303,8 +295,6 @@ _COUNTER_REGISTRY: Dict[str, str] = {
     "frames_fetched_sharded": "a frame sharded over the mesh was brought "
                               "to the host shard by shard and assembled "
                               "there (InSituSession._to_host)",
-    "frames_scan_dispatch": "a frame was delivered from a compiled scan "
-                            "block",
     "head_degraded_frames": "the head composited a frame with >= 1 rank "
                             "missing (degraded flag set)",
     "head_ranks_down": "head liveness marked a render rank silent",
@@ -335,16 +325,14 @@ _COUNTER_REGISTRY: Dict[str, str] = {
                              "rebalanced partition",
     "regime_host": "a frame's march regime was decided from the host "
                    "values of a steered camera (no device read)",
-    "regime_switches": "the session switched between scan and eager "
-                       "dispatch regimes",
+    "regime_switches": "the camera entered a march regime other than the "
+                       "previous frame's (carried state of the entered "
+                       "regime re-seeds)",
     "reuse_steps_built": "a temporal-reuse render step (carried "
                          "fragments) was built",
     "ring_exchange_builds": "a ring all-to-all exchange program was "
                             "built",
     "ring_steps_built": "one hop of a ring exchange was built",
-    "scan_blocks_dispatched": "a compiled scan block was dispatched",
-    "scan_tail_eager_frames": "tail frames finished eagerly after a "
-                              "partial scan block (count = frames)",
     "serve_answers": "the edge server sent one answer to a viewer",
     "serve_batch_cameras": "cameras rendered inside batched serve "
                            "dispatches (count = cameras)",
@@ -555,8 +543,8 @@ class Recorder:
         return _Span(self, name, frame, attrs or None)
 
     def count(self, name: str, n: float = 1) -> None:
-        """Bump a named counter (compile events, scan blocks, eager
-        frames, ...). O(1) dict update — cheap enough to leave in hot
+        """Bump a named counter (compile events, dispatched frames,
+        ...). O(1) dict update — cheap enough to leave in hot
         paths unconditionally; the counter event stream is only recorded
         when enabled."""
         with self._lock:

@@ -1962,18 +1962,7 @@ def _out_block_index(axis, topo):
 def distributed_plain_step_mxu(mesh: Mesh, tf: TransferFunction,
                                spec, cfg: Optional[RenderConfig] = None,
                                axis_name: Optional[str] = None,
-                               exchange: str = "all_to_all",
-                               wire: str = "f32",
-                               schedule: str = "frame",
-                               wave_tiles: int = 4,
-                               rebalance: str = "even",
-                               rebalance_period: int = 8,
-                               rebalance_hysteresis: float = 0.25,
-                               rebalance_min_depth: int = 4,
-                               rebalance_quantum: int = 4,
-                               rebalance_bricks: int = 0,
-                               rebalance_max_moves: int = 2,
-                               temporal_reuse: str = "off",
+                               comp_cfg: Optional[CompositeConfig] = None,
                                plan=None, bricks=None, topology=None):
     """Distributed plain-image rendering on the MXU slice-march engine —
     the TPU-fast counterpart of `distributed_plain_step` (the reference's
@@ -1991,21 +1980,19 @@ def distributed_plain_step_mxu(mesh: Mesh, tf: TransferFunction,
     background)``. ``axcam`` is replicated (every rank derives it from the
     shared global box), so the warp runs on the gathered global image.
 
-    ``exchange``: "all_to_all" (one collective) or "ring" (n-1 pipelined
-    single-fragment ppermute hops; bitwise-identical output — see
-    `_ring_exchange_plain`). ``wire``: the fragment encoding that crosses
-    ICI ("f32" bit-exact | "bf16" | "qpack8" — docs/PERF.md "Wire
-    formats"; lossy modes quantize the exchanged RGBA+depth only, the
-    composite runs in f32). Plain steps take both knobs directly because
-    they carry no CompositeConfig; the session forwards
-    ``cfg.composite.exchange`` / ``cfg.composite.wire`` (and
-    ``schedule``/``wave_tiles`` — docs/PERF.md "Tile waves": under
-    "waves" each rank `render_slices`-marches one column-block wave at a
-    time while the previous wave's fragments exchange+composite, sharing
-    one permuted copy and occupancy gate per frame). The ``rebalance*``
-    knobs + ``plan`` select the uneven render z bands (docs/PERF.md
-    "Render rebalancing") exactly like the whole-object builders'
-    ``comp_cfg.rebalance``.
+    ``comp_cfg.exchange``: "all_to_all" (one collective) or "ring" (n-1
+    pipelined single-fragment ppermute hops; bitwise-identical output —
+    see `_ring_exchange_plain`). ``comp_cfg.wire``: the fragment encoding
+    that crosses ICI ("f32" bit-exact | "bf16" | "qpack8" — docs/PERF.md
+    "Wire formats"; lossy modes quantize the exchanged RGBA+depth only,
+    the composite runs in f32). ``schedule``/``wave_tiles`` (docs/PERF.md
+    "Tile waves"): under "waves" each rank `render_slices`-marches one
+    column-block wave at a time while the previous wave's fragments
+    exchange+composite, sharing one permuted copy and occupancy gate per
+    frame. ``rebalance`` + ``plan`` select the uneven render z bands
+    (docs/PERF.md "Render rebalancing"). The fields a plain image has no
+    use for (``ring_slots`` caps an N*K supersegment accumulator,
+    ``k_budget`` re-targets the VDI threshold) are not read.
     """
     from scenery_insitu_tpu.ops import slicer
 
@@ -2014,25 +2001,16 @@ def distributed_plain_step_mxu(mesh: Mesh, tf: TransferFunction,
     if spec.ni % n:
         raise ValueError(f"intermediate width {spec.ni} not divisible by "
                          f"mesh size {n}")
-    # validates schedule/wave_tiles/rebalance_* values exactly like
-    # CompositeConfig (the plain builders carry the knob matrix
-    # explicitly; the session forwards cfg.composite.*)
-    knob_cfg = CompositeConfig(schedule=schedule, wave_tiles=wave_tiles,
-                               rebalance=rebalance,
-                               rebalance_period=rebalance_period,
-                               rebalance_hysteresis=rebalance_hysteresis,
-                               rebalance_min_depth=rebalance_min_depth,
-                               rebalance_quantum=rebalance_quantum,
-                               rebalance_bricks=rebalance_bricks,
-                               rebalance_max_moves=rebalance_max_moves,
-                               temporal_reuse=temporal_reuse)
-    waves = _resolve_waves(knob_cfg, n, spec.ni, slicer)
+    comp_cfg = comp_cfg or CompositeConfig()
+    exchange, wire = comp_cfg.exchange, comp_cfg.wire
+    wave_tiles = comp_cfg.wave_tiles
+    waves = _resolve_waves(comp_cfg, n, spec.ni, slicer)
     # a planned band must be at least as deep as the AO shade halo
-    plan = _resolve_plan(knob_cfg, n, plan,
+    plan = _resolve_plan(comp_cfg, n, plan,
                          min_halo=(cfg.ao_radius + 1
                                    if cfg.ao_strength > 0.0 else 1))
     _bricks_inert(bricks, "the plain-image MXU step")
-    _resolve_reuse(knob_cfg, supported=False,
+    _resolve_reuse(comp_cfg, supported=False,
                    where="the plain-image MXU step")
 
     # distributed AO: pre-shade each rank's slab with TF + occlusion on a
@@ -2108,47 +2086,30 @@ def distributed_plain_step(mesh: Mesh, tf: TransferFunction,
                            width: int, height: int,
                            cfg: Optional[RenderConfig] = None,
                            axis_name: Optional[str] = None,
-                           exchange: str = "all_to_all",
-                           wire: str = "f32",
-                           schedule: str = "frame",
-                           wave_tiles: int = 4,
-                           rebalance: str = "even",
-                           rebalance_period: int = 8,
-                           rebalance_hysteresis: float = 0.25,
-                           rebalance_min_depth: int = 4,
-                           rebalance_quantum: int = 4,
-                           rebalance_bricks: int = 0,
-                           rebalance_max_moves: int = 2,
-                           temporal_reuse: str = "off",
+                           comp_cfg: Optional[CompositeConfig] = None,
                            plan=None, bricks=None, topology=None):
     """Build the jitted distributed plain-image render step (the reference's
     non-VDI mode: VolumeRaycaster + PlainImageCompositor,
     DistributedVolumeRenderer.kt:175-189). Returns ``f(vol_data, origin,
     spacing, cam) -> image f32[4, height, width]`` sharded by W.
-    ``exchange`` selects the column-exchange schedule ("all_to_all" |
-    "ring"), ``wire`` the fragment encoding that crosses ICI, and
-    ``schedule``/``wave_tiles`` the frame granularity (the gather march
-    is monolithic, so "waves" pipelines exchange against composite at
-    column-block granularity) — see `distributed_plain_step_mxu`."""
+    ``comp_cfg.exchange`` selects the column-exchange schedule
+    ("all_to_all" | "ring"), ``wire`` the fragment encoding that crosses
+    ICI, and ``schedule``/``wave_tiles`` the frame granularity (the gather
+    march is monolithic, so "waves" pipelines exchange against composite
+    at column-block granularity) — see `distributed_plain_step_mxu`."""
     cfg = cfg or RenderConfig(width=width, height=height)
     axis, n, topo = resolve_mesh_topology(mesh, axis_name, topology)
     if width % n:
         raise ValueError(f"width {width} not divisible by mesh size {n}")
-    knob_cfg = CompositeConfig(schedule=schedule, wave_tiles=wave_tiles,
-                               rebalance=rebalance,
-                               rebalance_period=rebalance_period,
-                               rebalance_hysteresis=rebalance_hysteresis,
-                               rebalance_min_depth=rebalance_min_depth,
-                               rebalance_quantum=rebalance_quantum,
-                               rebalance_bricks=rebalance_bricks,
-                               rebalance_max_moves=rebalance_max_moves,
-                               temporal_reuse=temporal_reuse)
-    waves = _resolve_waves(knob_cfg, n, width)
-    plan = _resolve_plan(knob_cfg, n, plan,
+    comp_cfg = comp_cfg or CompositeConfig()
+    exchange, wire = comp_cfg.exchange, comp_cfg.wire
+    wave_tiles = comp_cfg.wave_tiles
+    waves = _resolve_waves(comp_cfg, n, width)
+    plan = _resolve_plan(comp_cfg, n, plan,
                          min_halo=(cfg.ao_radius + 1
                                    if cfg.ao_strength > 0.0 else 1))
     _bricks_inert(bricks, "the plain-image gather step")
-    _resolve_reuse(knob_cfg, supported=False,
+    _resolve_reuse(comp_cfg, supported=False,
                    where="the plain-image gather step")
 
     # rank partials must stay background-free — the background is blended
@@ -2215,78 +2176,3 @@ def shard_volume(data: jnp.ndarray, mesh: Mesh,
     """Place a global volume onto the mesh z-sharded (host → HBM shards)."""
     axis = axis_name or mesh.axis_names[0]
     return jax.device_put(data, NamedSharding(mesh, P(axis, None, None)))
-
-
-def frame_scan(step, advance, frames: int, temporal: bool = False,
-               field=lambda s: s.field, sim_ranges: bool = False):
-    """Roll ``frames`` (sim advance → render step → camera orbit)
-    iterations into ONE ``lax.scan``-based jitted executable — a single
-    launch per block instead of one executable launch per frame,
-    amortizing the per-launch dispatch tax (docs/PERF.md hypothesis H2;
-    bench.py's SCAN_FRAMES A/B measures the same lever single-chip).
-
-    ``step``: a built frame step — any of this module's distributed
-    steps or a single-chip equivalent — with signature
-    ``f(field, origin, spacing, cam) -> out`` (``temporal=True``:
-    ``f(field, origin, spacing, cam, thr) -> (out, thr')``).
-    ``advance``: traceable one-frame sim advance, ``state -> state``.
-    ``field``: extracts the rendered f32[D, H, W] field from the sim
-    state (default: the ``.field`` property every built-in volume sim
-    exposes).
-
-    Returns jitted ``run(state, origin, spacing, cam, orbit_rate
-    [, thr]) -> ((state', cam', thr'), outs)`` where ``outs`` stacks the
-    per-frame step outputs on a leading frame axis. The camera orbits by
-    ``orbit_rate`` radians AFTER each frame (pass 0.0 for a static
-    camera — ``orbit(cam, 0.0)`` is exact), so frame i renders with the
-    same camera the eager session loop would use. Steering (and, on the
-    MXU engine, march-regime changes) can only take effect at block
-    boundaries — the caller owns that check.
-
-    ``sim_ranges=True`` threads the occupancy pyramid's sim-fused update
-    through the scan body (ISSUE 6): ``advance`` must return ``(state,
-    ops/occupancy.FieldRanges)`` (e.g. grayscott.multi_step_fast_ranges)
-    and ``step`` gains a trailing ``ranges`` argument — frame i renders
-    with the ranges its own advance emitted, so no frame in the block
-    re-derives occupancy from the volume.
-
-    Tile-wave steps (CompositeConfig.schedule == "waves") scan cleanly:
-    the per-wave state lives INSIDE the step (the wave scan's
-    double-buffered fragment slot; temporal threshold maps update
-    wave-by-wave but cross frames as the same full-frame carry), so the
-    frame scan nests a wave scan per frame — the step's
-    ``wave_schedule_build`` trace event fires when the block traces.
-    """
-    from scenery_insitu_tpu import obs as _obs
-    from scenery_insitu_tpu.core.camera import orbit as _orbit
-
-    # host-side build marker: every frame_scan() call mints one scanned
-    # executable per (step, block) — the trace correlates a dispatch
-    # stall with this rather than with the frames inside the block
-    rec = _obs.get_recorder()
-    rec.count("frame_scan_builds")
-    rec.event("frame_scan_build", frames=frames, temporal=temporal,
-              sim_ranges=sim_ranges)
-
-    def run(state, origin, spacing, cam, orbit_rate, thr=None):
-        def body(carry, _):
-            st, cam, thr = carry
-            if sim_ranges:
-                with _phase("sim_step"):
-                    st, rng = advance(st)
-                extra = (rng,)
-            else:
-                with _phase("sim_step"):
-                    st = advance(st)
-                extra = ()
-            if temporal:
-                out, thr2 = step(field(st), origin, spacing, cam, thr,
-                                 *extra)
-            else:
-                out, thr2 = step(field(st), origin, spacing, cam,
-                                 *extra), thr
-            return (st, _orbit(cam, orbit_rate), thr2), out
-
-        return jax.lax.scan(body, (state, cam, thr), None, length=frames)
-
-    return jax.jit(run)

@@ -76,8 +76,8 @@ def save_session(sess: "InSituSession", path: str) -> None:
         "mesh_devices": int(sess._n_ranks),
         "frame_index": sess.frame_index,
         "orbit_rate": float(sess.orbit_rate),
-        "thr_regimes": sorted(sess._mxu_thr.keys()),
-        "last_regime": getattr(sess, "_last_regime_key", None),
+        "thr_regimes": sorted(sess._steps.thr.keys()),
+        "last_regime": sess._steps.last_key,
     }
     arrays = {f"sim/{k}": np.asarray(v)
               for k, v in _sim_arrays(sess.sim).items()}
@@ -88,7 +88,7 @@ def save_session(sess: "InSituSession", path: str) -> None:
     # render with the constructor TF
     for name, val in zip(type(sess.tf)._fields, sess.tf):
         arrays[f"tf/{name}"] = np.asarray(val)
-    for regime, thr in sess._mxu_thr.items():
+    for regime, thr in sess._steps.thr.items():
         # join EVERY key part: hybrid-mode keys are ('hybrid', axis, sign)
         # and both signs of an axis must keep distinct tags
         tag = "thr/" + "_".join(str(p) for p in regime)
@@ -172,7 +172,7 @@ def load_session(sess: "InSituSession", path: str) -> None:
         # (older checkpoints have no tf/ keys: constructor TF applies)
         sess.frame_index = int(header["frame_index"])
         sess.orbit_rate = header["orbit_rate"]
-        sess._mxu_thr = {}
+        sess._steps.thr = {}
         for regime in header.get("thr_regimes", []):
             regime = tuple(regime)
             tag = "thr/" + "_".join(str(p) for p in regime)
@@ -185,16 +185,13 @@ def load_session(sess: "InSituSession", path: str) -> None:
                     f"threshold state for regime {regime} has shape "
                     f"{tuple(state.thr.shape)}, session expects {expect} "
                     "— same slicer/mesh config required")
-            sess._mxu_thr[regime] = state
-        # restore the regime tracker VERBATIM: _enter_regime drops the
+            sess._steps.thr[regime] = state
+        # restore the regime tracker VERBATIM: StepTable.enter drops the
         # entered regime's carried state on a regime CHANGE, and the
         # resumed run must make the same drop/keep decisions as the
         # uninterrupted one
         last = header.get("last_regime")
-        if last is not None:
-            sess._last_regime_key = tuple(last)
-        elif hasattr(sess, "_last_regime_key"):
-            del sess._last_regime_key
+        sess._steps.last_key = None if last is None else tuple(last)
 
 
 def _thr_shape(sess, regime):

@@ -26,6 +26,7 @@ from scenery_insitu_tpu.core.camera import Camera
 from scenery_insitu_tpu.core.scene import MultiGridScene
 from scenery_insitu_tpu.core.transfer import TransferFunction, for_dataset
 from scenery_insitu_tpu.runtime.failsafe import SinkGuard
+from scenery_insitu_tpu.runtime.steps import StepEntry, StepTable
 
 Sink = Callable[[int, dict], None]
 
@@ -77,9 +78,13 @@ class SceneSession:
         from scenery_insitu_tpu.ops import slicer as _slicer
         self._slicer = _slicer
         self.engine = _slicer.resolve_engine(self.cfg.slicer.engine)
-        self._steps = {}   # (regime, grid-set signature) -> jitted step
-        self._thr = {}      # same key -> carried temporal threshold state
-        self._thr_init = {}  # same key -> jitted threshold seeder
+        # keyed (regime, grid-set signature, ...). Bounded: a drifting
+        # scene mints a new extent key per movement, and an unbounded
+        # table would retain every stale executable + [G, nj, ni]
+        # threshold state for the life of the session. Insertion order ≈
+        # recency here (a key is inserted once and then only hit), so
+        # dropping the oldest entries is an adequate LRU.
+        self._steps = StepTable(self.obs, max_entries=8)
         self._extent_cache = None  # (lo, hi, sp, rounded tuple) host copy
         self._temporal = (self.cfg.runtime.generate_vdis
                           and self.engine == "mxu"
@@ -89,18 +94,13 @@ class SceneSession:
         self.on_steer.append(self._apply_tf_message)
 
     def _apply_tf_message(self, msg: dict) -> None:
-        """'tf' steering: drop the per-signature step/threshold caches so
-        the next frame compiles with the new transfer function. Shared
-        protocol logic (parsing, malformed-payload containment) lives in
-        session.apply_tf_steering."""
+        """'tf' steering: drop the compiled steps and their threshold
+        state so the next frame compiles with the new transfer function.
+        Shared protocol logic (parsing, malformed-payload containment)
+        lives in session.apply_tf_steering."""
         from scenery_insitu_tpu.runtime.session import apply_tf_steering
 
-        def invalidate():
-            self._steps.clear()
-            self._thr.clear()
-            self._thr_init.clear()
-
-        apply_tf_steering(self, msg, invalidate)
+        apply_tf_steering(self, msg, self._steps.reset)
 
     # ------------------------------------------------- operator boundary
     def update_data(self, partner: int, grids, origins, spacing,
@@ -137,21 +137,10 @@ class SceneSession:
         with self.obs.span("dispatch", frame=self.frame_index,
                            engine=self.engine,
                            grids=self.scene.num_grids):
-            step, key = self._step()
-            gs = self.scene.grids
-            args = (tuple(g.volume.data for g in gs),
-                    tuple(g.volume.origin for g in gs),
-                    tuple(g.volume.spacing for g in gs), self.camera)
+            entry, key = self._step()
             if self._temporal:
-                from scenery_insitu_tpu.runtime.session import (
-                    drop_on_regime_reentry)
-                drop_on_regime_reentry(self, self._thr, key)
-                thr = self._thr.get(key)
-                if thr is None:     # seed on first frame of this regime
-                    thr = self._thr_init[key](*args)
-                out, self._thr[key] = step(*args, thr)
-            else:
-                out = step(*args)
+                self._steps.enter(key)
+            out = self._steps.run(key, entry, self._frame_args())
         with self.obs.span("fetch", frame=self.frame_index):
             if self.cfg.runtime.generate_vdis:
                 vdi, meta = out
@@ -194,7 +183,7 @@ class SceneSession:
         InSituSession.prewarm_regimes: a regime crossing mid-session
         otherwise stalls on a fresh jit). Call after `update_data` —
         a later grid-set signature change recompiles regardless (the
-        cache is keyed on both). Temporal threshold state and the
+        table is keyed on both). Temporal threshold state and the
         reentry tracker are snapshotted and restored; the camera and
         frame index are untouched. Returns {(axis, sign): seconds}."""
         import time as _time
@@ -211,52 +200,44 @@ class SceneSession:
         if regimes is None:
             regimes = [(a, s) for a in (0, 1, 2) for s in (1, -1)]
         cam0 = self.camera
-        thr0 = dict(self._thr)
-        had_last = hasattr(self, "_last_regime_key")
-        last0 = getattr(self, "_last_regime_key", None)
-        active_key = None
+        table = self._steps
+        kept = table.snapshot()
         times = {}
         try:
             for regime in regimes:
-                cam = regime_camera(cam0, regime, self._slicer)
-                self.camera = cam
+                self.camera = regime_camera(cam0, regime, self._slicer)
                 t0 = _time.perf_counter()
-                step, key = self._step()
-                gs = self.scene.grids
-                args = (tuple(g.volume.data for g in gs),
-                        tuple(g.volume.origin for g in gs),
-                        tuple(g.volume.spacing for g in gs), cam)
-                if self._temporal:
-                    thr = self._thr_init[key](*args)
-                    out, _ = step(*args, thr)
-                else:
-                    out = step(*args)
-                jax.block_until_ready(out)
+                entry, key = self._step()
+                jax.block_until_ready(
+                    table.run(key, entry, self._frame_args()))
                 times[tuple(regime)] = round(_time.perf_counter() - t0, 2)
         finally:
             self.camera = cam0
+            table.restore(kept)
             # drop restored threshold entries whose step was evicted by
-            # the cache bound (they would be orphaned forever), and keep
-            # the ACTIVE regime's step most-recent so prewarming many
-            # regimes can't evict the one the loop is about to use
-            self._thr = {kk: v for kk, v in thr0.items()
-                         if kk in self._steps}
+            # the table's bound (they would be orphaned forever), and
+            # keep the ACTIVE regime's step most-recent so prewarming
+            # many regimes can't evict the one the loop is about to use
+            table.thr = {kk: v for kk, v in table.thr.items()
+                         if kk in table.steps}
             try:
                 _, active_key = self._step()
-                if active_key in self._steps:
-                    self._steps[active_key] = self._steps.pop(active_key)
+                if active_key in table.steps:
+                    table.steps[active_key] = table.steps.pop(active_key)
             except Exception:
                 pass
-            if had_last:
-                self._last_regime_key = last0
-            elif hasattr(self, "_last_regime_key"):
-                del self._last_regime_key
         return times
 
+    def _frame_args(self):
+        gs = self.scene.grids
+        return (tuple(g.volume.data for g in gs),
+                tuple(g.volume.origin for g in gs),
+                tuple(g.volume.spacing for g in gs), self.camera)
+
     def _step(self):
-        """(jitted step, cache key) for the current camera regime and the
+        """(`StepEntry`, table key) for the current camera regime and the
         current grid-set SIGNATURE — one compilation per signature, like
-        InSituSession._mxu_step. Data, origins, spacings and the camera
+        InSituSession._regime_frame. Data, origins, spacings and the camera
         are traced; shapes + ghosts are static, and so is the mxu
         intermediate-grid spec, whose dims derive from the scene's world
         extent — hence the signature also carries the rounded global
@@ -288,13 +269,15 @@ class SceneSession:
             lo, hi, sp, extent = self._extent_cache
         key = (regime, sig, extent, self.engine,
                self.cfg.runtime.generate_vdis)
-        step = self._steps.get(key)
-        if step is not None:
-            return step, key
+        entry = self._steps.steps.get(key)
+        if entry is None:
+            entry = self._steps.compile(
+                key, lambda: self._build_step(regime, mxu_vdi, lo, hi, sp),
+                self.frame_index, "scene_step", regime)
+        return entry, key
 
-        self.obs.count("compile_step")
-        self.obs.event("compile", frame=self.frame_index,
-                       what="scene_step", regime=str(regime))
+    def _build_step(self, regime, mxu_vdi: bool, lo, hi, sp):
+        gs = self.scene.grids
         ghosts = [(g.ghost_lo, g.ghost_hi) for g in gs]
         r = self.cfg.render
         cfg = self.cfg
@@ -314,23 +297,16 @@ class SceneSession:
             return sc
 
         if self._temporal:
-            def fn(datas, origins, spacings, cam, thr):
-                sc = scene_of(datas, origins, spacings)
-                return sc.generate_vdi_mxu_temporal(tf, cam, spec, thr,
-                                                    cfg.vdi, cfg.composite)
-
             def fn_out(datas, origins, spacings, cam, thr):
-                out, meta, thr2 = fn(datas, origins, spacings, cam, thr)
+                sc = scene_of(datas, origins, spacings)
+                out, meta, thr2 = sc.generate_vdi_mxu_temporal(
+                    tf, cam, spec, thr, cfg.vdi, cfg.composite)
                 return (out, meta), thr2
 
-            step = jax.jit(fn_out)
-            self._thr_init[key] = jax.jit(
+            return StepEntry(jax.jit(fn_out), seed_thr=jax.jit(
                 lambda datas, origins, spacings, cam:
                 scene_of(datas, origins, spacings).initial_thresholds(
-                    tf, cam, spec, cfg.vdi))
-            self._steps[key] = step
-            self._evict()
-            return step, key
+                    tf, cam, spec, cfg.vdi)))
 
         def fn(datas, origins, spacings, cam):
             sc = scene_of(datas, origins, spacings)
@@ -343,22 +319,4 @@ class SceneSession:
                                        max_steps=r.max_steps)
             return sc.render(tf, cam, r.width, r.height, r)
 
-        step = jax.jit(fn)
-        self._steps[key] = step
-        self._evict()
-        return step, key
-
-    _MAX_CACHED_STEPS = 8
-
-    def _evict(self):
-        """Bound the compiled-step / threshold caches: a drifting scene
-        mints a new extent key per movement, and an unbounded dict would
-        retain every stale executable + [G, nj, ni] threshold state for
-        the life of the session. Insertion order ≈ recency here (a key is
-        inserted once and then only hit), so dropping the oldest entries
-        is an adequate LRU."""
-        while len(self._steps) > self._MAX_CACHED_STEPS:
-            old = next(iter(self._steps))
-            self._steps.pop(old)
-            self._thr.pop(old, None)
-            self._thr_init.pop(old, None)
+        return StepEntry(jax.jit(fn))

@@ -25,7 +25,7 @@ import sys
 import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
-from typing import Callable, List, Optional, Sequence
+from typing import Callable, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -39,10 +39,9 @@ from scenery_insitu_tpu.core.vdi import VDI
 from scenery_insitu_tpu.obs.profiler import scoped_step
 from scenery_insitu_tpu.parallel.topology import (make_topology_mesh,
                                                   resolve_mesh_topology)
-from scenery_insitu_tpu.parallel.pipeline import (distributed_plain_step,
-                                                  distributed_vdi_step,
-                                                  shard_volume)
+from scenery_insitu_tpu.parallel.pipeline import shard_volume
 from scenery_insitu_tpu.runtime.failsafe import SinkGuard
+from scenery_insitu_tpu.runtime.steps import StepEntry, StepTable
 from scenery_insitu_tpu.sim import grayscott as gs
 from scenery_insitu_tpu.sim import vortex as vx
 
@@ -173,17 +172,14 @@ def regime_camera(cam0, regime, slicer_mod):
     return cam
 
 
-def drop_on_regime_reentry(sess, store: dict, key) -> None:
-    """Shared temporal-threshold policy of both sessions: when the camera
-    enters a regime key other than the previous frame's, drop that key's
-    carried threshold state so it re-seeds — a map frozen many frames ago
-    (while the camera was elsewhere and the data kept evolving) would cost
-    the controller several overflow-degraded frames to walk back. The
-    tracker attribute is checkpoint-restored VERBATIM (runtime/checkpoint)
-    so resumed runs make identical drop/keep decisions."""
-    if key != getattr(sess, "_last_regime_key", key):
-        store.pop(key, None)
-    sess._last_regime_key = key
+class _RegimeMode(NamedTuple):
+    """What a mode that compiles per march regime contributes to
+    `InSituSession._regime_frame`."""
+    what: str           # the `compile` event's `what`
+    site: str           # the `camera_readback` span's `site`
+    prefix: tuple       # of the table key, before (axis, sign)
+    entry: Callable     # AxisSpec -> StepEntry
+    frame: Callable     # (key, entry) -> (out, meta or None)
 
 
 def advance_camera_and_index(sess) -> None:
@@ -544,10 +540,7 @@ class InSituSession:
         self._plan = None
         self._bricks = None
         self._plan_frame = None
-        # steered-TF recompile-or-reuse (docs/SCENARIOS.md "Steered
-        # transfer functions"): compiled-step caches stashed under the
-        # outgoing TF's identity key, restored when a steered TF repeats
-        self._step_cache = {}
+        self._steps = StepTable(self.obs)
         self.orbit_rate = 0.0  # radians/frame camera sweep (benchmark mode)
         self.steering = None   # optional streaming.SteeringEndpoint
         self.on_steer: List[Callable[[dict], None]] = []  # non-camera msgs
@@ -587,29 +580,31 @@ class InSituSession:
                     f"for auto, or a divisor)")
 
     def _build_steps(self) -> None:
-        """(Re)build the distributed steps for the current mode/engine/TF
-        and reset the per-regime caches. Called at construction and after
-        a runtime transfer-function change (the TF is a compile-time
-        constant of every step)."""
+        """Resolve the mode for the current sim/engine and forget every
+        compiled step and all carried state (`StepTable.reset`). Called
+        at construction, after a render re-plan, and after a runtime
+        transfer-function change not seen before (TF, plan and brick map
+        are compile-time constants of every step). A mode that compiles
+        per march regime only names its parts here (``_regime_mode``);
+        `_regime_frame` builds its steps as the camera reaches them."""
+        from scenery_insitu_tpu.parallel import pipeline
+
         r = self.cfg.render
         # step-cache rebuilds drop every compiled executable — counted so
         # a trace can attribute a mid-run compile stall (e.g. a TF
         # steering update) to its cause
         self.obs.count("build_steps")
-        self._mxu_steps = {}   # regime key -> jitted distributed step
-        self._mxu_thr = {}     # regime key -> temporal threshold state
-        self._mxu_reuse = {}   # regime key -> temporal-reuse ReuseState
-        self._scan_steps = {}  # (kind, regime, block) -> scan executable
-        self._profile_fn = None  # jitted z-live-profile fetch (replan)
-        self._ranges_fn = None   # jitted z-range fetch (LOD TF gate)
+        table = self._steps
+        table.reset()
         self._tf_key = _tf_fingerprint(self.tf)
         self.mode = "vdi"
+        self._regime_mode = None
         if isinstance(self.sim, ParticleSimAdapter):
             # sort-first sphere rendering (≅ InVisRenderer + Head)
             from scenery_insitu_tpu.parallel.particles import (
                 distributed_particle_step)
             self.mode = "particles"
-            self._step = scoped_step(distributed_particle_step(
+            table.fixed = scoped_step(distributed_particle_step(
                 self.mesh, r.width, r.height,
                 radius=self.cfg.sim.particle_radius), self.obs)
         elif isinstance(self.sim, HybridSimAdapter):
@@ -618,41 +613,32 @@ class InSituSession:
             # knob is overridden so telemetry reports what actually runs
             self.mode = "hybrid"
             self.engine = "mxu"
-            self._step = None
+            self._regime_mode = _RegimeMode(
+                "hybrid_step", "hybrid", ("hybrid",), self._hybrid_entry,
+                self._hybrid_frame)
         elif self.cfg.runtime.generate_vdis and self.engine == "mxu":
-            self._step = None
+            self._regime_mode = _RegimeMode(
+                "vdi_step", "mxu_step", (), self._vdi_entry,
+                self._vdi_frame)
         elif self.cfg.runtime.generate_vdis:
-            self._step = scoped_step(distributed_vdi_step(
+            table.fixed = scoped_step(pipeline.distributed_vdi_step(
                 self.mesh, self.tf, r.width, r.height,
                 self.cfg.vdi, self.cfg.composite, max_steps=r.max_steps,
-                plan=self._plan, bricks=self._bricks,
-                topology=self.cfg.topology), self.obs)
+                **self._decomp()), self.obs)
         elif self.engine == "mxu":
             # TPU plain mode: slice march + column exchange + nearest-first
             # composite on the intermediate grid, homography-warped to the
             # display camera per frame (≅ DistributedVolumeRenderer.kt:
             # 175-189's plain pipeline, re-scheduled for the MXU)
             self.mode = "plain"
-            self._step = None
+            self._regime_mode = _RegimeMode(
+                "plain_step", "plain", ("plain",), self._plain_entry,
+                self._plain_frame)
         else:
             self.mode = "plain"
-            cc = self.cfg.composite
-            self._step = scoped_step(distributed_plain_step(
+            table.fixed = scoped_step(pipeline.distributed_plain_step(
                 self.mesh, self.tf, r.width, r.height, r,
-                exchange=cc.exchange,
-                wire=cc.wire,
-                schedule=cc.schedule,
-                wave_tiles=cc.wave_tiles,
-                rebalance=cc.rebalance,
-                rebalance_period=cc.rebalance_period,
-                rebalance_hysteresis=cc.rebalance_hysteresis,
-                rebalance_min_depth=cc.rebalance_min_depth,
-                rebalance_quantum=cc.rebalance_quantum,
-                rebalance_bricks=cc.rebalance_bricks,
-                rebalance_max_moves=cc.rebalance_max_moves,
-                temporal_reuse=cc.temporal_reuse,
-                plan=self._plan, bricks=self._bricks,
-                topology=self.cfg.topology), self.obs)
+                comp_cfg=self.cfg.composite, **self._decomp()), self.obs)
 
         self._temporal = (self.cfg.vdi.adaptive
                           and self.cfg.vdi.adaptive_mode == "temporal"
@@ -667,7 +653,7 @@ class InSituSession:
         # ledgers the inert knob (delta.reuse) when a map is active
         self._reuse = (self.cfg.composite.temporal_reuse == "ranges"
                        and self.mode == "vdi" and self.engine == "mxu"
-                       and self._step is None and self._bricks is None)
+                       and self._bricks is None)
         if self.cfg.composite.temporal_reuse == "ranges" \
                 and not self._reuse and self.mode == "particles":
             _obs.degrade("delta.reuse", "ranges", "off",
@@ -691,6 +677,13 @@ class InSituSession:
         protocol logic lives in `apply_tf_steering`."""
         apply_tf_steering(self, msg, self._tf_invalidate)
 
+    def _decomp(self) -> dict:
+        """The build-time geometry every step builder takes: the planned
+        z bands or the brick map of the render decomposition, and the
+        mesh topology."""
+        return dict(plan=self._plan, bricks=self._bricks,
+                    topology=self.cfg.topology)
+
     def _decomp_key(self):
         """The render-decomposition half of the step-cache key — cached
         steps bake the plan / brick map in as build-time geometry (for
@@ -704,9 +697,9 @@ class InSituSession:
     def _tf_invalidate(self) -> None:
         """Steered-TF recompile-or-reuse keyed on TF identity
         (docs/SCENARIOS.md "Steered transfer functions"): the outgoing
-        TF's compiled steps are stashed under its fingerprint, and a
-        steered TF seen before (same knots, same render decomposition)
-        restores them instead of recompiling — a time-varying TF
+        TF's compiled steps are put aside under its fingerprint
+        (`StepTable.swap`), and a steered TF seen before (same knots,
+        same render decomposition) takes them back instead of recompiling — a time-varying TF
         schedule cycling through k looks pays k compiles total, not one
         per update. Carried temporal threshold / reuse state re-seeds
         either way (it tracks scene content under the OLD TF)."""
@@ -717,20 +710,10 @@ class InSituSession:
             # very next frame, never a stale one (render_frame replans
             # before it dispatches; tests/test_lod.py property test)
             self._plan_frame = None
-        old_key = (self._tf_key,) + self._decomp_key()
-        self._step_cache[old_key] = (self._mxu_steps, self._scan_steps,
-                                     self._step, self._profile_fn,
-                                     self._ranges_fn)
-        while len(self._step_cache) > 8:        # bound compiled-step pins
-            self._step_cache.pop(next(iter(self._step_cache)))
+        decomp = self._decomp_key()
         new_fp = _tf_fingerprint(self.tf)
         self.obs.count("tf_updates")
-        entry = self._step_cache.get((new_fp,) + self._decomp_key())
-        if entry is not None:
-            (self._mxu_steps, self._scan_steps, self._step,
-             self._profile_fn, self._ranges_fn) = entry
-            self._mxu_thr = {}
-            self._mxu_reuse = {}
+        if self._steps.swap((self._tf_key,) + decomp, (new_fp,) + decomp):
             self._tf_key = new_fp
             self.obs.count("tf_steps_reused")
             self.obs.event("tf_update", frame=self.frame_index,
@@ -761,30 +744,20 @@ class InSituSession:
             self.sim.advance(self.cfg.sim.steps_per_frame)
         with self.obs.span("dispatch", frame=self.frame_index,
                            mode=self.mode, engine=self.engine):
+            meta = None
             if self.mode == "particles":
                 from scenery_insitu_tpu.parallel.particles import (
                     shard_particles)
                 centered = self.sim.pos - self.sim.state.box / 2.0
-                out = self._step(shard_particles(centered, self.mesh),
-                                 shard_particles(self.sim.vel, self.mesh),
-                                 self.camera)
-                meta = self.frame_metadata(self.frame_index)
-            elif self.mode == "hybrid":
-                out, meta = self._hybrid_dispatch()
-                meta = meta._replace(index=jnp.int32(self.frame_index))
+                out = self._steps.fixed(
+                    shard_particles(centered, self.mesh),
+                    shard_particles(self.sim.vel, self.mesh), self.camera)
+            elif self._steps.fixed is not None:
+                out = self._steps.fixed(*self._field_args())
             else:
-                field = self._shard(self.sim.field)
-                if self._step is not None:
-                    out = self._step(field, self._origin, self._spacing,
-                                     self.camera)
-                    meta = self.frame_metadata(self.frame_index)
-                elif self.mode == "plain":
-                    out = self._plain_mxu_dispatch(field)
-                    meta = self.frame_metadata(self.frame_index)
-                else:
-                    out, meta = self._mxu_step()(field, self._origin,
-                                                 self._spacing, self.camera)
-                    meta = meta._replace(index=jnp.int32(self.frame_index))
+                out, meta = self._regime_frame()
+            meta = (self.frame_metadata(self.frame_index) if meta is None
+                    else meta._replace(index=jnp.int32(self.frame_index)))
         # metadata snapshot BEFORE the camera advances (fetch is pipelined
         # one frame behind, so it must not see the next frame's pose)
         self._pending_meta[self.frame_index] = meta
@@ -810,21 +783,7 @@ class InSituSession:
         host-side timers cannot see because the frame is one fused program
         (the reference logged host-side phase spans instead,
         DistributedVolumeRenderer.kt:622-648; see also
-        benchmarks/phase_bench.py for the split-stage numbers).
-
-        ``cfg.runtime.scan_frames > 1`` rolls blocks of frames into one
-        lax.scan executable per launch (parallel/pipeline.frame_scan) —
-        same frames, one dispatch — for supported modes; unsupported
-        modes log the downgrade and run the eager loop."""
-        if self.cfg.runtime.scan_frames > 1:
-            ok, reason = self._scan_supported()
-            if ok:
-                return self._run_scan(frames, fetch, profile_dir)
-            self.log(f"scan_frames={self.cfg.runtime.scan_frames}: "
-                     f"falling back to the eager loop ({reason})")
-            _obs.degrade("session.scan_frames", "scan", "eager", reason,
-                         warn=False)
-
+        benchmarks/phase_bench.py for the split-stage numbers)."""
         ctx = (jax.profiler.trace(profile_dir) if profile_dir
                else contextlib.nullcontext())
         depth = self.cfg.runtime.pipeline_depth
@@ -842,21 +801,15 @@ class InSituSession:
                 for i in range(frames):
                     t_f = time.perf_counter()
                     out = self.render_frame()
-                    if fetch:
-                        # start the device->host copy at dispatch time,
-                        # but only when somebody consumes it (sinks
-                        # registered, or the caller-visible payload of
-                        # the final frame) — a sink-less run pays no
-                        # host transfer at all
-                        consume = bool(self.sinks or self.tile_sinks) \
-                            or i == last
-                        if consume:
-                            self._start_host_copy(out)
-                        pending.append(
-                            (self.frame_index - 1, out, consume))
-                    else:
-                        pending.append(
-                            (self.frame_index - 1, out, False))
+                    # start the device->host copy at dispatch time, but
+                    # only when somebody consumes it (sinks registered,
+                    # or the caller-visible payload of the final frame)
+                    # — a sink-less run pays no host transfer at all
+                    consume = fetch and (
+                        bool(self.sinks or self.tile_sinks) or i == last)
+                    if consume:
+                        self._start_host_copy(out)
+                    pending.append((self.frame_index - 1, out, consume))
                     out = None      # the deque holds the only device ref
                     while len(pending) > depth:
                         payload = self._retire(pending.popleft(),
@@ -901,7 +854,7 @@ class InSituSession:
         pipeline pins exactly `pipeline_depth` frames of HBM, never
         more."""
         index, out, consume = entry
-        if fetch and consume:
+        if consume:
             return self._fetch(index, out)
         if fetch:
             self._sync_nofetch(index, out)
@@ -1007,8 +960,7 @@ class InSituSession:
                         # is assembled — the frame "closes" (frame
                         # sinks run) only after every tile is already
                         # out the door
-                        self._deliver_tiles(index, None, meta,
-                                            color=color, depth=depth)
+                        self._deliver_tiles(index, meta, color, depth)
                 payload = {"vdi_color": color, "vdi_depth": depth}
             elif isinstance(out, SplatOutput):
                 payload = {"image": np.asarray(out.image),
@@ -1046,18 +998,11 @@ class InSituSession:
             "col0": t * wb, "meta": meta,
         } for t in range(tiles)]
 
-    def _deliver_tiles(self, index: int, out, meta=None,
-                       color=None, depth=None) -> None:
+    def _deliver_tiles(self, index: int, meta, color, depth) -> None:
         """Hand every column-block tile of one composited VDI frame to
         the tile sinks, in ascending global column order (the delivery
         contract: tile t arrives before tile t+1 and before the frame's
         own sinks)."""
-        if meta is None:
-            meta = self._pending_meta.get(index,
-                                          self.frame_metadata(index))
-        if color is None:
-            color = np.asarray(out.color)
-            depth = np.asarray(out.depth)
         for payload in self._tile_payloads(index, meta, color, depth):
             with self.obs.span("tile", frame=index,
                                tile=payload["tile"]):
@@ -1067,58 +1012,49 @@ class InSituSession:
 
     # ------------------------------------------------ render rebalancing
 
-    def _replan_profile(self):
-        """Fetch the GLOBAL per-z-bin live profile of the current field
-        (host numpy) — each rank reduces its even slab in data layout
-        (ops/occupancy.z_live_profile, one sweep, no permute) and the
-        profiles concatenate along the mesh axis. The jitted reduction
-        is cached until the TF or steps change (_build_steps resets)."""
+    def _z_bins(self, name: str, per_slab, n_out: int):
+        """A GLOBAL per-z-bin reduction of the current field, on the
+        device: each rank reduces its even slab in data layout
+        (``per_slab(local, nzb)``, one sweep, no permute) and the bins
+        concatenate along the mesh axis. The jitted reduction is one of
+        the step table's helpers: it goes when the TF or the steps
+        change."""
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from scenery_insitu_tpu.ops import occupancy as _occ
-        from jax import shard_map
 
-        if self._profile_fn is None:
+        fn = self._steps.helpers.get(name)
+        if fn is None:
             axis = self._flat_axis
-            n = self._n_ranks
-            tf = self.tf
-            dn = int(self.sim.field.shape[0]) // n
-            nzb = _occ._cap_divisor(dn, 32)
+            nzb = _occ._cap_divisor(
+                int(self.sim.field.shape[0]) // self._n_ranks, 32)
+            fn = self._steps.helpers[name] = jax.jit(shard_map(
+                lambda local: per_slab(local, nzb), mesh=self.mesh,
+                in_specs=P(axis, None, None),
+                out_specs=P(axis) if n_out == 1 else (P(axis),) * n_out,
+                check_vma=False))
+        return fn(self._shard(self.sim.field))
 
-            def prof(local):
-                return _occ.z_live_profile(local, tf, nzb=nzb)
+    def _replan_profile(self):
+        """The live profile of the current field (host numpy):
+        ops/occupancy.z_live_profile per z bin."""
+        from scenery_insitu_tpu.ops import occupancy as _occ
 
-            self._profile_fn = jax.jit(shard_map(
-                prof, mesh=self.mesh, in_specs=P(axis, None, None),
-                out_specs=P(axis), check_vma=False))
-        field = self._shard(self.sim.field)
-        return np.asarray(self._profile_fn(field))
+        tf = self.tf
+        return np.asarray(self._z_bins(
+            "profile",
+            lambda local, nzb: _occ.z_live_profile(local, tf, nzb=nzb), 1))
 
     def _replan_ranges(self):
-        """Fetch the GLOBAL per-z-bin sampled value range of the current
-        field (host numpy) — `ops/occupancy.z_range_profile` on each
-        rank's even slab, concatenated along the mesh axis. The LOD
-        planner's TF-straddle gate input (docs/PERF.md "LOD marching");
-        cached like `_replan_profile`."""
-        from jax.sharding import PartitionSpec as P
-
+        """The sampled value range of the current field per z bin (host
+        numpy, ``(lo, hi)``): `ops/occupancy.z_range_profile`, the LOD
+        planner's TF-straddle gate input (docs/PERF.md "LOD marching")."""
         from scenery_insitu_tpu.ops import occupancy as _occ
-        from jax import shard_map
 
-        if self._ranges_fn is None:
-            axis = self._flat_axis
-            n = self._n_ranks
-            dn = int(self.sim.field.shape[0]) // n
-            nzb = _occ._cap_divisor(dn, 32)
-
-            def rng(local):
-                return _occ.z_range_profile(local, nzb=nzb)
-
-            self._ranges_fn = jax.jit(shard_map(
-                rng, mesh=self.mesh, in_specs=P(axis, None, None),
-                out_specs=(P(axis), P(axis)), check_vma=False))
-        field = self._shard(self.sim.field)
-        lo, hi = self._ranges_fn(field)
+        lo, hi = self._z_bins(
+            "ranges",
+            lambda local, nzb: _occ.z_range_profile(local, nzb=nzb), 2)
         return np.asarray(lo), np.asarray(hi)
 
     def _maybe_replan(self) -> None:
@@ -1283,17 +1219,6 @@ class InSituSession:
         self._bricks = new
         self._build_steps()
 
-    def _enter_regime(self, key) -> None:
-        if key != getattr(self, "_last_regime_key", key):
-            self.obs.count("regime_switches")
-            # carried reuse fragments share the temporal-threshold
-            # staleness policy: the field kept evolving while the
-            # camera was in another regime, and a re-entered regime's
-            # retained signature could mask that (the camera leaves
-            # match again) — re-seed instead
-            self._mxu_reuse.pop(key, None)
-        drop_on_regime_reentry(self, self._mxu_thr, key)
-
     def _note_dirty(self, ru) -> None:
         """Host-side accounting of the reuse carry's LAST decision
         (docs/OBSERVABILITY.md): ``delta_march_skipped`` counts tiles
@@ -1317,250 +1242,6 @@ class InSituSession:
                        skipped_tiles=clean * tiles_per_rank,
                        total_tiles=n * tiles_per_rank)
 
-    # ------------------------------------------------- frame-scan blocks
-
-    def _scan_supported(self):
-        """Can this session roll frames into lax.scan blocks? Volume-sim
-        VDI sessions only: particles/hybrid/plain carry host-side render
-        state per frame, and a custom sim adapter gives no traceable
-        (state, advance) pair."""
-        if self.mode != "vdi":
-            return False, f"mode {self.mode!r} (volume VDI sessions only)"
-        if not isinstance(self.sim, VolumeSimAdapter):
-            return (False, "custom sim adapter (need the built-in "
-                           "traceable state/advance pair)")
-        return True, ""
-
-    def _scan_runner(self, block: int, regime):
-        """Build (or fetch) the scanned-block executable for a march
-        regime (None = the gather engine's regime-free step) and block
-        size; returns (runner, seed) where seed is the temporal
-        threshold seeder or None."""
-        from scenery_insitu_tpu.parallel.pipeline import (
-            distributed_initial_threshold_mxu, distributed_vdi_step_mxu,
-            distributed_vdi_step_mxu_temporal, frame_scan)
-
-        key = ("scan", regime, block)
-        entry = self._scan_steps.get(key)
-        if entry is None:
-            # cache miss = one fresh scan-block jit at next dispatch
-            self.obs.count("compile_scan_block")
-            self.obs.event("compile", frame=self.frame_index,
-                           what="scan_block", regime=str(regime),
-                           block=block)
-            comp_cfg = self.cfg.composite
-            if self._reuse:
-                # the scan body does not thread the reuse carry — a
-                # scanned block re-marches every frame (the scan's
-                # whole point is zero host round trips per frame, which
-                # is also what the host-held carry would need)
-                import dataclasses as _dc
-
-                comp_cfg = _dc.replace(comp_cfg, temporal_reuse="off")
-                _obs.degrade("delta.reuse", "ranges", "off",
-                             "scan blocks do not thread the reuse "
-                             "carry; scanned frames re-march",
-                             warn=False)
-            if regime is None:
-                step, seed = self._step, None
-            else:
-                n = self._n_ranks
-                spec = self._slicer.make_spec(
-                    self.camera, self.sim.field.shape, self.cfg.slicer,
-                    axis_sign=regime, multiple_of=n)
-                if self._temporal:
-                    step = distributed_vdi_step_mxu_temporal(
-                        self.mesh, self.tf, spec, self.cfg.vdi,
-                        comp_cfg, plan=self._plan, bricks=self._bricks,
-                        topology=self.cfg.topology)
-                    seed = distributed_initial_threshold_mxu(
-                        self.mesh, self.tf, spec, self.cfg.vdi,
-                        plan=self._plan, bricks=self._bricks)
-                else:
-                    step = distributed_vdi_step_mxu(
-                        self.mesh, self.tf, spec, self.cfg.vdi,
-                        comp_cfg, plan=self._plan, bricks=self._bricks,
-                        topology=self.cfg.topology)
-                    seed = None
-            steps_per_frame = self.cfg.sim.steps_per_frame
-            advance = lambda s: self.sim._advance(s, steps_per_frame)
-            entry = (frame_scan(step, advance, block,
-                                temporal=self._temporal), seed)
-            self._scan_steps[key] = entry
-        return entry
-
-    def _run_scan(self, frames: int, fetch: bool,
-                  profile_dir: Optional[str]) -> dict:
-        """The scan-block twin of the eager loop: identical frames (same
-        sim advance, same per-frame camera ladder, same metadata), one
-        executable launch per block. Steering drains and regime changes
-        take effect at block boundaries only; a block whose host-replayed
-        camera path crosses march regimes runs eagerly instead (a scan
-        body cannot re-specialize mid-block). In temporal mode a missing
-        threshold state is seeded from the PRE-block field (the eager
-        loop seeds post-advance — one frame of controller lag, adapted
-        away like any temporal-mode scene change)."""
-        import contextlib
-
-        ctx = (jax.profiler.trace(profile_dir) if profile_dir
-               else contextlib.nullcontext())
-        payload = {}
-        try:
-            with ctx:
-                payload = self._scan_loop(frames, fetch, payload)
-        except BaseException:
-            # flight recorder (same contract as the eager loop): drain
-            # the delivery queue first, then dump
-            if self._delivery is not None:
-                self._delivery.drain()
-            _obs.flight_flush(self.obs, where="run_scan")
-            if self._obs_pub is not None:
-                self._obs_pub.pump(self.obs, force=True)
-            raise
-        if self._delivery is not None:
-            self._delivery.drain()
-        self.timers.dump_totals()
-        self.obs.flush()
-        if self._obs_pub is not None:
-            self._obs_pub.pump(self.obs, force=True)
-        return payload
-
-    def _scan_loop(self, frames: int, fetch: bool, payload: dict) -> dict:
-        done = 0
-        while done < frames:
-            t_blk = time.perf_counter()
-            block = min(self.cfg.runtime.scan_frames, frames - done)
-            drain_steering(self)
-            self._maybe_replan()
-            # host replay of the block's camera ladder — frame i of
-            # the scan renders with exactly this camera (orbit is
-            # applied identically in-scan)
-            cams = [self.camera]
-            for _ in range(block - 1):
-                cams.append(orbit(cams[-1],
-                                  jnp.float32(self.orbit_rate)))
-            mxu = self._step is None
-            regime = None
-            crossing = False
-            if mxu:
-                regimes = {self._slicer.choose_axis(c) for c in cams}
-                crossing = len(regimes) > 1
-            # eager fallback for blocks the cached scan executable
-            # cannot serve: a regime crossing (the step is
-            # regime-specialized) or a short TAIL block (compiling a
-            # one-off scan of the whole pipeline for a different
-            # length costs far more than the frames it would save)
-            if crossing or block < self.cfg.runtime.scan_frames:
-                if crossing:
-                    self.log(f"scan_frames: march regime crossing "
-                             f"inside a {block}-frame block — running "
-                             "it eagerly")
-                    _obs.degrade(
-                        "session.scan_block", "scan", "eager",
-                        "march regime crossing inside a block",
-                        warn=False)
-                else:
-                    # a tail block is expected on long runs, but it
-                    # still ran eagerly — the ledger must say so (a
-                    # run SHORTER than scan_frames is all tail, and
-                    # an empty ledger would read as "scan was live")
-                    self.obs.count("scan_tail_eager_frames", block)
-                    self.log(f"scan_frames: {block}-frame tail block "
-                             "below the scan length — running it "
-                             "eagerly")
-                    _obs.degrade(
-                        "session.scan_block", "scan", "eager",
-                        "tail block shorter than scan_frames",
-                        warn=False)
-                for _ in range(block):
-                    out = self.render_frame()
-                    if fetch:
-                        payload = self._fetch(self.frame_index - 1,
-                                              out)
-                    self.timers.frame_done()
-                self._scan_block_done(t_blk, block)
-                done += block
-                continue
-            if mxu:
-                regime = next(iter(regimes))
-                if self._temporal:
-                    self._enter_regime(regime)
-            runner, seed = self._scan_runner(block, regime)
-            self.obs.count("scan_blocks_dispatched")
-            self.obs.count("frames_scan_dispatch", block)
-            with self.obs.span("dispatch", frame=self.frame_index,
-                               scan_block=block,
-                               regime=str(regime)):
-                args = (self.sim.state, self._origin, self._spacing,
-                        self.camera, jnp.float32(self.orbit_rate))
-                if self._temporal:
-                    thr = self._mxu_thr.get(regime)
-                    if thr is None:
-                        field = self._shard(self.sim.field)
-                        thr = seed(field, self._origin, self._spacing,
-                                   self.camera)
-                    (st, cam, thr2), outs = runner(*args, thr)
-                    self._mxu_thr[regime] = thr2
-                else:
-                    (st, cam, _), outs = runner(*args)
-            self.sim.state = st
-            self.camera = cam
-            start = self.frame_index
-            self.frame_index += block
-            if fetch:
-                vdi = outs[0] if mxu else outs
-                metas = outs[1] if mxu else None
-                with self.obs.span("fetch", frame=start,
-                                   scan_block=block):
-                    if self._n_ranks > 1 or self.obs.enabled:
-                        vdi = self._to_host(start, vdi)
-                    color = np.asarray(vdi.color)
-                    depth = np.asarray(vdi.depth)
-                for i in range(block):
-                    idx = start + i
-                    if metas is not None:
-                        meta = jax.tree_util.tree_map(
-                            lambda x, i=i: x[i], metas)
-                        meta = meta._replace(index=jnp.int32(idx))
-                    else:
-                        meta = self.frame_metadata(idx, camera=cams[i])
-                    tiled = bool(self.tile_sinks) \
-                        and self.cfg.composite.schedule == "waves"
-                    payload = {"vdi_color": color[i],
-                               "vdi_depth": depth[i],
-                               "frame": idx, "meta": meta}
-                    if self._delivery is not None:
-                        tiles = (self._tile_payloads(
-                            idx, meta, color[i], depth[i])
-                            if tiled else ())
-                        self._delivery.submit(idx, payload, tiles)
-                    else:
-                        if tiled:
-                            self._deliver_tiles(idx, None, meta,
-                                                color=color[i],
-                                                depth=depth[i])
-                        with self.obs.span("sinks", frame=idx):
-                            self._sink_guard.run(self.sinks, idx,
-                                                 payload)
-                    self.timers.frame_done()
-            else:
-                for _ in range(block):
-                    self.timers.frame_done()
-            self._scan_block_done(t_blk, block)
-            done += block
-        return payload
-
-    def _scan_block_done(self, t_blk: float, block: int) -> None:
-        """Per-block SLO + telemetry bookkeeping: the block's wall clock
-        amortizes over its frames (the scan's per-frame latency is the
-        block mean by construction)."""
-        dt_ms = (time.perf_counter() - t_blk) * 1e3 / max(1, block)
-        for i in range(block):
-            self.slo.observe("frame_ms", dt_ms,
-                             frame=self.frame_index - block + i)
-        if self._obs_pub is not None:
-            self._obs_pub.pump(self.obs)
-
     def prewarm_regimes(self, regimes=None) -> dict:
         """Precompile the distributed MXU step for each (axis, sign) march
         regime BEFORE the camera path reaches it. A regime crossing
@@ -1581,254 +1262,159 @@ class InSituSession:
         regimes: iterable of (axis, sign); default all six.
         Returns {(axis, sign): seconds} (compile + one frame each).
         """
-        import time as _time
-
-        if self.engine != "mxu" or self.mode == "particles" \
-                or (self.mode == "vdi" and self._step is not None):
+        if self._regime_mode is None:
             return {}
         if regimes is None:
             regimes = [(a, s) for a in (0, 1, 2) for s in (1, -1)]
         cam0 = self.camera
-        thr0 = dict(self._mxu_thr)
-        reuse0 = dict(self._mxu_reuse)
-        had_last = hasattr(self, "_last_regime_key")
-        last0 = getattr(self, "_last_regime_key", None)
+        kept = self._steps.snapshot()
         times = {}
         try:
             for regime in regimes:
-                a, s = regime
-                cam = regime_camera(cam0, regime, self._slicer)
-                self.camera = cam
-                t0 = _time.perf_counter()
+                self.camera = regime_camera(cam0, regime, self._slicer)
+                t0 = time.perf_counter()
                 with self.obs.span("prewarm", frame=self.frame_index,
                                    regime=str(regime)):
-                    if self.mode == "hybrid":
-                        out, _ = self._hybrid_dispatch()
-                    else:
-                        field = self._shard(self.sim.field)
-                        if self.mode == "plain":
-                            out = self._plain_mxu_dispatch(field)
-                        else:
-                            out, _ = self._mxu_step()(field, self._origin,
-                                                      self._spacing, cam)
+                    out, _ = self._regime_frame()
                     jax.block_until_ready(out)
-                times[(a, s)] = round(_time.perf_counter() - t0, 2)
+                times[tuple(regime)] = round(time.perf_counter() - t0, 2)
         finally:
             self.camera = cam0
-            self._mxu_thr = thr0
-            self._mxu_reuse = reuse0
-            if had_last:
-                self._last_regime_key = last0
-            elif hasattr(self, "_last_regime_key"):
-                del self._last_regime_key
+            self._steps.restore(kept)
         return times
 
-    def _hybrid_dispatch(self):
-        """Dispatch one distributed hybrid frame: volume VDI + tracers,
-        merged on the virtual grid, warped to the display camera. In
-        temporal mode the VDI pass carries per-regime threshold state
-        (seeded on first use) exactly like the plain VDI pipeline."""
-        from scenery_insitu_tpu.core.volume import Volume
-        from scenery_insitu_tpu.parallel.particles import shard_particles
-        from scenery_insitu_tpu.parallel.pipeline import (
-            distributed_hybrid_step_mxu, distributed_initial_threshold_mxu)
-        from scenery_insitu_tpu.sim import vortex as _vx
+    # ------------------------------------------- per-regime compiled steps
 
-        regime = camera_regime(self, "hybrid")
-        key = ("hybrid",) + regime
-        if self._temporal:
-            self._enter_regime(key)
-        entry = self._mxu_steps.get(key)
-        if entry is None:
-            self.obs.count("compile_step")
-            self.obs.event("compile", frame=self.frame_index,
-                           what="hybrid_step", regime=str(regime))
-            n = self._n_ranks
-            spec = self._slicer.make_spec(self.camera, self.sim.field.shape,
-                                          self.cfg.slicer, axis_sign=regime,
-                                          multiple_of=n)
-            step = scoped_step(distributed_hybrid_step_mxu(
-                self.mesh, self.tf, spec, self.cfg.vdi, self.cfg.composite,
-                radius=self.cfg.sim.particle_radius * float(self._spacing[0]),
-                stamp=5, temporal=self._temporal, plan=self._plan,
-                bricks=self._bricks, topology=self.cfg.topology), self.obs)
-            seed = (distributed_initial_threshold_mxu(
-                        self.mesh, self.tf, spec, self.cfg.vdi,
-                        plan=self._plan)
-                    if self._temporal else None)
-            r = self.cfg.render
-            slicer = self._slicer
-
-            @jax.jit
-            def warp(img, field, cam):
-                vol = Volume(field, self._origin, self._spacing)
-                axcam = slicer.make_axis_camera(vol, cam, spec)
-                return slicer.warp_to_camera(img, axcam, spec, cam,
-                                             r.width, r.height, r.background)
-
-            entry = (step, seed, warp)
-            self._mxu_steps[key] = entry
-        step, seed, warp = entry
-        field = self.sim.field
-        vel = _vx.tracer_velocities(self.sim.flow.u, self.sim.tracers)
-        world = _vx.tracers_to_world(self.sim.tracers, self._origin,
-                                     self._spacing)
-        sfield = self._shard(field)
-        args = (sfield, self._origin, self._spacing,
-                shard_particles(world, self.mesh),
-                shard_particles(vel, self.mesh), self.camera)
-        if self._temporal:
-            thr = self._mxu_thr.get(key)
-            if thr is None:
-                thr = seed(sfield, self._origin, self._spacing, self.camera)
-            (img, meta), self._mxu_thr[key] = step(*args, thr)
-        else:
-            img, meta = step(*args)
-        return warp(img, field, self.camera), meta
-
-    def _plain_mxu_dispatch(self, field):
-        """Dispatch one distributed plain-image frame on the slice-march
-        engine: per-rank `render_slices` + column all_to_all + nearest-
-        first composite (one SPMD program per march regime), then the
-        homography warp to the display camera."""
-        from scenery_insitu_tpu.parallel.pipeline import (
-            distributed_plain_step_mxu)
-
-        regime = camera_regime(self, "plain")
-        key = ("plain",) + regime
-        entry = self._mxu_steps.get(key)
-        if entry is None:
-            self.obs.count("compile_step")
-            self.obs.event("compile", frame=self.frame_index,
-                           what="plain_step", regime=str(regime))
-            n = self._n_ranks
-            spec = self._slicer.make_spec(self.camera, self.sim.field.shape,
-                                          self.cfg.slicer, axis_sign=regime,
-                                          multiple_of=n)
-            cc = self.cfg.composite
-            step = scoped_step(distributed_plain_step_mxu(
-                self.mesh, self.tf, spec, self.cfg.render,
-                exchange=cc.exchange,
-                wire=cc.wire,
-                schedule=cc.schedule,
-                wave_tiles=cc.wave_tiles,
-                rebalance=cc.rebalance,
-                rebalance_period=cc.rebalance_period,
-                rebalance_hysteresis=cc.rebalance_hysteresis,
-                rebalance_min_depth=cc.rebalance_min_depth,
-                rebalance_quantum=cc.rebalance_quantum,
-                rebalance_bricks=cc.rebalance_bricks,
-                rebalance_max_moves=cc.rebalance_max_moves,
-                temporal_reuse=cc.temporal_reuse,
-                plan=self._plan, bricks=self._bricks,
-                topology=self.cfg.topology), self.obs)
-            r = self.cfg.render
-            slicer = self._slicer
-
-            @jax.jit
-            def warp(img, axcam, cam):
-                return slicer.warp_to_camera(img, axcam, spec, cam,
-                                             r.width, r.height, r.background)
-
-            entry = (step, warp)
-            self._mxu_steps[key] = entry
-        step, warp = entry
-        img, axcam = step(field, self._origin, self._spacing, self.camera)
-        return warp(img, axcam, self.camera)
-
-    def _mxu_step(self):
-        """Jitted MXU distributed step for the camera's current march
-        regime; one compilation per (axis, sign), cached (the camera may
-        orbit across axis boundaries mid-session). In temporal mode the
-        returned callable seeds and threads the per-regime threshold
-        state internally, so callers see the same 4-arg signature."""
-        from scenery_insitu_tpu.parallel.pipeline import (
-            distributed_initial_reuse_mxu,
-            distributed_initial_threshold_mxu, distributed_vdi_step_mxu,
-            distributed_vdi_step_mxu_temporal)
-
-        regime = camera_regime(self, "mxu_step")
+    def _regime_frame(self):
+        """Dispatch one frame of a mode whose step is compiled per march
+        regime (MXU VDI, hybrid, plain MXU; the camera may orbit across
+        axis boundaries mid-session): the camera's regime, the table's
+        entry for it — built on a miss from the regime's `AxisSpec` —
+        and the mode's own call. Returns ``(out, meta)``, ``meta`` None
+        where the step gives none."""
+        mode = self._regime_mode
+        regime = camera_regime(self, mode.site)
+        key = mode.prefix + regime
+        table = self._steps
         if self._temporal or self._reuse:
-            self._enter_regime(regime)
-        step = self._mxu_steps.get(regime)
-        if step is None:
-            self.obs.count("compile_step")
-            self.obs.event("compile", frame=self.frame_index,
-                           what="vdi_step", regime=str(regime))
-            n = self._n_ranks
-            spec = self._slicer.make_spec(self.camera, self.sim.field.shape,
-                                          self.cfg.slicer, axis_sign=regime,
-                                          multiple_of=n)
-            tol = self.cfg.delta.range_tol
-            rseed = (distributed_initial_reuse_mxu(
-                         self.mesh, self.tf, spec, self.cfg.vdi,
-                         self.cfg.composite, plan=self._plan)
-                     if self._reuse else None)
-            if self._temporal:
-                inner = scoped_step(distributed_vdi_step_mxu_temporal(
-                    self.mesh, self.tf, spec, self.cfg.vdi,
-                    self.cfg.composite, plan=self._plan,
-                    bricks=self._bricks, reuse_tol=tol,
-                    topology=self.cfg.topology), self.obs)
-                seed = distributed_initial_threshold_mxu(
-                    self.mesh, self.tf, spec, self.cfg.vdi,
-                    plan=self._plan, bricks=self._bricks)
+            table.enter(key)
+        entry = table.steps.get(key)
+        if entry is None:
+            entry = table.compile(
+                key, lambda: mode.entry(self._slicer.make_spec(
+                    self.camera, self.sim.field.shape, self.cfg.slicer,
+                    axis_sign=regime, multiple_of=self._n_ranks)),
+                self.frame_index, mode.what, regime)
+        return mode.frame(key, entry)
 
-                def step(field, origin, spacing, cam,
-                         _regime=regime, _inner=inner, _seed=seed,
-                         _rseed=rseed):
-                    thr = self._mxu_thr.get(_regime)
-                    if thr is None:
-                        thr = _seed(field, origin, spacing, cam)
-                    if _rseed is None:
-                        out, self._mxu_thr[_regime] = _inner(
-                            field, origin, spacing, cam, thr)
-                        return out
-                    ru = self._mxu_reuse.get(_regime)
-                    if ru is None:
-                        ru = _rseed(field, origin, spacing, cam)
-                    if getattr(self.obs, "enabled", False):
-                        self._note_dirty(ru)
-                    out, self._mxu_thr[_regime], \
-                        self._mxu_reuse[_regime] = _inner(
-                            field, origin, spacing, cam, thr, ru)
-                    return out
-            elif self._reuse:
-                inner = scoped_step(distributed_vdi_step_mxu(
-                    self.mesh, self.tf, spec, self.cfg.vdi,
-                    self.cfg.composite, plan=self._plan, reuse_tol=tol,
-                    topology=self.cfg.topology), self.obs)
-                # (bricks force _reuse off at _build_steps, so this
-                # branch never carries a brick map)
+    def _field_args(self):
+        return (self._shard(self.sim.field), self._origin, self._spacing,
+                self.camera)
 
-                def step(field, origin, spacing, cam,
-                         _regime=regime, _inner=inner, _rseed=rseed):
-                    ru = self._mxu_reuse.get(_regime)
-                    if ru is None:
-                        ru = _rseed(field, origin, spacing, cam)
-                    if getattr(self.obs, "enabled", False):
-                        self._note_dirty(ru)
-                    out, self._mxu_reuse[_regime] = _inner(
-                        field, origin, spacing, cam, ru)
-                    return out
-            else:
-                step = scoped_step(distributed_vdi_step_mxu(
-                    self.mesh, self.tf, spec, self.cfg.vdi,
-                    self.cfg.composite, plan=self._plan,
-                    bricks=self._bricks, topology=self.cfg.topology), self.obs)
-            self._mxu_steps[regime] = step
-        return step
+    def _vdi_entry(self, spec) -> StepEntry:
+        """The MXU sort-last VDI step for ``spec``'s regime, with the
+        seeders of the state it carries: threshold maps in temporal
+        mode, marched fragments under ``temporal_reuse`` (bricks turn
+        that off at `_build_steps`, so a reuse step never carries a
+        brick map)."""
+        from scenery_insitu_tpu.parallel import pipeline
 
-    def frame_metadata(self, index: int, camera: Optional[Camera] = None):
+        c = self.cfg
+        build = (pipeline.distributed_vdi_step_mxu_temporal
+                 if self._temporal else pipeline.distributed_vdi_step_mxu)
+        return StepEntry(
+            build(self.mesh, self.tf, spec, c.vdi, c.composite,
+                  reuse_tol=c.delta.range_tol, **self._decomp()),
+            seed_thr=(pipeline.distributed_initial_threshold_mxu(
+                self.mesh, self.tf, spec, c.vdi, plan=self._plan,
+                bricks=self._bricks) if self._temporal else None),
+            seed_reuse=(pipeline.distributed_initial_reuse_mxu(
+                self.mesh, self.tf, spec, c.vdi, c.composite,
+                plan=self._plan) if self._reuse else None))
+
+    def _vdi_frame(self, key, entry):
+        if self._reuse and self.obs.enabled:
+            ru = self._steps.reuse.get(key)
+            if ru is not None:
+                self._note_dirty(ru)
+        return self._steps.run(key, entry, self._field_args())
+
+    def _hybrid_entry(self, spec) -> StepEntry:
+        """Distributed hybrid frame: volume VDI + tracers, merged on the
+        virtual grid, then warped to the display camera. In temporal
+        mode the VDI pass carries per-regime threshold state exactly
+        like the plain VDI pipeline."""
+        from scenery_insitu_tpu.core.volume import Volume
+        from scenery_insitu_tpu.parallel import pipeline
+
+        c, r, slicer = self.cfg, self.cfg.render, self._slicer
+
+        @jax.jit
+        def warp(img, field, cam):
+            vol = Volume(field, self._origin, self._spacing)
+            axcam = slicer.make_axis_camera(vol, cam, spec)
+            return slicer.warp_to_camera(img, axcam, spec, cam,
+                                         r.width, r.height, r.background)
+
+        return StepEntry(
+            pipeline.distributed_hybrid_step_mxu(
+                self.mesh, self.tf, spec, c.vdi, c.composite,
+                radius=c.sim.particle_radius * float(self._spacing[0]),
+                stamp=5, temporal=self._temporal, **self._decomp()),
+            seed_thr=(pipeline.distributed_initial_threshold_mxu(
+                self.mesh, self.tf, spec, c.vdi, plan=self._plan)
+                if self._temporal else None),
+            after=warp)
+
+    def _hybrid_frame(self, key, entry):
+        from scenery_insitu_tpu.parallel.particles import shard_particles
+
+        field = self.sim.field
+        vel = vx.tracer_velocities(self.sim.flow.u, self.sim.tracers)
+        world = vx.tracers_to_world(self.sim.tracers, self._origin,
+                                    self._spacing)
+        sfield = self._shard(field)
+        img, meta = self._steps.run(
+            key, entry,
+            (sfield, self._origin, self._spacing,
+             shard_particles(world, self.mesh),
+             shard_particles(vel, self.mesh), self.camera),
+            seed_args=(sfield, self._origin, self._spacing, self.camera))
+        return entry.after(img, field, self.camera), meta
+
+    def _plain_entry(self, spec) -> StepEntry:
+        """Distributed plain-image frame on the slice-march engine:
+        per-rank `render_slices` + column all_to_all + nearest-first
+        composite (one SPMD program per march regime), then the
+        homography warp to the display camera."""
+        from scenery_insitu_tpu.parallel import pipeline
+
+        c, r, slicer = self.cfg, self.cfg.render, self._slicer
+
+        @jax.jit
+        def warp(img, axcam, cam):
+            return slicer.warp_to_camera(img, axcam, spec, cam,
+                                         r.width, r.height, r.background)
+
+        return StepEntry(
+            pipeline.distributed_plain_step_mxu(
+                self.mesh, self.tf, spec, r, comp_cfg=c.composite,
+                **self._decomp()),
+            after=warp)
+
+    def _plain_frame(self, key, entry):
+        img, axcam = self._steps.run(key, entry, self._field_args())
+        return entry.after(img, axcam, self.camera), None
+
+    def frame_metadata(self, index: int):
         """VDIMetadata for the current camera/volume placement (≅ the
         per-frame VDIData the reference builds, DistributedVolumes.kt:
-        706-716). NOTE: built from the CURRENT camera (or the explicit
-        ``camera`` — the scan path replays the block's camera ladder) —
-        call before the camera advances for exact correspondence."""
+        706-716). NOTE: built from the CURRENT camera — call before the
+        camera advances for exact correspondence."""
         from scenery_insitu_tpu.core.camera import (projection_matrix,
                                                     view_matrix)
         from scenery_insitu_tpu.core.vdi import VDIMetadata
-        camera = camera if camera is not None else self.camera
+        camera = self.camera
         r = self.cfg.render
         shape = (np.asarray(self.sim.field.shape)
                  if hasattr(self.sim, "field") else np.zeros(3, np.int32))
@@ -1841,38 +1427,40 @@ class InSituSession:
 
     def device_snapshot(self) -> dict:
         """Per-regime XLA cost-analysis snapshot (bytes/flops) of every
-        compiled step this session holds, keyed like the step caches
+        compiled step this session holds, keyed like the step table
         (obs/device.cost_snapshot — the same numbers bench.py's roofline
-        fields use). Best-effort: steps that are host-side closures
-        (temporal mode threads threshold state in Python) or whose mode
-        takes different operands report as unavailable rather than
-        raising; lowering hits the compile cache, so this is cheap after
-        the first frame. The snapshot is also recorded as an obs event so
-        a metrics file carries the device-side truth next to the spans."""
+        fields use). Best-effort: a step whose carried state is not
+        seeded yet, or whose mode takes operands this generic path does
+        not reconstruct, reports as unavailable rather than raising;
+        lowering hits the compile cache, so this is cheap after the
+        first frame. The snapshot is also recorded as an obs event so a
+        metrics file carries the device-side truth next to the spans."""
         from scenery_insitu_tpu.obs import device as _dev
 
+        table = self._steps
         snaps = {}
         if self.mode in ("vdi", "plain"):
-            field = self._shard(self.sim.field)
-            args = (field, self._origin, self._spacing, self.camera)
-            if self._step is not None:
+            args = self._field_args()
+            if table.fixed is not None:
                 snaps["gather" if self.mode == "vdi" else "plain"] = \
-                    _dev.cost_snapshot(self._step, *args)
-            for key, entry in self._mxu_steps.items():
-                step = entry[0] if isinstance(entry, tuple) else entry
-                if not hasattr(step, "lower"):
+                    _dev.cost_snapshot(table.fixed, *args)
+            for key, entry in table.steps.items():
+                carried = [store.get(key) for seed, store in
+                           ((entry.seed_thr, table.thr),
+                            (entry.seed_reuse, table.reuse))
+                           if seed is not None]
+                if any(state is None for state in carried):
                     snaps[str(key)] = {"source": "unavailable",
-                                       "error": "host-side closure "
-                                                "(temporal step)"}
+                                       "error": "carried state not "
+                                                "seeded yet"}
                     continue
-                snaps[str(key)] = _dev.cost_snapshot(step, *args)
+                snaps[str(key)] = _dev.cost_snapshot(entry.step, *args,
+                                                     *carried)
         else:
             # hybrid/particle steps take mode-specific operands this
             # generic path does not reconstruct — report them as
             # unavailable rather than returning an empty dict
-            keys = (list(self._mxu_steps) if self._mxu_steps
-                    else ([self.mode] if self._step is not None else []))
-            for key in keys:
+            for key in (list(table.steps) or [self.mode]):
                 snaps[str(key)] = {"source": "unavailable",
                                    "error": f"mode {self.mode!r} operands "
                                             "not snapshotted"}

@@ -7,9 +7,9 @@ had to repeat:
 
 - ``SITPU-LEDGER`` (ledger.py): behavior-changing fallback branches must
   mint ``obs.degrade`` entries (PR 3's completeness invariant).
-- ``SITPU-THREAD`` (thread.py): the CompositeConfig knob matrix — derived
-  from the dataclass fields — threads through every distributed step
-  builder and the session plumbing (the PR 4/5/8 audit).
+- ``SITPU-THREAD`` (thread.py): every distributed step builder takes the
+  CompositeConfig whole, forwards it and never rebuilds it, and consumes
+  the topology (the PR 4/5/8 audit).
 - ``SITPU-TRACE`` (trace.py): host-sync / retrace hazards inside
   jitted/scanned code (protects the pipelined overlap structure).
 - ``SITPU-PALLAS`` (pallas.py): every ``pallas_call`` sits behind a

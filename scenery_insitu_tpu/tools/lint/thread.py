@@ -1,45 +1,35 @@
-"""SITPU-THREAD — CompositeConfig knob threading through the distributed
-step builders.
+"""SITPU-THREAD — CompositeConfig threading through the distributed step
+builders.
 
-PRs 4, 5, 6 and 8 each added a ``CompositeConfig`` knob (``exchange``,
+PRs 4, 5, 6 and 8 each added a ``CompositeConfig`` field (``exchange``,
 ``wire``, ``k_budget``, ``schedule``/``wave_tiles``) and each had to
-hand-audit that EVERY distributed step builder and the session plumbing
-forwarded it — a mechanical invariant that rots silently: a builder that
-drops a knob still renders, it just quietly ignores the configuration
-(exactly the reference's three-tier config failure mode the config module
-docstring complains about).
-
-The knob matrix is DERIVED from ``config.py``'s ``CompositeConfig``
-dataclass fields (minus the composite-internal fields that the composite
-fold itself consumes — ``max_output_supersegments``, ``adaptive``,
-``adaptive_iters``, ``backend``, ``k_budget_min``), so a future PR that
-adds a field gets enforcement for free: the new knob fails SITPU-THREAD on
-every builder until it is threaded (or explicitly baselined where
-inapplicable, e.g. the plain-image builders have no per-pixel K working
-set for ``ring_slots`` to cap).
+hand-audit that EVERY distributed step builder forwarded it — a
+mechanical invariant that rots silently: a builder that drops a field
+still renders, it just quietly ignores the configuration (exactly the
+reference's three-tier config failure mode the config module docstring
+complains about). Since PR 29 every builder takes the config object
+whole, so a new field rides along; what is left to check is that nobody
+unpacks it again.
 
 Rules, per builder (top-level ``distributed_*step*`` / ``_build_mxu_step``
 in ``parallel/pipeline.py``):
 
-- **whole-object builders** (a ``comp_cfg`` parameter): the config object
-  must be forwarded — appear as a direct argument of some call in the
-  body (including nested defs). Rebuilding it (``dataclasses.replace`` /
-  a fresh ``CompositeConfig(...)``) inside such a builder is flagged:
-  that is how whole-object threading silently drops knobs.
-- **explicit-knob builders** (no ``comp_cfg``): every knob in the matrix
-  must be accepted as a parameter of that exact name AND forwarded (used
-  as a call argument somewhere in the body).
-- **session plumbing** (``runtime/session.py``): every call to a
-  pipeline builder must bind ``comp_cfg`` (positionally or by keyword)
-  for whole-object builders, and pass each accepted knob by name for
-  explicit-knob builders.
+- it takes the config whole: a ``comp_cfg`` parameter. Loose per-field
+  parameters in its place are how a field gets lost between the session
+  and the composite.
+- ``comp_cfg`` must be forwarded — appear as a direct argument of some
+  call in the body (including nested defs). Rebuilding it
+  (``dataclasses.replace`` / a fresh ``CompositeConfig(...)`` with
+  fields) inside a builder is flagged: that is how whole-object
+  threading silently drops fields.
+- it must accept AND consume ``topology`` (below).
 """
 
 from __future__ import annotations
 
 import ast
 import re
-from typing import Dict, List, Optional
+from typing import List
 
 from scenery_insitu_tpu.tools.lint.core import (Diagnostic, SourceFile,
                                                 func_params, iter_calls)
@@ -52,33 +42,9 @@ COMP_PARAM = "comp_cfg"
 # the scale-out plane (docs/MULTIHOST.md): every distributed step builder
 # must accept AND forward the TopologyConfig — a builder that drops it
 # silently renders the flat single-domain composite on a hierarchical
-# mesh, exactly the class of rot this checker exists for. Enforced for
-# whole-object and explicit-knob builders alike (topology is its own
-# config object, not a CompositeConfig field), and the session must bind
-# it at every builder call.
+# mesh, exactly the class of rot this checker exists for (topology is
+# its own config object, not a CompositeConfig field).
 TOPO_PARAM = "topology"
-
-# consumed inside the composite fold itself (ops/composite.py), not
-# threaded through builder signatures; everything else in CompositeConfig
-# is a knob by default — new fields are enforced automatically
-NON_THREADED_FIELDS = {"max_output_supersegments", "adaptive",
-                       "adaptive_iters", "backend", "k_budget_min"}
-
-
-def derive_knobs(config_src: SourceFile) -> List[str]:
-    """CompositeConfig dataclass fields -> the threaded knob matrix."""
-    for node in config_src.tree.body:
-        if isinstance(node, ast.ClassDef) and node.name == COMPOSITE_CLASS:
-            fields = [s.target.id for s in node.body
-                      if isinstance(s, ast.AnnAssign)
-                      and isinstance(s.target, ast.Name)]
-            knobs = [f for f in fields if f not in NON_THREADED_FIELDS]
-            if knobs:
-                return knobs
-            raise ValueError(
-                f"{COMPOSITE_CLASS} in {config_src.path} has no threaded "
-                f"knob fields — NON_THREADED_FIELDS is stale")
-    raise ValueError(f"no {COMPOSITE_CLASS} dataclass in {config_src.path}")
 
 
 def _name_used_as_call_arg(fn: ast.AST, name: str) -> bool:
@@ -101,8 +67,7 @@ def _builders(pipeline_src: SourceFile) -> List[ast.FunctionDef]:
             if isinstance(n, ast.FunctionDef) and BUILDER_RE.match(n.name)]
 
 
-def _check_builder(src: SourceFile, fn: ast.FunctionDef,
-                   knobs: List[str]) -> List[Diagnostic]:
+def _check_builder(src: SourceFile, fn: ast.FunctionDef) -> List[Diagnostic]:
     diags = []
     params = func_params(fn)
     if TOPO_PARAM not in params:
@@ -116,122 +81,47 @@ def _check_builder(src: SourceFile, fn: ast.FunctionDef,
             src.path, fn.lineno, CODE,
             f"accepts '{TOPO_PARAM}' but never consumes it — the "
             f"hierarchical composite is silently dropped", fn.name))
-    if COMP_PARAM in params:
-        if not _name_used_as_call_arg(fn, COMP_PARAM):
-            diags.append(Diagnostic(
-                src.path, fn.lineno, CODE,
-                f"accepts {COMP_PARAM} but never forwards it — the whole "
-                f"knob matrix ({', '.join(knobs)}) is dropped", fn.name))
-        for c in iter_calls(fn):
-            callee = c.func
-            # a bare CompositeConfig() is the `comp_cfg or
-            # CompositeConfig()` default fill — only a RE-construction
-            # with explicit fields (or dataclasses.replace on the
-            # threaded object) can drop knobs
-            rebuilt = (isinstance(callee, ast.Name)
-                       and callee.id == COMPOSITE_CLASS
-                       and (c.args or c.keywords)) or \
-                      (isinstance(callee, ast.Attribute)
-                       and callee.attr == "replace"
-                       and any(isinstance(a, ast.Name)
-                               and a.id == COMP_PARAM for a in c.args))
-            if rebuilt:
-                diags.append(Diagnostic(
-                    src.path, c.lineno, CODE,
-                    f"rebuilds {COMPOSITE_CLASS} inside a whole-object "
-                    f"builder — knobs not restated here are silently "
-                    f"dropped; forward {COMP_PARAM} itself", fn.name))
+    if COMP_PARAM not in params:
+        diags.append(Diagnostic(
+            src.path, fn.lineno, CODE,
+            f"does not accept {COMP_PARAM} — a distributed builder takes "
+            f"the {COMPOSITE_CLASS} whole, never its fields one by one",
+            fn.name))
         return diags
-    for knob in knobs:
-        if knob not in params:
+    if not _name_used_as_call_arg(fn, COMP_PARAM):
+        diags.append(Diagnostic(
+            src.path, fn.lineno, CODE,
+            f"accepts {COMP_PARAM} but never forwards it — every "
+            f"{COMPOSITE_CLASS} field is dropped", fn.name))
+    for c in iter_calls(fn):
+        callee = c.func
+        # a bare CompositeConfig() is the `comp_cfg or
+        # CompositeConfig()` default fill — only a RE-construction
+        # with explicit fields (or dataclasses.replace on the
+        # threaded object) can drop fields
+        rebuilt = (isinstance(callee, ast.Name)
+                   and callee.id == COMPOSITE_CLASS
+                   and (c.args or c.keywords)) or \
+                  (isinstance(callee, ast.Attribute)
+                   and callee.attr == "replace"
+                   and any(isinstance(a, ast.Name)
+                           and a.id == COMP_PARAM for a in c.args))
+        if rebuilt:
             diags.append(Diagnostic(
-                src.path, fn.lineno, CODE,
-                f"does not accept knob '{knob}' "
-                f"(CompositeConfig field; explicit-knob builder must take "
-                f"the full matrix or baseline the gap)", fn.name))
-        elif not _name_used_as_call_arg(fn, knob):
-            diags.append(Diagnostic(
-                src.path, fn.lineno, CODE,
-                f"accepts knob '{knob}' but never forwards it",
-                fn.name))
-    return diags
-
-
-def _param_index(fn: ast.FunctionDef, name: str) -> Optional[int]:
-    a = fn.args
-    pos = [p.arg for p in a.posonlyargs + a.args]
-    return pos.index(name) if name in pos else None
-
-
-def _check_session_calls(session_src: SourceFile,
-                         builders: Dict[str, ast.FunctionDef],
-                         knobs: List[str],
-                         pipeline_path: str) -> List[Diagnostic]:
-    diags = []
-    for c in iter_calls(session_src.tree):
-        f = c.func
-        name = f.attr if isinstance(f, ast.Attribute) else \
-            getattr(f, "id", "")
-        fn = builders.get(name)
-        if fn is None:
-            continue
-        params = func_params(fn)
-        kw_names = {k.arg for k in c.keywords if k.arg}
-        has_doublestar = any(k.arg is None for k in c.keywords)
-        if TOPO_PARAM in params:
-            idx = _param_index(fn, TOPO_PARAM)
-            bound = (TOPO_PARAM in kw_names or has_doublestar
-                     or (idx is not None and len(c.args) > idx))
-            if not bound:
-                diags.append(Diagnostic(
-                    session_src.path, c.lineno, CODE,
-                    f"call to {name} does not bind '{TOPO_PARAM}' — the "
-                    f"session must thread cfg.topology (a hierarchical "
-                    f"mesh would silently composite flat)", "session"))
-        if COMP_PARAM in params:
-            idx = _param_index(fn, COMP_PARAM)
-            bound = (COMP_PARAM in kw_names or has_doublestar
-                     or (idx is not None and len(c.args) > idx))
-            if not bound:
-                diags.append(Diagnostic(
-                    session_src.path, c.lineno, CODE,
-                    f"call to {name} (defined {pipeline_path}) does not "
-                    f"bind {COMP_PARAM} — the session must thread "
-                    f"cfg.composite, not the builder default", "session"))
-            continue
-        for knob in knobs:
-            if knob not in params:
-                continue            # the builder-side rule owns that gap
-            idx = _param_index(fn, knob)
-            bound = (knob in kw_names or has_doublestar
-                     or (idx is not None and len(c.args) > idx))
-            if not bound:
-                diags.append(Diagnostic(
-                    session_src.path, c.lineno, CODE,
-                    f"call to {name} does not forward knob '{knob}' "
-                    f"(builder defaults mask cfg.composite.{knob})",
-                    "session"))
+                src.path, c.lineno, CODE,
+                f"rebuilds {COMPOSITE_CLASS} inside a builder — fields "
+                f"not restated here are silently dropped; forward "
+                f"{COMP_PARAM} itself", fn.name))
     return diags
 
 
 def check(sources: List[SourceFile],
-          config_path: str = "scenery_insitu_tpu/config.py",
-          pipeline_path: str = "scenery_insitu_tpu/parallel/pipeline.py",
-          session_paths: tuple = (
-              "scenery_insitu_tpu/runtime/session.py",)) -> List[Diagnostic]:
-    by_path = {s.path: s for s in sources}
-    config_src = by_path.get(config_path)
-    pipeline_src = by_path.get(pipeline_path)
-    if config_src is None or pipeline_src is None:
-        return []            # custom path sets without the core files
-    knobs = derive_knobs(config_src)
+          pipeline_path: str = "scenery_insitu_tpu/parallel/pipeline.py"
+          ) -> List[Diagnostic]:
+    pipeline_src = {s.path: s for s in sources}.get(pipeline_path)
+    if pipeline_src is None:
+        return []            # custom path sets without the core file
     diags: List[Diagnostic] = []
-    builders = {}
     for fn in _builders(pipeline_src):
-        builders[fn.name] = fn
-        diags.extend(_check_builder(pipeline_src, fn, knobs))
-    for sp in session_paths:
-        if sp in by_path:
-            diags.extend(_check_session_calls(by_path[sp], builders, knobs,
-                                              pipeline_path))
+        diags.extend(_check_builder(pipeline_src, fn))
     return diags

@@ -1,26 +1,35 @@
-# SITPU-THREAD bad fixture: distributed step builders that drop knobs.
-# Parsed by the linter only.
+# SITPU-THREAD bad fixture: distributed step builders that lose
+# CompositeConfig fields. Parsed by the linter only.
+import dataclasses
 
 
-def distributed_bad_step(mesh, tf, width, height,
-                         exchange="all_to_all", wire="f32",
-                         schedule="frame", wave_tiles=4,
-                         ring_slots=0, k_budget="static"):
-    """Accepts the full knob matrix but the ``wire`` forwarding has been
-    DELETED (the acceptance-criteria demo: this is exactly what removing
-    ``wire=...`` from a real builder's composite call looks like)."""
+def distributed_bad_step(mesh, tf, width, height, comp_cfg=None):
+    """Takes the config whole and then REBUILDS it from the fields it
+    remembers — ``wire`` is not among them (what the plain builders'
+    ``knob_cfg`` did until PR 29)."""
+    knob_cfg = CompositeConfig(schedule=comp_cfg.schedule,
+                               wave_tiles=comp_cfg.wave_tiles)
+
     def step(data, cam):
-        frag = march(data, cam)
-        return composite(frag, exchange=exchange,
-                         schedule=schedule, wave_tiles=wave_tiles,
-                         ring_slots=ring_slots, k_budget=k_budget)
+        return composite_cfg(march(data, cam), knob_cfg)
+    return step
+
+
+def distributed_replaced_step(mesh, tf, comp_cfg=None):
+    """Forwards the config, but a copy with a field overwritten."""
+    comp_cfg = comp_cfg or CompositeConfig()    # the default fill is fine
+    quiet = dataclasses.replace(comp_cfg, temporal_reuse="off")
+
+    def step(data, cam):
+        return composite_cfg(march(data, cam), quiet)
     return step
 
 
 def distributed_missing_step(mesh, tf, width, height,
                              exchange="all_to_all"):
-    """Accepts only one knob of the matrix — every other knob is
-    invisible to callers and silently pinned to the composite default."""
+    """Takes one field by name instead of the config — every other field
+    is invisible to callers and silently pinned to the composite
+    default."""
     def step(data, cam):
         return composite(march(data, cam), exchange=exchange)
     return step
@@ -38,6 +47,10 @@ def march(data, cam):
 
 
 def composite(frag, **kw):
+    return frag
+
+
+def composite_cfg(frag, cfg):
     return frag
 
 

@@ -5,7 +5,7 @@ import itertools
 
 
 def render(rec, data):
-    rec.count("frame_scan_builds")
+    rec.count("build_steps")
     return data
 
 

@@ -1,12 +1,12 @@
-# SITPU-THREAD good fixture: the two compliant builder shapes. Parsed by
-# the linter only.
+# SITPU-THREAD good fixture: the compliant builder shapes. Parsed by the
+# linter only.
 
 
 def distributed_obj_step(mesh, tf, vdi_cfg=None, comp_cfg=None,
                          topology=None):
     """Whole-object threading: comp_cfg flows into the composite call —
-    every current and future knob rides along — and the mesh topology is
-    resolved, not dropped."""
+    every current and future field rides along — and the mesh topology
+    is resolved, not dropped."""
     topo = resolve_topology(mesh, topology)
 
     def step(data, cam):
@@ -14,19 +14,18 @@ def distributed_obj_step(mesh, tf, vdi_cfg=None, comp_cfg=None,
     return step
 
 
-def distributed_knob_step(mesh, tf, width, height,
-                          exchange="all_to_all", wire="f32",
-                          schedule="frame", wave_tiles=4,
-                          ring_slots=0, k_budget="static",
-                          topology=None):
-    """Explicit-knob threading: the full matrix accepted and forwarded."""
+def distributed_plain_like_step(mesh, tf, width, height, comp_cfg=None,
+                                topology=None):
+    """The plain builders' shape: the config is taken whole, filled with
+    the default when absent, forwarded to the resolvers, and the fields
+    this step consumes are read off it."""
+    comp_cfg = comp_cfg or CompositeConfig()
     topo = resolve_topology(mesh, topology)
+    waves = resolve_waves(comp_cfg, width)
 
     def step(data, cam):
-        return composite(march(data, cam), exchange=exchange, wire=wire,
-                         schedule=schedule, wave_tiles=wave_tiles,
-                         ring_slots=ring_slots, k_budget=k_budget,
-                         topo=topo)
+        return composite(march(data, cam), exchange=comp_cfg.exchange,
+                         wire=comp_cfg.wire, waves=waves, topo=topo)
     return step
 
 
@@ -38,9 +37,13 @@ def composite(frag, **kw):
     return frag
 
 
-def composite_cfg(frag, cfg):
+def composite_cfg(frag, cfg, topo):
     return frag
 
 
 def resolve_topology(mesh, topology):
     return topology
+
+
+def resolve_waves(cfg, width):
+    return False

@@ -523,7 +523,8 @@ def test_bricks_inert_builders_ledger():
     vc, cc = _cfgs()
     distributed_hybrid_step_mxu(mesh, _tf(), _mxu_spec(_cam()), vc, cc,
                                 bricks=bm)
-    distributed_plain_step(mesh, _tf(), HW, HW, rebalance="bricks",
+    distributed_plain_step(mesh, _tf(), HW, HW,
+                           comp_cfg=CompositeConfig(rebalance="bricks"),
                            bricks=bm)
     rows = [e for e in obs.ledger()
             if e["component"] == "bricks.partition"]

@@ -44,7 +44,7 @@ def test_resume_is_bit_exact(tmp_path):
     load_session(c, path)
     assert c.frame_index == b.frame_index
     assert c.orbit_rate == 0.05
-    assert len(c._mxu_thr) == len(b._mxu_thr)
+    assert len(c._steps.thr) == len(b._steps.thr)
     got = c.run(2)
 
     assert got["frame"] == ref["frame"]
@@ -110,18 +110,18 @@ def test_resume_bit_exact_across_regime_switches(tmp_path):
 
     a = mk()
     ref = a.run(20)
-    assert len(a._mxu_thr) >= 2          # the orbit crossed regimes
+    assert len(a._steps.thr) >= 2          # the orbit crossed regimes
 
     b = mk()
     b.run(12)
-    assert len(b._mxu_thr) >= 2   # the checkpoint itself is multi-regime
+    assert len(b._steps.thr) >= 2   # the checkpoint itself is multi-regime
     save_session(b, path)
     c = mk()
     load_session(c, path)
     # the drop/keep tracker must survive the round trip — without it the
     # first post-resume frame makes a different drop decision than the
     # uninterrupted run whenever the boundary lands on a regime switch
-    assert c._last_regime_key == b._last_regime_key
+    assert c._steps.last_key == b._steps.last_key
     got = c.run(8)
 
     assert got["frame"] == ref["frame"]
@@ -144,25 +144,25 @@ def test_hybrid_temporal_checkpoint_roundtrip(tmp_path):
     a = InSituSession(cfg)
     assert a._temporal
     a.run(2)
-    (key,) = list(a._mxu_thr)
+    (key,) = list(a._steps.thr)
     assert key[0] == "hybrid" and len(key) == 3
     # fabricate the opposite-sign regime with distinct values: a tag
     # collision would make one of the two restore as the other
     other = (key[0], key[1], -key[2])
-    a._mxu_thr[other] = ThresholdState(
-        *(jnp.asarray(x) + 0.125 for x in a._mxu_thr[key]))
+    a._steps.thr[other] = ThresholdState(
+        *(jnp.asarray(x) + 0.125 for x in a._steps.thr[key]))
     save_session(a, path)
 
     b = InSituSession(cfg)
     b.run(2)
     load_session(b, path)
-    assert set(b._mxu_thr) == {key, other}
-    np.testing.assert_array_equal(np.asarray(a._mxu_thr[key].thr),
-                                  np.asarray(b._mxu_thr[key].thr))
-    np.testing.assert_array_equal(np.asarray(a._mxu_thr[other].thr),
-                                  np.asarray(b._mxu_thr[other].thr))
-    assert not np.array_equal(np.asarray(b._mxu_thr[key].thr),
-                              np.asarray(b._mxu_thr[other].thr))
+    assert set(b._steps.thr) == {key, other}
+    np.testing.assert_array_equal(np.asarray(a._steps.thr[key].thr),
+                                  np.asarray(b._steps.thr[key].thr))
+    np.testing.assert_array_equal(np.asarray(a._steps.thr[other].thr),
+                                  np.asarray(b._steps.thr[other].thr))
+    assert not np.array_equal(np.asarray(b._steps.thr[key].thr),
+                              np.asarray(b._steps.thr[other].thr))
 
 
 def test_steered_tf_survives_checkpoint(tmp_path):

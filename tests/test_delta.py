@@ -642,8 +642,8 @@ def test_reuse_inert_ledger_on_unsupported_builders():
         distributed_vdi_step(mesh, tf, 32, 32, VDIConfig(
             max_supersegments=4), CompositeConfig(
             temporal_reuse="ranges"))
-        distributed_plain_step(mesh, tf, 32, 32,
-                               temporal_reuse="ranges")
+        distributed_plain_step(mesh, tf, 32, 32, comp_cfg=CompositeConfig(
+            temporal_reuse="ranges"))
     finally:
         obs.set_recorder(prev)
     rows = [e for e in obs.ledger() if e["component"] == "delta.reuse"]

@@ -103,84 +103,73 @@ class TestCounter:
     def test_discovery(self):
         srcs = fixture_sources("good_counter.py")
         disc = C.discover_counters(srcs)
-        assert set(disc) == {"frame_scan_builds", "ring_steps_built",
+        assert set(disc) == {"build_steps", "ring_steps_built",
                              "dcn_hops_built"}
 
 
 # ------------------------------------------------------------ SITPU-THREAD
 
-THREAD_KW = dict(config_path="tests/lint_fixtures/thread_config.py",
-                 session_paths=("tests/lint_fixtures/thread_session.py",))
+def thread_check(pipeline):
+    return TH.check(fixture_sources(pipeline),
+                    pipeline_path=f"tests/lint_fixtures/{pipeline}")
 
 
-def thread_check(pipeline, with_session=False):
-    names = ["thread_config.py", pipeline]
-    kw = dict(THREAD_KW)
-    if with_session:
-        names.append("thread_session.py")
-    else:
-        kw["session_paths"] = ()
-    srcs = fixture_sources(*names)
-    return TH.check(srcs,
-                    pipeline_path=f"tests/lint_fixtures/{pipeline}", **kw)
+def thread_messages(pipeline):
+    by_sym = {}
+    for d in thread_check(pipeline):
+        by_sym.setdefault(d.symbol, []).append(d.message)
+    return by_sym
 
 
 class TestThread:
-    def test_knob_derivation_from_config(self):
-        srcs = fixture_sources("thread_config.py")
-        knobs = TH.derive_knobs(srcs[0])
-        assert knobs == ["exchange", "ring_slots", "wire", "schedule",
-                         "wave_tiles", "k_budget"]
+    def test_loose_fields_builder_flagged(self):
+        """A builder that takes CompositeConfig fields one by one (the
+        plain builders until PR 29) instead of the config is refused."""
+        msgs = thread_messages("bad_thread.py")["distributed_missing_step"]
+        assert any("does not accept comp_cfg" in m for m in msgs), msgs
 
-    def test_real_config_derivation(self):
-        srcs = load_sources(
-            ROOT, [os.path.join(ROOT, "scenery_insitu_tpu", "config.py")])
-        knobs = TH.derive_knobs(srcs[0])
-        assert set(knobs) == {"exchange", "ring_slots", "wire", "schedule",
-                              "wave_tiles", "k_budget", "rebalance",
-                              "rebalance_period", "rebalance_hysteresis",
-                              "rebalance_min_depth", "rebalance_quantum",
-                              "rebalance_bricks", "rebalance_max_moves",
-                              "temporal_reuse"}
+    def test_rebuilt_config_flagged(self):
+        """Both ways of handing on a different config than the one that
+        came in: a fresh CompositeConfig(fields...) and
+        dataclasses.replace. The bare default fill is neither."""
+        by_sym = thread_messages("bad_thread.py")
+        for sym in ("distributed_bad_step", "distributed_replaced_step"):
+            assert sum("rebuilds CompositeConfig" in m
+                       for m in by_sym[sym]) == 1, by_sym[sym]
 
-    def test_deleted_wire_forwarding_fails(self):
-        """The acceptance-criteria demo: a builder whose wire= forwarding
-        was deleted fails SITPU-THREAD."""
-        diags = thread_check("bad_thread.py")
-        by_sym = {}
-        for d in diags:
-            by_sym.setdefault(d.symbol, []).append(d.message)
-        assert any("accepts knob 'wire' but never forwards it" in m
-                   for m in by_sym["distributed_bad_step"])
-        # the one-knob builder is missing the rest of the matrix
-        missing = [m for m in by_sym["distributed_missing_step"]
-                   if "does not accept knob" in m]
-        assert len(missing) == 5
-        # the dropped-object builder never threads comp_cfg
+    def test_dropped_config_fails(self):
+        """A builder that accepts the config and never hands it on."""
+        by_sym = thread_messages("bad_thread.py")
         assert any("never forwards it" in m
                    for m in by_sym["distributed_dropped_obj_step"])
+        # the rebuilding builder forwards only its own copy
+        assert any("never forwards it" in m
+                   for m in by_sym["distributed_bad_step"])
 
     def test_good_builders_clean(self):
         diags = thread_check("good_thread.py")
         assert diags == [], [d.render() for d in diags]
 
-    def test_session_plumbing(self):
-        diags = thread_check("good_thread.py", with_session=True)
-        msgs = [d.message for d in diags]
-        assert len(diags) == 3, [d.render() for d in diags]
-        assert any("does not forward knob 'wire'" in m for m in msgs)
-        assert any("does not bind comp_cfg" in m for m in msgs)
-        # the same forgetful call also fails the topology binding rule
-        assert any("does not bind 'topology'" in m for m in msgs)
+    def test_plain_builders_take_config_whole(self):
+        """The real plain builders name no CompositeConfig field in
+        their signatures: the config arrives as ``comp_cfg``."""
+        import dataclasses
+        import inspect
+
+        from scenery_insitu_tpu.config import CompositeConfig
+        from scenery_insitu_tpu.parallel import pipeline
+
+        fields = {f.name for f in dataclasses.fields(CompositeConfig)}
+        for fn in (pipeline.distributed_plain_step,
+                   pipeline.distributed_plain_step_mxu):
+            params = set(inspect.signature(fn).parameters)
+            assert "comp_cfg" in params and not params & fields, params
 
     def test_topology_threading_enforced(self):
         """ISSUE 14: every distributed builder must accept AND consume
         the TopologyConfig — a builder that drops it silently composites
         flat on a hierarchical mesh."""
-        diags = thread_check("bad_thread.py")
-        by_sym = {}
-        for d in diags:
-            by_sym.setdefault(d.symbol, []).append(d.message)
+        by_sym = thread_messages("bad_thread.py")
         for sym in ("distributed_bad_step", "distributed_missing_step",
                     "distributed_dropped_obj_step"):
             assert any("does not accept 'topology'" in m
@@ -188,19 +177,12 @@ class TestThread:
         # the compliant fixtures resolve it — clean
         assert thread_check("good_thread.py") == []
 
-    def test_real_builders_thread_whole_matrix(self):
-        """The real pipeline/session: only the documented, baselined
-        plain-builder gaps (ring_slots/k_budget) may appear."""
-        paths = [os.path.join(ROOT, p) for p in
-                 ("scenery_insitu_tpu/config.py",
-                  "scenery_insitu_tpu/parallel/pipeline.py",
-                  "scenery_insitu_tpu/runtime/session.py")]
-        diags = TH.check(load_sources(ROOT, paths))
-        assert all("does not accept knob" in d.message
-                   and d.symbol.startswith("distributed_plain_step")
-                   for d in diags), [d.render() for d in diags]
-        assert {d.symbol for d in diags} <= {"distributed_plain_step",
-                                             "distributed_plain_step_mxu"}
+    def test_real_builders_thread_whole_config(self):
+        """The real pipeline: every builder takes the config whole,
+        forwards it and consumes the topology — nothing is baselined."""
+        diags = TH.check(load_sources(ROOT, [os.path.join(
+            ROOT, "scenery_insitu_tpu/parallel/pipeline.py")]))
+        assert diags == [], [d.render() for d in diags]
 
 
 # ------------------------------------------------------------- SITPU-TRACE

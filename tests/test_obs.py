@@ -65,10 +65,10 @@ def test_counters_and_summary():
     rec = Recorder(enabled=True)
     rec.count("compile_step")
     rec.count("compile_step")
-    rec.count("frames_scan_dispatch", 8)
+    rec.count("frames_eager_dispatch", 8)
     s = rec.summary()
     assert s["counters"]["compile_step"] == 2
-    assert s["counters"]["frames_scan_dispatch"] == 8
+    assert s["counters"]["frames_eager_dispatch"] == 8
     assert s["enabled"] is True
     assert isinstance(s["degradations"], list)
 
@@ -89,37 +89,6 @@ def test_forced_codec_degrade_in_ledger(monkeypatch):
     assert entries[0]["count"] == 2          # deduped, counted
     # the warning the inline site used to emit still fires (once)
     assert sum("zstandard" in str(x.message) for x in w) == 1
-
-
-def test_forced_eager_scan_fallback_in_ledger():
-    class OpaqueSim:
-        """Custom adapter: no traceable (state, advance) pair, so
-        scan_frames must degrade to the eager loop."""
-
-        def __init__(self, inner):
-            self._inner = inner
-            self.kind = inner.kind
-
-        def advance(self, n):
-            self._inner.advance(n)
-
-        @property
-        def field(self):
-            return self._inner.field
-
-    from scenery_insitu_tpu.runtime.session import VolumeSimAdapter
-
-    cfg = _session_cfg(**{"runtime.scan_frames": 2})
-    sess = InSituSession(cfg, mesh=make_mesh(2),
-                         sim=OpaqueSim(VolumeSimAdapter(cfg)))
-    sess.run(2)
-    entries = [e for e in obs.ledger()
-               if e["component"] == "session.scan_frames"]
-    assert len(entries) == 1, obs.ledger()
-    assert entries[0]["from"] == "scan" and entries[0]["to"] == "eager"
-    assert "custom sim adapter" in entries[0]["reason"]
-    # the frames actually ran eagerly
-    assert sess.obs.counters.get("frames_eager_dispatch") == 2
 
 
 # ---------------------------------------------------------------- exporters

@@ -521,50 +521,6 @@ def test_k_budget_occupancy_sparse_smoke_8dev():
     assert rec.counters.get("occupancy_kbudget_builds", 0) > before
 
 
-# -------------------------------------------------- frame-scan ranges carry
-
-
-def test_frame_scan_sim_ranges_matches_eager():
-    """frame_scan(sim_ranges=True) threads the advance's FieldRanges to
-    each frame's step through the scan carry; the scanned frames must
-    equal the eager loop running the same (advance, pyramid, generate)
-    chain."""
-    from scenery_insitu_tpu.core.camera import orbit
-    from scenery_insitu_tpu.parallel.pipeline import frame_scan
-
-    tf = _tf()
-    st0 = gs.GrayScott.init((16, 16, 16))
-    cam = Camera.create((0.0, 0.2, 3.0), fov_y_deg=45.0)
-    spec = slicer.make_spec(cam, st0.v.shape,
-                            SliceMarchConfig(matmul_dtype="f32",
-                                             scale=1.0,
-                                             occupancy_vtiles=4))
-    cfg = VDIConfig(max_supersegments=4, adaptive_iters=2)
-
-    def advance(st):
-        return gs.multi_step_fast_ranges(st, 2)
-
-    def step(field, origin, spacing, cam, rng):
-        vol = Volume(field, origin, spacing)
-        pyr = occ.pyramid_from_ranges(rng, vol, tf, spec)
-        vdi, _, _ = slicer.generate_vdi_mxu(vol, tf, cam, spec, cfg,
-                                            occupancy=pyr)
-        return vdi.color
-
-    vol0 = Volume.centered(st0.v, extent=2.0)
-    run = frame_scan(step, advance, frames=3, sim_ranges=True)
-    (_, _, _), outs = run(st0, vol0.origin, vol0.spacing, cam,
-                          jnp.float32(0.1))
-
-    st, c = st0, cam
-    for i in range(3):
-        st, rng = advance(st)
-        want = step(st.field, vol0.origin, vol0.spacing, c, rng)
-        np.testing.assert_allclose(np.asarray(outs[i]), np.asarray(want),
-                                   rtol=1e-6, atol=1e-7)
-        c = orbit(c, jnp.float32(0.1))
-
-
 # ------------------------------------------------------- clamps and ledger
 
 

@@ -354,7 +354,8 @@ def test_rebalanced_plain_steps_match_even():
     for build in ("gather", "mxu"):
         imgs = {}
         for p in (None, PLAN):
-            kw = dict(rebalance="occupancy" if p else "even", plan=p)
+            kw = dict(comp_cfg=CompositeConfig(
+                rebalance="occupancy" if p else "even"), plan=p)
             if build == "gather":
                 step = distributed_plain_step(
                     mesh, _tf(), HW, HW, RenderConfig(max_steps=48), **kw)

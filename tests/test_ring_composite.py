@@ -286,7 +286,8 @@ def test_ring_plain_step_matches_all_to_all(background):
     data = shard_volume(vol.data, mesh)
     imgs = {}
     for ex in ("all_to_all", "ring"):
-        step = distributed_plain_step(mesh, _tf(), W, H, cfg, exchange=ex)
+        step = distributed_plain_step(
+            mesh, _tf(), W, H, cfg, comp_cfg=CompositeConfig(exchange=ex))
         imgs[ex] = np.asarray(step(data, vol.origin, vol.spacing, _cam()))
     np.testing.assert_array_equal(imgs["ring"], imgs["all_to_all"])
 
@@ -306,7 +307,8 @@ def test_ring_plain_mxu_step_matches_all_to_all():
     data = shard_volume(vol.data, mesh)
     imgs = {}
     for ex in ("all_to_all", "ring"):
-        step = distributed_plain_step_mxu(mesh, _tf(), spec, exchange=ex)
+        step = distributed_plain_step_mxu(
+            mesh, _tf(), spec, comp_cfg=CompositeConfig(exchange=ex))
         img, _ = step(data, vol.origin, vol.spacing, cam)
         imgs[ex] = np.asarray(img)
     np.testing.assert_array_equal(imgs["ring"], imgs["all_to_all"])

@@ -200,21 +200,21 @@ def test_scene_session_temporal_mode(vol, tf):
     sess.update_data(0, [data], [np.asarray(vol.origin)], vol.spacing)
     p1 = sess.render_frame()
     assert np.isfinite(p1["vdi_color"]).all()
-    assert len(sess._thr) == 1
-    thr1 = next(iter(sess._thr.values()))
+    assert len(sess._steps.thr) == 1
+    thr1 = next(iter(sess._steps.thr.values()))
     assert thr1.thr.shape[0] == 1      # one grid
 
     p2 = sess.render_frame()        # carried state, same compiled step
     assert np.isfinite(p2["vdi_color"]).all()
-    assert len(sess._steps) == 1
+    assert len(sess._steps.steps) == 1
 
     # moving the scene (same shapes, new extent) must recompile the step
     # (stale-spec guard) and seed a fresh threshold entry
     sess.update_data(0, [data], [np.asarray(vol.origin) + 1.5], vol.spacing)
     p3 = sess.render_frame()
     assert np.isfinite(p3["vdi_color"]).all()
-    assert len(sess._steps) == 2
-    assert len(sess._thr) == 2
+    assert len(sess._steps.steps) == 2
+    assert len(sess._steps.thr) == 2
 
 
 def test_insitu_session_rejects_temporal():
@@ -277,8 +277,8 @@ def test_scene_session_temporal_reseeds_on_regime_reentry(vol, tf):
                           far=20.0)
     sess.camera = cam_z
     sess.render_frame()
-    (key_z,) = list(sess._thr)
-    stale = sess._thr[key_z]
+    (key_z,) = list(sess._steps.thr)
+    stale = sess._steps.thr[key_z]
 
     sess.camera = cam_x                      # leave the +z regime
     sess.render_frame()
@@ -286,7 +286,7 @@ def test_scene_session_temporal_reseeds_on_regime_reentry(vol, tf):
 
     sess.camera = cam_z                      # return: must re-seed
     sess.render_frame()
-    assert sess._thr[key_z] is not stale
+    assert sess._steps.thr[key_z] is not stale
 
 
 def test_scene_session_prewarm_regimes(vol, tf):
@@ -308,10 +308,10 @@ def test_scene_session_prewarm_regimes(vol, tf):
     eye0 = np.asarray(sess.camera.eye).copy()
     times = sess.prewarm_regimes(regimes=[start, (0, 1)])
     assert set(times) == {start, (0, 1)}
-    assert len(sess._steps) == 2
-    assert sess._thr == {}                 # invisible to the loop
+    assert len(sess._steps.steps) == 2
+    assert sess._steps.thr == {}                 # invisible to the loop
     assert sess.frame_index == 0
     assert np.allclose(eye0, np.asarray(sess.camera.eye))
     p = sess.render_frame()
     assert np.isfinite(p["vdi_color"]).all()
-    assert len(sess._steps) == 2           # no third compile
+    assert len(sess._steps.steps) == 2           # no third compile

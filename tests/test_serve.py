@@ -47,11 +47,28 @@ def _cams(cam0, n):
 
 # ---------------------------------------------------- batch render parity
 
+# The sweep and proxy tiers batch under lax.map, and XLA's
+# while-loop-invariant-code-motion pass lifts what does not depend on the
+# camera out of the loop body. A product that the independent render fuses
+# with the sum it feeds (one rounding, an FMA) is then rounded on its own,
+# so under 1 % of the samples come out one unit in the last place of a
+# value in [0.5, 1) apart, and none further. With the pass off
+# (--xla_disable_hlo_passes=while-loop-invariant-code-motion), with the
+# batch unrolled, and for a batch of one, the two are bit-equal; the pass
+# is what amortizes the per-plane decode over the batch, so it stays on.
+LICM_ATOL = 2.0 ** -23
+
+
+def assert_equal_up_to_hoisting(batch, independent):
+    np.testing.assert_allclose(batch, independent, rtol=0, atol=LICM_ATOL)
+    assert np.mean(batch != independent) < 0.01
+
 
 def test_batch_sweep_bitwise_vs_independent_mxu(fixture):
     """The batched N-camera render equals N independent render_vdi_mxu
-    calls BITWISE (the lax.map body is the unmodified single-camera
-    renderer — a vmapped batch would drift ~1e-5)."""
+    calls to the last place (the lax.map body is the unmodified
+    single-camera renderer — a vmapped batch would drift ~1e-5), and a
+    batch of one bitwise."""
     vol, cam0, spec, vdi, meta, axcam = fixture
     regime = slicer.choose_axis(cam0)
     cams = _cams(cam0, 4)
@@ -61,7 +78,11 @@ def test_batch_sweep_bitwise_vs_independent_mxu(fixture):
     s = np.stack([np.asarray(jax.jit(lambda c: render_vdi_mxu(
         vdi, axcam, spec, c, W, H, num_slices=NS, axis_sign=regime))(c))
         for c in cams])
-    np.testing.assert_array_equal(b, s)
+    assert_equal_up_to_hoisting(b, s)
+    one = np.asarray(jax.jit(lambda cs: render_vdi_batch(
+        vdi, axcam, spec, cs, W, H, tier="sweep", num_slices=NS,
+        axis_sign=regime))(stack_cameras(cams[:1])))
+    np.testing.assert_array_equal(one[0], s[0])
 
 
 def test_batch_exact_bitwise_vs_independent_exact(fixture):
@@ -76,7 +97,8 @@ def test_batch_exact_bitwise_vs_independent_exact(fixture):
 
 def test_batch_proxy_bitwise_vs_independent_proxy(fixture):
     """Proxy tier: one shared vdi_to_rgba_volume expansion, per-camera
-    marches — batch equals independent render_vdi_proxy calls bitwise."""
+    marches — batch equals independent render_vdi_proxy calls to the
+    last place (`assert_equal_up_to_hoisting`)."""
     vol, cam0, spec, vdi, meta, axcam = fixture
     regime = slicer.choose_axis(cam0)
     proxy = vdi_to_rgba_volume(vdi, axcam, spec, num_slices=NS)
@@ -88,7 +110,7 @@ def test_batch_proxy_bitwise_vs_independent_proxy(fixture):
         spec_new=spec_new))(stack_cameras(cams)))
     s = np.stack([np.asarray(jax.jit(lambda c: render_vdi_proxy(
         proxy, c, W, H, spec_new))(c)) for c in cams])
-    np.testing.assert_array_equal(b, s)
+    assert_equal_up_to_hoisting(b, s)
 
 
 def test_padded_bucket_invariance(fixture):
@@ -129,6 +151,24 @@ def _pump(srv, clients, cond, secs=30):
     return None
 
 
+def _rebind(bind, secs=10):
+    """A publisher on the port another just closed: zmq releases the
+    port on its I/O thread, some time after `close` returns (a restarted
+    process finds it free; a test that restarts in place has to wait)."""
+    import zmq
+
+    from scenery_insitu_tpu.runtime.streaming import VDIPublisher
+
+    deadline = time.monotonic() + secs
+    while True:
+        try:
+            return VDIPublisher(bind, codec="zlib")
+        except zmq.ZMQError as e:
+            if e.errno != zmq.EADDRINUSE or time.monotonic() > deadline:
+                raise
+            time.sleep(0.05)
+
+
 def _serve_pair(fixture, *overrides, publish=True):
     from scenery_insitu_tpu.runtime.streaming import VDIPublisher
     from scenery_insitu_tpu.serve import ViewerServer
@@ -149,8 +189,9 @@ def _serve_pair(fixture, *overrides, publish=True):
 
 def test_loopback_mixed_tier_batch(fixture):
     """One server, three tiers in one pump cycle: every client gets its
-    own tier's pixels, proxy == direct render bitwise, wire == the u8
-    quantization of the same render."""
+    own tier's pixels, proxy == direct render to the last place
+    (`assert_equal_up_to_hoisting`: proxy and wire share a bucket of two),
+    wire == the u8 quantization of the proxy answer beside it."""
     from scenery_insitu_tpu.serve import ViewerClient, ViewerFrame
 
     vol, cam0, spec, vdi, meta, axcam = fixture
@@ -170,9 +211,9 @@ def test_loopback_mixed_tier_batch(fixture):
         assert done, [c.stats for c in cs]
         fp, fe, fw = (c.last for c in cs)
         assert (fp.tier, fe.tier, fw.tier) == ("proxy", "exact", "wire")
-        # proxy answer == the independent proxy render, bitwise (the
-        # reference takes the proxy as jit ARGUMENTS like the server
-        # does — a closure constant would fold differently)
+        # proxy answer == the independent proxy render (the reference
+        # takes the proxy as jit ARGUMENTS like the server does — a
+        # closure constant would fold differently)
         from scenery_insitu_tpu.core.volume import Volume
 
         regime = slicer.choose_axis(novel)
@@ -182,11 +223,12 @@ def test_loopback_mixed_tier_batch(fixture):
         ref = np.asarray(jax.jit(lambda pd, po, ps, c: render_vdi_proxy(
             Volume(pd, po, ps), c, W, H, spec_new))(
             proxy.data, proxy.origin, proxy.spacing, novel))
-        np.testing.assert_array_equal(fp.image, ref)
-        # wire answer is the u8 wire quantization of that same render
+        assert_equal_up_to_hoisting(fp.image, ref)
+        # wire answer is the u8 wire quantization of the same camera's
+        # render in the same batch
         np.testing.assert_array_equal(
             fw.image,
-            np.clip(np.round(ref * 255), 0, 255).astype(np.uint8)
+            np.clip(np.round(fp.image * 255), 0, 255).astype(np.uint8)
             .astype(np.float32) / 255.0)
         # exact differs from proxy (different renderer) but is finite
         assert np.isfinite(fe.image).all() and fe.image[3].max() > 0.0
@@ -287,8 +329,13 @@ def test_queue_cap_sheds_and_coalescing(fixture):
         srv.pump_clients()
         assert len(srv.queue) == 1
         # a second client while the queue is full: shed
+        # (intake only: a render would empty the queue before a late
+        # request arrives, and then nothing is shed)
         c2.request(orbit(cam0, 0.3))
-        shed = _pump(srv, (c2,), lambda: c2.poll(timeout_ms=0))
+        shed, deadline = None, time.monotonic() + 10
+        while shed is None and time.monotonic() < deadline:
+            srv.pump_clients()
+            shed = c2.poll(timeout_ms=10)
         assert isinstance(shed, ServeDrop) and shed.reason == "queue_cap"
     finally:
         c1.close()
@@ -654,7 +701,6 @@ def test_server_survives_publisher_restart(fixture):
     the server's OWN assembler and stream-head tracking: without the
     mirror reset, the late-tile guard wedges assembly (new indices sit
     below the old head) and every answer reads stale forever."""
-    from scenery_insitu_tpu.runtime.streaming import VDIPublisher
     from scenery_insitu_tpu.serve import ViewerClient, ViewerFrame
 
     vol, cam0, spec, vdi, meta, axcam = fixture
@@ -671,8 +717,7 @@ def test_server_survives_publisher_restart(fixture):
         assert isinstance(f0, ViewerFrame) and not f0.cached
         # restart: new epoch, indices restart near zero
         pub.close()
-        pub2 = VDIPublisher(pub.endpoint.replace("127.0.0.1", "*"),
-                            codec="zlib")
+        pub2 = _rebind(pub.endpoint.replace("127.0.0.1", "*"))
         time.sleep(0.25)
         deadline = time.monotonic() + 15
         while (srv.frame["index"] != 1

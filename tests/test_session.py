@@ -70,7 +70,7 @@ def test_session_mxu_engine(tmp_path):
     assert ni % 4 == 0                      # divisible by mesh size
     assert np.isfinite(payload["vdi_color"]).all()
     assert int(payload["meta"].index) == 2
-    assert len(s._mxu_steps) == 1
+    assert len(s._steps.steps) == 1
 
 
 def test_session_mxu_temporal(tmp_path):
@@ -86,8 +86,8 @@ def test_session_mxu_temporal(tmp_path):
     s = InSituSession(cfg)
     payload = s.run(3)
     assert np.isfinite(payload["vdi_color"]).all()
-    assert len(s._mxu_thr) == 1             # one regime seeded
-    thr = next(iter(s._mxu_thr.values()))
+    assert len(s._steps.thr) == 1             # one regime seeded
+    thr = next(iter(s._steps.thr.values()))
     assert np.isfinite(np.asarray(thr.thr)).all()
 
 
@@ -108,15 +108,15 @@ def test_session_prewarm_regimes():
     times = s.prewarm_regimes(regimes=[start_regime, (0, -1)])
     assert set(times) == {start_regime, (0, -1)}
     assert all(t >= 0 for t in times.values())
-    assert len(s._mxu_steps) == 2           # both regimes compiled
-    assert s._mxu_thr == {}                 # threshold state untouched
+    assert len(s._steps.steps) == 2           # both regimes compiled
+    assert s._steps.thr == {}                 # threshold state untouched
     assert s.frame_index == 0               # no frames consumed
     assert np.allclose(eye0, np.asarray(s.camera.eye))
     # the first real frames run in start_regime: must reuse the
     # prewarmed step, not compile a third entry
     payload = s.run(2)
     assert np.isfinite(payload["vdi_color"]).all()
-    assert len(s._mxu_steps) == 2           # nothing new compiled
+    assert len(s._steps.steps) == 2           # nothing new compiled
 
 
 def test_session_prewarm_noop_modes():
@@ -194,8 +194,8 @@ def test_session_soak_state_bounded():
     payload = s.run(60)
     assert np.isfinite(payload["vdi_color"]).all()
     # 4 regimes visited at most around one orbit in a horizontal plane
-    assert len(s._mxu_steps) <= 4
-    assert len(s._mxu_thr) <= 4
+    assert len(s._steps.steps) <= 4
+    assert len(s._steps.thr) <= 4
     assert len(s._pending_meta) <= 2   # metadata snapshots are drained
 
 
@@ -206,7 +206,7 @@ def test_session_plain_mxu_mode():
                   "slicer.engine": "mxu", "slicer.matmul_dtype": "f32"})
     sess = InSituSession(cfg, mesh=make_mesh(2))
     assert sess.mode == "plain" and sess.engine == "mxu"
-    assert sess._step is None           # per-regime MXU steps, not gather
+    assert sess._steps.fixed is None    # per-regime MXU steps, not gather
     payload = sess.run(2)
     assert payload["image"].shape == (4, 24, 32)
     assert np.isfinite(payload["image"]).all()
@@ -224,7 +224,7 @@ def test_session_hybrid_temporal_mode():
     payload = sess.run(3)
     assert payload["image"].shape == (4, 24, 32)
     assert np.isfinite(payload["image"]).all()
-    assert any(k[0] == "hybrid" for k in sess._mxu_thr)
+    assert any(k[0] == "hybrid" for k in sess._steps.thr)
 
 
 def test_session_pending_meta_bounded_headless():
@@ -250,7 +250,7 @@ def test_session_prewarm_covers_orbit_crossing():
     s = InSituSession(cfg, mesh=make_mesh(2))
     times = s.prewarm_regimes()
     assert len(times) == 6
-    n_steps = len(s._mxu_steps)
+    n_steps = len(s._steps.steps)
     assert n_steps == 6
     # ~0.6 rad/frame crosses at least one regime boundary within 6 frames
     s.orbit_rate = 0.6
@@ -258,5 +258,5 @@ def test_session_prewarm_covers_orbit_crossing():
     assert np.isfinite(payload["vdi_color"]).all()
     # the premise must actually hold: temporal mode seeds one threshold
     # entry per VISITED regime, so >= 2 proves the orbit really crossed
-    assert len(s._mxu_thr) >= 2
-    assert len(s._mxu_steps) == n_steps     # nothing compiled mid-orbit
+    assert len(s._steps.thr) >= 2
+    assert len(s._steps.steps) == n_steps     # nothing compiled mid-orbit

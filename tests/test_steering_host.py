@@ -278,8 +278,8 @@ def test_steered_payloads_are_bitwise_the_parents(mode, orbit):
     assert len(new) == len(old) == 6
     assert a.obs.counters["regime_host"] == (3 if orbit else 6)
     assert "regime_host" not in b.obs.counters
-    assert sorted(a._mxu_steps) == sorted(b._mxu_steps)
-    assert len(a._mxu_steps) == 2               # the run crossed regimes
+    assert sorted(a._steps.steps) == sorted(b._steps.steps)
+    assert len(a._steps.steps) == 2               # the run crossed regimes
     for p, q in zip(new, old):
         assert p["frame"] == q["frame"]
         keys = [k for k in ("vdi_color", "vdi_depth", "image") if k in p]

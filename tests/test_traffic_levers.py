@@ -1,20 +1,18 @@
 """Tests for the ISSUE-1 HBM-traffic levers: the time-fused 2D-blocked
-sim stencil's guard rails, the bf16 marched-volume path, the on-device
-frame scan, and the pallas_seg argument-form/probe fixes that rode along
-(ADVICE.md round 5)."""
+sim stencil's guard rails, the bf16 marched-volume path, and the
+pallas_seg argument-form/probe fixes that rode along (ADVICE.md round
+5)."""
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from scenery_insitu_tpu.config import (FrameworkConfig, SliceMarchConfig,
-                                       VDIConfig)
+from scenery_insitu_tpu.config import SliceMarchConfig, VDIConfig
 from scenery_insitu_tpu.core.camera import Camera
 from scenery_insitu_tpu.core.transfer import for_dataset
 from scenery_insitu_tpu.core.volume import Volume
 from scenery_insitu_tpu.ops import slicer
 from scenery_insitu_tpu.parallel.mesh import make_mesh
-from scenery_insitu_tpu.runtime.session import InSituSession
 from scenery_insitu_tpu.sim import grayscott as gs
 
 
@@ -147,111 +145,6 @@ def test_bf16_distributed_matches_f32():
         vdi, _ = step(shard_volume(st.field, mesh), origin, spacing, cam)
         outs[rdt] = np.asarray(vdi.color)
     np.testing.assert_allclose(outs["bf16"], outs["f32"], atol=0.05)
-
-
-# ------------------------------------------------- on-device frame scan
-
-
-def _session_cfg(extra=()):
-    base = ["render.width=32", "render.height=24", "render.max_steps=24",
-            "vdi.max_supersegments=6", "vdi.adaptive_iters=2",
-            "composite.max_output_supersegments=8",
-            "composite.adaptive_iters=2", "sim.grid=[16,16,16]",
-            "sim.steps_per_frame=2"]
-    return FrameworkConfig().with_overrides(*(base + list(extra)))
-
-
-def _collect(sess, frames):
-    got = []
-    sess.sinks.append(lambda i, p: got.append((i, p["vdi_color"].copy())))
-    sess.run(frames)
-    return got
-
-
-def test_scan_frames_matches_eager_gather():
-    """scan_frames must produce the same frame sequence as the eager
-    loop (same sim ladder, same per-frame cameras), one launch per
-    block — including a final partial block."""
-    eager = InSituSession(_session_cfg(), mesh=make_mesh(2))
-    eager.orbit_rate = 0.1
-    scan = InSituSession(_session_cfg(["runtime.scan_frames=2"]),
-                         mesh=make_mesh(2))
-    scan.orbit_rate = 0.1
-    fe = _collect(eager, 5)
-    fs = _collect(scan, 5)
-    assert [i for i, _ in fe] == [i for i, _ in fs] == list(range(5))
-    for (_, a), (_, b) in zip(fe, fs):
-        np.testing.assert_allclose(a, b, atol=1e-4)
-    assert np.allclose(np.asarray(eager.camera.eye),
-                       np.asarray(scan.camera.eye))
-    assert scan.frame_index == 5
-
-
-def test_scan_frames_matches_eager_mxu_temporal():
-    extra = ["slicer.engine=mxu", "slicer.scale=1.0",
-             "slicer.matmul_dtype=f32", "vdi.adaptive_mode=temporal",
-             "mesh.num_devices=4"]
-    eager = InSituSession(_session_cfg(extra))
-    scan = InSituSession(_session_cfg(extra + ["runtime.scan_frames=2"]))
-    fe = _collect(eager, 4)
-    fs = _collect(scan, 4)
-    assert len(fe) == len(fs) == 4
-    for (_, a), (_, b) in zip(fe, fs):
-        assert np.isfinite(b).all()
-        np.testing.assert_allclose(a, b, atol=1e-3)
-    # the temporal threshold state was carried across blocks
-    assert len(scan._mxu_thr) == 1
-
-
-def test_scan_frames_meta_matches_eager():
-    """Per-frame metadata (index, view of the replayed camera) must be
-    identical between the scan blocks and the eager loop."""
-    metas_e, metas_s = [], []
-    eager = InSituSession(_session_cfg(), mesh=make_mesh(2),
-                          sinks=[lambda i, p: metas_e.append(p["meta"])])
-    eager.orbit_rate = 0.2
-    eager.run(4)
-    scan = InSituSession(_session_cfg(["runtime.scan_frames=4"]),
-                         mesh=make_mesh(2),
-                         sinks=[lambda i, p: metas_s.append(p["meta"])])
-    scan.orbit_rate = 0.2
-    scan.run(4)
-    for me, ms in zip(metas_e, metas_s):
-        assert int(me.index) == int(ms.index)
-        np.testing.assert_allclose(np.asarray(me.view),
-                                   np.asarray(ms.view), atol=1e-6)
-
-
-def test_scan_frames_unsupported_mode_falls_back():
-    """Particle sessions have no traceable volume state — the session
-    must log the downgrade and run the eager loop, not die."""
-    logs = []
-    cfg = _session_cfg(["sim.kind=lennard_jones", "sim.num_particles=32",
-                        "sim.particle_radius=0.3",
-                        "runtime.scan_frames=3"])
-    sess = InSituSession(cfg, mesh=make_mesh(2), log=logs.append)
-    payload = sess.run(2)
-    assert payload["image"].shape == (4, 24, 32)
-    assert any("falling back to the eager loop" in l for l in logs)
-
-
-def test_scan_frames_regime_crossing_block_runs_eagerly():
-    """A block whose camera ladder crosses march regimes cannot be
-    scanned (the step is regime-specialized) — it must run eagerly and
-    still produce every frame."""
-    extra = ["slicer.engine=mxu", "slicer.scale=1.0",
-             "slicer.matmul_dtype=f32", "mesh.num_devices=2",
-             "runtime.scan_frames=6"]
-    logs = []
-    sess = InSituSession(_session_cfg(extra), log=logs.append)
-    sess.orbit_rate = 0.6           # crosses a regime within 6 frames
-    got = _collect(sess, 6)
-    assert [i for i, _ in got] == list(range(6))
-    assert all(np.isfinite(c).all() for _, c in got)
-    assert any("regime crossing" in l for l in logs)
-
-
-# ------------------------------------------------- pallas_seg satellites
 
 
 def test_fold_chunk_packed_rejects_mixed_depth_forms():

@@ -192,8 +192,9 @@ def test_waves_plain_step_matches_frame():
     data = shard_volume(vol.data, mesh)
     imgs = {}
     for sched in ("frame", "waves"):
-        step = distributed_plain_step(mesh, _tf(), W, H, cfg,
-                                      schedule=sched, wave_tiles=T)
+        step = distributed_plain_step(
+            mesh, _tf(), W, H, cfg,
+            comp_cfg=CompositeConfig(schedule=sched, wave_tiles=T))
         imgs[sched] = np.asarray(step(data, vol.origin, vol.spacing,
                                       _cam()))
     np.testing.assert_array_equal(imgs["waves"], imgs["frame"])
@@ -212,8 +213,9 @@ def test_waves_plain_mxu_step_matches_frame():
     data = shard_volume(vol.data, mesh)
     imgs = {}
     for sched in ("frame", "waves"):
-        step = distributed_plain_step_mxu(mesh, _tf(), spec,
-                                          schedule=sched, wave_tiles=T)
+        step = distributed_plain_step_mxu(
+            mesh, _tf(), spec,
+            comp_cfg=CompositeConfig(schedule=sched, wave_tiles=T))
         img, _ = step(data, vol.origin, vol.spacing, cam)
         imgs[sched] = np.asarray(img)
     np.testing.assert_allclose(imgs["waves"], imgs["frame"], atol=ATOL,
@@ -251,33 +253,6 @@ def test_waves_hybrid_step_matches_frame():
         imgs[sched] = np.asarray(img)
     np.testing.assert_allclose(imgs["waves"], imgs["frame"], atol=ATOL,
                                rtol=0)
-
-
-def test_waves_under_frame_scan_matches_eager():
-    """A waves step rolls into parallel.pipeline.frame_scan unchanged:
-    the wave scan nests inside the frame scan, per-wave temporal state
-    crosses frames as the same full-frame carry."""
-    from scenery_insitu_tpu.parallel.pipeline import (
-        distributed_vdi_step_mxu, frame_scan)
-
-    mesh = make_mesh(N)
-    vol = procedural_volume(16, kind="blobs")
-    cam = _cam()
-    spec = _mxu_spec(cam, vol)
-    data = shard_volume(vol.data, mesh)
-    ccfg = CompositeConfig(max_output_supersegments=8, adaptive_iters=2,
-                           schedule="waves", wave_tiles=T)
-    step = distributed_vdi_step_mxu(
-        mesh, _tf(), spec, VDIConfig(max_supersegments=6,
-                                     adaptive_iters=2), ccfg)
-    eager, _ = step(data, vol.origin, vol.spacing, cam)
-    run = frame_scan(step, lambda s: s, 2, field=lambda s: s)
-    _, (vdis, _) = run(data, vol.origin, vol.spacing, cam,
-                       jnp.float32(0.0))
-    # static field + static camera: both scanned frames == the eager one
-    for i in range(2):
-        _assert_vdi_close((vdis.color[i], vdis.depth[i]),
-                          (eager.color, eager.depth), atol=1e-6)
 
 
 # -------------------------------------------- degrade + observability

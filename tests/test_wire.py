@@ -425,7 +425,8 @@ def test_wire_plain_step():
     for ex in EXCHANGES:
         _qpack8_ring_vs_f32(
             lambda wire, ex=ex: distributed_plain_step(
-                mesh, _tf(), W, H, cfg, exchange=ex, wire=wire),
+                mesh, _tf(), W, H, cfg,
+                comp_cfg=CompositeConfig(exchange=ex, wire=wire)),
             lambda step: np.asarray(
                 step(data, vol.origin, vol.spacing, _cam())),
             f"plain-{ex}")
@@ -448,8 +449,9 @@ def test_wire_plain_mxu_step():
         return np.asarray(img)
 
     _qpack8_ring_vs_f32(
-        lambda wire: distributed_plain_step_mxu(mesh, _tf(), spec,
-                                                exchange="ring", wire=wire),
+        lambda wire: distributed_plain_step_mxu(
+            mesh, _tf(), spec,
+            comp_cfg=CompositeConfig(exchange="ring", wire=wire)),
         run, "plain-mxu")
 
 

@@ -27,6 +27,11 @@ VMEM footprint explicit: scratch + double-buffered blocks are counted by
 `_vmem_bytes` and requested through ``vmem_limit_bytes``; nothing rides
 on Mosaic's 16 MiB default scoped limit.
 
+A frame's ``n`` steps are a static walk over `schedule` (n = 10: two T=4
+passes and one T=2): `_multi_step_impl` calls the kernel once per pass
+and hands each pass's u and v straight to the next, so the compiled sim
+program is those kernels and no whole-grid copy between them.
+
 Used by the single-device fast path only: the *sharded* simulation keeps
 the roll formulation, where XLA lowers the rolls across a z-sharded mesh
 to ICI halo collectives (see sim/grayscott.py docstring) — a Pallas kernel
@@ -344,42 +349,38 @@ def schedule(shape, n: int) -> tuple:
 def _multi_step_impl(u, v, params_vec, n: int, interpret: bool,
                      ranges_to):
     """The `schedule` walk shared by `multi_step_pallas` and
-    `multi_step_pallas_ranges`. ``ranges_to = (nzb, nyb)`` threads the
-    occupancy epilogue through every pass: each kernel's native-
-    granularity v ranges are normalized onto the fixed (nzb, nyb) brick
-    grid (occupancy.remap_ranges) so the fori_loop carry keeps one shape
-    across schedules; the LAST executed pass's ranges describe the final
-    field, which is what the caller gets."""
+    `multi_step_pallas_ranges`: a static walk, one `_fused_call` per
+    scheduled pass, each pass's outputs handed straight to the next.
+    The schedule is static (``n`` is a static argument, ``reps`` are
+    Python ints), so the compiled program is the ``sum(reps)`` kernels
+    and nothing between them. Not a ``fori_loop`` per pass: XLA copies
+    each kernel's whole-grid results into the loop's carry buffers (u
+    and v once per trip: six 537 MB copies per frame at 512^3, n = 10).
+
+    ``ranges_to = (nzb, nyb)`` threads the occupancy epilogue through
+    every pass; the LAST executed pass's ranges describe the final
+    field, which is what the caller gets, normalized from the kernel's
+    native granularity onto the fixed (nzb, nyb) brick grid
+    (occupancy.remap_ranges)."""
     with_ranges = ranges_to is not None
     if with_ranges:
         from scenery_insitu_tpu.ops.occupancy import (field_ranges,
                                                       remap_ranges)
-        nzb, nyb = ranges_to
         if n == 0:
-            # no pass runs to overwrite the seed — a (+inf, -inf) seed
-            # would gate every cell off under a band-pass TF; reduce
-            # the field as-is instead (the render-only sim_steps=0 A/B)
-            r = field_ranges(v, nzb, nyb)
+            # no pass runs to produce ranges — reduce the field as-is
+            # (the render-only sim_steps=0 A/B)
+            r = field_ranges(v, *ranges_to)
             return (u, v, r.lo, r.hi)
-        s = (u, v,
-             jnp.full((nzb, nyb), jnp.inf, jnp.float32),
-             jnp.full((nzb, nyb), -jnp.inf, jnp.float32))
-    else:
-        s = (u, v)
     passes, remaining = schedule(u.shape, n)
     if remaining:   # fused_supported(shape) is False: caller should gate
         raise ValueError(f"no fused-stencil tile fits grid {u.shape}")
     for _, t, tz, th, reps in passes:
-        def one(s, t=t, tz=tz, th=th):
-            out = _fused_call(s[0], s[1], params_vec, t, tz, th, interpret,
-                              with_ranges)
-            if not with_ranges:
-                return tuple(out)
-            un, vn, lo, hi = out
-            return (un, vn) + remap_ranges(lo, hi, ranges_to)
-
-        s = jax.lax.fori_loop(0, reps, lambda _, s: one(s), s)
-    return s
+        for _ in range(reps):
+            u, v, *rng = _fused_call(u, v, params_vec, t, tz, th, interpret,
+                                     with_ranges)
+    if with_ranges:
+        return (u, v) + remap_ranges(*rng, ranges_to)
+    return (u, v)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "interpret"))

@@ -101,6 +101,64 @@ def test_pallas_multistep_remainder():
     np.testing.assert_allclose(np.asarray(ref.v), np.asarray(v2), atol=1e-5)
 
 
+def _eqns_outside_kernels(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold
+    (jit, loops, branches), in order; a ``pallas_call``'s kernel body is
+    not entered (the kernel's own plane loops are scans)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns_outside_kernels(sub)
+
+
+@pytest.mark.parametrize("with_ranges", [False, True],
+                         ids=["plain", "ranges"])
+@pytest.mark.parametrize("n", [10, 6, 4, 3, 1])
+def test_pallas_multistep_is_a_static_walk(n, with_ranges):
+    """The traced sim program is exactly the scheduled kernels, in
+    order, each fed by the one before: sum(reps) `pallas_call`s of the
+    scheduled (T, tz, th) and no loop around them (a `fori_loop` with
+    static bounds traces as `scan`): around a loop the TPU compiler
+    copies u and v into the carry once per trip."""
+    from scenery_insitu_tpu.sim import pallas_stencil as ps
+
+    shape = (16, 32, 128)
+    assert ps.fused_supported(shape)
+    u = jnp.zeros(shape, jnp.float32)
+    pvec = jnp.zeros((5,), jnp.float32)
+    if with_ranges:
+        fn = lambda u, v, p: ps.multi_step_pallas_ranges(
+            u, v, p, n=n, nzb=2, nyb=2, interpret=True)
+    else:
+        fn = lambda u, v, p: ps.multi_step_pallas(u, v, p, n=n,
+                                                  interpret=True)
+    eqns = list(_eqns_outside_kernels(jax.make_jaxpr(fn)(u, u, pvec).jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert not {"scan", "while"} & set(names), names
+
+    passes, remaining = ps.schedule(shape, n)
+    assert remaining == 0
+    want = [(t, tz, th) for _, t, tz, th, reps in passes
+            for _ in range(reps)]
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    got = []
+    for e in calls:
+        # in_specs: params (SMEM), then the centre (tz, th, W) block of u
+        centre = e.params["grid_mapping"].block_mappings[1].block_shape
+        tz, th, _ = (int(getattr(b, "block_size", b)) for b in centre)
+        assert e.params["name"].startswith("gray_scott_fused_t")
+        got.append((int(e.params["name"].rsplit("t", 1)[1]), tz, th))
+    assert got == want
+    # each pass reads the u and v the pass before it wrote, directly
+    for prev, nxt in zip(calls, calls[1:]):
+        assert set(nxt.invars[1:]) == set(prev.outvars[:2])
+
+
 def test_stencil_rejection_raises_on_default_path(monkeypatch):
     """On TPU the fused stencil is the default sim path and nothing
     stands between it and the compiler: a kernel the backend cannot

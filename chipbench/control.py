@@ -6,8 +6,9 @@ the cell's own size on the chip: the program's sound runs, and the control.
 
 The control is the lower precision put in the program's place: `rounded`
 holds the plain references themselves in bfloat16 where the program's field
-and frames would stand (the mildest bf16 path: anything computed in bf16
-errs at least as much), and `program` switches the configuration's
+(the field source's own `rounded`) and frames would stand (the mildest bf16
+path: anything computed in bf16 errs at least as much), and `program`
+switches the configuration's
 `control_overrides` on, where it has a lower-precision path of its own.
 The benchmark's own runs never run this; PERF.md gives the readings each
 limit was set from. Every session is built, measured over a short window
@@ -26,15 +27,14 @@ sys.path.insert(0, ROOT)
 
 
 def rounded(cell: dict, seed: int, produced: dict, refs: dict) -> dict:
-    """What `harness.close_run` hands over, with the references held in
-    bfloat16 in the program's place: the plain roll computed in bfloat16,
-    and the reference session's frames rounded to bfloat16."""
-    from chipbench import reference
+    """What `harness.produced_by` hands over, with the references held in
+    bfloat16 in the program's place: the source's plain reference of what
+    it kept, computed in bfloat16, and the reference session's frames
+    rounded to bfloat16."""
+    from chipbench import harness, reference
 
-    shape, traf = cell["config_file"]["shape"], cell["traffic_file"]
-    return dict(produced, field0=reference.gray_scott_frame0(
-        shape["grid"], seed, shape["steps_per_frame"], "bfloat16",
-        amplitude=traf["field_perturbation"]), frames={
+    return dict(produced, kept=harness.load_source(cell).rounded(
+        cell, seed, produced["kept"]), frames={
             f: {k: reference.round_bf16(p[k])
                 for k in ("vdi_color", "vdi_depth")}
             for f, p in refs["frames"].items()})

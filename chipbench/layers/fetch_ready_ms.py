@@ -1,6 +1,6 @@
 """The session's `fetch.ready` spans per frame (host clock): inside `fetch`,
 the host waits for the frame's device programs (`jax.block_until_ready`).
-0 from a program that has no such span."""
+Nothing from a program that has no such span."""
 
 NAME = "fetch_ready_ms"
 UNIT = "ms"

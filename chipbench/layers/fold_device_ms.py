@@ -2,7 +2,7 @@
 `sitpu_*` scope is `fold`: the supersegment fold of the marched chunks
 (the Pallas kernel `sitpu_fold_*` or its XLA twin), turning its state into
 the VDI's slots, and the threshold controller (self time, averaged over the
-devices). 0 from a program that has no such scope."""
+devices). Nothing from a program that keeps no scope table."""
 
 NAME = "fold_device_ms"
 UNIT = "ms"

@@ -7,7 +7,7 @@ phase: the update-slice fusions and prefetch copies the compiler makes to
 stage a chunk for the fold (12.8 of 36.4 ms at 512^3, 0.005 of 2.18 at
 128^3: PERF.md section 5). The run's log splits the two ("of which by
 inheritance"); a change to the fold's input layout moves this metric, not
-`fold_device_ms`. 0 from a program that keeps no scope table."""
+`fold_device_ms`. Nothing from a program that keeps no scope table."""
 
 NAME = "march_device_ms"
 UNIT = "ms"

@@ -8,7 +8,7 @@ UNIT = "%"
 SOURCE = "device_trace"
 LAYER = "sim"
 MOVES = "fps"
-CELLS = "all"
+CELLS = ["gs512-insitu", "gs128-insitu", "gs512-4rank-insitu"]
 
 
 def read(ctx):

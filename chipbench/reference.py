@@ -1,9 +1,9 @@
 """The benchmark's own plain references and comparisons. Imports nothing of
 the program: a later PR may change the program, not the yardstick.
 
-- `gray_scott_frame0`: the Gray-Scott initial field made from the seed and
-  advanced by the plain roll formulation, f32 (or, for the control, in a
-  lower precision).
+- `gray_scott_start`, `gray_scott_steps`, `gray_scott_frame0`: the
+  Gray-Scott initial field made from the seed and advanced by the plain roll
+  formulation, f32 (or, for the control, in a lower precision).
 - `decode`, `psnr`: one delivered VDI decoded from its own view, and the
   agreement of two decoded images.
 - `payload_faults`: what the sink's frames must satisfy one by one.
@@ -67,13 +67,20 @@ def seed_key(seed: int):
     return jax.random.PRNGKey(fold_seed(seed))
 
 
-def gray_scott_frame0(grid, seed: int, steps: int, dtype: str = "float32",
-                      amplitude: float = 0.0) -> np.ndarray:
-    """The rendered field (v) after one frame's `steps` steps of the plain
-    roll formulation, as a host array: the session's default start,
-    perturbed from `seed`. `dtype` below float32 is
-    the control: the same mathematics with state and arithmetic in that
-    type."""
+def gray_scott_start(grid, seed: int, amplitude: float = 0.0) -> tuple:
+    """(u, v) of the session's default start with v perturbed from `seed`,
+    f32, on the device."""
+    import jax
+    import jax.numpy as jnp
+
+    u, v = gray_scott_init(tuple(grid))
+    return u, jax.jit(perturb)(v, seed_key(seed), jnp.float32(amplitude))
+
+
+def gray_scott_steps(u, v, steps: int, dtype: str = "float32") -> tuple:
+    """(u, v) after `steps` steps of the plain roll formulation, on the
+    device, held in `dtype`. `dtype` below float32 is the control: the same
+    mathematics with state and arithmetic in that type."""
     import jax
     import jax.numpy as jnp
 
@@ -95,11 +102,21 @@ def gray_scott_frame0(grid, seed: int, steps: int, dtype: str = "float32",
     @jax.jit
     def run(u, v):
         return jax.lax.fori_loop(0, steps, step,
-                                 (u.astype(dt), v.astype(dt)))[1]
+                                 (u.astype(dt), v.astype(dt)))
 
-    u, v = gray_scott_init(tuple(grid))
-    v = jax.jit(perturb)(v, seed_key(seed), jnp.float32(amplitude))
-    return np.asarray(run(u, v).astype(jnp.float32))
+    return run(u, v)
+
+
+def gray_scott_frame0(grid, seed: int, steps: int, dtype: str = "float32",
+                      amplitude: float = 0.0) -> np.ndarray:
+    """The rendered field (v) after one frame's `steps` steps of the plain
+    roll formulation, as a host array: the session's default start,
+    perturbed from `seed`."""
+    import jax.numpy as jnp
+
+    v = gray_scott_steps(*gray_scott_start(grid, seed, amplitude), steps,
+                         dtype)[1]
+    return np.asarray(v.astype(jnp.float32))
 
 
 def round_bf16(x: np.ndarray) -> np.ndarray:

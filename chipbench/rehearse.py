@@ -2,11 +2,14 @@
 section 2): the harness end to end at a tiny size on the CPU, Pallas in
 interpret mode, on one virtual device and on a mesh of four.
 
-    python chipbench/rehearse.py [--ranks 1|4] [--trace 0|1] [--seed N]
+    python chipbench/rehearse.py [--ranks 1|4 | --config <name>]
+        [--traffic <name>] [--trace 0|1] [--seed N]
 
-Not a cell: its sizes are in `rehearsal/configs/`, BENCHMARK.json does not
-name them, and it prints counts and checks only: no time, rate or share of
-the device, because a CPU run has none to give.
+Not a cell: its sizes are in `rehearsal/configs/` (`--ranks N` is
+`--config tiny-Nrank`), a field source that exists only here is in
+`rehearsal/sources/`, BENCHMARK.json names neither, and it prints counts
+and checks only: no time, rate or share of the device, because a CPU run
+has none to give. The traffic is any file of `traffic/`.
 """
 
 import os
@@ -23,17 +26,15 @@ sys.path.insert(0, ROOT)
 COUNTS = ("frames_in_flight", "d2h_MB_per_frame")
 
 
-def rehearsal_cell(ranks: int) -> dict:
+def rehearsal_cell(ranks: int = 1, config: str = "",
+                   traffic: str = "insitu10-steer") -> dict:
     from chipbench import harness
 
-    name = f"tiny-{ranks}rank"
-    return {"name": f"rehearsal-{name}", "config": name,
-            "traffic": "insitu10-steer", "chips": ranks,
-            "config_file": harness.load_json(harness.HERE, "rehearsal",
-                                             "configs", name + ".json"),
-            "traffic_file": harness.load_json(harness.HERE, "traffic",
-                                              "insitu10-steer.json"),
-            "bench": harness.load_json(harness.ROOT, "BENCHMARK.json")}
+    name = config or f"tiny-{ranks}rank"
+    cell = harness.find_files(
+        {"name": f"rehearsal-{name}", "config": name, "traffic": traffic},
+        home=os.path.join(harness.HERE, "rehearsal"))
+    return dict(cell, chips=cell["config_file"]["chips"])
 
 
 def rehearse(cell: dict, seed: int, seconds: float, trace: bool) -> dict:
@@ -53,12 +54,14 @@ def main(argv=None) -> int:
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--ranks", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--config", default="")
+    ap.add_argument("--traffic", default="insitu10-steer")
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--seed", type=int, default=3000000019)
     ap.add_argument("--seconds", type=float, default=1.0)
     args = ap.parse_args(argv)
 
-    cell = rehearsal_cell(args.ranks)
+    cell = rehearsal_cell(args.ranks, args.config, args.traffic)
     res = rehearse(cell, args.seed, args.seconds, bool(args.trace))
     for name, value, limit, ok in res["checks"]:
         print(f"check {name}: {value} (limit {limit}) "

@@ -16,12 +16,11 @@ in `Recorder.hlo_inherited`, and `by_scope` keeps their time apart, so
 what a phase owns by the source's scope and what it owns by position stay
 two numbers.
 
-A missing source reads as a number, not as nothing: `run.py` ends a run
-whose line lacks a declared metric, and a reader new in a PR also runs on
-the parent. A program that keeps no table joins nothing (every scope 0, the
-unscoped share 100 %), and a span that no site opens sums to 0 — both with
-a `MISSING SOURCE` line on stderr, because 0 is the best value a `lower`
-metric can have.
+A missing source reads as nothing, not as a number: where the program
+keeps no table, or records spans but none of the name, the reader returns
+None with a `MISSING SOURCE` line on stderr, and the harness leaves the
+metric out of the line. 0 is the best value a `lower` metric can have, so
+a renamed span must not read as one.
 """
 
 import bisect
@@ -30,21 +29,22 @@ import sys
 
 
 def _missing(what: str) -> None:
-    print(f"[chipbench] MISSING SOURCE: {what}. The metric is reported "
-          "because run.py requires every declared one; it is NOT a "
-          "measurement.", file=sys.stderr, flush=True)
+    print(f"[chipbench] MISSING SOURCE: {what}. The metric is left out of "
+          "the line.", file=sys.stderr, flush=True)
 
 
 def span_ms(ctx, name: str):
     """Host ms per frame inside the recorder spans called `name`; None
-    from a run that recorded no span at all; 0, and a `MISSING SOURCE`
-    line, where spans were recorded but none is called `name`."""
+    from a run that recorded no span at all, and None with a `MISSING
+    SOURCE` line where spans were recorded but none is called `name`."""
     if not ctx["spans"]:
         return None
     durs = [e["dur"] for e in ctx["spans"] if e["name"] == name]
     if not durs:
         _missing(f"the program recorded spans but none called {name!r} "
-                 "(renamed, removed, or a commit from before it): 0 ms")
+                 "(renamed, removed, not opened under this traffic, or a "
+                 "commit from before it)")
+        return None
     return sum(durs) / ctx["frames"] * 1e3
 
 
@@ -111,9 +111,10 @@ def step(ctx) -> dict:
         scopes, inherited = table()
         if not scopes:
             _missing("the program keeps no scope table "
-                     "(Recorder.hlo_scopes): every scope 0 ms, "
-                     "step_unscoped_share 100 %")
+                     "(Recorder.hlo_scopes): no scope's device time and no "
+                     "step_unscoped_share")
         got = by_scope(ctx["trace"], pattern, scopes, inherited)
+        got["table"] = bool(scopes)
         got["runs"] = runs = ctx["trace"].program_runs(pattern)
         ctx["_step_by_scope"] = got
         if runs:
@@ -136,9 +137,10 @@ def step(ctx) -> dict:
 
 def step_scope_ms(ctx, *phases):
     """Device ms per frame of the step program's ops whose innermost
-    scope is one of `phases`; None where the step program did not run."""
+    scope is one of `phases`; None where the step program did not run or
+    the program keeps no scope table."""
     got = step(ctx)
-    if not got["runs"]:
+    if not got["runs"] or not got["table"]:
         return None
     return sum(got["scopes"].get(p, 0.0) for p in phases) \
         / got["runs"] * 1e3

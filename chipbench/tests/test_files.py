@@ -1,10 +1,13 @@
-"""BENCHMARK.json against the files it names: every cell's configuration
-and traffic file is there, every per-layer entry is a reader file saying
-the same, and the arithmetic gives the published sizes."""
+"""BENCHMARK.json against the files it names: every cell's configuration,
+traffic and field-source file is there, every per-layer entry is a reader
+file saying the same, every `workloads` list names cells that exist, and
+the arithmetic gives the published sizes."""
 
 import os
 import subprocess
 import sys
+
+import pytest
 
 from chipbench import arith, harness
 
@@ -22,6 +25,10 @@ def test_cells_find_their_files():
         assert cell["config_file"]["name"] == w["config"]
         assert cell["config_file"]["chips"] == w["chips"]
         assert cell["traffic_file"]["name"] == w["traffic"]
+        source = harness.load_source(cell)
+        for part in ("build_session", "keep", "wait", "window_checks",
+                     "plain_reference", "compare", "rounded"):
+            assert callable(getattr(source, part)), (w["name"], part)
     for c in b["configs"]:
         f = harness.load_json(ROOT, c["file"])
         assert f["source"] == c["source"] and f["reduced"] == c["reduced"]
@@ -42,6 +49,38 @@ def test_layer_entries_are_reader_files():
             (m.UNIT, m.LAYER, m.MOVES, m.SOURCE)
         assert e.get("workloads", "all") == m.CELLS
         assert m.MOVES in e2e
+
+
+def test_workloads_lists_name_cells_that_report_what_they_move():
+    b = bench()
+    cells = {w["name"] for w in b["workloads"]}
+    cells_of = lambda m: set(m.get("workloads", cells))
+    e2e = {m["name"]: cells_of(m) for m in b["end_to_end"]}
+    for m in b["end_to_end"] + b["per_layer"]:
+        assert cells_of(m) and cells_of(m) <= cells, m["name"]
+    for m in b["per_layer"]:
+        assert cells_of(m) <= e2e[m["moves"]], m["name"]
+    for cell in cells:      # set-up, one more end to end, one per layer
+        assert sum(cell in c for c in e2e.values()) >= 2 and cell in e2e[
+            "setup_s"]
+        assert any(cell in cells_of(m) for m in b["per_layer"])
+
+
+def test_every_rehearsal_configuration_finds_its_source():
+    import glob
+
+    from chipbench.rehearse import rehearsal_cell
+
+    names = sorted(os.path.basename(p)[:-5] for p in glob.glob(os.path.join(
+        harness.HERE, "rehearsal", "configs", "*.json")))
+    assert names == ["tiny-1rank", "tiny-4rank", "tiny-hostring"]
+    for name in names:
+        cell = rehearsal_cell(config=name)
+        assert cell["config_file"]["name"] == name
+        assert cell["chips"] == cell["config_file"]["shape"]["ranks"]
+        assert callable(harness.load_source(cell).build_session)
+    with pytest.raises(harness.BenchFailure, match="no field source"):
+        harness.load_source({"config_file": {"field_source": "nowhere"}})
 
 
 def test_shape_arithmetic():

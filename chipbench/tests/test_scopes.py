@@ -20,7 +20,7 @@ WANT = {
     "fetch_copy_ms": 14.0,              # (10 + 6 + 2 + 1 + 9) / 2
     "fetch_concat_ms": 3.0,
     "fetch_shard_max_ms": 11.0,         # frame 1: shard 1 = 2 + 9; frame 0
-    "camera_readback_ms": 5.5,          # has no shard and does not count
+                                        # has no shard and does not count
     "sim_dispatch_ms": 3.0,
     "step_dispatch_ms": 6.0,
 }
@@ -77,39 +77,77 @@ def test_by_scope_partitions_the_program():
                                    pytest.approx(0.010)}}
 
 
-@pytest.mark.parametrize("name,want", [
-    ("march_device_ms", 0.0), ("fold_device_ms", 0.0),
-    ("composite_device_ms", 0.0), ("step_unscoped_share", 100.0)])
-def test_a_program_without_a_table_joins_nothing(name, want, monkeypatch,
-                                                 capsys):
-    """A commit before PR 24 keeps no table: the readers do not raise,
-    every op of the step program is unexplained, and stderr says that
-    the number stands for a missing source."""
+@pytest.mark.parametrize("name", [
+    "march_device_ms", "fold_device_ms", "composite_device_ms",
+    "step_unscoped_share"])
+def test_a_program_without_a_table_reads_nothing(name, monkeypatch, capsys):
+    """A commit before PR 24 keeps no table: the readers do not raise and
+    give nothing (0 ms would be the best a `lower` metric can read), and
+    stderr says that a source is missing. With a table, a scope in which
+    no op ran is 0: that is a reading."""
     got = readers()[name].read(ctx(table=({}, {}), monkeypatch=monkeypatch))
-    assert got == pytest.approx(want)
+    assert got is None
     assert "MISSING SOURCE" in capsys.readouterr().err
+    table = ({"jit_another_program": {"fusion.3": "march"}}, {})
+    got = readers()[name].read(ctx(table=table, monkeypatch=monkeypatch))
+    assert got == (100.0 if name == "step_unscoped_share" else 0.0)
 
 
 NAMES = {"fetch_ready_ms": "fetch.ready", "fetch_copy_ms": "fetch.copy",
-         "camera_readback_ms": "camera_readback"}
+         "fetch_concat_ms": "fetch.concat", "sim_dispatch_ms": "sim",
+         "step_dispatch_ms": "dispatch"}
 
 
 @pytest.mark.parametrize("name", sorted(NAMES))
-def test_a_program_without_the_span_reads_zero(name, capsys):
-    """A commit before PR 24 has `fetch`, `sim`, `dispatch` only: run.py
-    ends a run that leaves a declared metric out, so the sum over no span
-    is 0, not nothing — and stderr says so, since 0 is the best a `lower`
-    metric can read; with no span at all there is nothing to read, and a
-    span that is there is read without a word."""
-    old = [e for e in FIX["spans"] if "." not in e["name"]
-           and e["name"] != "camera_readback"]
-    c = dict(ctx(), spans=old)
-    assert readers()[name].read(c) == 0.0
+def test_a_span_absent_from_the_recorder_reads_nothing(name, capsys):
+    """Spans were recorded but none of the reader's name (renamed, removed,
+    not opened under this traffic, a commit from before it): the reader
+    gives None, not 0, which is the best a `lower` metric can read, and
+    stderr names the span; with no span at all there is nothing to read
+    either, and a span that is there is read without a word."""
+    others = [e for e in FIX["spans"] if e["name"] != NAMES[name]]
+    c = dict(ctx(), spans=others)
+    assert readers()[name].read(c) is None
     err = capsys.readouterr().err
-    assert "MISSING SOURCE" in err and NAMES[name] in err
+    assert "MISSING SOURCE" in err and repr(NAMES[name]) in err
     assert readers()[name].read(dict(c, spans=[])) is None
     assert readers()[name].read(ctx()) > 0.0
     assert capsys.readouterr().err == ""
+
+
+def test_a_traced_line_leaves_out_what_no_reader_read(monkeypatch, capsys):
+    """`run.py` gives the result of a traced run whose readers found
+    nothing for a declared per-layer metric, without that metric; it goes
+    on refusing a run that lacks a declared end-to-end metric."""
+    import json
+
+    from chipbench import run
+
+    res = {"correct": True, "attempted": 8, "failed": 0,
+           "end_to_end": {"setup_s": (1.0, "s"), "fps": (2.0, "frames/s"),
+                          "steer_to_pixel_ms": (4.0, "ms")},
+           "per_layer": {"dispatch_ms": (3.0, "ms")},
+           "device": {"platform": "tpu"}, "breakdown": None,
+           "checks": [("frames_failed", 0, 0, True),
+                      ("decoded_psnr_dB_window_frame", float("inf"), 120.0,
+                       True)]}
+    monkeypatch.setattr(harness, "run_cell", lambda *a: res)
+    argv = ["--workload", "gs512-4rank-insitu", "--seed", "1", "--seconds",
+            "1", "--trace"]
+    assert run.main(argv + ["1"]) == 0
+    out, err = capsys.readouterr()
+    line = json.loads(out.strip().splitlines()[-1])
+    assert list(line["metrics"]) == ["dispatch_ms"]
+    assert "fetch_ready_ms" in err and "nothing to read" in err
+    assert list(line)[-1] == "checks"
+    assert line["checks"] == {"frames_failed": [0, 0],
+                              "decoded_psnr_dB_window_frame": ["inf", 120.0]}
+    assert err.strip().splitlines()[-1].startswith(
+        "[chipbench] compared decoded_psnr_dB_window_frame: inf")
+    res["end_to_end"].pop("fps")
+    assert run.main(argv + ["0"]) == 1
+    out, err = capsys.readouterr()
+    assert "did not report ['fps']" in err and '"correct"' not in out
 
 
 def test_table_is_the_recorders():

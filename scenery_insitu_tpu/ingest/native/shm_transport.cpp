@@ -58,6 +58,7 @@ struct Control {
   sem_t fresh;                           // posted on publish when waited on
   std::atomic<uint64_t> frames_dropped;  // acquire failures (all slots busy)
   SlotState slots[kMaxSlots];
+  std::atomic<uint64_t> consumed_seq;    // newest seq any reader has pinned
 };
 
 static_assert(sizeof(Control) <= kHeaderBytes, "control block too large");
@@ -209,6 +210,12 @@ int32_t shm_consumer_latest(void* handle, int64_t timeout_ms, void** data,
         c->slots[l].readers.fetch_add(1, std::memory_order_acq_rel);
         if (c->slots[l].seq.load(std::memory_order_acquire) == seq) {
           h->last_seen = seq;
+          // a lockstep producer reads this through its own handle: the
+          // one trace a reader leaves that does not go away with its pin
+          uint64_t seen = c->consumed_seq.load(std::memory_order_relaxed);
+          while (seen < seq && !c->consumed_seq.compare_exchange_weak(
+                                   seen, seq, std::memory_order_acq_rel)) {
+          }
           *data = h->base + static_cast<size_t>(l) * c->slot_size;
           if (seq_out) *seq_out = seq;
           return l;
@@ -253,14 +260,14 @@ void shm_consumer_release(void* handle, int32_t slot) {
 // inspection is a read and recovery is clearing stale reader pins left by
 // crashed consumers.
 
-// Fills out[0..7+2*nslots): nslots, slot_size, next_seq, latest(+1, so 0
+// Fills out[0..8+2*nslots): nslots, slot_size, next_seq, latest(+1, so 0
 // means "none"), waiters, writer_attached, frames_dropped, then per slot
-// (readers, seq). Returns the number of u64s written, or 0 if out_len is
-// too small.
+// (readers, seq), then consumed_seq. Returns the number of u64s written,
+// or 0 if out_len is too small.
 uint32_t shm_channel_stats(void* handle, uint64_t* out, uint32_t out_len) {
   Handle* h = static_cast<Handle*>(handle);
   Control* c = h->ctl;
-  uint32_t need = 7 + 2 * c->nslots;
+  uint32_t need = 8 + 2 * c->nslots;
   if (out_len < need) return 0;
   out[0] = c->nslots;
   out[1] = c->slot_size;
@@ -273,6 +280,7 @@ uint32_t shm_channel_stats(void* handle, uint64_t* out, uint32_t out_len) {
     out[7 + 2 * i] = c->slots[i].readers.load(std::memory_order_acquire);
     out[8 + 2 * i] = c->slots[i].seq.load(std::memory_order_acquire);
   }
+  out[7 + 2 * c->nslots] = c->consumed_seq.load(std::memory_order_acquire);
   return need;
 }
 

@@ -17,9 +17,12 @@ simulation can drive InSituSession exactly like the built-in sims — the
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
 import os
 import subprocess
+import threading
+import time
 from typing import Optional, Sequence, Tuple
 
 import numpy as np
@@ -97,40 +100,50 @@ def _load():
     return lib
 
 
+def _stats_of(lib, h, channel: str) -> dict:
+    """The control block behind an open handle, as `channel_stats` gives
+    it."""
+    # size the buffer from the channel's actual slot count instead of a
+    # fixed 32 (which silently relied on kMaxSlots=8 in the C++ side)
+    nslots_c = int(lib.shm_channel_nslots(h))
+    need = 8 + 2 * nslots_c
+    buf = (ctypes.c_uint64 * need)()
+    n = lib.shm_channel_stats(h, buf, need)
+    if n == 0:
+        raise OSError(
+            f"shm_channel_stats returned no data for {channel!r} "
+            f"(buffer {need} u64, nslots {nslots_c})")
+    vals = list(buf[:n])
+    nslots = int(vals[0])
+    return {
+        "channel": channel,
+        "nslots": nslots,
+        "slot_bytes": int(vals[1]),
+        "last_seq": int(vals[2]),
+        "latest_slot": int(vals[3]) - 1,
+        "waiters": int(vals[4]),
+        "writer_attached": bool(vals[5]),
+        "frames_dropped": int(vals[6]),
+        "slots": [{"readers": int(vals[7 + 2 * i]),
+                   "seq": int(vals[8 + 2 * i])}
+                  for i in range(nslots)],
+        "consumed_seq": int(vals[7 + 2 * nslots]),
+    }
+
+
 def channel_stats(channel: str) -> dict:
     """Inspect a live channel's control block (≅ sem_get.cpp's semaphore
     dump, reference src/test/cpp/sem_get.cpp). Raises FileNotFoundError if
-    the channel does not exist."""
+    the channel does not exist. ``consumed_seq`` is the newest sequence
+    number any reader has pinned: what a producer in lockstep with its
+    reader waits for (``ShmProducer.stats`` reads the same block through
+    the producer's own handle, also once the name is unlinked)."""
     lib = _load()
     h = lib.shm_consumer_open(channel.encode())
     if not h:
         raise FileNotFoundError(f"no shm channel {channel!r}")
     try:
-        # size the buffer from the channel's actual slot count instead of a
-        # fixed 32 (which silently relied on kMaxSlots=8 in the C++ side)
-        nslots_c = int(lib.shm_channel_nslots(h))
-        need = 7 + 2 * nslots_c
-        buf = (ctypes.c_uint64 * need)()
-        n = lib.shm_channel_stats(h, buf, need)
-        if n == 0:
-            raise OSError(
-                f"shm_channel_stats returned no data for {channel!r} "
-                f"(buffer {need} u64, nslots {nslots_c})")
-        vals = list(buf[:n])
-        nslots = int(vals[0])
-        return {
-            "channel": channel,
-            "nslots": nslots,
-            "slot_bytes": int(vals[1]),
-            "last_seq": int(vals[2]),
-            "latest_slot": int(vals[3]) - 1,
-            "waiters": int(vals[4]),
-            "writer_attached": bool(vals[5]),
-            "frames_dropped": int(vals[6]),
-            "slots": [{"readers": int(vals[7 + 2 * i]),
-                       "seq": int(vals[8 + 2 * i])}
-                      for i in range(nslots)],
-        }
+        return _stats_of(lib, h, channel)
     finally:
         lib.shm_channel_close(h)
 
@@ -167,6 +180,21 @@ class ShmProducer:
         if not self.handle:
             raise OSError(f"could not create shm channel {channel!r}")
 
+    def acquire(self) -> Optional[np.ndarray]:
+        """The slot the next frame goes into, as a writable array of the
+        channel's shape, to be filled in place and then ``commit``ted;
+        None (and one more ``frames_dropped``) when every writable slot
+        is pinned by a reader — the producer never blocks."""
+        ptr = self.lib.shm_producer_acquire(self.handle)
+        if not ptr:
+            return None
+        buf = (ctypes.c_float * (self.nbytes // 4)).from_address(ptr)
+        return np.frombuffer(buf, np.float32).reshape(self.shape)
+
+    def commit(self) -> int:
+        """Publish the slot last acquired; returns its sequence number."""
+        return self.lib.shm_producer_publish(self.handle)
+
     def publish(self, frame: np.ndarray) -> int:
         """Copy one frame in and publish; returns seq (0 = dropped: every
         writable slot was pinned by slow readers — the producer never
@@ -174,11 +202,17 @@ class ShmProducer:
         frame = np.ascontiguousarray(frame, np.float32)
         if frame.shape != self.shape:
             raise ValueError(f"frame shape {frame.shape} != {self.shape}")
-        ptr = self.lib.shm_producer_acquire(self.handle)
-        if not ptr:
+        slot = self.acquire()
+        if slot is None:
             return 0
-        ctypes.memmove(ptr, frame.ctypes.data, self.nbytes)
-        return self.lib.shm_producer_publish(self.handle)
+        np.copyto(slot, frame)
+        return self.commit()
+
+    def stats(self) -> dict:
+        """The channel's control block (`channel_stats`) through this
+        handle: it needs no name, so it still answers once the channel
+        is unlinked."""
+        return _stats_of(self.lib, self.handle, self.channel)
 
     @property
     def frames_dropped(self) -> int:
@@ -198,8 +232,8 @@ class ShmConsumer:
 
     def __init__(self, channel: str, shape: Sequence[int],
                  timeout_ms: int = 5000, poll_interval_ms: int = 20):
-        import time
         self.lib = _load()
+        self.channel = channel
         self.shape = tuple(shape)
         deadline = time.monotonic() + timeout_ms / 1000.0
         self.handle = None
@@ -248,6 +282,15 @@ class ShmConsumer:
 
     def release(self, slot: int) -> None:
         self.lib.shm_consumer_release(self.handle, slot)
+
+    def stats(self) -> dict:
+        """The channel's control block (`channel_stats`) through this
+        handle, also once the name is unlinked. A read of the block's
+        atomics that touches nothing of the handle: safe beside a thread
+        that pins and releases slots through the same handle."""
+        if not self.handle:
+            raise RuntimeError(f"shm consumer of {self.channel!r} is closed")
+        return _stats_of(self.lib, self.handle, self.channel)
 
     def close(self) -> None:
         if self.handle:
@@ -343,8 +386,6 @@ class ShmShardedVolumeSource:
         return len(seqs) == 1
 
     def advance(self, n: int = 1) -> None:   # n meaningless for external
-        import time
-
         from scenery_insitu_tpu import obs as _obs
 
         # while stalled, one non-blocking refresh pass per advance (same
@@ -413,19 +454,49 @@ class ShmShardedVolumeSource:
             con.close()
 
 
+_NO_SPAN = contextlib.nullcontext()     # a span site with obs off
+
+
 class ShmVolumeSource:
-    """Session sim-adapter over a shm channel: ``advance(n)`` pulls the
-    newest frame (blocking until one arrives), ``.field`` is the device
-    array. Plugs an EXTERNAL simulation into InSituSession.
+    """Session sim-adapter over a shm channel: plugs an EXTERNAL
+    simulation into InSituSession (``advance(n)`` + ``.field``).
+
+    The host -> device hop runs beside the frame loop, not on it (the
+    reference's double buffer, SURVEY §0: one field is handed over while
+    the last one is rendered). An uploader thread owned by the source
+    pins the newest slot WITHOUT copying it (``latest(copy=False)``),
+    puts it on the device, waits until the transfer has landed, releases
+    the slot, and keeps the landed field until ``advance`` takes it;
+    only then does it pin the next one. ``advance`` therefore only swaps
+    references (it blocks, in an ``ingest.wait`` span, while no field
+    has landed yet), the loop's thread never copies a field, and beside
+    the field being marched at most two more are alive on the device:
+    the one landed or just taken, and the one on its way. A producer in
+    lockstep (one that waits for ``consumed_seq``, ``ShmProducer.stats``)
+    gets every field rendered exactly once, in order. Under a
+    free-running one each frame renders the newest field at the time
+    the one before was TAKEN: one frame interval staler than the
+    blocking pull this replaced (newest at `advance`), the price of
+    taking the upload off the loop's thread. A landed field is never
+    replaced by a newer one: a lockstep producer counts a pin as a
+    field rendered (docs/ROBUSTNESS.md).
 
     Stall supervision (docs/ROBUSTNESS.md): when no strictly-newer frame
-    arrives within ``frame_timeout_ms`` (default: ``timeout_ms``) the
+    lands within ``frame_timeout_ms`` (default: ``timeout_ms``) the
     source marks itself STALLED — minted once per episode on the
-    ``ingest.stall`` ledger — and keeps rendering the last-good frame;
-    while stalled, ``advance`` polls without blocking so a dead producer
-    cannot throttle the render loop to one frame per timeout. The
-    moment frames resume the stall clears (``ingest_stall_recoveries``
-    counter + ``ingest_recovered`` event)."""
+    ``ingest.stall`` ledger — and keeps rendering the last-good frame
+    (counter ``ingest_fields_repeated``); while stalled, ``advance``
+    polls without blocking so a dead producer cannot throttle the render
+    loop to one frame per timeout, unless the channel already holds a
+    newer frame that is on its way. The moment frames resume the stall
+    clears (``ingest_stall_recoveries`` counter + ``ingest_recovered``
+    event).
+
+    ``device_put=False`` lands a host copy of the slot instead (tests).
+    ``close()`` joins the uploader and detaches from the channel;
+    ``InSituSession.close`` calls it."""
+
+    _POLL_MS = 50       # the uploader's wait for a frame: bounds close()
 
     def __init__(self, channel: str, grid: Sequence[int],
                  timeout_ms: int = 10000, device_put: bool = True,
@@ -439,26 +510,122 @@ class ShmVolumeSource:
                                  else frame_timeout_ms)
         self._device_put = device_put
         self._jax = jax
+        # the CPU backend takes an aligned host buffer as the array's own
+        # memory instead of copying it: the slot would be read after its
+        # release
+        self._put_aliases = jax.default_backend() == "cpu"
         self._field = None
         self.stalled = False
         self.stall_count = 0
         self.last_seq = None
+        self._cond = threading.Condition()
+        self._landed = None         # (field, seq) waiting for `advance`
+        self._error = None          # what ended the uploader
+        self._closing = False
+        self._thread = None         # started by the first advance / field
+
+    # ---------------------------------------------------------- uploader
+
+    def _land(self, view: np.ndarray):
+        if self._put_aliases or not self._device_put:
+            view = view.copy()
+        if not self._device_put:
+            return view
+        field = self._jax.device_put(view)
+        field.block_until_ready()
+        return field
+
+    def _upload_one(self, view: np.ndarray, seq: int):
+        from scenery_insitu_tpu import obs as _obs
+
+        rec = _obs.get_recorder()
+        with (rec.span("ingest.upload", bytes=view.nbytes, seq=seq)
+              if rec.enabled else _NO_SPAN):
+            field = self._land(view)
+            # inside the span: a reader of the window's events finds the
+            # bytes where it finds the upload
+            rec.count("ingest_bytes", view.nbytes)
+            rec.count("ingest_fields_uploaded")
+        return field
+
+    def _upload_loop(self) -> None:
+        try:
+            self._upload_until_closed()
+        except BaseException as e:      # handed to the loop's thread
+            with self._cond:
+                self._error = e
+                self._cond.notify_all()
+
+    def _upload_until_closed(self) -> None:
+        while True:
+            with self._cond:
+                while self._landed is not None and not self._closing:
+                    self._cond.wait()
+                if self._closing:
+                    return
+            got = self.consumer.latest(timeout_ms=self._POLL_MS, copy=False)
+            if got is None:
+                continue
+            view, seq = got[0], int(got[1])
+            try:
+                field = self._upload_one(view, seq)
+            finally:
+                self.consumer.release(view.slot)
+            with self._cond:
+                self._landed = (field, seq)
+                self._cond.notify_all()
+
+    def _wait_landed(self, wait_ms: float, take: bool):
+        """The landed (field, seq), waiting up to ``wait_ms`` for one;
+        None on timeout. ``take`` hands it over for good and lets the
+        uploader pin the next slot."""
+        if self._closing:
+            raise RuntimeError("the shm source is closed")
+        if self._thread is None:
+            self._thread = threading.Thread(
+                target=self._upload_loop, name="shm-uploader", daemon=True)
+            self._thread.start()
+        deadline = time.monotonic() + wait_ms / 1000.0
+        with self._cond:
+            while self._landed is None:
+                if self._error is not None:
+                    raise RuntimeError("the shm uploader ended") \
+                        from self._error
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    return None
+                self._cond.wait(left)
+            got = self._landed
+            if take:
+                self._landed = None
+                self._cond.notify_all()
+        return got
+
+    # ------------------------------------------------------ the sim facade
 
     def advance(self, n: int) -> None:   # n is meaningless for external sims
         from scenery_insitu_tpu import obs as _obs
 
+        rec = _obs.get_recorder()
         # while stalled, poll non-blocking: the loop keeps pacing on
-        # last-good data instead of stalling frame_timeout_ms per frame
+        # last-good data instead of stalling frame_timeout_ms per frame —
+        # unless the channel already holds a frame newer than the one
+        # rendered last: then frames have resumed and it is on its way
+        # (`stats` only reads the control block: the uploader may be
+        # inside `latest` on the same handle meanwhile)
         wait = (self.timeout_ms if self._field is None
-                else 0 if self.stalled else self.frame_timeout_ms)
-        got = self.consumer.latest(timeout_ms=wait)
+                else self.frame_timeout_ms if not self.stalled
+                or self.consumer.stats()["last_seq"] > self.last_seq else 0)
+        with rec.span("ingest.wait") if rec.enabled else _NO_SPAN:
+            got = self._wait_landed(wait, take=True)
         if got is None:
             if self._field is None:
                 raise TimeoutError("no frame from external simulation")
+            rec.count("ingest_fields_repeated")
             if not self.stalled:
                 self.stalled = True
                 self.stall_count += 1
-                _obs.get_recorder().count("ingest_stalls")
+                rec.count("ingest_stalls")
                 _obs.degrade(
                     "ingest.stall", "live producer frames",
                     "re-rendering last-good frame",
@@ -466,17 +633,33 @@ class ShmVolumeSource:
                     f"frame_timeout_ms={self.frame_timeout_ms}; "
                     "producer stalled or dead", warn=False)
             return                        # keep rendering the last frame
-        frame, seq = got
+        self._field, seq = got
         if self.stalled:
             self.stalled = False
-            _obs.get_recorder().count("ingest_stall_recoveries")
-            _obs.get_recorder().event("ingest_recovered", seq=int(seq))
+            rec.count("ingest_stall_recoveries")
+            rec.event("ingest_recovered", seq=seq)
         self.last_seq = seq
-        self._field = (self._jax.device_put(frame) if self._device_put
-                       else frame)
 
     @property
     def field(self):
-        if self._field is None:
-            self.advance(1)
-        return self._field
+        """The field the last ``advance`` took. Before the first one: the
+        first field to land, which that ``advance`` then takes (a look at
+        the shape consumes nothing)."""
+        if self._field is not None:
+            return self._field
+        got = self._wait_landed(self.timeout_ms, take=False)
+        if got is None:
+            raise TimeoutError("no frame from external simulation")
+        return got[0]
+
+    def close(self) -> None:
+        """Join the uploader and detach from the channel; the fields
+        already on the device stay valid (`field` still shows the last
+        one taken). `advance` on a closed source raises."""
+        with self._cond:
+            self._closing = True
+            self._cond.notify_all()
+        if self._thread is not None:
+            self._thread.join()
+            self._thread = None
+        self.consumer.close()

@@ -306,6 +306,13 @@ _COUNTER_REGISTRY: Dict[str, str] = {
                          "hierarchical exchange was built",
     "iframe_forced": "the delta encoder forced a full I-tile (resync or "
                      "cadence)",
+    "ingest_bytes": "bytes of external-sim fields the shm uploader put "
+                    "on the device (count = bytes)",
+    "ingest_fields_repeated": "a frame was rendered from the field of "
+                              "the frame before: no newer shm field "
+                              "had landed",
+    "ingest_fields_uploaded": "the shm uploader landed one field on the "
+                              "device",
     "ingest_stall_recoveries": "shm ingest saw a strictly-newer producer "
                                "frame again after a stall",
     "ingest_stalls": "shm ingest found no strictly-newer producer frame "

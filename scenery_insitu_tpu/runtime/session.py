@@ -846,6 +846,14 @@ class InSituSession:
             self._obs_pub.pump(self.obs, force=True)
         return payload
 
+    def close(self) -> None:
+        """The session's end: a sim source that owns a thread or a
+        channel (`ingest.shm.ShmVolumeSource`) is closed; the built-in
+        sims have nothing to close."""
+        close = getattr(self.sim, "close", None)
+        if close is not None:
+            close()
+
     def _retire(self, entry, fetch: bool, payload: dict) -> dict:
         """Retire one pipelined frame: fetch + deliver it when it has
         consumers, otherwise just pace the loop on its device

@@ -19,7 +19,7 @@ pytestmark = pytest.mark.skipif(
 
 from scenery_insitu_tpu.ingest.shm import (DEMO_PRODUCER, ShmConsumer,
                                            ShmProducer, ShmVolumeSource,
-                                           ensure_built)
+                                           ensure_built, unlink)
 
 
 def _chan():
@@ -177,7 +177,7 @@ def test_shm_source_stall_and_recover():
         np.testing.assert_array_equal(np.asarray(src.field),
                                       np.full(shape, 2.0, np.float32))
     finally:
-        src.consumer.close()
+        src.close()
         prod.close()
 
 
@@ -241,6 +241,7 @@ def test_cpp_demo_producer_field_mode():
         cons.close()
     finally:
         proc.wait(timeout=10)
+        unlink(ch)              # the C++ producer leaves its channel
 
 
 def test_session_driven_by_external_cpp_sim():
@@ -270,9 +271,12 @@ def test_session_driven_by_external_cpp_sim():
         assert payload["vdi_color"].shape == (4, 4, 24, 32)
         assert np.isfinite(payload["vdi_color"]).all()
         assert payload["vdi_color"].max() > 0.0  # blob is visible
+        sess.close()            # the session's end: uploader, consumer
+        assert src.consumer.handle is None
     finally:
         proc.kill()
         proc.wait(timeout=10)
+        unlink(ch)
 
 
 def _run_slab_producers(n: int, d: int, frames: int):
@@ -372,7 +376,7 @@ def test_session_driven_by_multirank_external_producers():
                                       pay_s["vdi_depth"])
     finally:
         src_multi.close()
-        src_single.consumer.close()
+        src_single.close()
         for c in chans + [whole]:
             unlink(c)
 

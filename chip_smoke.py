@@ -10,8 +10,10 @@ one-chip share: 640×640 intermediate grid, K=16, temporal thresholds, 10
 sim steps per frame), checks what the sink receives, and compares it with
 the plain reference already in the repo: the same session with the XLA
 schedules named explicitly. With four or more devices it repeats the run
-on a 4-device mesh and checks that the sim state is sharded over all
-four and that the 4-rank frame matches the 1-rank frame.
+on a 4-device mesh (the fused stencil on every rank's shard, z halos
+from the ring neighbours) and checks that the sim state is sharded over
+all four, that its field matches the one-device XLA roll, and that the
+4-rank frame matches the 1-rank frame.
 
 It fails — non-zero exit, no result line — when JAX finds no TPU, when a
 phase raises, when a check fails, and when the fallback ledger holds any
@@ -209,8 +211,9 @@ def run_session(ov: tuple, warmup: int, steady: int,
             "composite": ("identity (one rank, k_out >= k)" if identity
                           else resolve_backend(cc)),
             "stencil": ([list(p) for p in ps.schedule(
-                field.shape, cfg.sim.steps_per_frame)[0]]
-                if cfg.sim.fused_stencil and n == 1 else "xla_roll"),
+                field.sharding.shard_shape(field.shape),
+                cfg.sim.steps_per_frame, ring=n > 1)[0]]
+                if cfg.sim.fused_stencil else "xla_roll"),
             "matmul_dtype": spec.matmul_dtype, "vtiles": spec.vtiles,
             "chunk": spec.chunk, "regime": list(regime), "ranks": n},
         "compile": {"warmup_wall_s": round(warm_s, 2),
@@ -294,8 +297,8 @@ def smoke(grid: int = GRID, k: int = K, warmup: int = WARMUP,
           extra: tuple = ()) -> dict:
     """The whole check at one size; raises SmokeFailure with the reason.
     ``extra`` are overrides added to the one-rank run (tests name the
-    CPU's schedules with them); ``four`` adds the 4-device run, whose sim
-    schedule is named explicitly (the fused stencil is one-device only)."""
+    CPU's schedules with them) and to the 4-device run that ``four``
+    adds, whose sim is then the fused stencil on every rank's shard."""
     meter = CompileMeter()
     try:
         return _smoke(meter, grid, k, warmup, steady, four, out_dir, extra)
@@ -353,13 +356,18 @@ def _smoke(meter: CompileMeter, grid: int, k: int, warmup: int, steady: int,
     print(f"[chip_smoke] vs explicit XLA: {summary['reference']}",
           flush=True)
     assert_clean_ledger("reference run")
+    ref_field0 = ref["field0"]
     del ref
 
     if four:
-        r4 = run_session(overrides(grid, k, 4, ("sim.fused_stencil=false",)),
-                         warmup, steady, meter)
+        r4 = run_session(overrides(grid, k, 4, extra), warmup, steady,
+                         meter)
         check(r4["sim_devices"] == 4,
               f"sim state spans {r4['sim_devices']} device(s), not 4")
+        sim_err4 = float(np.abs(r4["field0"] - ref_field0).max())
+        check(sim_err4 <= SIM_ATOL,
+              f"4-rank sim field after frame 0 differs from the one-device "
+              f"XLA roll reference by {sim_err4:.3g} (> {SIM_ATOL})")
         spec4 = r4["spec"]
         check_payloads(r4["payloads"], k, spec4.nj, spec4.ni)
         q4 = psnr(decode(r4["payloads"][frame_k]), img)
@@ -370,6 +378,7 @@ def _smoke(meter: CompileMeter, grid: int, k: int, warmup: int, steady: int,
         summary["four_ranks"] = {
             "schedules": r4["schedules"], "compile": r4["compile"],
             "steady": r4["steady"], "sim_devices": r4["sim_devices"],
+            "sim_field_max_abs_diff_frame0": sim_err4,
             "peak_bytes_in_use": r4["peak_bytes_in_use"],
             "decoded_psnr_db_vs_one_rank": (None if np.isinf(q4)
                                             else round(float(q4), 2))}

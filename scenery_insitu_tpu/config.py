@@ -520,10 +520,12 @@ class SimConfig:
     particle_radius: float = 0.35
     # Advance gray_scott through the time-fused Pallas stencil on TPU
     # (sim/pallas_stencil.py — T steps per volume round trip instead of
-    # one). Off-TPU, on a grid no tile of the kernel fits, and on a
-    # multi-rank mesh (z-sharded state) the XLA roll formulation runs
-    # instead, and the ledger says so (sim.fused_stencil). False pins the
-    # XLA roll formulation — the sim-fusion lever's A/B switch.
+    # one); on a multi-rank mesh every rank runs it on its shard of the
+    # z-sharded state and takes the z halos from its ring neighbours.
+    # Off-TPU and on a grid (or shard) no tile of the kernel fits the XLA
+    # roll formulation runs instead, and the ledger says so
+    # (sim.fused_stencil). False pins the XLA roll formulation — the
+    # sim-fusion lever's A/B switch.
     fused_stencil: bool = True
 
 

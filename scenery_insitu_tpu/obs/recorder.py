@@ -362,6 +362,12 @@ _COUNTER_REGISTRY: Dict[str, str] = {
                    "request",
     "serve_stale_answers": "an answer was rendered from a VDI beyond "
                            "the staleness budget (stamped stale)",
+    "sim_halo_bytes": "bytes of u and v planes one rank sent to its ring "
+                      "neighbours for the fused stencil's z halos on a "
+                      "z-sharded field (count = bytes; recorded runs only)",
+    "sim_halo_exchanges": "one fused stencil pass on a z-sharded field "
+                          "took its outer z halo from the ring "
+                          "neighbours (recorded runs only)",
     "sink_failures": "a frame/tile sink or steering callback raised",
     "sinks_quarantined": "a sink was disabled after repeated "
                          "consecutive failures",

@@ -191,9 +191,11 @@ def advance_camera_and_index(sess) -> None:
 
 def _sharded_sim(mesh) -> bool:
     """Does the sim state live z-sharded on ``mesh``? On a multi-rank mesh
-    it does: the jitted advance keeps the sharding (stencil rolls lower
-    to halo collectives), so the render step's z-sharded input is the
-    state's own field, not a per-frame scatter from the first device."""
+    it does: the jitted advance keeps the sharding (the fused Gray-Scott
+    kernel takes its z halos from the ring neighbours; stencil rolls
+    lower to halo collectives), so the render step's z-sharded input is
+    the state's own field, not a per-frame scatter from the first
+    device."""
     return mesh is not None and mesh.devices.size > 1
 
 
@@ -227,22 +229,16 @@ class VolumeSimAdapter:
         sharded = _sharded_sim(mesh)
         if kind == "gray_scott":
             st = gs.GrayScott.from_config(cfg.sim, seed=seed)
-            fused = cfg.sim.fused_stencil
             if sharded:
                 st = _place_sim_state(st, mesh, axis)
-                if fused:
-                    _obs.degrade(
-                        "sim.fused_stencil", "pallas", "xla_roll",
-                        f"sim state is z-sharded over {mesh.devices.size} "
-                        "ranks; the fused kernel's periodic wrap is per "
-                        "buffer", warn=False)
-                    fused = False
             self.state = st
             # fused_stencil routes through the time-fused Pallas kernel
-            # on TPU (T steps per HBM round trip of u, v); off-TPU or
-            # with the flag off it is exactly the XLA roll path
-            adv = gs.multi_step_fast if fused else gs.multi_step
-            self._advance = lambda s, n: adv(s, n)
+            # on TPU (T steps per HBM round trip of u, v), which reads
+            # from the state's placement whether its z halos are the
+            # buffer's own wrap or the ring neighbours' planes; off-TPU
+            # or with the flag off it is exactly the XLA roll path
+            self._advance = (gs.multi_step_fast if cfg.sim.fused_stencil
+                             else gs.multi_step)
         elif kind == "vortex":
             st = vx.VortexFlow.init_ring(tuple(cfg.sim.grid),
                                          vx.VortexParams.create(dt=cfg.sim.dt))

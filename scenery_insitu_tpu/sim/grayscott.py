@@ -6,7 +6,9 @@ reference explicitly could not: README.md:16 "can not be used standalone").
 The update is pure elementwise + 6-point Laplacian stencil (periodic BC via
 jnp.roll), so under jit with a z-sharded state XLA lowers the rolls to
 ppermute halo exchanges over ICI automatically — the same decomposition the
-render pipeline uses.
+render pipeline uses. That formulation (`multi_step`) is the plain reference;
+`multi_step_fast` runs the time-fused Pallas kernel where it can, on one
+device and on a z-sharded state alike.
 """
 
 from __future__ import annotations
@@ -98,36 +100,78 @@ def multi_step(state: GrayScott, n: int) -> GrayScott:
     return jax.lax.fori_loop(0, n, lambda _, s: step(s), state)
 
 
+def _z_ring(x):
+    """How ``x`` f32[D, H, W] is laid out, as the fused kernel has to
+    know it: ``(mesh, axis, local shape)`` of a field z-sharded over more
+    than one rank (``NamedSharding(mesh, P(axis, None, None))``, the
+    placement of a session's state on a multi-rank mesh);
+    ``(None, None, x.shape)`` of one that a single device holds or a
+    tracer stands for; None of any other placement."""
+    from jax.sharding import Mesh, NamedSharding
+
+    sh = getattr(x, "sharding", None)
+    if (isinstance(x, jax.core.Tracer) or sh is None
+            or len(sh.device_set) == 1):
+        return None, None, tuple(x.shape)
+    if not (isinstance(sh, NamedSharding) and isinstance(sh.mesh, Mesh)):
+        return None
+    axis, *rest = tuple(sh.spec) + (None,) * (3 - len(sh.spec))
+    if axis is None or any(a is not None for a in rest):
+        return None
+    return sh.mesh, axis, sh.shard_shape(x.shape)
+
+
 def multi_step_fast(state: GrayScott, n: int) -> GrayScott:
-    """Single-device fast path: the fused Pallas stencil kernel on TPU
+    """The fast path: the fused Pallas stencil kernel on TPU
     (sim/pallas_stencil.py), giving way to `multi_step` — on the ledger —
     on other backends and on grids no tile of the kernel fits. A Mosaic
-    refusal of the chosen tile is not caught: it reaches the caller. NOT
-    for sharded state — the Pallas kernel's periodic wrap is per-buffer,
-    so use `multi_step` (whose rolls XLA lowers to ICI halo exchanges)
-    there."""
+    refusal of the chosen tile is not caught: it reaches the caller.
+
+    A state that is z-sharded over the ranks of a mesh runs the same
+    kernels on every rank's shard, and each pass takes its outer z halo
+    from the ring neighbours (`pallas_stencil.multi_step_pallas_sharded`);
+    what decides then is whether a tile fits the SHARD. The kernel's own
+    periodic wrap is per buffer, so a state placed in any other way over
+    several devices gives way to `multi_step`, whose rolls XLA lowers to
+    collectives whatever the placement."""
     from scenery_insitu_tpu import obs
     from scenery_insitu_tpu.sim import pallas_stencil as ps
 
+    def roll(reason):
+        # ledger only (warn=False): this runs per frame and the
+        # downgrade is expected behavior of the platform or the grid —
+        # but a run that was CONFIGURED fused and silently ran the roll
+        # path must still end with that fact on the record (deduped,
+        # counted)
+        obs.degrade("sim.fused_stencil", "pallas", "xla_roll", reason,
+                    warn=False)
+        return multi_step(state, n)
+
     if jax.default_backend() != "tpu":
-        # ledger only (warn=False): this runs per frame and the off-TPU
-        # downgrade is expected platform behavior — but a run that was
-        # CONFIGURED fused and silently ran the roll path must still end
-        # with that fact on the record (deduped, counted)
-        obs.degrade("sim.fused_stencil", "pallas", "xla_roll",
-                    f"backend is {jax.default_backend()!r}, not tpu",
-                    warn=False)
-        return multi_step(state, n)
-    if not ps.fused_supported(state.u.shape):
-        obs.degrade("sim.fused_stencil", "pallas", "xla_roll",
-                    f"no fused-stencil tile fits grid "
-                    f"{tuple(state.u.shape)} (needs W % 128 == 0, "
-                    f"H % 8 == 0 and a tile under the VMEM limit)",
-                    warn=False)
-        return multi_step(state, n)
+        return roll(f"backend is {jax.default_backend()!r}, not tpu")
+    ring = _z_ring(state.u)
+    if ring is None:
+        return roll(f"sim state is placed as {state.u.sharding}: the fused "
+                    "kernel serves one device or a z-sharded field")
+    mesh, axis, shape = ring
+    if not ps.fused_supported(shape, ring=mesh is not None):
+        return roll(f"no fused-stencil tile fits grid {shape}"
+                    + (f", one rank's shard of {tuple(state.u.shape)}"
+                       if mesh is not None else "")
+                    + " (needs W % 128 == 0, H % 8 == 0 and a tile under "
+                      "the VMEM limit)")
     p = state.params
-    pvec = jnp.stack([p.f, p.k, p.du, p.dv, p.dt])
-    u, v = ps.multi_step_pallas(state.u, state.v, pvec, n)
+    if mesh is None:
+        pvec = jnp.stack([p.f, p.k, p.du, p.dv, p.dt])
+        u, v = ps.multi_step_pallas(state.u, state.v, pvec, n)
+        return GrayScott(u, v, p)
+    u, v = ps.multi_step_pallas_sharded(state.u, state.v, tuple(p), n, mesh,
+                                        axis)
+    rec = obs.get_recorder()
+    if rec.enabled:
+        exchanges, sent = ps.ring_halo_traffic(shape, n)
+        rec.count("sim_halo_exchanges", exchanges)
+        rec.count("sim_halo_bytes", sent)
     return GrayScott(u, v, p)
 
 

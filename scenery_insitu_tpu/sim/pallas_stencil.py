@@ -32,10 +32,15 @@ passes and one T=2): `_multi_step_impl` calls the kernel once per pass
 and hands each pass's u and v straight to the next, so the compiled sim
 program is those kernels and no whole-grid copy between them.
 
-Used by the single-device fast path only: the *sharded* simulation keeps
-the roll formulation, where XLA lowers the rolls across a z-sharded mesh
-to ICI halo collectives (see sim/grayscott.py docstring) — a Pallas kernel
-with per-shard periodic wrap would silently corrupt shard boundaries.
+A field that is z-sharded over the ranks of a mesh runs the same kernel
+on every rank's shard (`multi_step_pallas_sharded`, under ``shard_map``).
+The periodic wrap of the BlockSpec is per buffer, which at a shard's two
+ends is the wrong neighbour: there the kernel's first and last z-block
+take their outer T planes from two more operands per field, the ring
+neighbours' boundary planes, which each pass fetches by ``ppermute``
+before its kernel (T planes of u and v each way, nothing the size of a
+shard). With no such operands (one device) the kernel, its BlockSpecs and
+its tiles are what they were before shards were served.
 
 On CPU the kernel runs in interpret mode (used by the parity tests); the
 production CPU path stays on the XLA formulation.
@@ -60,18 +65,24 @@ _FUSE_T = 4
 _VMEM_LIMIT = 100 * 1024 * 1024
 
 
-def _vmem_bytes(tz: int, th: int, t: int, w: int) -> int:
+def _vmem_bytes(tz: int, th: int, t: int, w: int,
+                ring: bool = False) -> int:
     """VMEM the fused kernel holds at tile (tz, th): the ping-pong scratch
     copies of padded u and v, the double-buffered input views (center,
-    z halos, h halos, corners) and output tiles, plus a margin of 24
+    z halos, h halos, corners; with ``ring`` also the neighbours' z
+    halos and their corners) and output tiles, plus a margin of 24
     planes for the per-plane update's temporaries. Without the margin
     this is Mosaic's own accounting (libtpu 0.0.34 compiling for v5e, at
     512^3): T=4 (32, 64) is 66.0 MiB here and 66.02 MiB allocated there;
-    T=1 (32, 128) is 89.4 MiB here and 89.38 MiB there."""
+    T=1 (32, 128) is 89.4 MiB here and 89.38 MiB there. With ``ring``
+    (a 128 x 512 x 512 shard, compiled for four v5e): T=4 (32, 64) is
+    71.0 MiB here and 71.00 MiB there."""
     hh = _HALO_H
     thp = th + 2 * hh
     scratch = (4 if t > 1 else 2) * (tz + 2 * t) * thp
     blocks = 2 * 2 * (tz * th + 2 * t * th + 2 * tz * hh + 4 * t * hh)
+    if ring:
+        blocks += 2 * 2 * 2 * t * thp
     outs = 2 * 2 * tz * th
     temps = 24 * thp
     return (scratch + blocks + outs + temps) * w * 4
@@ -82,10 +93,12 @@ def _read_amp(tz: int, th: int, t: int) -> float:
     return (tz + 2 * t) * (th + 2 * _HALO_H) / (tz * th)
 
 
-def _tiles(shape, t: int):
+def _tiles(shape, t: int, ring: bool = False):
     """Every (traffic, tz, th) tile that satisfies the lattice
     (T | tz | D, 8 | th | H) and fits `_VMEM_LIMIT`, cheapest first by
-    modeled HBM traffic per step."""
+    modeled HBM traffic per step. ``ring``: ``shape`` is one rank's
+    shard of a z-sharded field, whose kernel also holds the neighbours'
+    halo blocks."""
     d, h, w = shape
     out = []
     for tz in (64, 32, 16, 8, 4, 2, 1):
@@ -94,7 +107,7 @@ def _tiles(shape, t: int):
         for th in sorted({h, 256, 128, 64, 32, 16, 8}, reverse=True):
             if th > h or h % th or th % _HALO_H:
                 continue
-            if _vmem_bytes(tz, th, t, w) > _VMEM_LIMIT:
+            if _vmem_bytes(tz, th, t, w, ring) > _VMEM_LIMIT:
                 continue
             out.append(((_read_amp(tz, th, t) + 1.0) / t, tz, th))
     out.sort()
@@ -120,28 +133,34 @@ def tile2d_candidates(shape, t_steps: int = 1) -> tuple:
                  if th < h)
 
 
-def _best_schedule(shape, t: int):
+def _best_schedule(shape, t: int, ring: bool = False):
     """The cheapest fitting tile for a T-step pass by modeled HBM traffic:
     ("2d", tz, th), ("1d", tz, H) when the whole-H slab wins, or None when
     no tile satisfies the lattice and the VMEM limit. Deterministic in
     the shape — what Mosaic then says about it reaches the caller."""
-    tiles = _tiles(shape, t)
+    tiles = _tiles(shape, t, ring)
     if not tiles:
         return None
     _, tz, th = tiles[0]
     return ("1d" if th == shape[1] else "2d"), tz, th
 
 
-def fused_supported(shape, t_steps: int = 1) -> bool:
-    """Does some tile of the fused kernel fit this grid? W must fill whole
-    128-lane tiles for the periodic lane rotate."""
-    return shape[2] % 128 == 0 and bool(_tiles(shape, t_steps))
+def fused_supported(shape, t_steps: int = 1, ring: bool = False) -> bool:
+    """Does some tile of the fused kernel fit this grid (with ``ring``:
+    this shard of a z-sharded grid)? W must fill whole 128-lane tiles
+    for the periodic lane rotate. A tile is at least ``t_steps`` planes
+    deep (T | tz | D), so a shard that has one holds the T planes its
+    ring neighbours take from it."""
+    return shape[2] % 128 == 0 and bool(_tiles(shape, t_steps, ring))
 
 
-def _kernel(t, tz, th, with_ranges, p_ref,
-            uc, un, us, uw, ue, unw, une, usw, use_,
-            vc, vn, vs, vw, ve, vnw, vne, vsw, vse,
-            uo_ref, vo_ref, *rest):
+def _kernel(t, tz, th, with_ranges, ring, p_ref, *refs):
+    # per field: center, n, s, w, e, nw, ne, sw, se and, with `ring`,
+    # the neighbours' z halos (nw, n, ne of the rank before; sw, s, se
+    # of the rank after)
+    nv = 15 if ring else 9
+    u_in, v_in, (uo_ref, vo_ref, *rest) = refs[:nv], refs[nv:2 * nv], \
+        refs[2 * nv:]
     if with_ranges:
         vlo_ref, vhi_ref, *rest = rest
     # (u, v) scratch pairs: one for T == 1, two to ping-pong between
@@ -150,9 +169,9 @@ def _kernel(t, tz, th, with_ranges, p_ref,
     hh = _HALO_H
     thp = th + 2 * hh
     tzp = tz + 2 * t
-    w = uc.shape[-1]
+    w = u_in[0].shape[-1]
 
-    def assemble(dst, c, n, s, w_, e, nw, ne, sw, se):
+    def assemble(dst, c, n, s, w_, e, nw, ne, sw, se, *ring_halos):
         def rows(z, west, mid, east):
             dst[z, 0:hh] = west
             dst[z, hh:hh + th] = mid
@@ -162,13 +181,28 @@ def _kernel(t, tz, th, with_ranges, p_ref,
             rows(t + i, w_[i], c[i], e[i])
             return 0
 
-        jax.lax.fori_loop(0, tz, center, 0)
-        for i in range(t):
-            rows(i, nw[i], n[i], ne[i])
-            rows(t + tz + i, sw[i], s[i], se[i])
+        def halo(z0, west, mid, east):
+            for i in range(t):
+                rows(z0 + i, west[i], mid[i], east[i])
 
-    assemble(pairs[0][0], uc, un, us, uw, ue, unw, une, usw, use_)
-    assemble(pairs[0][1], vc, vn, vs, vw, ve, vnw, vne, vsw, vse)
+        jax.lax.fori_loop(0, tz, center, 0)
+        if not ring_halos:
+            halo(0, nw, n, ne)
+            halo(t + tz, sw, s, se)
+            return
+        # a shard's first z-block continues north into the rank before,
+        # its last one south into the rank after; between them the
+        # buffer's own planes are the neighbours, as on one device
+        rnw, rn, rne, rsw, rs, rse = ring_halos
+        first = pl.program_id(0) == 0
+        last = pl.program_id(0) == pl.num_programs(0) - 1
+        pl.when(first)(lambda: halo(0, rnw, rn, rne))
+        pl.when(jnp.logical_not(first))(lambda: halo(0, nw, n, ne))
+        pl.when(last)(lambda: halo(t + tz, rsw, rs, rse))
+        pl.when(jnp.logical_not(last))(lambda: halo(t + tz, sw, s, se))
+
+    assemble(pairs[0][0], *u_in)
+    assemble(pairs[0][1], *v_in)
 
     def lap(src, i, x):
         # h and w neighbors by rotate: w is truly periodic; the h wrap
@@ -220,7 +254,11 @@ def _kernel(t, tz, th, with_ranges, p_ref,
 
 
 def _fused_call(u, v, params_vec, t: int, tz: int, th: int,
-                interpret: bool, with_ranges: bool):
+                interpret: bool, with_ranges: bool, ring_halos: tuple = ()):
+    """One T-step pass over ``u``, ``v`` f32[D, H, W]. The z halo of the
+    first and last z-block is the buffer's own periodic wrap, or, with
+    ``ring_halos = (u_north, u_south, v_north, v_south)`` (f32[T, H, W]:
+    the T planes before plane 0 and after plane D-1), those planes."""
     d, h, w = u.shape
     hh = _HALO_H
     if t > hh:
@@ -250,6 +288,25 @@ def _fused_call(u, v, params_vec, t: int, tz: int, th: int,
     sw = pl.BlockSpec((t, hh, w), lambda i, j: (zp(i), hm(j), 0))
     se = pl.BlockSpec((t, hh, w), lambda i, j: (zp(i), hp(j), 0))
     specs = [c_, n_, s_, w_, e_, nw, ne, sw, se]
+    fields = [[u] * 9, [v] * 9]
+    if ring_halos:
+        # the neighbours' planes are read by one row of z-blocks each
+        # (the first, the last). Off it the index holds still, at the
+        # block that row ends or starts with, so that nothing is fetched
+        # for the blocks in between
+        jn = lambda i, j: jnp.where(i == 0, j, nhb - 1)
+        js = lambda i, j: jnp.where(i == nzb - 1, j, 0)
+        for jr in (jn, js):
+            specs += [
+                pl.BlockSpec((t, hh, w),
+                             lambda i, j, jr=jr: (0, hm(jr(i, j)), 0)),
+                pl.BlockSpec((t, th, w),
+                             lambda i, j, jr=jr: (0, jr(i, j), 0)),
+                pl.BlockSpec((t, hh, w),
+                             lambda i, j, jr=jr: (0, hp(jr(i, j)), 0))]
+        un, us, vn, vs = ring_halos
+        fields[0] += [un] * 3 + [us] * 3
+        fields[1] += [vn] * 3 + [vs] * 3
 
     out_specs = [c_, c_]
     out_shape = [jax.ShapeDtypeStruct((d, h, w), jnp.float32)] * 2
@@ -261,7 +318,7 @@ def _fused_call(u, v, params_vec, t: int, tz: int, th: int,
         out_shape += [jax.ShapeDtypeStruct((nzb, nhb), jnp.float32)] * 2
     pad = pltpu.VMEM((tz + 2 * t, th + 2 * hh, w), jnp.float32)
     return pl.pallas_call(
-        functools.partial(_kernel, t, tz, th, with_ranges),
+        functools.partial(_kernel, t, tz, th, with_ranges, bool(ring_halos)),
         grid=(nzb, nhb),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + specs + specs,
         out_specs=out_specs,
@@ -271,7 +328,7 @@ def _fused_call(u, v, params_vec, t: int, tz: int, th: int,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name=f"gray_scott_fused_t{t}",
-    )(params_vec, *([u] * 9), *([v] * 9))
+    )(params_vec, *fields[0], *fields[1])
 
 
 @functools.partial(jax.jit, static_argnames=("t_steps", "interpret", "tz",
@@ -328,17 +385,17 @@ def step_pallas2d(u, v, params_vec, t_steps: int = 1,
     return _fused_call(u, v, params_vec, t, tz, th, interpret, with_ranges)
 
 
-def schedule(shape, n: int) -> tuple:
-    """The passes ``n`` steps decompose into on ``shape``, greedily by
-    fusion factor (n=10 runs two T=4 passes and one T=2 pass instead of
-    degrading the whole loop to a smaller T):
-    ``((kind, T, tz, th, reps), ...)``, plus the steps no fused tile
-    covers (0 whenever `fused_supported`)."""
+def schedule(shape, n: int, ring: bool = False) -> tuple:
+    """The passes ``n`` steps decompose into on ``shape`` (with ``ring``:
+    on this shard of a z-sharded grid), greedily by fusion factor (n=10
+    runs two T=4 passes and one T=2 pass instead of degrading the whole
+    loop to a smaller T): ``((kind, T, tz, th, reps), ...)``, plus the
+    steps no fused tile covers (0 whenever `fused_supported`)."""
     out = []
     remaining = n
     for t in range(min(_FUSE_T, n), 0, -1):
         reps = remaining // t
-        sched = _best_schedule(shape, t) if reps else None
+        sched = _best_schedule(shape, t, ring) if reps else None
         if sched is None:
             continue
         out.append((sched[0], t, sched[1], sched[2], reps))
@@ -346,16 +403,38 @@ def schedule(shape, n: int) -> tuple:
     return tuple(out), remaining
 
 
+def _ring_halos(u, v, t: int, axis) -> tuple:
+    """`_fused_call`'s ``ring_halos`` of this rank's shard of a field
+    that is z-sharded over ``axis``: the last ``t`` planes of the rank
+    before and the first ``t`` of the rank after, in ring order, which
+    is what makes the global field periodic in z as the roll formulation
+    is. Runs inside ``shard_map`` (the idiom of
+    `parallel.mesh.halo_exchange_z`, without its edge clamp)."""
+    n = jax.lax.axis_size(axis)
+    up = [(r, (r + 1) % n) for r in range(n)]
+    down = [(r, (r - 1) % n) for r in range(n)]
+    return tuple(h for x in (u, v)
+                 for h in (jax.lax.ppermute(x[-t:], axis, up),
+                           jax.lax.ppermute(x[:t], axis, down)))
+
+
 def _multi_step_impl(u, v, params_vec, n: int, interpret: bool,
-                     ranges_to):
-    """The `schedule` walk shared by `multi_step_pallas` and
-    `multi_step_pallas_ranges`: a static walk, one `_fused_call` per
-    scheduled pass, each pass's outputs handed straight to the next.
-    The schedule is static (``n`` is a static argument, ``reps`` are
-    Python ints), so the compiled program is the ``sum(reps)`` kernels
-    and nothing between them. Not a ``fori_loop`` per pass: XLA copies
-    each kernel's whole-grid results into the loop's carry buffers (u
-    and v once per trip: six 537 MB copies per frame at 512^3, n = 10).
+                     ranges_to, axis=None):
+    """The `schedule` walk shared by `multi_step_pallas`,
+    `multi_step_pallas_sharded` and `multi_step_pallas_ranges`: a static
+    walk, one `_fused_call` per scheduled pass, each pass's outputs
+    handed straight to the next. The schedule is static (``n`` is a
+    static argument, ``reps`` are Python ints), so the compiled program
+    is the ``sum(reps)`` kernels and nothing between them. Not a
+    ``fori_loop`` per pass: XLA copies each kernel's whole-grid results
+    into the loop's carry buffers (u and v once per trip: six 537 MB
+    copies per frame at 512^3, n = 10).
+
+    ``axis`` (inside ``shard_map``): u and v are this rank's shard of a
+    field z-sharded over that mesh axis, and every pass takes its outer
+    z halo from the ring neighbours (`_ring_halos`): between the kernels
+    there are then the permutes of T planes, and still nothing that
+    writes a shard.
 
     ``ranges_to = (nzb, nyb)`` threads the occupancy epilogue through
     every pass; the LAST executed pass's ranges describe the final
@@ -363,6 +442,7 @@ def _multi_step_impl(u, v, params_vec, n: int, interpret: bool,
     native granularity onto the fixed (nzb, nyb) brick grid
     (occupancy.remap_ranges)."""
     with_ranges = ranges_to is not None
+    ring = axis is not None
     if with_ranges:
         from scenery_insitu_tpu.ops.occupancy import (field_ranges,
                                                       remap_ranges)
@@ -371,13 +451,14 @@ def _multi_step_impl(u, v, params_vec, n: int, interpret: bool,
             # (the render-only sim_steps=0 A/B)
             r = field_ranges(v, *ranges_to)
             return (u, v, r.lo, r.hi)
-    passes, remaining = schedule(u.shape, n)
+    passes, remaining = schedule(u.shape, n, ring)
     if remaining:   # fused_supported(shape) is False: caller should gate
         raise ValueError(f"no fused-stencil tile fits grid {u.shape}")
     for _, t, tz, th, reps in passes:
         for _ in range(reps):
+            halos = _ring_halos(u, v, t, axis) if ring else ()
             u, v, *rng = _fused_call(u, v, params_vec, t, tz, th, interpret,
-                                     with_ranges)
+                                     with_ranges, halos)
     if with_ranges:
         return (u, v) + remap_ranges(*rng, ranges_to)
     return (u, v)
@@ -387,6 +468,27 @@ def _multi_step_impl(u, v, params_vec, n: int, interpret: bool,
 def multi_step_pallas(u, v, params_vec, n: int, interpret: bool = False):
     """n Gray-Scott steps in the passes `schedule` gives."""
     return _multi_step_impl(u, v, params_vec, n, interpret, None)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "mesh", "axis",
+                                             "interpret"))
+def multi_step_pallas_sharded(u, v, params, n: int, mesh, axis,
+                              interpret: bool = False):
+    """`multi_step_pallas` of a field z-sharded over ``axis`` of ``mesh``
+    (``NamedSharding(mesh, P(axis, None, None))``, which u' and v' keep):
+    each rank runs the scheduled kernels on its own shard and takes every
+    pass's outer z halo from its ring neighbours. ``params`` are the five
+    scalars ``(f, k, du, dv, dt)`` themselves: stacked here, inside the
+    program, because on a mesh each eager op of the host's own stack is
+    a launch on every device (3.5 of a 51 ms frame on four v5e chips)."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    spec = P(axis, None, None)
+    return shard_map(
+        lambda u, v, p: _multi_step_impl(u, v, p, n, interpret, None, axis),
+        mesh=mesh, in_specs=(spec, spec, P()), out_specs=(spec, spec),
+        check_vma=False)(u, v, jnp.stack(params))
 
 
 @functools.partial(jax.jit, static_argnames=("n", "nzb", "nyb",
@@ -400,6 +502,17 @@ def multi_step_pallas_ranges(u, v, params_vec, n: int, nzb: int, nyb: int,
     structure rides out of the sim pass instead of costing a volume
     sweep."""
     return _multi_step_impl(u, v, params_vec, n, interpret, (nzb, nyb))
+
+
+def ring_halo_traffic(shape, n: int) -> tuple:
+    """What ``n`` steps on ``shape``, one rank's shard of a z-sharded
+    field, exchange with the ring neighbours: ``(exchanges, bytes)``, one
+    exchange per scheduled pass, in which the rank sends (and receives)
+    T planes of u and of v each way."""
+    passes, _ = schedule(shape, n, ring=True)
+    plane = 4 * shape[1] * shape[2]
+    return (sum(reps for *_, reps in passes),
+            sum(reps * 2 * 2 * t * plane for _, t, _, _, reps in passes))
 
 
 def modeled_sim_traffic(shape, n: int, fused: bool = True) -> float:

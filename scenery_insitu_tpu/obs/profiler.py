@@ -79,7 +79,9 @@ SCOPE_PREFIX = "sitpu_"
 # attribution lane asserts the captured breakdown names come from here
 # (plus the two synthetic phases the capture itself mints).
 PHASES = ("march", "fold", "halo", "exchange", "merge", "resegment",
-          "wire_encode", "sim_step", "dcn_hop", "wave")
+          "wire_encode", "sim_step", "dcn_hop", "wave",
+          # inside the vortex sim's per-frame program (sim/vortex.py)
+          "sim_advect", "sim_project", "sim_field")
 
 # Synthetic phases ProfileCapture adds on top of the scope catalog.
 EXTRA_PHASES = ("unattributed", "host")

@@ -220,41 +220,70 @@ def _place_sim_state(state, mesh, axis=None):
 class VolumeSimAdapter:
     """Uniform facade over the built-in volume sims (kind -> state/advance/
     field). ``mesh``/``axis``: the session's mesh and flat rank axis (see
-    `_sharded_sim`)."""
+    `_sharded_sim`); ``obs``: the session's recorder, for the scope table
+    of a sim program that has phases inside."""
 
     def __init__(self, cfg: FrameworkConfig, seed: int = 0, mesh=None,
-                 axis=None):
+                 axis=None, obs=None):
         kind = cfg.sim.kind
         self.kind = kind
         sharded = _sharded_sim(mesh)
         if kind == "gray_scott":
             st = gs.GrayScott.from_config(cfg.sim, seed=seed)
-            if sharded:
-                st = _place_sim_state(st, mesh, axis)
-            self.state = st
             # fused_stencil routes through the time-fused Pallas kernel
             # on TPU (T steps per HBM round trip of u, v), which reads
             # from the state's placement whether its z halos are the
             # buffer's own wrap or the ring neighbours' planes; off-TPU
             # or with the flag off it is exactly the XLA roll path
-            self._advance = (gs.multi_step_fast if cfg.sim.fused_stencil
-                             else gs.multi_step)
+            step = (gs.multi_step_fast if cfg.sim.fused_stencil
+                    else gs.multi_step)
+            # the rendered field is a leaf of the state (v): nothing to
+            # compute, so nothing is kept beside it
+            self._advance = lambda s, n: (step(s, n), None)
+            self._render = lambda s: (s, s.field)
         elif kind == "vortex":
             st = vx.VortexFlow.init_ring(tuple(cfg.sim.grid),
                                          vx.VortexParams.create(dt=cfg.sim.dt))
-            if sharded:
-                st = _place_sim_state(st, mesh, axis)
-            self.state = st
-            self._advance = lambda s, n: vx.multi_step(s, n)
+            # ONE program per frame hands back (u, field) in the
+            # placements it took: n steps, then |curl u| and its
+            # normalisation (sim/vortex.frame_program); a recording
+            # session keeps its `sim_*` scope table
+            frame = (vx.frame_program(mesh, axis or mesh.axis_names[0])
+                     if sharded else vx.frame_program())
+
+            def through(program):
+                def run(s, n):
+                    u, field = program(s.u, s.params, n)
+                    return s._replace(u=u), field
+                return run
+
+            self._advance = through(scoped_step(frame, obs)
+                                    if obs is not None else frame)
+            # of a state no frame has advanced: 0 steps, the field alone
+            # (around the wrapper, whose table is the frame's program's)
+            self._render = lambda s: through(frame)(s, 0)
         else:
             raise ValueError(f"unknown volume sim kind {cfg.sim.kind!r}")
+        self.state = _place_sim_state(st, mesh, axis) if sharded else st
+
+    @property
+    def state(self):
+        return self._state
+
+    @state.setter
+    def state(self, st) -> None:
+        # whoever replaces the state (a restore, a seeded start) also
+        # drops the field that was rendered from the old one
+        self._state, self._field = st, None
 
     def advance(self, n: int) -> None:
-        self.state = self._advance(self.state, n)
+        self._state, self._field = self._advance(self._state, n)
 
     @property
     def field(self) -> jnp.ndarray:
-        return self.state.field
+        if self._field is None:
+            self._state, self._field = self._render(self._state)
+        return self._field
 
 
 class ParticleSimAdapter:
@@ -488,7 +517,7 @@ class InSituSession:
                                         axis=self._flat_axis)
         else:
             self.sim = VolumeSimAdapter(self.cfg, mesh=self.mesh,
-                                        axis=self._flat_axis)
+                                        axis=self._flat_axis, obs=self.obs)
         self.tf = tf or for_dataset(
             self.cfg.sim.kind if self.cfg.runtime.dataset == "procedural"
             else self.cfg.runtime.dataset)

@@ -5,13 +5,23 @@ whose vorticity-magnitude volume is rendered in-situ).
 A stable-fluids incompressible solver on a periodic box, built from
 TPU-friendly primitives only:
 
-- semi-Lagrangian advection (trilinear back-trace via the same gather
-  sampler the renderer uses),
+- semi-Lagrangian advection (trilinear back-trace, one gather per point
+  of all eight corners of all three components),
 - spectral diffusion + pressure projection in one rFFT round-trip
   (jnp.fft; exact div-free projection, unconditionally stable).
 
 State is velocity ``u f32[3, D, H, W]``; the rendered field is |curl u|
 (vorticity magnitude), normalized to ≈[0, 1].
+
+A session advances it through `frame_program`: ONE jitted program per
+frame — n steps, then the rendered field — whose in and out placements are
+fixed on a mesh (u z-sharded ``P(None, axis, None, None)``, the field
+``P(axis, None, None)``), with the phases ``sim_advect``, ``sim_project``
+and ``sim_field`` scoped inside it (obs/profiler.py). On a mesh a rank
+back-traces only its own z-slab, from the field all-gathered; the
+transforms (DFT matmuls on a TPU) and the field's differences are laid
+over the ranks by the partitioner (PERF.md §5 says what the compiled
+program and its trace hold).
 """
 
 from __future__ import annotations
@@ -22,6 +32,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from scenery_insitu_tpu.obs.profiler import phase
 from scenery_insitu_tpu.ops.sampling import sample_trilinear
 
 
@@ -44,43 +55,50 @@ class VortexFlow(NamedTuple):
                   params: VortexParams = None, rings: int = 2,
                   radius: float = 0.22, strength: float = 6.0) -> "VortexFlow":
         """One or two coaxial vortex rings travelling along +z (two rings
-        leapfrog — the classic demo)."""
-        d, h, w = grid
-        z, y, x = jnp.meshgrid(
-            (jnp.arange(d) + 0.5) / d - 0.5,
-            (jnp.arange(h) + 0.5) / h - 0.5,
-            (jnp.arange(w) + 0.5) / w - 0.5, indexing="ij")
-        u = jnp.zeros((3, d, h, w), jnp.float32)
-        offsets = [-0.12, 0.12][:rings] if rings > 1 else [0.0]
-        for zo in offsets:
-            # solid-core ring vorticity -> induced velocity via stream fn
-            # approximation: add a swirling velocity field around the ring
-            # core circle (x²+y² = radius², z = zo)
-            rho = jnp.sqrt(x * x + y * y) + 1e-6
-            # distance from the ring core
-            dr = jnp.sqrt((rho - radius) ** 2 + (z - zo) ** 2)
-            core = 0.05
-            swirl = strength * jnp.exp(-(dr / core) ** 2 / 2)
-            # toroidal vorticity direction: (-y/rho, x/rho, 0); velocity
-            # circulates in the (rho, z) plane around the core:
-            #   u_rho ∝ -(z - zo), u_z ∝ (rho - radius)
-            u_rho = -swirl * (z - zo) / (dr + 1e-6) * core
-            u_z = swirl * (rho - radius) / (dr + 1e-6) * core
-            u = u.at[0].add(u_rho * x / rho)
-            u = u.at[1].add(u_rho * y / rho)
-            u = u.at[2].add(u_z)
-        # velocity is kept in voxel units / time everywhere (advection
-        # back-traces in voxel coords); the ring was built in domain units
-        scale = jnp.array([w, h, d], jnp.float32).reshape(3, 1, 1, 1)
-        flow = cls(u * scale, params or VortexParams.create())
+        leapfrog — the classic demo): `ring_velocity`, made div-free."""
+        flow = cls(ring_velocity(grid, rings, radius, strength),
+                   params or VortexParams.create())
         return flow._replace(u=project_divfree(flow.u, flow.params, 0.0))
 
     @property
     def field(self) -> jnp.ndarray:
         """Normalized vorticity magnitude f32[D, H, W] for rendering."""
-        w = vorticity(self.u)
-        mag = jnp.sqrt(jnp.sum(w * w, axis=0))
-        return mag / (jnp.max(mag) + 1e-6)
+        return render_field(self.u)
+
+
+def ring_velocity(grid: Tuple[int, int, int], rings: int = 2,
+                  radius: float = 0.22,
+                  strength: float = 6.0) -> jnp.ndarray:
+    """The swirling velocity of `VortexFlow.init_ring`'s rings before its
+    projection: f32[3, D, H, W] in voxel units / time."""
+    d, h, w = grid
+    z, y, x = jnp.meshgrid(
+        (jnp.arange(d) + 0.5) / d - 0.5,
+        (jnp.arange(h) + 0.5) / h - 0.5,
+        (jnp.arange(w) + 0.5) / w - 0.5, indexing="ij")
+    u = jnp.zeros((3, d, h, w), jnp.float32)
+    offsets = [-0.12, 0.12][:rings] if rings > 1 else [0.0]
+    for zo in offsets:
+        # solid-core ring vorticity -> induced velocity via stream fn
+        # approximation: add a swirling velocity field around the ring
+        # core circle (x²+y² = radius², z = zo)
+        rho = jnp.sqrt(x * x + y * y) + 1e-6
+        # distance from the ring core
+        dr = jnp.sqrt((rho - radius) ** 2 + (z - zo) ** 2)
+        core = 0.05
+        swirl = strength * jnp.exp(-(dr / core) ** 2 / 2)
+        # toroidal vorticity direction: (-y/rho, x/rho, 0); velocity
+        # circulates in the (rho, z) plane around the core:
+        #   u_rho ∝ -(z - zo), u_z ∝ (rho - radius)
+        u_rho = -swirl * (z - zo) / (dr + 1e-6) * core
+        u_z = swirl * (rho - radius) / (dr + 1e-6) * core
+        u = u.at[0].add(u_rho * x / rho)
+        u = u.at[1].add(u_rho * y / rho)
+        u = u.at[2].add(u_z)
+    # velocity is kept in voxel units / time everywhere (advection
+    # back-traces in voxel coords); the ring was built in domain units
+    scale = jnp.array([w, h, d], jnp.float32).reshape(3, 1, 1, 1)
+    return u * scale
 
 
 def _grad_axes(shape):
@@ -112,28 +130,70 @@ def vorticity(u: jnp.ndarray) -> jnp.ndarray:
     return jnp.stack([wx, wy, wz])
 
 
-def advect_semilagrangian(u: jnp.ndarray, dt: jnp.ndarray) -> jnp.ndarray:
+def render_field(u: jnp.ndarray) -> jnp.ndarray:
+    """|curl u| over its largest value: f32[D, H, W] in [0, 1)."""
+    w = vorticity(u)
+    mag = jnp.sqrt(jnp.sum(w * w, axis=0))
+    return mag / (jnp.max(mag) + 1e-6)
+
+
+# the eight corners of a trilinear cell, (dz, dy, dx), low corner first
+_CORNERS = tuple((a, b, c) for a in (0, 1) for b in (0, 1) for c in (0, 1))
+
+
+def advect_semilagrangian(u: jnp.ndarray, dt: jnp.ndarray,
+                          axis=None) -> jnp.ndarray:
     """Back-trace each grid point through the velocity field and resample
-    (periodic wrap)."""
-    _, d, h, w = u.shape
-    z, y, x = jnp.meshgrid(jnp.arange(d, dtype=jnp.float32) + 0.5,
+    trilinearly (periodic wrap).
+
+    Under ``shard_map`` over the mesh axis ``axis`` ``u`` is the rank's own
+    z-slab: the rank all-gathers the field, back-traces only its own
+    planes and returns them.
+
+    The resampling is ONE gather per point: every cell of the wrap-padded
+    field holds, side by side, the three components at its eight
+    neighbours towards +z, +y, +x (24 values, built by rolls), so a
+    point's low corner fetches all it blends. On a TPU a gather costs by
+    the index far more than by the width of what it fetches (PERF.md §6,
+    PR 37: 24 scalar gathers per point took 810 ms of a 256^3 step on
+    four ranks)."""
+    _, planes, h, w = u.shape
+    if axis is None:
+        whole, first = u, 0
+    else:
+        whole = jax.lax.all_gather(u, axis, axis=1, tiled=True)
+        first = jax.lax.axis_index(axis) * planes
+    d = whole.shape[1]
+    z, y, x = jnp.meshgrid(jnp.arange(planes, dtype=jnp.float32) + 0.5,
                            jnp.arange(h, dtype=jnp.float32) + 0.5,
                            jnp.arange(w, dtype=jnp.float32) + 0.5,
                            indexing="ij")
-    # velocity components are in grid-units / time
-    bx = jnp.mod(x - dt * u[0], w)
-    by = jnp.mod(y - dt * u[1], h)
-    bz = jnp.mod(z - dt * u[2], d)
-    pos = jnp.stack([bx, by, bz], axis=-1)
-
-    def samp(f):
-        # pad one wrap layer on BOTH faces (and shift coords by +1) so the
-        # clamped trilinear sampler interpolates periodically across the low
-        # boundary too — positions in [0, 0.5) must blend f[0] with f[n-1]
-        fp = jnp.pad(f, ((1, 1), (1, 1), (1, 1)), mode="wrap")
-        return sample_trilinear(fp, pos + 1.0)
-
-    return jnp.stack([samp(u[0]), samp(u[1]), samp(u[2])])
+    z = z + jnp.asarray(first, jnp.float32)
+    low, frac = [], []
+    # velocity components are in grid-units / time; component 2 moves
+    # along z, 1 along y, 0 along x
+    for comp, at, n in ((2, z, d), (1, y, h), (0, x, w)):
+        # the back-traced position in the grid padded by one wrap layer on
+        # BOTH faces, less the half cell of the centres: positions in
+        # [0, 0.5) blend f[n-1] with f[0] across the low boundary too
+        p = jnp.mod(at - dt * u[comp], n) + 0.5
+        i0 = jnp.floor(p)
+        low.append(i0.astype(jnp.int32))
+        frac.append(p - i0)
+    (z0, y0, x0), (fz, fy, fx) = low, frac
+    padded = jnp.pad(jnp.moveaxis(whole, 0, -1),
+                     ((1, 1), (1, 1), (1, 1), (0, 0)), mode="wrap")
+    pz, py, px, _ = padded.shape
+    cells = jnp.concatenate(
+        [jnp.roll(padded, (-dz, -dy, -dx), (0, 1, 2))
+         for dz, dy, dx in _CORNERS], -1).reshape(pz * py * px, 24)
+    got = cells.at[(z0 * py + y0) * px + x0].get(mode="promise_in_bounds")
+    weight = jnp.stack(
+        [(fz if dz else 1.0 - fz) * (fy if dy else 1.0 - fy)
+         * (fx if dx else 1.0 - fx) for dz, dy, dx in _CORNERS], -1)
+    out = jnp.sum(got.reshape(got.shape[:-1] + (8, 3)) * weight[..., None],
+                  axis=-2)
+    return jnp.moveaxis(out, -1, 0)
 
 
 def project_divfree(u: jnp.ndarray, params: VortexParams,
@@ -204,3 +264,44 @@ def step(flow: VortexFlow) -> VortexFlow:
 @partial(jax.jit, static_argnums=1)
 def multi_step(flow: VortexFlow, n: int) -> VortexFlow:
     return jax.lax.fori_loop(0, n, lambda _, f: step(f), flow)
+
+
+def frame_program(mesh=None, axis=None):
+    """The jitted program of one frame of a session's vortex sim,
+    ``vortex_frame(u, params, n) -> (u, field)``: ``n`` steps (static, and
+    walked statically: a frame takes a few, and a loop's carry costs
+    whole-field copies around it), then the rendered field.
+
+    On a ``mesh`` the placements are fixed — u z-sharded over ``axis``
+    going in and coming out, the parameters replicated, the field
+    z-sharded as the render step takes it — so the second frame compiles
+    nothing and no eager op has to place anything; a rank back-traces
+    only its own z-slab (`advect_semilagrangian` under ``shard_map``),
+    and the transforms and the field's differences are the
+    partitioner's."""
+    if mesh is None:
+        advect, shardings = advect_semilagrangian, {}
+    else:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        slab = P(None, axis, None, None)
+        advect = jax.shard_map(
+            partial(advect_semilagrangian, axis=axis), mesh=mesh,
+            in_specs=(slab, P()), out_specs=slab)
+        shardings = dict(
+            in_shardings=(NamedSharding(mesh, slab),
+                          NamedSharding(mesh, P())),
+            out_shardings=(NamedSharding(mesh, slab),
+                           NamedSharding(mesh, P(axis, None, None))))
+
+    def vortex_frame(u, params, n):
+        for _ in range(n):
+            with phase("sim_advect"):
+                u = advect(u, params.dt)
+            with phase("sim_project"):
+                u = project_divfree(u, params)
+        with phase("sim_field"):
+            field = render_field(u)
+        return u, field
+
+    return jax.jit(vortex_frame, static_argnums=2, **shardings)

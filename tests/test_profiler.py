@@ -409,6 +409,12 @@ def test_publish_attribution_is_an_instant_event():
     ("jit(f)/sitpu_sim_step_fused/pallas_call", "sim_step"),
     ("jit(step)/sitpu_march/sitpu_fold/reshape", "fold"),
     ("jit(step)/sitpu_foldable/add", "foldable"),
+    # the vortex sim program's phases are in the catalog under their own
+    # names: none begins `sim_step_`, so none collapses into `sim_step`
+    ("jit(vortex_frame)/sitpu_sim_advect/shard_map/gather", "sim_advect"),
+    ("jit(vortex_frame)/sitpu_sim_project/jit(fft)/dot.9", "sim_project"),
+    ("jit(vortex_frame)/sitpu_sim_field/sqrt", "sim_field"),
+    ("jit(f)/sitpu_sim_step/sitpu_sim_advect/gather", "sim_advect"),
 ])
 def test_scope_of_reads_a_kernel_name_as_its_phase(op_name, want):
     assert scope_of(op_name) == want

@@ -220,6 +220,10 @@ _LEDGER_REGISTRY: Dict[str, str] = {
                     "quarantined (disabled) for the rest of the run",
     "sim.fused_stencil": "fused Pallas stencil unavailable; XLA roll "
                          "formulation advances the sim",
+    "sim.vortex_window": "a vortex step reached further in z than the "
+                         "halo of its rank's back-trace window serves; "
+                         "the step all-gathered the field and read all "
+                         "of it (one row per step, with the reach and H)",
     "stream.delta_resync": "a temporal-delta P/SKIP record arrived "
                            "without its base tile retained (an earlier "
                            "message was lost); dropped while waiting "
@@ -362,6 +366,12 @@ _COUNTER_REGISTRY: Dict[str, str] = {
                    "request",
     "serve_stale_answers": "an answer was rendered from a VDI beyond "
                            "the staleness budget (stamped stale)",
+    "sim.vortex_window.whole_field": "a vortex step on a mesh whose "
+                                     "reach the window did not serve "
+                                     "read the all-gathered field",
+    "sim.vortex_window.windowed": "a vortex step on a mesh back-traced "
+                                  "from its rank's window (slab + halo "
+                                  "planes of the ring neighbours)",
     "sim_halo_bytes": "bytes of u and v planes one rank sent to its ring "
                       "neighbours for the fused stencil's z halos on a "
                       "z-sharded field (count = bytes; recorded runs only)",

@@ -239,31 +239,36 @@ class VolumeSimAdapter:
                     else gs.multi_step)
             # the rendered field is a leaf of the state (v): nothing to
             # compute, so nothing is kept beside it
-            self._advance = lambda s, n: (step(s, n), None)
+            self._advance = lambda s, n: (step(s, n), None, None)
             self._render = lambda s: (s, s.field)
         elif kind == "vortex":
             st = vx.VortexFlow.init_ring(tuple(cfg.sim.grid),
                                          vx.VortexParams.create(dt=cfg.sim.dt))
-            # ONE program per frame hands back (u, field) in the
-            # placements it took: n steps, then |curl u| and its
-            # normalisation (sim/vortex.frame_program); a recording
-            # session keeps its `sim_*` scope table
+            # ONE program per frame hands back (u, field, windows) in
+            # the placements it took: n steps, then |curl u| and its
+            # normalisation, and what each step's back-trace read
+            # (sim/vortex.frame_program); a recording session keeps its
+            # `sim_*` scope table
             frame = (vx.frame_program(mesh, axis or mesh.axis_names[0])
                      if sharded else vx.frame_program())
 
             def through(program):
                 def run(s, n):
-                    u, field = program(s.u, s.params, n)
-                    return s._replace(u=u), field
+                    u, field, windows = program(s.u, s.params, n)
+                    return s._replace(u=u), field, windows
                 return run
 
             self._advance = through(scoped_step(frame, obs)
                                     if obs is not None else frame)
             # of a state no frame has advanced: 0 steps, the field alone
             # (around the wrapper, whose table is the frame's program's)
-            self._render = lambda s: through(frame)(s, 0)
+            self._render = lambda s: through(frame)(s, 0)[:2]
         else:
             raise ValueError(f"unknown volume sim kind {cfg.sim.kind!r}")
+        self._rec = obs
+        # the vortex frames' `windows` that nobody has read yet, oldest
+        # first, and whether a step's window has given way before
+        self._windows, self._gave_way = deque(), False
         self.state = _place_sim_state(st, mesh, axis) if sharded else st
 
     @property
@@ -277,7 +282,37 @@ class VolumeSimAdapter:
         self._state, self._field = st, None
 
     def advance(self, n: int) -> None:
-        self._state, self._field = self._advance(self._state, n)
+        self._state, self._field, windows = self._advance(self._state, n)
+        if windows is not None:
+            self._windows.append(windows)
+            self.poll()
+
+    def poll(self, wait: bool = False) -> None:
+        """Account for the vortex steps whose ``windows`` the device has
+        written — with ``wait`` for all of them — and never sync
+        otherwise: a step that asked for the whole field where the
+        program holds a window mints one ``sim.vortex_window`` ledger
+        row, and a recording session counts the steps by the branch
+        they took."""
+        while self._windows and (wait or self._windows[0].is_ready()):
+            steps = np.asarray(self._windows.popleft())
+            held = steps[steps[:, 1] > 0]
+            for reach, halo, _ in held[held[:, 2] == 0]:
+                _obs.degrade(
+                    "sim.vortex_window", "windowed", "whole_field",
+                    f"a step reaches {reach:.4f} voxels in z; the "
+                    f"window's halo of {int(halo)} planes serves a reach "
+                    f"under {int(halo) - 1}", warn=not self._gave_way)
+                self._gave_way = True
+            if self._rec is not None and len(held):
+                windowed = int(held[:, 2].sum())
+                self._rec.count("sim.vortex_window.windowed", windowed)
+                self._rec.count("sim.vortex_window.whole_field",
+                                len(held) - windowed)
+
+    def close(self) -> None:
+        """The session's end: the steps not yet accounted for are."""
+        self.poll(wait=True)
 
     @property
     def field(self) -> jnp.ndarray:
@@ -869,12 +904,18 @@ class InSituSession:
         self.obs.flush()
         if self._obs_pub is not None:
             self._obs_pub.pump(self.obs, force=True)
+        # every frame of the run has been fetched or waited for, so what
+        # a vortex sim's steps read is there to be accounted for
+        poll = getattr(self.sim, "poll", None)
+        if poll is not None:
+            poll()
         return payload
 
     def close(self) -> None:
         """The session's end: a sim source that owns a thread or a
-        channel (`ingest.shm.ShmVolumeSource`) is closed; the built-in
-        sims have nothing to close."""
+        channel (`ingest.shm.ShmVolumeSource`) is closed, and the
+        built-in volume sims account for the vortex steps nobody has
+        read yet (`VolumeSimAdapter.poll`)."""
         close = getattr(self.sim, "close", None)
         if close is not None:
             close()

@@ -5,8 +5,9 @@ whose vorticity-magnitude volume is rendered in-situ).
 A stable-fluids incompressible solver on a periodic box, built from
 TPU-friendly primitives only:
 
-- semi-Lagrangian advection (trilinear back-trace, one gather per point
-  of all eight corners of all three components),
+- semi-Lagrangian advection (trilinear back-trace; in XLA one gather per
+  point of all eight corners of all three components, on a TPU and a
+  mesh a windowed Pallas kernel with no gather at all),
 - spectral diffusion + pressure projection in one rFFT round-trip
   (jnp.fft; exact div-free projection, unconditionally stable).
 
@@ -14,14 +15,24 @@ State is velocity ``u f32[3, D, H, W]``; the rendered field is |curl u|
 (vorticity magnitude), normalized to ≈[0, 1].
 
 A session advances it through `frame_program`: ONE jitted program per
-frame — n steps, then the rendered field — whose in and out placements are
-fixed on a mesh (u z-sharded ``P(None, axis, None, None)``, the field
-``P(axis, None, None)``), with the phases ``sim_advect``, ``sim_project``
-and ``sim_field`` scoped inside it (obs/profiler.py). On a mesh a rank
-back-traces only its own z-slab, from the field all-gathered; the
-transforms (DFT matmuls on a TPU) and the field's differences are laid
-over the ranks by the partitioner (PERF.md §5 says what the compiled
-program and its trace hold).
+frame — n steps, then the rendered field, and what each step's
+back-trace read — whose in and out placements are fixed on a mesh (u
+z-sharded ``P(None, axis, None, None)``, the field ``P(axis, None,
+None)``), with the phases ``sim_advect``, ``sim_project`` and
+``sim_field`` scoped inside it (obs/profiler.py). On a mesh a rank
+back-traces only its own z-slab, and since PR 38 from a window bounded
+by the step's displacement (`advect_window`): its slab plus
+`window_halo` planes of each ring neighbour, taken by ``ppermute``. The
+program decides on its input — the largest ``|dt u_z|`` over all ranks
+against the halo, in a ``lax.cond`` — and holds the old path as the
+other branch: the field all-gathered, turned into 24-wide cells and
+gathered from (`whole_field`), which is also all that one device and a
+slab too thin to window ever run. The windowed branch is the same cells
++ one-gather code on the smaller operand or, on a TPU where its tiles
+fit, the kernel of `sim/pallas_backtrace.py`. The transforms (DFT
+matmuls on a TPU) and the field's differences are laid over the ranks
+by the partitioner (PERF.md §5 says what the compiled program and its
+trace hold).
 """
 
 from __future__ import annotations
@@ -33,7 +44,9 @@ import jax
 import jax.numpy as jnp
 
 from scenery_insitu_tpu.obs.profiler import phase
+from scenery_insitu_tpu.ops.pallas_util import should_interpret
 from scenery_insitu_tpu.ops.sampling import sample_trilinear
+from scenery_insitu_tpu.sim import pallas_backtrace
 
 
 class VortexParams(NamedTuple):
@@ -140,30 +153,33 @@ def render_field(u: jnp.ndarray) -> jnp.ndarray:
 # the eight corners of a trilinear cell, (dz, dy, dx), low corner first
 _CORNERS = tuple((a, b, c) for a in (0, 1) for b in (0, 1) for c in (0, 1))
 
+# the shallowest halo a rank's window takes: the bound leaves H - 1 voxels
+# of room, and under two voxels of it nearly every flow would give way
+_HALO_MIN = 4
 
-def advect_semilagrangian(u: jnp.ndarray, dt: jnp.ndarray,
-                          axis=None) -> jnp.ndarray:
-    """Back-trace each grid point through the velocity field and resample
-    trilinearly (periodic wrap).
 
-    Under ``shard_map`` over the mesh axis ``axis`` ``u`` is the rank's own
-    z-slab: the rank all-gathers the field, back-traces only its own
-    planes and returns them.
+def window_halo(planes: int, ranks: int) -> int:
+    """H, the z-planes a rank takes from each ring neighbour to back-trace
+    its own ``planes`` from a window of ``planes + 2 H``: a quarter of the
+    slab, and 0 — no window, the whole field — where there is no
+    neighbour or the slab is thinner than ``2 H``."""
+    halo = max(planes // 4, _HALO_MIN)
+    return halo if ranks > 1 and planes >= 2 * halo else 0
 
-    The resampling is ONE gather per point: every cell of the wrap-padded
-    field holds, side by side, the three components at its eight
-    neighbours towards +z, +y, +x (24 values, built by rolls), so a
-    point's low corner fetches all it blends. On a TPU a gather costs by
-    the index far more than by the width of what it fetches (PERF.md §6,
-    PR 37: 24 scalar gathers per point took 810 ms of a 256^3 step on
-    four ranks)."""
+
+def _window_kernel(planes: int, halo: int, h: int, w: int) -> bool:
+    """Does the windowed Pallas kernel take a rank's back-trace here? On
+    a TPU, where its tiles fit the slab; elsewhere the window is read by
+    the XLA gather."""
+    return (jax.default_backend() == "tpu"
+            and pallas_backtrace.fits(planes, halo, h, w))
+
+
+def _back_trace(u, dt, first, d):
+    """Where each point of ``u`` (planes ``first ...`` of ``d``) came
+    from: per axis z, y, x the low corner's index in the grid padded by
+    one wrap layer on BOTH faces, and the weight of the high corner."""
     _, planes, h, w = u.shape
-    if axis is None:
-        whole, first = u, 0
-    else:
-        whole = jax.lax.all_gather(u, axis, axis=1, tiled=True)
-        first = jax.lax.axis_index(axis) * planes
-    d = whole.shape[1]
     z, y, x = jnp.meshgrid(jnp.arange(planes, dtype=jnp.float32) + 0.5,
                            jnp.arange(h, dtype=jnp.float32) + 0.5,
                            jnp.arange(w, dtype=jnp.float32) + 0.5,
@@ -173,16 +189,22 @@ def advect_semilagrangian(u: jnp.ndarray, dt: jnp.ndarray,
     # velocity components are in grid-units / time; component 2 moves
     # along z, 1 along y, 0 along x
     for comp, at, n in ((2, z, d), (1, y, h), (0, x, w)):
-        # the back-traced position in the grid padded by one wrap layer on
-        # BOTH faces, less the half cell of the centres: positions in
-        # [0, 0.5) blend f[n-1] with f[0] across the low boundary too
+        # the back-traced position in the padded grid, less the half cell
+        # of the centres: positions in [0, 0.5) blend f[n-1] with f[0]
+        # across the low boundary too
         p = jnp.mod(at - dt * u[comp], n) + 0.5
         i0 = jnp.floor(p)
         low.append(i0.astype(jnp.int32))
         frac.append(p - i0)
+    return low, frac
+
+
+def _gather_blend(padded, low, frac):
+    """The trilinear blend at (``low``, ``frac``) of ``padded``
+    f32[Z, Y, X, 3], in which every low corner and its seven neighbours
+    towards +z, +y, +x lie: ONE gather per point, of a cell that holds
+    all 24 values it blends side by side (built by rolls)."""
     (z0, y0, x0), (fz, fy, fx) = low, frac
-    padded = jnp.pad(jnp.moveaxis(whole, 0, -1),
-                     ((1, 1), (1, 1), (1, 1), (0, 0)), mode="wrap")
     pz, py, px, _ = padded.shape
     cells = jnp.concatenate(
         [jnp.roll(padded, (-dz, -dy, -dx), (0, 1, 2))
@@ -194,6 +216,86 @@ def advect_semilagrangian(u: jnp.ndarray, dt: jnp.ndarray,
     out = jnp.sum(got.reshape(got.shape[:-1] + (8, 3)) * weight[..., None],
                   axis=-2)
     return jnp.moveaxis(out, -1, 0)
+
+
+def _wrap_pad(u, z: bool):
+    """``u`` f32[3, Z, H, W] as f32[Z', H + 2, W + 2, 3] with one wrap
+    layer on both faces of y and x, and of z where asked."""
+    return jnp.pad(jnp.moveaxis(u, 0, -1),
+                   ((int(z),) * 2, (1, 1), (1, 1), (0, 0)), mode="wrap")
+
+
+def advect_semilagrangian(u: jnp.ndarray, dt: jnp.ndarray) -> jnp.ndarray:
+    """Back-trace each grid point through the velocity field and resample
+    trilinearly (periodic wrap), on one device: `advect_window`'s field
+    alone."""
+    return advect_window(u, dt)[0]
+
+
+def advect_window(u: jnp.ndarray, dt: jnp.ndarray, axis=None,
+                  ranks: int = 1):
+    """The semi-Lagrangian step and what it read: ``(u, window)``, the
+    advected field and f32[3] ``(reach, H, windowed)`` — the largest
+    ``|dt u_z|`` of the step in voxels over all ranks, the halo of the
+    window the program holds (0 where it holds none) and 1.0 where the
+    step read it.
+
+    A step reaches only as far as ``dt |u|``. Under ``shard_map`` over
+    the mesh axis ``axis`` of ``ranks`` devices ``u`` is the rank's own
+    z-slab, and the rank back-traces it from a WINDOW: its slab plus
+    ``H = window_halo(...)`` planes of each ring neighbour (two
+    ``ppermute``s; the periodic wrap in z is the ring). The window
+    serves a step iff every low corner and the one above it lie inside
+    it, which ``reach < H - 1`` ensures; the reach is a ``pmax``, the
+    same on every rank, and ``lax.cond`` on it takes either the window
+    or ``whole_field``: the rank all-gathers the field and back-traces
+    its planes from all of it, as every step did until PR 38. Without an
+    ``axis`` (one device: nobody to take a halo from) and on a slab
+    thinner than ``2 H`` there is no window and no ``cond``.
+
+    In XLA the resampling is ONE gather per point either way
+    (`_gather_blend`). On a TPU a gather costs by the index far more
+    than by the width of what it fetches (PERF.md §6, PR 37: 24 scalar
+    gathers per point took 810 ms of a 256^3 step on four ranks), and
+    building the cells costs by the planes they cover (§6, PR 38), which
+    is what the window cuts; where `_window_kernel` says so the window is
+    read by `pallas_backtrace.back_trace` instead, from the same low
+    corners and weights, with neither cells nor gather."""
+    _, planes, h, w = u.shape
+    d, halo = planes * ranks, window_halo(planes, ranks)
+    first = 0 if axis is None else jax.lax.axis_index(axis) * planes
+    reach = jnp.max(jnp.abs(dt * u[2]))
+    if axis is not None:
+        reach = jax.lax.pmax(reach, axis)
+    low, frac = _back_trace(u, dt, first, d)
+
+    def whole_field(u):
+        whole = (u if axis is None
+                 else jax.lax.all_gather(u, axis, axis=1, tiled=True))
+        return _gather_blend(_wrap_pad(whole, z=True), low, frac)
+
+    def windowed(u):
+        up = [(i, (i + 1) % ranks) for i in range(ranks)]
+        down = [(j, i) for i, j in up]
+        window = jnp.concatenate(
+            [jax.lax.ppermute(u[:, -halo:], axis, up), u,
+             jax.lax.ppermute(u[:, :halo], axis, down)], 1)
+        # the padded grid's plane z0 is the grid's z0 - 1, and the
+        # window's plane 0 the grid's first - H
+        inside = [jnp.mod(low[0] - 1 - first + halo, d)] + low[1:]
+        if _window_kernel(planes, halo, h, w):
+            return pallas_backtrace.back_trace(
+                window, inside, frac, halo=halo,
+                interpret=should_interpret())
+        return _gather_blend(_wrap_pad(window, z=False), inside, frac)
+
+    if halo:
+        fits = reach < halo - 1
+        out = jax.lax.cond(fits, windowed, whole_field, u)
+    else:
+        fits, out = False, whole_field(u)
+    return out, jnp.stack([reach, jnp.float32(halo),
+                           jnp.asarray(fits, jnp.float32)])
 
 
 def project_divfree(u: jnp.ndarray, params: VortexParams,
@@ -268,40 +370,51 @@ def multi_step(flow: VortexFlow, n: int) -> VortexFlow:
 
 def frame_program(mesh=None, axis=None):
     """The jitted program of one frame of a session's vortex sim,
-    ``vortex_frame(u, params, n) -> (u, field)``: ``n`` steps (static, and
-    walked statically: a frame takes a few, and a loop's carry costs
-    whole-field copies around it), then the rendered field.
+    ``vortex_frame(u, params, n) -> (u, field, windows)``: ``n`` steps
+    (static, and walked statically: a frame takes a few, and a loop's
+    carry costs whole-field copies around it), then the rendered field;
+    ``windows`` f32[n, 3] holds each step's ``(reach, H, windowed)``
+    (`advect_window`), a few bytes that say which branch the input asked
+    for.
 
     On a ``mesh`` the placements are fixed — u z-sharded over ``axis``
     going in and coming out, the parameters replicated, the field
-    z-sharded as the render step takes it — so the second frame compiles
-    nothing and no eager op has to place anything; a rank back-traces
-    only its own z-slab (`advect_semilagrangian` under ``shard_map``),
-    and the transforms and the field's differences are the
-    partitioner's."""
+    z-sharded as the render step takes it, ``windows`` replicated — so
+    the second frame compiles nothing and no eager op has to place
+    anything; a rank back-traces only its own z-slab, from its window or
+    from the whole field (`advect_window` under ``shard_map``: the
+    program holds both, and the halo ``ppermute``s or the all-gather run
+    inside the branch taken), and the transforms and the field's
+    differences are the partitioner's."""
     if mesh is None:
-        advect, shardings = advect_semilagrangian, {}
+        advect, shardings = advect_window, {}
     else:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         slab = P(None, axis, None, None)
         advect = jax.shard_map(
-            partial(advect_semilagrangian, axis=axis), mesh=mesh,
-            in_specs=(slab, P()), out_specs=slab)
+            partial(advect_window, axis=axis, ranks=mesh.shape[axis]),
+            mesh=mesh, in_specs=(slab, P()), out_specs=(slab, P()),
+            # a Pallas call (and its interpreter) carries no varying-axes
+            # types, as under `sim/pallas_stencil`'s shard_map
+            check_vma=False)
         shardings = dict(
             in_shardings=(NamedSharding(mesh, slab),
                           NamedSharding(mesh, P())),
             out_shardings=(NamedSharding(mesh, slab),
-                           NamedSharding(mesh, P(axis, None, None))))
+                           NamedSharding(mesh, P(axis, None, None)),
+                           NamedSharding(mesh, P())))
 
     def vortex_frame(u, params, n):
+        windows = []
         for _ in range(n):
             with phase("sim_advect"):
-                u = advect(u, params.dt)
+                u, window = advect(u, params.dt)
+            windows.append(window)
             with phase("sim_project"):
                 u = project_divfree(u, params)
         with phase("sim_field"):
             field = render_field(u)
-        return u, field
+        return u, field, jnp.stack(windows) if n else jnp.zeros((0, 3))
 
     return jax.jit(vortex_frame, static_argnums=2, **shardings)

@@ -520,6 +520,10 @@ class ShmVolumeSource:
         self.last_seq = None
         self._cond = threading.Condition()
         self._landed = None         # (field, seq) waiting for `advance`
+        # an upload is between its `device_put` and landed: set and
+        # cleared by the uploader, read (never waited for) by a recorded
+        # session when it launches a frame
+        self.upload_busy = False
         self._error = None          # what ended the uploader
         self._closing = False
         self._thread = None         # started by the first advance / field
@@ -539,13 +543,17 @@ class ShmVolumeSource:
         from scenery_insitu_tpu import obs as _obs
 
         rec = _obs.get_recorder()
-        with (rec.span("ingest.upload", bytes=view.nbytes, seq=seq)
-              if rec.enabled else _NO_SPAN):
-            field = self._land(view)
-            # inside the span: a reader of the window's events finds the
-            # bytes where it finds the upload
-            rec.count("ingest_bytes", view.nbytes)
-            rec.count("ingest_fields_uploaded")
+        self.upload_busy = True
+        try:
+            with (rec.span("ingest.upload", bytes=view.nbytes, seq=seq)
+                  if rec.enabled else _NO_SPAN):
+                field = self._land(view)
+                # inside the span: a reader of the window's events finds
+                # the bytes where it finds the upload
+                rec.count("ingest_bytes", view.nbytes)
+                rec.count("ingest_fields_uploaded")
+        finally:
+            self.upload_busy = False
         return field
 
     def _upload_loop(self) -> None:

@@ -294,8 +294,6 @@ _COUNTER_REGISTRY: Dict[str, str] = {
                     "after an unhandled frame-loop exception",
     "frames_abandoned": "the tile assembler abandoned a frame that "
                         "stayed incomplete past its window",
-    "frames_eager_dispatch": "a frame went through the eager per-frame "
-                             "dispatch path",
     "frames_fetched_sharded": "a frame sharded over the mesh was brought "
                               "to the host shard by shard and assembled "
                               "there (InSituSession._to_host)",
@@ -450,10 +448,12 @@ class _Span:
     ``jax.profiler.TraceAnnotation`` of the same name, with ``frame`` and
     the scalar attrs as its stats: it lands on the ``/host:CPU`` plane of
     whatever profile is being taken, on the device ops' clock, and costs
-    one flag test when none is."""
+    one flag test when none is. Its event carries ``thread``, the name
+    of the thread that opened it (looked up only when enabled): spans of
+    two threads are both ``depth`` 0 and are told apart by nothing else."""
 
     __slots__ = ("rec", "name", "frame", "attrs", "t0", "depth", "parent",
-                 "ann")
+                 "ann", "thread")
 
     def __init__(self, rec: "Recorder", name: str,
                  frame: Optional[int], attrs: Optional[dict]):
@@ -462,6 +462,14 @@ class _Span:
         self.frame = frame
         self.attrs = attrs
 
+    def note(self, **attrs) -> None:
+        """Attributes that are known only once the span is open (what a
+        drain found, whether the pool allocated). They go into the event;
+        the annotation took its stats when the span opened. Nothing is
+        kept in a run that records nothing."""
+        if self.rec.enabled:
+            self.attrs = {**(self.attrs or {}), **attrs}
+
     def __enter__(self):
         rec = self.rec
         if rec.enabled:
@@ -469,6 +477,7 @@ class _Span:
             self.depth = len(stack)
             self.parent = stack[-1] if stack else None
             stack.append(self.name)
+            self.thread = threading.current_thread().name
             ann = _annotation()
             if ann is not None:
                 stats = {k: v for k, v in (self.attrs or {}).items()
@@ -493,7 +502,7 @@ class _Span:
             ev = {"type": "span", "name": self.name,
                   "rank": rec.rank,
                   "ts": self.t0 - rec.epoch, "dur": dt,
-                  "depth": self.depth}
+                  "depth": self.depth, "thread": self.thread}
             if self.parent is not None:
                 ev["parent"] = self.parent
             if self.frame is not None:
@@ -618,14 +627,19 @@ class Recorder:
         """Chrome-trace / Perfetto event list: spans as complete ("X")
         events, counters as "C", instants as "i", plus process-name
         metadata. ``pid`` is the rank, timestamps in µs from the
-        recorder epoch."""
+        recorder epoch. Every thread that opened a span has a ``tid`` of
+        its own (1, 2, ... in order of appearance, each with a
+        ``thread_name`` row): the uploader's and the delivery worker's
+        spans run beside the loop's and would overlap on one row. What
+        carries no thread (counters, instants, the ledger) is on 0."""
         out = [{"ph": "M", "name": "process_name", "pid": self.rank,
                 "tid": 0,
                 "args": {"name": f"rank {self.rank}"}}]
+        tids: Dict[tuple, int] = {}
         for ev in self.events:
             ts = round(ev["ts"] * 1e6, 1)
-            base = {"name": ev["name"], "pid": ev.get("rank", self.rank),
-                    "tid": 0, "ts": ts}
+            pid = ev.get("rank", self.rank)
+            base = {"name": ev["name"], "pid": pid, "tid": 0, "ts": ts}
             args = dict(ev.get("attrs") or {})
             if "frame" in ev:
                 args["frame"] = ev["frame"]
@@ -634,6 +648,15 @@ class Recorder:
                             cat="phase")
                 if "parent" in ev:
                     args["parent"] = ev["parent"]
+                thread = ev.get("thread")
+                if thread is not None:
+                    tid = tids.get((pid, thread))
+                    if tid is None:
+                        tid = tids[pid, thread] = len(tids) + 1
+                        out.append({"ph": "M", "name": "thread_name",
+                                    "pid": pid, "tid": tid,
+                                    "args": {"name": thread}})
+                    base["tid"] = tid
             elif ev["type"] == "counter":
                 base.update(ph="C", cat="counter")
                 args = {"value": ev["value"]}

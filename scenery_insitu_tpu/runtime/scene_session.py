@@ -55,6 +55,7 @@ class SceneSession:
         self.camera = camera or Camera.create(
             (0.0, 0.6, 3.0), fov_y_deg=50.0, near=0.3, far=20.0)
         self._host_pose = None      # set by steer_session
+        self._steer_seq = 0         # camera messages applied (drain_steering)
         self.sinks: List[Sink] = list(sinks)
         # same per-callable failure isolation as InSituSession (sinks +
         # on_steer run behind the guard; see drain_steering)

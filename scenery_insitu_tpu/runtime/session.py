@@ -104,12 +104,34 @@ def camera_regime(sess, site: str):
 def drain_steering(sess) -> None:
     """Apply all pending steering messages to ``sess``. Shared by
     InSituSession and SceneSession so the steering protocol has ONE
-    consumer (`steer_session`)."""
+    consumer (`steer_session`).
+
+    A camera message is the one request a viewer sends, so each one
+    applied takes the next number (``sess._steer_seq``): the frames
+    rendered from it carry that number on their spans, and a recorded
+    run's ``steer`` span says how many messages it drained (``msgs``),
+    the newest number it applied (``seq``) and when, on
+    ``time.perf_counter``'s own scale (``t_drain``: what a viewer that
+    stamped its send on the same clock measures the queueing against)."""
     if sess.steering is None:
         return
-    with sess.obs.span("steer", frame=sess.frame_index):
+    rec = sess.obs.enabled
+    with sess.obs.span("steer", frame=sess.frame_index) as span:
+        msgs, seq0, t_drain = 0, sess._steer_seq, None
         for msg in sess.steering.drain():
+            if rec and t_drain is None:
+                t_drain = time.perf_counter()
+            msgs += 1
             steer_session(sess, msg)
+            if msg.get("type") == "camera":
+                sess._steer_seq += 1
+        if rec:
+            found = {"msgs": msgs}
+            if msgs:
+                found["t_drain"] = t_drain
+            if sess._steer_seq > seq0:
+                found["seq"] = sess._steer_seq
+            span.note(**found)
 
 
 def apply_tf_steering(sess, msg: dict, invalidate) -> None:
@@ -436,19 +458,22 @@ class HostFrames:
     def __init__(self):
         self._bufs: list = []
         self._threads = None        # started by the first sharded frame
+        self.last_fresh = False     # the last `take` had to allocate
 
     def take(self, shape, dtype) -> np.ndarray:
         """A writeable C-contiguous array of this shape and dtype whose
         contents are undefined. Free arrays it does not hand out are let
         go — the others of this size at once, those of another size when
         it has to allocate — so the list never holds more than the frames
-        alive at one time."""
+        alive at one time. ``last_fresh`` says whether it allocated (the
+        ``fresh`` of a recorded run's ``fetch.concat`` span)."""
         bufs = self._bufs
         free = [i for i in range(len(bufs)) if _holders(bufs, i) == _UNHELD]
         same = [i for i in free
                 if bufs[i].shape == shape and bufs[i].dtype == dtype]
         drop = set(same[1:] if same else free)
         self._bufs = [b for i, b in enumerate(bufs) if i not in drop]
+        self.last_fresh = not same
         if same:
             buf = bufs[same[0]]
             buf.flags.writeable = True
@@ -604,7 +629,11 @@ class InSituSession:
         self.orbit_rate = 0.0  # radians/frame camera sweep (benchmark mode)
         self.steering = None   # optional streaming.SteeringEndpoint
         self.on_steer: List[Callable[[dict], None]] = []  # non-camera msgs
-        self._pending_meta = {}  # frame index -> VDIMetadata at dispatch
+        # frame index -> (VDIMetadata at dispatch, the number of the
+        # newest camera message that frame was rendered from)
+        self._pending_meta = {}
+        self._steer_seq = 0     # camera messages applied (drain_steering)
+        self._pending = deque()     # run()'s in-flight frames, newest last
         self._host_frames = HostFrames()    # see _to_host
 
         from scenery_insitu_tpu.ops import slicer as _slicer
@@ -797,13 +826,21 @@ class InSituSession:
 
     def render_frame(self):
         """Advance the sim and dispatch one render step (device arrays)."""
+        rec = self.obs.enabled
         drain_steering(self)
         self._maybe_replan()
         with self.obs.span("sim", frame=self.frame_index,
                            kind=self.sim.kind):
             self.sim.advance(self.cfg.sim.steps_per_frame)
+        # what a recorded launch says of itself: the camera message it
+        # renders from, and what was in flight when it was made (two
+        # non-blocking reads, neither made in a run that records nothing)
+        launch = {"steer_seq": self._steer_seq,
+                  "upload_busy": bool(getattr(self.sim, "upload_busy",
+                                              False)),
+                  "prev_ready": self._prev_ready()} if rec else {}
         with self.obs.span("dispatch", frame=self.frame_index,
-                           mode=self.mode, engine=self.engine):
+                           mode=self.mode, engine=self.engine, **launch):
             meta = None
             if self.mode == "particles":
                 from scenery_insitu_tpu.parallel.particles import (
@@ -818,9 +855,30 @@ class InSituSession:
                 out, meta = self._regime_frame()
             meta = (self.frame_metadata(self.frame_index) if meta is None
                     else meta._replace(index=jnp.int32(self.frame_index)))
+        if rec:
+            with self.obs.span("upkeep", frame=self.frame_index):
+                self._frame_dispatched(meta)
+        else:
+            self._frame_dispatched(meta)
+        return out
+
+    def _prev_ready(self) -> bool:
+        """Whether the device has finished the newest frame in flight
+        (every leaf answers ``is_ready()``; nothing waits): its
+        device->host copy, started at its dispatch, is then under way or
+        done, and the launch that reads this found the device idle. False
+        when nothing is in flight."""
+        if not self._pending:
+            return False
+        return all(leaf.is_ready() for leaf in
+                   jax.tree_util.tree_leaves(self._pending[-1][1]))
+
+    def _frame_dispatched(self, meta) -> None:
+        """The loop's bookkeeping after a dispatch (a recorded run's
+        ``upkeep`` span, with `_upkeep`)."""
         # metadata snapshot BEFORE the camera advances (fetch is pipelined
         # one frame behind, so it must not see the next frame's pose)
-        self._pending_meta[self.frame_index] = meta
+        self._pending_meta[self.frame_index] = (meta, self._steer_seq)
         # bound the dict: the fetch runs at most pipeline_depth frames
         # behind, so any older entry is unreachable — without this, a
         # headless run(fetch=False) loop (which never pops) grows it
@@ -829,9 +887,7 @@ class InSituSession:
                   if k < self.frame_index
                   - self.cfg.runtime.pipeline_depth]:
             del self._pending_meta[k]
-        self.obs.count("frames_eager_dispatch")
         advance_camera_and_index(self)
-        return out
 
     def run(self, frames: int, fetch: bool = True,
             profile_dir: Optional[str] = None) -> dict:
@@ -855,40 +911,75 @@ class InSituSession:
                 # delivery, device refs dropped) once `depth` newer
                 # dispatches are in flight. depth 1 is bitwise the
                 # historical one-deep overlap.
-                pending = deque()
+                pending = self._pending
                 payload = {}
                 last = frames - 1
+                # read once: in a recorded run every statement of the
+                # body is under a leaf span (no span around the whole
+                # body: it would cover every idle gap of a trace), in any
+                # other run under none
+                rec, span = self.obs.enabled, self.obs.span
+
+                def retire() -> None:
+                    """Retire the oldest frame in flight. Where that
+                    fetches, the payload of the frame before is let go
+                    first, by name: a rebind would free it (157 MB at
+                    512^3 where no sink kept it) between two spans. A
+                    recorded run also lets the retired frame's device
+                    arrays go by name (on four chips 1.1 ms a frame)."""
+                    nonlocal payload
+                    index, consume = pending[0][0], pending[0][2]
+                    if not rec:
+                        if consume:
+                            payload = None
+                        payload = self._retire(pending.popleft(), fetch,
+                                               payload)
+                        return
+                    if consume:
+                        with span("release", frame=index, bytes=sum(
+                                v.nbytes for v in payload.values()
+                                if isinstance(v, np.ndarray))):
+                            payload = None
+                    entry = pending.popleft()
+                    payload = self._retire(entry, fetch, payload)
+                    with span("release", frame=index, device=True):
+                        del entry
+
                 for i in range(frames):
                     t_f = time.perf_counter()
                     out = self.render_frame()
+                    index = self.frame_index - 1
                     # start the device->host copy at dispatch time, but
                     # only when somebody consumes it (sinks registered,
                     # or the caller-visible payload of the final frame)
                     # — a sink-less run pays no host transfer at all
                     consume = fetch and (
                         bool(self.sinks or self.tile_sinks) or i == last)
-                    if consume:
+                    if consume and rec:
+                        with span("host_copy.start", frame=index,
+                                  bytes=sum(
+                                      leaf.nbytes for leaf in
+                                      jax.tree_util.tree_leaves(out))):
+                            self._start_host_copy(out)
+                    elif consume:
                         self._start_host_copy(out)
-                    pending.append((self.frame_index - 1, out, consume))
+                    pending.append((index, out, consume))
                     out = None      # the deque holds the only device ref
                     while len(pending) > depth:
-                        payload = self._retire(pending.popleft(),
-                                               fetch, payload)
-                    self.timers.frame_done()
-                    self.slo.observe(
-                        "frame_ms",
-                        (time.perf_counter() - t_f) * 1e3,
-                        frame=self.frame_index - 1)
-                    if self._obs_pub is not None:
-                        self._obs_pub.pump(self.obs)
+                        retire()
+                    if rec:
+                        with span("upkeep", frame=index):
+                            self._upkeep(t_f)
+                    else:
+                        self._upkeep(t_f)
                 while pending:
-                    payload = self._retire(pending.popleft(), fetch,
-                                           payload)
+                    retire()
         except BaseException:
             # flight recorder: an unhandled exception must not lose the
             # final unflushed obs window — drain the delivery queue
             # first (frames the device already paid for), dump, then
             # keep raising
+            self._pending.clear()
             if self._delivery is not None:
                 self._delivery.drain()
             _obs.flight_flush(self.obs, where="run")
@@ -919,6 +1010,16 @@ class InSituSession:
         close = getattr(self.sim, "close", None)
         if close is not None:
             close()
+
+    def _upkeep(self, t_f: float) -> None:
+        """The end of a loop iteration begun at ``t_f``: the timers'
+        window, the SLO engine's sample, the collector's batch (a recorded
+        run's ``upkeep`` span, with `_frame_dispatched`)."""
+        self.timers.frame_done()
+        self.slo.observe("frame_ms", (time.perf_counter() - t_f) * 1e3,
+                         frame=self.frame_index - 1)
+        if self._obs_pub is not None:
+            self._obs_pub.pump(self.obs)
 
     def _retire(self, entry, fetch: bool, payload: dict) -> dict:
         """Retire one pipelined frame: fetch + deliver it when it has
@@ -970,7 +1071,8 @@ class InSituSession:
         (``jax.block_until_ready``: the frame's device programs; the one
         extra device wait of a recorded run), ``fetch.copy`` (attr
         ``bytes``; one per shard on a mesh, attr ``shard``), and
-        ``fetch.concat`` around the host assembly of each sharded leaf."""
+        ``fetch.concat`` around the host assembly of each sharded leaf
+        (attrs ``bytes`` and ``fresh``: the pool had to allocate)."""
         if self.obs.enabled:
             span = self.obs.span
             with span("fetch.ready", frame=index):
@@ -998,20 +1100,23 @@ class InSituSession:
                     with span("fetch.copy", frame=index,
                               shard=sh.device.id, bytes=sh.data.nbytes):
                         parts.append((sh.index, np.asarray(sh.data)))
-            with span("fetch.concat", frame=index, bytes=leaf.nbytes):
+            with span("fetch.concat", frame=index,
+                      bytes=leaf.nbytes) as concat:
                 host.append(self._host_frames.assemble(
                     leaf.shape, leaf.dtype, parts))
+                if concat is not None:      # a recorded run
+                    concat.note(fresh=self._host_frames.last_fresh)
         return jax.tree_util.tree_unflatten(treedef, host)
 
     def _fetch(self, index: int, out) -> dict:
         from scenery_insitu_tpu.ops.splat import SplatOutput
-        meta = self._pending_meta.pop(index, None)
+        meta, steer_seq = self._pending_meta.pop(index, (None, None))
         if meta is None:
             meta = self.frame_metadata(index)
         tiles = ()
         tiled = bool(self.tile_sinks) \
             and self.cfg.composite.schedule == "waves"
-        with self.obs.span("fetch", frame=index):
+        with self.obs.span("fetch", frame=index, steer_seq=steer_seq):
             if self._n_ranks > 1 or self.obs.enabled:
                 # a frame on a mesh, or a recorded run (the copy, timed)
                 out = self._to_host(index, out)
@@ -1049,7 +1154,7 @@ class InSituSession:
             # pays the enqueue (or backpressure, per overflow policy)
             self._delivery.submit(index, payload, tiles)
         else:
-            with self.obs.span("sinks", frame=index):
+            with self.obs.span("sinks", frame=index, steer_seq=steer_seq):
                 self._sink_guard.run(self.sinks, index, payload)
         return payload
 

@@ -250,6 +250,50 @@ def test_spans_and_counters(enabled):
         assert all("parent" not in e for e in ups)      # its own thread
 
 
+def test_upload_busy_and_the_uploaders_own_row():
+    """`upload_busy` is true from an upload's start until it has landed
+    and false otherwise; a recorded launch reads it; every span says which
+    thread opened it, and the Chrome trace gives the uploader a row of its
+    own, where its spans do not overlap the loop's."""
+    with limit(300):
+        ch = chan()
+        prod = Lockstep(ch, 17, 5)
+        src = ShmVolumeSource(ch, GRID, timeout_ms=5000,
+                              frame_timeout_ms=5000)
+        seen, land = [], src._land
+        src._land = lambda view: (seen.append(src.upload_busy),
+                                  land(view))[1]
+        try:
+            assert src.upload_busy is False
+            sess, _ = session(src, "obs.enabled=true")
+            sess.run(4)
+            rec = sess.obs
+        finally:
+            src.close()
+            prod.end()
+        assert seen and all(seen) and src.upload_busy is False
+        spans = [e for e in rec.events if e["type"] == "span"]
+        assert all(isinstance(e.get("thread"), str) for e in spans)
+        by_name = lambda name: {e["thread"] for e in spans
+                                if e["name"] == name}
+        assert by_name("ingest.upload") == {"shm-uploader"}
+        assert by_name("dispatch") == by_name("ingest.wait") == \
+            by_name("fetch") == {"MainThread"}
+        launches = [e["attrs"] for e in spans if e["name"] == "dispatch"]
+        assert len(launches) == 4
+        assert all(isinstance(a["upload_busy"], bool)
+                   and isinstance(a["prev_ready"], bool) for a in launches)
+        evs = rec.chrome_trace_events()
+        rows = {e["args"]["name"]: e["tid"] for e in evs
+                if e.get("ph") == "M" and e["name"] == "thread_name"}
+        assert rows["shm-uploader"] != rows["MainThread"]
+        assert {e["tid"] for e in evs if e.get("ph") == "X"
+                and e["name"] == "ingest.upload"} == {rows["shm-uploader"]}
+        assert rows["shm-uploader"] not in {
+            e["tid"] for e in evs if e.get("ph") == "X"
+            and e["name"] != "ingest.upload"}
+
+
 def test_close_joins_and_detaches():
     with limit(60):
         ch = chan()

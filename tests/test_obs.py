@@ -65,10 +65,10 @@ def test_counters_and_summary():
     rec = Recorder(enabled=True)
     rec.count("compile_step")
     rec.count("compile_step")
-    rec.count("frames_eager_dispatch", 8)
+    rec.count("build_steps", 8)
     s = rec.summary()
     assert s["counters"]["compile_step"] == 2
-    assert s["counters"]["frames_eager_dispatch"] == 8
+    assert s["counters"]["build_steps"] == 8
     assert s["enabled"] is True
     assert isinstance(s["degradations"], list)
 
@@ -166,7 +166,7 @@ def test_session_run_writes_trace_and_metrics(tmp_path):
     lines = [json.loads(l) for l in open(metrics) if l.strip()]
     assert lines and lines[-1]["type"] == "summary"
     assert lines[-1]["frames"] == 3
-    assert lines[-1]["counters"].get("frames_eager_dispatch") == 3
+    assert lines[-1]["counters"].get("build_steps") == 1
 
 
 def test_session_disabled_obs_zero_events():
@@ -717,3 +717,214 @@ def test_disabled_span_makes_no_annotation(monkeypatch):
     with rec.span("sim", frame=0, kind="x", arr=object()):
         pass
     assert made == [1] and rec.events[0]["frame"] == 0
+
+
+# ------------------------------------- the host's frame from inside (PR 39)
+
+_LOOP_SPANS = {"steer", "sim", "dispatch", "upkeep", "host_copy.start",
+               "release", "fetch", "sinks"}
+
+
+class _Mailbox:
+    """A steering source that hands over what the test put in it."""
+
+    def __init__(self):
+        self.msgs = []
+
+    def drain(self):
+        msgs, self.msgs = self.msgs, []
+        return msgs
+
+
+def _steered_session(enabled, ranks=1, sink=None, **kw):
+    sess = InSituSession(
+        _session_cfg(**{"obs.enabled": str(enabled).lower(), **_MXU, **kw}),
+        mesh=make_mesh(ranks), sinks=[sink or (lambda i, p: None)])
+    sess.steering = _Mailbox()
+    return sess
+
+
+def test_loop_thread_spans_cover_the_run():
+    """With the recorder on, the loop thread's depth-0 spans are leaf
+    spans side by side (none covers an iteration) and leave under 5 % of
+    `run`'s wall time under no span."""
+    import time
+
+    sess = _steered_session(True, **{"sim.grid": "[48,48,48]",
+                                     "render.width": "96",
+                                     "render.height": "64"})
+    sess.run(2)                             # compiles
+    n0 = len(sess.obs.events)
+    t0 = time.perf_counter()
+    sess.run(6)
+    wall = time.perf_counter() - t0
+    spans = [e for e in sess.obs.events[n0:] if e["type"] == "span"]
+    top = sorted((e for e in spans if e["depth"] == 0
+                  and e["thread"] == "MainThread"), key=lambda e: e["ts"])
+    assert {e["name"] for e in top} == _LOOP_SPANS
+    for a, b in zip(top, top[1:]):          # side by side, in order
+        assert a["ts"] + a["dur"] <= b["ts"]
+    covered = sum(e["dur"] for e in top)
+    assert covered >= 0.95 * wall, (covered, wall)
+    assert max(e["dur"] for e in top) < 0.5 * wall      # no root span
+    # two `upkeep` spans a frame (after the dispatch, after the retire),
+    # two `release` for every fetch: before it the payload of the frame
+    # before, with its bytes, after it the retired frame's device arrays
+    per_frame = lambda name: [e for e in top if e["name"] == name]
+    assert len(per_frame("upkeep")) == 12 and len(per_frame("fetch")) == 6
+    nbytes = per_frame("host_copy.start")[0]["attrs"]["bytes"]
+    assert nbytes > 0
+    assert [e["attrs"] for e in per_frame("release")] == [
+        a for n in [0] + [nbytes] * 5 for a in ({"bytes": n},
+                                                {"device": True})]
+    assert all(isinstance(e["thread"], str) for e in spans)
+
+
+def test_unrecorded_run_opens_no_new_span_and_reads_nothing(monkeypatch):
+    """With the recorder off: no span of the new names is made, no
+    `is_ready()` is asked of a frame in flight, the sim's `upload_busy` is
+    not read, no thread name is looked up, and `events` stays empty."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from scenery_insitu_tpu.obs import recorder as rec_mod
+
+    def boom(*_a, **_k):
+        raise AssertionError("touched in a run that records nothing")
+
+    class _Sim:
+        """A sim facade whose `upload_busy` may not be read."""
+
+        kind = "external"
+        upload_busy = property(boom)
+
+        def __init__(self):
+            self.field = jnp.zeros((16, 16, 16), jnp.float32)
+
+        def advance(self, n):
+            pass
+
+    made = []
+    init = rec_mod._Span.__init__
+    monkeypatch.setattr(
+        rec_mod._Span, "__init__",
+        lambda self, rec, name, *a: (made.append(name),
+                                     init(self, rec, name, *a))[1])
+    sess = InSituSession(_session_cfg(**_MXU), sim=_Sim(),
+                         mesh=make_mesh(1), sinks=[lambda i, p: None])
+    sess.steering = _Mailbox()
+    sess.run(1)
+    monkeypatch.setattr(type(jnp.zeros(1)), "is_ready", boom)
+    proxy = types.SimpleNamespace(**{
+        k: getattr(rec_mod.threading, k) for k in dir(rec_mod.threading)
+        if not k.startswith("__")})
+    proxy.current_thread = boom
+    monkeypatch.setattr(rec_mod, "threading", proxy)
+    sess.steering.msgs = [{"type": "camera", "eye": [0.2, 0.6, 3.0]}]
+    made.clear()
+    payload = sess.run(3)
+    assert payload["frame"] == 3 and sess._steer_seq == 1
+    assert sess.obs.events == []
+    assert made and not set(made) & {"upkeep", "host_copy.start", "release"}
+    assert not {"upkeep", "host_copy.start", "release"} & set(
+        sess.timers.stats)
+    # the same reads are made, once per launch, in a recorded run
+    rec = InSituSession(_session_cfg(**{"obs.enabled": "true", **_MXU}),
+                        sim=_Sim(), mesh=make_mesh(1),
+                        sinks=[lambda i, p: None])
+    with pytest.raises(AssertionError, match="records nothing"):
+        rec.run(1)
+
+
+def test_a_camera_message_is_followed_from_drain_to_sinks():
+    """A message drained before frame 2 is number 1: that frame's `steer`
+    span says so (with how many it drained and when), its `dispatch`,
+    `fetch` and `sinks` spans carry the number, the frames before carry 0,
+    and the frame's own view matrix is the message's camera."""
+    import time
+
+    import numpy as np
+
+    views = {}
+    sess = _steered_session(
+        True, sink=lambda i, p: views.__setitem__(
+            i, np.asarray(p["meta"].view, np.float64)))
+    eye_of = lambda v: -v[:3, :3].T @ v[:3, 3]
+    sess.run(2)
+    eye = [0.3, 0.5, 2.9]
+    t_sent = time.perf_counter()
+    sess.steering.msgs = [{"type": "record", "on": True},
+                          {"type": "camera", "eye": eye}]
+    sess.run(2)
+    sess.steering.msgs = [{"type": "camera", "eye": [0.0, 0.6, 3.0]},
+                          {"type": "camera", "eye": [0.1, 0.6, 3.0]}]
+    sess.run(1)
+    spans = [e for e in sess.obs.events if e["type"] == "span"]
+    of = lambda name: {e["frame"]: e.get("attrs") or {} for e in spans
+                       if e["name"] == name}
+    steer = of("steer")
+    assert [steer[f]["msgs"] for f in range(5)] == [0, 0, 2, 0, 2]
+    assert [steer[f].get("seq") for f in range(5)] == [None, None, 1,
+                                                       None, 3]
+    assert t_sent < steer[2]["t_drain"] < time.perf_counter()
+    assert "t_drain" not in steer[1]
+    for name in ("dispatch", "fetch", "sinks"):
+        assert [of(name)[f]["steer_seq"] for f in range(5)] == \
+            [0, 0, 1, 1, 3], name
+    assert np.allclose(eye_of(views[2]), eye, atol=1e-5)
+    assert np.allclose(eye_of(views[3]), eye, atol=1e-5)
+    assert not np.allclose(eye_of(views[1]), eye, atol=1e-3)
+    assert np.allclose(eye_of(views[4]), [0.1, 0.6, 3.0], atol=1e-5)
+
+
+def test_a_launch_says_what_was_in_flight():
+    """`prev_ready` and `upload_busy` on every recorded `dispatch` span:
+    nothing is in flight at the first launch of a run, and a sim with no
+    uploader is never busy; a frame the loop has waited for is ready."""
+    import jax
+
+    sess = _steered_session(True, **{"runtime.pipeline_depth": "2"})
+    sess.run(1)
+    ready = []
+    orig = sess.render_frame
+
+    def waited():
+        if sess._pending:                   # the frame before, finished
+            jax.block_until_ready(sess._pending[-1][1])
+            ready.append(sess._prev_ready())
+        return orig()
+
+    sess.render_frame = waited
+    sess.run(3)
+    launches = [e["attrs"] for e in sess.obs.events
+                if e["type"] == "span" and e["name"] == "dispatch"]
+    assert [a["prev_ready"] for a in launches] == [False, False, True, True]
+    assert ready == [True, True]
+    assert {a["upload_busy"] for a in launches} == {False}
+    assert not sess._pending                # nothing held after a run
+
+
+def test_chrome_trace_gives_each_thread_a_row():
+    import threading
+
+    rec = Recorder(enabled=True)
+    with rec.span("sim", frame=0):
+        worker = threading.Thread(
+            target=lambda: rec.span("ingest.upload").__enter__()
+            .__exit__(None, None, None), name="shm-uploader")
+        worker.start()
+        worker.join(10)
+    rec.count("compile_step")
+    evs = rec.chrome_trace_events()
+    rows = {e["args"]["name"]: e["tid"] for e in evs
+            if e.get("ph") == "M" and e["name"] == "thread_name"}
+    assert set(rows) == {"MainThread", "shm-uploader"}
+    assert len(set(rows.values())) == 2 and 0 not in rows.values()
+    tid = {e["name"]: e["tid"] for e in evs if e.get("ph") == "X"}
+    assert tid == {"sim": rows["MainThread"],
+                   "ingest.upload": rows["shm-uploader"]}
+    assert {e["tid"] for e in evs if e.get("ph") == "C"} == {0}
+    assert {e["thread"] for e in rec.events if e["type"] == "span"} == \
+        set(rows)

@@ -102,13 +102,14 @@ def test_one_device_frame_does_not_come_this_way():
 
 
 def test_unkept_frames_share_their_host_arrays():
-    """A sink that keeps nothing: the loop's own last payload and the
-    frame being assembled are the two generations alive, whatever the
-    number of frames."""
+    """A sink that keeps nothing: the loop lets its last payload go
+    before it fetches the next frame (PR 39: by name, inside a recorded
+    run's `release` span), so one generation of arrays serves every
+    frame, whatever their number."""
     sess, keys = _session(4, False, "vdi", lambda i, p: None)
     payload = sess.run(6)
     bufs = sess._host_frames._bufs
-    assert len(bufs) == 2 * len(keys)
+    assert len(bufs) == len(keys)
     assert any(payload["vdi_color"] is b for b in bufs)
 
 
@@ -159,3 +160,32 @@ def test_host_frames_assemble_under_thread_switching():
     finally:
         sys.setswitchinterval(interval)
     assert len(pool._bufs) <= 2
+
+
+def test_fetch_concat_says_when_the_pool_allocates():
+    """`fresh` on a recorded run's `fetch.concat` spans: true on the first
+    frame from the mesh and on the frame after one a sink kept, false on
+    every other (the pool handed out an array nobody held)."""
+    kept = []
+    sess, keys = _session(4, True, "vdi",
+                          lambda i, p: kept.append(p) if i == 2 else None)
+    sess.run(6)
+    fresh = {}
+    for e in sess.obs.events:
+        if e["type"] == "span" and e["name"] == "fetch.concat":
+            assert e["attrs"]["bytes"] > 0 and e["thread"] == "MainThread"
+            fresh.setdefault(e["frame"], set()).add(e["attrs"]["fresh"])
+    assert fresh == {0: {True}, 1: {False}, 2: {False}, 3: {True},
+                     4: {False}, 5: {False}}
+    assert len(kept) == 1 and len(sess._host_frames._bufs) == 2 * len(keys)
+
+
+def test_host_frames_last_fresh():
+    pool = HostFrames()
+    a = pool.take((4, 6), np.float32)
+    assert pool.last_fresh
+    del a
+    b = pool.take((4, 6), np.float32)
+    assert not pool.last_fresh
+    c = pool.take((4, 6), np.float32)       # `b` is held
+    assert pool.last_fresh and c is not b

@@ -294,6 +294,10 @@ _COUNTER_REGISTRY: Dict[str, str] = {
                     "after an unhandled frame-loop exception",
     "frames_abandoned": "the tile assembler abandoned a frame that "
                         "stayed incomplete past its window",
+    "frames_fetched_kmajor": "a frame fetched from the mesh whose every "
+                             "sharded leaf was cut along its leading axis "
+                             "alone: contiguous blocks for the host "
+                             "(pipeline._frame_out)",
     "frames_fetched_sharded": "a frame sharded over the mesh was brought "
                               "to the host shard by shard and assembled "
                               "there (InSituSession._to_host)",

@@ -266,16 +266,23 @@ def gather_vdi_tiles(vdi, codec: str = "zstd"):
 
     from scenery_insitu_tpu.io.vdi_io import compress, decompress
 
-    # addressable column block of this process (contiguous by construction
-    # of the 1-D W sharding)
-    col_shards = sorted(
-        (s for s in vdi.color.addressable_shards),
-        key=lambda s: s.index[-1].start or 0)
-    dep_shards = sorted(
-        (s for s in vdi.depth.addressable_shards),
-        key=lambda s: s.index[-1].start or 0)
-    local_c = np.concatenate([np.asarray(s.data) for s in col_shards], -1)
-    local_d = np.concatenate([np.asarray(s.data) for s in dep_shards], -1)
+    if vdi.color.is_fully_addressable:
+        # one process holds the whole frame, however it is sharded (on a
+        # one-process mesh it leaves slot-major: pipeline._frame_out)
+        local_c, local_d = np.asarray(vdi.color), np.asarray(vdi.depth)
+    else:
+        # addressable column block of this process (contiguous by
+        # construction of the 1-D W sharding a multi-process mesh keeps)
+        col_shards = sorted(
+            (s for s in vdi.color.addressable_shards),
+            key=lambda s: s.index[-1].start or 0)
+        dep_shards = sorted(
+            (s for s in vdi.depth.addressable_shards),
+            key=lambda s: s.index[-1].start or 0)
+        local_c = np.concatenate([np.asarray(s.data) for s in col_shards],
+                                 -1)
+        local_d = np.concatenate([np.asarray(s.data) for s in dep_shards],
+                                 -1)
     blobs, lengths = _allgather_blobs(
         compress(local_c.tobytes() + local_d.tobytes(), codec))
 

@@ -8,9 +8,12 @@ The reference's per-frame chain — per-rank VDI generation, JNI/MPI
     generate (local z-slab, halo-exact)
       → lax.all_to_all on the width axis over ICI
       → sort-merge composite of the n received column slices
-      → output left sharded by W (the gather is implicit in the output
-        sharding; an explicit all_gather is one call away when a host
-        needs the full frame)
+      → a VDI frame re-sharded from column blocks to slot blocks (one
+        more all_to_all a leaf: `_frame_out`), so that the host that
+        takes it shard by shard copies n contiguous blocks; a plain
+        image, a frame whose slots the ranks do not divide, a two-level
+        or multi-process mesh leave sharded by W as composited (the
+        gather is implicit in the output sharding either way)
 
 No postRenderLambda/AtomicInteger interlock machinery survives
 (DistributedVolumes.kt:736-796): XLA schedules generation, collective and
@@ -49,8 +52,10 @@ per-wave ``all_to_all``, per ``exchange``) concurrently with the next
 wave's resampling matmuls inside ONE compiled step. Lossless waves are
 parity-exact with the frame schedule (same per-pixel fragments, same
 merge order), and the per-wave outputs land in the same W-sharded layout
-— plus the session can deliver finished column blocks to subscribers
-before the frame closes (runtime/session.py tile sinks).
+(which `_frame_out` then re-shards like the frame schedule's) — plus
+the session can deliver finished column blocks to subscribers before
+the frame closes (runtime/session.py tile sinks slice the fetched
+frame, whatever its sharding was).
 
 A fourth axis, ``CompositeConfig.temporal_reuse = "ranges"``
 (docs/PERF.md "Temporal deltas"), exploits coherence across FRAMES: the
@@ -405,7 +410,7 @@ def _wave_assemble(x: jnp.ndarray) -> jnp.ndarray:
     """[T, ..., wb] per-wave tiles -> [..., T*wb]: wave w's tile is the
     w-th sub-block of this rank's contiguous owned column block, so
     concatenating along waves reproduces EXACTLY the frame schedule's
-    output layout (W-sharded, rank blocks contiguous)."""
+    composited layout (W-sharded, rank blocks contiguous)."""
     t = x.shape[0]
     moved = jnp.moveaxis(x, 0, -2)                    # [..., T, wb]
     return moved.reshape(moved.shape[:-2] + (t * moved.shape[-1],))
@@ -487,6 +492,60 @@ def _composite_exchanged_sched(color: jnp.ndarray, depth: jnp.ndarray,
                      warn=False)
     return _composite_exchanged(color, depth, n, axis_name, comp_cfg,
                                 topo=topo)
+
+
+def _slots_from_columns(x: jnp.ndarray, axis_name) -> jnp.ndarray:
+    """The composited frame's last move: this rank's column block of
+    every slot, [K, C, H, W/n], for whole rows of ITS K/n slots,
+    [K/n, C, H, W] — one tiled ``all_to_all`` over the rank axis (slot
+    block j goes to rank j, the received column blocks line up along W in
+    source order) plus the compiler's layout copy. Exact: every element of
+    the global array stays where it was, only its owner changes."""
+    with _phase("exchange"):
+        return jax.lax.all_to_all(x, axis_name, split_axis=0, concat_axis=3,
+                                  tiled=True)
+
+
+def _leaves_slot_major(mesh: Mesh, n: int, topo, k_out: int) -> bool:
+    """Whether a composited VDI frame of ``k_out`` slots leaves this mesh
+    sharded over its slots (`_frame_out`): the ranks divide the slots,
+    the rank axis is flat (the two-level composite hands its columns out
+    ranks-major, ``topo.out_axis``, to consumers of column tiles), and
+    one process holds the whole mesh (across processes a frame is
+    gathered column block by column block,
+    parallel/multihost.gather_vdi_tiles). One rank has nothing to
+    re-shard."""
+    return (topo is None and n > 1 and k_out % n == 0
+            and not mesh.is_multi_process)
+
+
+def _frame_out(mesh: Mesh, axis, n: int, topo, k_out: int):
+    """How a composited VDI frame leaves the mesh: ``(out_specs, leave)``
+    for a builder whose ranks each hold their column block [k_out, C, H,
+    W/n] — ``out_specs`` the VDI of PartitionSpecs, ``leave(vdi)`` the
+    body's last op.
+
+    The host takes a frame shard by shard and copies each into its place
+    in one array (runtime/session.py ``HostFrames``). A W-sharded frame
+    makes that W/n-wide runs under a W-wide stride — 245,760 runs of
+    640 B for a 157 MB frame on four ranks, 9 GB/s on 16 threads. Sharded
+    over its LEADING axis the same frame is n contiguous blocks. So where
+    `_leaves_slot_major` says it can, the frame is re-sharded slot-major
+    on the device before it leaves (`_slots_from_columns`): the same
+    global array, shape, dtype and bytes, under ``P(axis, None, None,
+    None)``. Anywhere else it leaves W-sharded as it always did."""
+    if _leaves_slot_major(mesh, n, topo, k_out):
+        spec = P(axis, None, None, None)
+
+        def leave(vdi: VDI) -> VDI:
+            return VDI(_slots_from_columns(vdi.color, axis),
+                       _slots_from_columns(vdi.depth, axis))
+    else:
+        spec = P(None, None, None, axis if topo is None else topo.out_axis)
+
+        def leave(vdi: VDI) -> VDI:
+            return vdi
+    return VDI(spec, spec), leave
 
 
 def _resolve_waves(comp_cfg, n: int, width: int, slicer_mod=None) -> bool:
@@ -1112,8 +1171,10 @@ def distributed_vdi_step(mesh: Mesh, tf: TransferFunction,
     """Build the jitted distributed VDI render step.
 
     Returns ``f(vol_data f32[D, H, W] (z-sharded), origin f32[3],
-    spacing f32[3], cam Camera) -> VDI`` whose color/depth are W-sharded
-    global arrays ([K_out, 4, height, width] / [K_out, 2, height, width]).
+    spacing f32[3], cam Camera) -> VDI`` whose color/depth are global
+    arrays ([K_out, 4, height, width] / [K_out, 2, height, width]),
+    sharded over their slots where `_frame_out` can (K_out % ranks == 0
+    on a flat one-process mesh), else over their width.
 
     ``topology`` (a config.TopologyConfig; docs/MULTIHOST.md) selects
     the two-level composite on a hierarchical ``(hosts, ranks)`` mesh —
@@ -1163,9 +1224,9 @@ def distributed_vdi_step(mesh: Mesh, tf: TransferFunction,
                                           sample_max=smax)
                 cs.append(vdi.color)
                 ds.append(vdi.depth)
-            return _composite_exchanged_sched(
+            return leave(_composite_exchanged_sched(
                 jnp.concatenate(cs, axis=0), jnp.concatenate(ds, axis=0),
-                n, axis, comp_cfg, topo=topo)
+                n, axis, comp_cfg, topo=topo))
         vol, cmin, cmax, smin, smax = _local_volume_and_clip(
             local_data, origin, spacing, d_global, axis, plan=plan)
         with _phase("march"):
@@ -1173,12 +1234,12 @@ def distributed_vdi_step(mesh: Mesh, tf: TransferFunction,
                                   max_steps=max_steps, clip_min=cmin,
                                   clip_max=cmax, sample_min=smin,
                                   sample_max=smax)
-        return _composite_exchanged_sched(vdi.color, vdi.depth, n, axis,
-                                          comp_cfg, topo=topo)
+        return leave(_composite_exchanged_sched(vdi.color, vdi.depth, n,
+                                                axis, comp_cfg, topo=topo))
 
-    w_axis = axis if topo is None else topo.out_axis
     spec_vol = P(axis, None, None)
-    spec_out = VDI(P(None, None, None, w_axis), P(None, None, None, w_axis))
+    spec_out, leave = _frame_out(mesh, axis, n, topo,
+                                 comp_cfg.max_output_supersegments)
     f = shard_map(step, mesh=mesh,
                   in_specs=(spec_vol, P(), P(), P()),
                   out_specs=spec_out, check_vma=False)
@@ -1613,7 +1674,10 @@ def distributed_vdi_step_mxu(mesh: Mesh, tf: TransferFunction,
     ``spec`` is the static `slicer.AxisSpec` for the *current camera
     regime* (march axis/sign + intermediate resolution); the session keeps
     one jitted step per regime. The output VDI lives on the virtual
-    axis camera's global pixel grid, sharded over its width (i) axis.
+    axis camera's global pixel grid. It is composited sharded over its
+    width (i) axis and leaves the mesh sharded over its SLOTS where
+    `_frame_out` can re-shard it (K_out % ranks == 0, flat one-process
+    mesh: blocks the host copies whole), else as composited.
 
     Domain decomposition is the same z-slab sharding as
     `distributed_vdi_step`; ownership of in-plane samples is half-open per
@@ -1666,7 +1730,7 @@ def _build_mxu_step(mesh, tf, spec, vdi_cfg, comp_cfg, axis_name,
     reuse = _resolve_reuse(comp_cfg, supported=bricks is None,
                            where="the brick-partitioned MXU step")
 
-    def body(local_data, origin, spacing, cam, thr, ru):
+    def composited(local_data, origin, spacing, cam, thr, ru):
         if bricks is not None:
             if waves:
                 out, meta, _, thr2 = _mxu_rank_generate_bricks_waves(
@@ -1694,9 +1758,14 @@ def _build_mxu_step(mesh, tf, spec, vdi_cfg, comp_cfg, axis_name,
                                      comp_cfg, topo=topo), meta, thr2,
                 ru2)
 
-    w_axis = axis if topo is None else topo.out_axis
+    def body(local_data, origin, spacing, cam, thr, ru):
+        out, meta, thr2, ru2 = composited(local_data, origin, spacing, cam,
+                                          thr, ru)
+        return leave(out), meta, thr2, ru2
+
     spec_vol = P(axis, None, None)
-    out_vdi = VDI(P(None, None, None, w_axis), P(None, None, None, w_axis))
+    out_vdi, leave = _frame_out(mesh, axis, n, topo,
+                                comp_cfg.max_output_supersegments)
     out_meta = VDIMetadata(*(P() for _ in VDIMetadata._fields))
 
     if temporal and reuse:

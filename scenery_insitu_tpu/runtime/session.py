@@ -451,7 +451,17 @@ class HostFrames:
     remaining reference, and every numpy view, slice or buffer export of
     an array that owns its data holds one (numpy collapses a view's
     ``base`` to the owner). A frame that a sink does keep costs one fresh
-    array, which the threads keep inside a four-rank frame's time."""
+    array, which the threads keep inside a four-rank frame's time.
+
+    What it copies depends on how the frame left the mesh. A VDI frame
+    leaves sharded over its LEADING (slot) axis wherever the step can
+    re-shard it (parallel/pipeline.py ``_frame_out``): each shard is one
+    contiguous piece of the whole, and the threads' tasks are whole
+    slots of it (6.5 MB each at 640 x 640). A frame sharded over its
+    minor axis (a plain image; a VDI whose slots the ranks do not
+    divide) is W/n-wide rows between the other shards' rows, the same
+    assignments at a fraction of the rate (157 MB as 640 B runs: 9 GB/s
+    on a v5e's host, PERF.md, PR 40)."""
 
     WORKERS = 16
 
@@ -1072,7 +1082,12 @@ class InSituSession:
         extra device wait of a recorded run), ``fetch.copy`` (attr
         ``bytes``; one per shard on a mesh, attr ``shard``), and
         ``fetch.concat`` around the host assembly of each sharded leaf
-        (attrs ``bytes`` and ``fresh``: the pool had to allocate)."""
+        (attrs ``bytes``, ``fresh``: the pool had to allocate, and
+        ``kmajor``: every shard's ``index`` leaves all axes but the
+        first whole, so the assembly is contiguous copies). Counters,
+        recorded or not: ``frames_fetched_sharded``, and
+        ``frames_fetched_kmajor`` for a frame whose every sharded leaf
+        came that way."""
         if self.obs.enabled:
             span = self.obs.span
             with span("fetch.ready", frame=index):
@@ -1088,7 +1103,7 @@ class InSituSession:
                 host = [np.asarray(leaf) for leaf in leaves]
             return jax.tree_util.tree_unflatten(treedef, host)
         self.obs.count("frames_fetched_sharded")
-        host = []
+        host, kmajor = [], True
         for leaf, split in zip(leaves, sharded):
             if not split:
                 with span("fetch.copy", frame=index, bytes=leaf.nbytes):
@@ -1100,12 +1115,21 @@ class InSituSession:
                     with span("fetch.copy", frame=index,
                               shard=sh.device.id, bytes=sh.data.nbytes):
                         parts.append((sh.index, np.asarray(sh.data)))
+            # blocks cut along the leading axis alone: each one is a
+            # contiguous piece of the whole, not rows of it
+            whole = all(cut.indices(size) == (0, size, 1)
+                        for ix, _ in parts
+                        for cut, size in zip(ix[1:], leaf.shape[1:]))
+            kmajor = kmajor and whole
             with span("fetch.concat", frame=index,
                       bytes=leaf.nbytes) as concat:
                 host.append(self._host_frames.assemble(
                     leaf.shape, leaf.dtype, parts))
                 if concat is not None:      # a recorded run
-                    concat.note(fresh=self._host_frames.last_fresh)
+                    concat.note(fresh=self._host_frames.last_fresh,
+                                kmajor=whole)
+        if kmajor:
+            self.obs.count("frames_fetched_kmajor")
         return jax.tree_util.tree_unflatten(treedef, host)
 
     def _fetch(self, index: int, out) -> dict:

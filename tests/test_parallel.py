@@ -107,17 +107,28 @@ def test_distributed_vdi_matches_single():
     assert psnr(ref, img) > 25.0, psnr(ref, img)
 
 
-def test_distributed_vdi_output_sharding():
+@pytest.mark.parametrize("k_out,spec", [
+    # the ranks divide the slots: the frame leaves the mesh slot-major,
+    # each rank holding whole rows of its K/n slots (pipeline._frame_out)
+    (16, P("ranks", None, None, None)),
+    # they do not: W-sharded as composited, each rank its column block
+    (5, P(None, None, None, "ranks"))])
+def test_distributed_vdi_output_sharding(k_out, spec):
+    from jax.sharding import NamedSharding
+
     mesh = make_mesh(2)
     vol = procedural_volume(8)
     step = distributed_vdi_step(mesh, _tf(), W, H,
                                 VDIConfig(max_supersegments=6,
                                           adaptive=False, threshold=0.1),
+                                CompositeConfig(
+                                    max_output_supersegments=k_out),
                                 max_steps=16)
     vdi = step(shard_volume(vol.data, mesh), vol.origin, vol.spacing, _cam())
-    # composited output is W-sharded: each rank owns its column block
-    spec = vdi.color.sharding.spec
-    assert spec[-1] == "ranks", spec
+    assert vdi.color.shape == (k_out, 4, H, W)
+    for leaf in (vdi.color, vdi.depth):
+        assert leaf.sharding.is_equivalent_to(NamedSharding(mesh, spec),
+                                              leaf.ndim), leaf.sharding
 
 
 def test_width_divisibility_check():

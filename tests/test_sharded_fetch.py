@@ -162,6 +162,78 @@ def test_host_frames_assemble_under_thread_switching():
     assert len(pool._bufs) <= 2
 
 
+def _blocks(want: np.ndarray, cut: str):
+    """``want`` as the parts a frame sharded over four ranks arrives in:
+    by its minor axis (`w`), by its leading axis (`k`: how a VDI frame
+    leaves the mesh, pipeline._frame_out), or whole (`one`)."""
+    full = (slice(None),) * want.ndim
+    if cut == "one":
+        return [(full, want.copy())]
+    axis = 0 if cut == "k" else want.ndim - 1
+    step = want.shape[axis] // 4
+    parts = []
+    for r in range(4):
+        index = list(full)
+        index[axis] = slice(r * step, (r + 1) * step)
+        parts.append((tuple(index), np.ascontiguousarray(want[tuple(index)])))
+    return parts
+
+
+@pytest.mark.parametrize("cut", ["w", "k", "one"])
+@pytest.mark.parametrize("shape", [(16, 4, 10, 32), (8, 2, 6, 8),
+                                   (4, 3, 5, 12)])
+def test_host_frames_assemble_any_disjoint_blocks(cut, shape):
+    """Column blocks, slot blocks and a single block: each assembly has
+    `np.asarray`'s bytes, is read-only, and takes the array of the one
+    before once nobody holds it."""
+    rng = np.random.default_rng(40)
+    pool = HostFrames()
+    idents = set()
+    for _ in range(3):
+        want = rng.random(shape).astype(np.float32)
+        got = pool.assemble(want.shape, want.dtype, _blocks(want, cut))
+        assert isinstance(got, np.ndarray) and not got.flags.writeable
+        assert got.flags.c_contiguous and got.dtype == want.dtype
+        assert got.tobytes() == want.tobytes()
+        idents.add(id(got))
+        del got
+    assert len(idents) == 1 and len(pool._bufs) == 1
+    held = pool.assemble(want.shape, want.dtype, _blocks(want, cut))
+    again = pool.assemble(want.shape, want.dtype, _blocks(2 * want, cut))
+    assert again is not held and held.tobytes() == want.tobytes()
+    assert again.tobytes() == (2 * want).tobytes()
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+@pytest.mark.parametrize("k_out,payload,kmajor", [
+    (8, "vdi", True),           # 8 slots over 4 ranks: slot blocks
+    (6, "vdi", False),          # 4 does not divide 6: column blocks
+    (8, "image", False)])       # a plain image: 4 channels lead
+def test_to_host_says_how_the_frame_left_the_mesh(k_out, payload, kmajor,
+                                                  enabled):
+    """`kmajor` on a recorded run's `fetch.concat` spans, and the counter
+    `frames_fetched_kmajor` recorded or not: true exactly for a frame
+    whose every sharded leaf is cut along its leading axis alone."""
+    extra, keys = _PAYLOADS[payload]
+    cfg = FrameworkConfig().with_overrides(
+        "render.width=32", "render.height=24", "render.max_steps=24",
+        "vdi.max_supersegments=6", "vdi.adaptive_iters=2",
+        f"composite.max_output_supersegments={k_out}",
+        "composite.adaptive_iters=2", "sim.grid=[16,16,16]",
+        "sim.steps_per_frame=2", f"obs.enabled={str(enabled).lower()}",
+        *[f"{k}={v}" for k, v in extra.items()])
+    sess = InSituSession(cfg, mesh=make_mesh(4), sinks=[lambda i, p: None])
+    last = sess.run(FRAMES)
+    for key in keys:
+        assert not last[key].flags.writeable
+    assert sess.obs.counters["frames_fetched_sharded"] == FRAMES
+    assert sess.obs.counters.get("frames_fetched_kmajor", 0) == \
+        (FRAMES if kmajor else 0)
+    said = [e["attrs"]["kmajor"] for e in sess.obs.events
+            if e["type"] == "span" and e["name"] == "fetch.concat"]
+    assert said == ([kmajor] * (FRAMES * len(keys)) if enabled else [])
+
+
 def test_fetch_concat_says_when_the_pool_allocates():
     """`fresh` on a recorded run's `fetch.concat` spans: true on the first
     frame from the mesh and on the frame after one a sink kept, false on

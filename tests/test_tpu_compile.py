@@ -88,3 +88,66 @@ def test_the_four_rank_frame_program_compiles_with_both_branches(
     assert "collective-permute" in text
     # the fallback's 24-wide cells are the program's temp, window or not
     assert compiled.memory_analysis().temp_size_in_bytes < 8e9
+
+
+def test_the_four_rank_frame_step_hands_its_frame_out_slot_major(
+        topo, monkeypatch):
+    """`vortex256-4rank`'s step program (march + fold + column exchange +
+    composite, 64 planes a rank, 320 x 320, K = 16) for the 2x2 as a TPU
+    builds it: after the composite one more `all-to-all` a leaf under the
+    `exchange` scope, and the frame leaves as f32[4, 4|2, 320, 320] a
+    rank, `P(ranks, None, None, None)`; where the ranks do not divide
+    the slots, the two column exchanges alone and W-blocks."""
+    from scenery_insitu_tpu.config import FrameworkConfig
+    from scenery_insitu_tpu.core.camera import Camera
+    from scenery_insitu_tpu.core.transfer import for_dataset
+    from scenery_insitu_tpu.ops import slicer
+    from scenery_insitu_tpu.parallel import pipeline
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = FrameworkConfig().with_overrides(
+        "sim.kind=vortex", f"sim.grid=[{PLANES * RANKS},{Y},{X}]",
+        "slicer.engine=mxu", "vdi.adaptive_mode=temporal",
+        "vdi.max_supersegments=16")
+    mesh = Mesh(np.array(topo.devices[:RANKS]), ("ranks",))
+    on = lambda spec: NamedSharding(mesh, spec)
+    like = lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.asarray(x).dtype,
+                                          sharding=on(P()))
+    cam = Camera.create((0.0, 0.6, 3.0), fov_y_deg=50.0, near=0.3, far=20.0)
+    grid = tuple(cfg.sim.grid)
+    spec = slicer.make_spec(cam, grid, cfg.slicer,
+                            axis_sign=slicer.choose_axis(cam),
+                            multiple_of=RANKS)
+    args = (jax.ShapeDtypeStruct(grid, jnp.float32,
+                                 sharding=on(P("ranks", None, None))),
+            like(np.zeros(3, np.float32)),
+            like(np.full(3, 2.0 / max(grid), np.float32)),
+            jax.tree_util.tree_map(like, cam))
+    tf = for_dataset("vortex")
+    seed = pipeline.distributed_initial_threshold_mxu(mesh, tf, spec,
+                                                      cfg.vdi)
+    thr = jax.tree_util.tree_map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(seed, *args),
+        seed.lower(*args).compile().output_shardings)
+    for k_out, slot_major in ((16, True), (6, False)):
+        comp = cfg.composite.__class__(max_output_supersegments=k_out)
+        compiled = pipeline.distributed_vdi_step_mxu_temporal(
+            mesh, tf, spec, cfg.vdi, comp).lower(*args, thr).compile()
+        text = compiled.as_text()
+        a2a = re.findall(r"= (\S+) all-to-all\(.*op_name=\"([^\"]*)\"",
+                         text)
+        assert all("sitpu_exchange" in name for _, name in a2a)
+        (vdi, _), _ = compiled.output_shardings
+        want = (P("ranks", None, None, None) if slot_major
+                else P(None, None, None, "ranks"))
+        for leaf in (vdi.color, vdi.depth):
+            assert leaf.is_equivalent_to(on(want), 4)
+        assert len(a2a) == (4 if slot_major else 2), a2a
+        out = spec.ni // RANKS
+        if slot_major:
+            assert f"f32[{k_out // RANKS},4,{spec.nj},{spec.ni}]" in text
+            # what crosses the ICI is the unpadded H-minor block
+            assert sum(f"[{k_out},4,{spec.nj},{out}]" in shape
+                       or f"[{k_out},2,{spec.nj},{out}]" in shape
+                       for shape, _ in a2a) == 2

@@ -380,6 +380,10 @@ _COUNTER_REGISTRY: Dict[str, str] = {
     "sim_halo_exchanges": "one fused stencil pass on a z-sharded field "
                           "took its outer z halo from the ring "
                           "neighbours (recorded runs only)",
+    "sim_state_shards_built": "field shards of a volume sim's start "
+                              "placed where the state lives, under the "
+                              "`sim.build` span (count = shards of all "
+                              "fields; recorded runs only)",
     "sink_failures": "a frame/tile sink or steering callback raised",
     "sinks_quarantined": "a sink was disabled after repeated "
                          "consecutive failures",

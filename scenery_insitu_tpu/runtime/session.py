@@ -221,6 +221,17 @@ def _sharded_sim(mesh) -> bool:
     return mesh is not None and mesh.devices.size > 1
 
 
+def _field_sharding(mesh, axis=None, ndim: int = 3):
+    """Where a sim field f32[..., D, H, W] lives on a multi-rank mesh:
+    z-sharded over the flat rank axis, as `shard_volume` shards the
+    rendered field."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    axis = axis or mesh.axis_names[0]
+    return NamedSharding(mesh, P(*([None] * (ndim - 3)
+                                   + [axis, None, None])))
+
+
 def _place_sim_state(state, mesh, axis=None):
     """Place a volume-sim state pytree on a multi-rank mesh: fields
     (f32[D, H, W], f32[3, D, H, W]) z-sharded like `shard_volume` shards
@@ -229,12 +240,9 @@ def _place_sim_state(state, mesh, axis=None):
     does not recompile it for a changed input placement."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    axis = axis or mesh.axis_names[0]
-
     def place(x):
-        spec = (P(*([None] * (x.ndim - 3) + [axis, None, None]))
-                if x.ndim >= 3 else P())
-        return jax.device_put(x, NamedSharding(mesh, spec))
+        return jax.device_put(x, _field_sharding(mesh, axis, x.ndim)
+                              if x.ndim >= 3 else NamedSharding(mesh, P()))
 
     return jax.tree_util.tree_map(place, state)
 
@@ -251,7 +259,12 @@ class VolumeSimAdapter:
         self.kind = kind
         sharded = _sharded_sim(mesh)
         if kind == "gray_scott":
-            st = gs.GrayScott.from_config(cfg.sim, seed=seed)
+            # born z-sharded where the state lives so (`_seed_cubes`):
+            # no device ever holds more of the start than its own slab
+            st = self._built(lambda: gs.GrayScott.from_config(
+                cfg.sim, seed=seed,
+                sharding=_field_sharding(mesh, axis) if sharded else None),
+                mesh, axis, obs)
             # fused_stencil routes through the time-fused Pallas kernel
             # on TPU (T steps per HBM round trip of u, v), which reads
             # from the state's placement whether its z halos are the
@@ -264,8 +277,9 @@ class VolumeSimAdapter:
             self._advance = lambda s, n: (step(s, n), None, None)
             self._render = lambda s: (s, s.field)
         elif kind == "vortex":
-            st = vx.VortexFlow.init_ring(tuple(cfg.sim.grid),
-                                         vx.VortexParams.create(dt=cfg.sim.dt))
+            st = self._built(lambda: vx.VortexFlow.init_ring(
+                tuple(cfg.sim.grid), vx.VortexParams.create(dt=cfg.sim.dt)),
+                mesh, axis, obs)
             # ONE program per frame hands back (u, field, windows) in
             # the placements it took: n steps, then |curl u| and its
             # normalisation, and what each step's back-trace read
@@ -291,7 +305,32 @@ class VolumeSimAdapter:
         # the vortex frames' `windows` that nobody has read yet, oldest
         # first, and whether a step's window has given way before
         self._windows, self._gave_way = deque(), False
-        self.state = _place_sim_state(st, mesh, axis) if sharded else st
+        self.state = st
+
+    @staticmethod
+    def _built(make, mesh, axis, rec):
+        """The start ``make()`` builds, placed where the state lives
+        (`_place_sim_state`: a no-op for leaves that were born there),
+        under the `sim.build` span: attrs ``devices`` and
+        ``bytes_per_device`` (the largest device's share of the leaves),
+        counter ``sim_state_shards_built`` (field shards placed)."""
+        with (rec.span("sim.build", frame=0) if rec is not None
+              else contextlib.nullcontext()) as span:
+            st = make()
+            if _sharded_sim(mesh):
+                st = _place_sim_state(st, mesh, axis)
+            if rec is not None and rec.enabled:
+                held, shards = {}, 0
+                for x in jax.tree_util.tree_leaves(st):
+                    if x.ndim >= 3:
+                        shards += len(x.addressable_shards)
+                    for sh in x.addressable_shards:
+                        held[sh.device] = (held.get(sh.device, 0)
+                                           + sh.data.nbytes)
+                span.note(devices=len(held),
+                          bytes_per_device=max(held.values()))
+                rec.count("sim_state_shards_built", shards)
+        return st
 
     @property
     def state(self):

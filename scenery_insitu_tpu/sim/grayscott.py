@@ -13,7 +13,7 @@ device and on a z-sharded state alike.
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 from typing import NamedTuple, Tuple
 
 import jax
@@ -45,40 +45,64 @@ class GrayScott(NamedTuple):
 
     @classmethod
     def init(cls, grid: Tuple[int, int, int], params: GrayScottParams = None,
-             seed: int = 0, n_seeds: int = 4) -> "GrayScott":
+             seed: int = 0, n_seeds: int = 4, sharding=None) -> "GrayScott":
         """Uniform u=1, v=0 with one central seed cube (quarter-width — small
-        seeds diffuse away in 3D) plus ``n_seeds`` random satellite cubes."""
+        seeds diffuse away in 3D) plus ``n_seeds`` random satellite cubes.
+
+        u and v are born in ``sharding`` (default: whole, on the default
+        device) by ONE jitted program, `_seed_cubes`: with a
+        ``NamedSharding(mesh, P(axis, None, None))`` every rank writes its
+        own z-slab and nothing else, so a grid no single device could
+        hold (1024^3 on four chips: 2.15 GB of state a rank) has a start;
+        the values are the same whatever the sharding."""
         d, h, w = grid
-        u = jnp.ones(grid, jnp.float32)
-        v = jnp.zeros(grid, jnp.float32)
-        zz, yy, xx = jnp.meshgrid(jnp.arange(d), jnp.arange(h),
-                                  jnp.arange(w), indexing="ij")
-
-        def stamp(u, v, c, r):
-            mask = ((jnp.abs(zz - c[0]) < r) & (jnp.abs(yy - c[1]) < r)
-                    & (jnp.abs(xx - c[2]) < r))
-            return jnp.where(mask, 0.5, u), jnp.where(mask, 0.25, v)
-
-        u, v = stamp(u, v, (d // 2, h // 2, w // 2), max(min(d, h, w) // 4, 2))
-        key = jax.random.PRNGKey(seed)
         rs = max(min(d, h, w) // 8, 2)
-        for k in jax.random.split(key, n_seeds):
-            c = jax.random.randint(k, (3,), rs,
-                                   jnp.array([d - rs, h - rs, w - rs]))
-            u, v = stamp(u, v, c, rs)
+        hi = jnp.array([d - rs, h - rs, w - rs])
+        centers = [jnp.array([d // 2, h // 2, w // 2])] + [
+            jax.random.randint(k, (3,), rs, hi)
+            for k in jax.random.split(jax.random.PRNGKey(seed), n_seeds)]
+        radii = [max(min(d, h, w) // 4, 2)] + [rs] * n_seeds
+        u, v = _seed_cubes(tuple(grid), sharding)(
+            jnp.stack(centers).astype(jnp.int32),
+            jnp.array(radii, jnp.int32))
         return cls(u, v, params or GrayScottParams.create())
 
     @classmethod
-    def from_config(cls, cfg: SimConfig, seed: int = 0) -> "GrayScott":
+    def from_config(cls, cfg: SimConfig, seed: int = 0,
+                    sharding=None) -> "GrayScott":
         return cls.init(tuple(cfg.grid),
                         GrayScottParams.create(cfg.gs_f, cfg.gs_k,
                                                cfg.gs_du, cfg.gs_dv, cfg.dt),
-                        seed=seed)
+                        seed=seed, sharding=sharding)
 
     @property
     def field(self) -> jnp.ndarray:
         """The scalar field rendered in-situ (v concentration, ≈[0, 1])."""
         return self.v
+
+
+@lru_cache(maxsize=8)       # a process builds a handful: bounded
+def _seed_cubes(grid, sharding=None):
+    """The program that builds the Gray-Scott start: ``(centers i32[n, 3],
+    radii i32[n]) -> (u, v)`` f32[grid], u = 1 and v = 0, and u = 0.5,
+    v = 0.25 inside any of the cubes |index - centers[i]| < radii[i]
+    (traced, so one program serves every seed). Each cell is decided
+    from its own index — three `iota`s that fuse into the selects, no
+    index volume — and u and v leave in ``sharding`` (`out_shardings`),
+    so the partitioner gives every device its own block of the iotas
+    and of nothing larger."""
+    def seed_cubes(centers, radii):
+        zz, yy, xx = (jax.lax.broadcasted_iota(jnp.int32, grid, a)
+                      for a in range(3))
+        inside = jnp.zeros(grid, bool)
+        for i in range(centers.shape[0]):
+            c, r = centers[i], radii[i]
+            inside |= ((jnp.abs(zz - c[0]) < r) & (jnp.abs(yy - c[1]) < r)
+                       & (jnp.abs(xx - c[2]) < r))
+        return (jnp.where(inside, jnp.float32(0.5), jnp.float32(1.0)),
+                jnp.where(inside, jnp.float32(0.25), jnp.float32(0.0)))
+
+    return jax.jit(seed_cubes, out_shardings=sharding)
 
 
 def _laplacian(x: jnp.ndarray) -> jnp.ndarray:

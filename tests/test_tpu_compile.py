@@ -90,25 +90,18 @@ def test_the_four_rank_frame_program_compiles_with_both_branches(
     assert compiled.memory_analysis().temp_size_in_bytes < 8e9
 
 
-def test_the_four_rank_frame_step_hands_its_frame_out_slot_major(
-        topo, monkeypatch):
-    """`vortex256-4rank`'s step program (march + fold + column exchange +
-    composite, 64 planes a rank, 320 x 320, K = 16) for the 2x2 as a TPU
-    builds it: after the composite one more `all-to-all` a leaf under the
-    `exchange` scope, and the frame leaves as f32[4, 4|2, 320, 320] a
-    rank, `P(ranks, None, None, None)`; where the ranks do not divide
-    the slots, the two column exchanges alone and W-blocks."""
-    from scenery_insitu_tpu.config import FrameworkConfig
+def four_rank_step(topo, cfg, dataset: str):
+    """What lowering a four-rank temporal MXU step for the 2x2 takes, as
+    a session on a TPU has it: ``(mesh, tf, spec, args, seeded, thr)`` —
+    ``args`` the z-sharded field, origin, spacing and the default camera
+    as shapes on the mesh, ``seeded`` the compiled threshold seeder and
+    ``thr`` its output as shapes in the shardings it leaves in. The
+    caller has set `jax.default_backend` to say "tpu"."""
     from scenery_insitu_tpu.core.camera import Camera
     from scenery_insitu_tpu.core.transfer import for_dataset
     from scenery_insitu_tpu.ops import slicer
     from scenery_insitu_tpu.parallel import pipeline
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = FrameworkConfig().with_overrides(
-        "sim.kind=vortex", f"sim.grid=[{PLANES * RANKS},{Y},{X}]",
-        "slicer.engine=mxu", "vdi.adaptive_mode=temporal",
-        "vdi.max_supersegments=16")
     mesh = Mesh(np.array(topo.devices[:RANKS]), ("ranks",))
     on = lambda spec: NamedSharding(mesh, spec)
     like = lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.asarray(x).dtype,
@@ -123,13 +116,34 @@ def test_the_four_rank_frame_step_hands_its_frame_out_slot_major(
             like(np.zeros(3, np.float32)),
             like(np.full(3, 2.0 / max(grid), np.float32)),
             jax.tree_util.tree_map(like, cam))
-    tf = for_dataset("vortex")
+    tf = for_dataset(dataset)
     seed = pipeline.distributed_initial_threshold_mxu(mesh, tf, spec,
                                                       cfg.vdi)
+    seeded = seed.lower(*args).compile()
     thr = jax.tree_util.tree_map(
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-        jax.eval_shape(seed, *args),
-        seed.lower(*args).compile().output_shardings)
+        jax.eval_shape(seed, *args), seeded.output_shardings)
+    return mesh, tf, spec, args, seeded, thr
+
+
+def test_the_four_rank_frame_step_hands_its_frame_out_slot_major(
+        topo, monkeypatch):
+    """`vortex256-4rank`'s step program (march + fold + column exchange +
+    composite, 64 planes a rank, 320 x 320, K = 16) for the 2x2 as a TPU
+    builds it: after the composite one more `all-to-all` a leaf under the
+    `exchange` scope, and the frame leaves as f32[4, 4|2, 320, 320] a
+    rank, `P(ranks, None, None, None)`; where the ranks do not divide
+    the slots, the two column exchanges alone and W-blocks."""
+    from scenery_insitu_tpu.config import FrameworkConfig
+    from scenery_insitu_tpu.parallel import pipeline
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = FrameworkConfig().with_overrides(
+        "sim.kind=vortex", f"sim.grid=[{PLANES * RANKS},{Y},{X}]",
+        "slicer.engine=mxu", "vdi.adaptive_mode=temporal",
+        "vdi.max_supersegments=16")
+    mesh, tf, spec, args, _, thr = four_rank_step(topo, cfg, "vortex")
+    on = lambda spec: NamedSharding(mesh, spec)
     for k_out, slot_major in ((16, True), (6, False)):
         comp = cfg.composite.__class__(max_output_supersegments=k_out)
         compiled = pipeline.distributed_vdi_step_mxu_temporal(
@@ -151,3 +165,109 @@ def test_the_four_rank_frame_step_hands_its_frame_out_slot_major(
             assert sum(f"[{k_out},4,{spec.nj},{out}]" in shape
                        or f"[{k_out},2,{spec.nj},{out}]" in shape
                        for shape, _ in a2a) == 2
+
+
+# gs1024-4rank (PR 41): 1024^3 over the 2x2, 256 planes a rank, a
+# 1280 x 1280 intermediate grid, K = 16
+GS_GRID, GS_OVERRIDES = (1024, 1024, 1024), (
+    "sim.grid=[1024,1024,1024]", "sim.steps_per_frame=10",
+    "slicer.engine=mxu", "vdi.adaptive_mode=temporal",
+    "vdi.max_supersegments=16", "composite.max_output_supersegments=16",
+    "runtime.dataset=gray_scott", "mesh.num_devices=4")
+CHIP_BYTES = 16e9
+
+
+def device_bytes(compiled) -> int:
+    """What one device holds while the program runs, by the compiler's
+    own account: arguments + outputs + temps."""
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes)
+
+
+def test_the_1024_cube_start_is_born_in_shards(topo):
+    """The program `GrayScott.init` runs for gs1024-4rank's state: every
+    device writes its own 256 planes of u and v and holds nothing else —
+    no index volume, no whole-grid array (8.59 GB would not fit)."""
+    from scenery_insitu_tpu.sim import grayscott as gs
+
+    mesh = Mesh(np.array(topo.devices[:RANKS]), ("ranks",))
+    field = NamedSharding(mesh, P("ranks", None, None))
+    small = lambda s: jax.ShapeDtypeStruct(s, jnp.int32,
+                                           sharding=NamedSharding(mesh, P()))
+    compiled = gs._seed_cubes(GS_GRID, field).lower(
+        small((5, 3)), small((5,))).compile()
+    d, h, w = GS_GRID
+    m = compiled.memory_analysis()
+    # u and v of one rank's 256 planes, and the tuple that names them
+    assert 0 <= m.output_size_in_bytes - 2 * (d // RANKS) * h * w * 4 \
+        < 4096
+    assert m.argument_size_in_bytes < 4096
+    assert m.temp_size_in_bytes < 4 * h * w     # under one f32 plane
+    for leaf in compiled.output_shardings:
+        assert leaf.is_equivalent_to(field, 3)
+    assert f"f32[{d // RANKS},{h},{w}]" in compiled.as_text()
+    assert f"[{d},{h},{w}]" not in compiled.as_text()
+
+
+def test_the_1024_cube_sim_program_fits_a_chip(topo):
+    """gs1024-4rank's sim program for the 2x2 as a TPU builds it: the
+    fused stencil at tiles (16, 64) on a 256 x 1024 x 1024 shard, which
+    Mosaic had never compiled, with its ring halos; under 16 GB a
+    device."""
+    from scenery_insitu_tpu.sim import pallas_stencil as ps
+
+    shard = (GS_GRID[0] // RANKS,) + GS_GRID[1:]
+    assert ps.schedule(shard, 10, ring=True) == (
+        (("2d", 4, 16, 64, 2), ("2d", 2, 16, 64, 1)), 0)
+    mesh = Mesh(np.array(topo.devices[:RANKS]), ("ranks",))
+    field = jax.ShapeDtypeStruct(
+        GS_GRID, jnp.float32,
+        sharding=NamedSharding(mesh, P("ranks", None, None)))
+    scalar = jax.ShapeDtypeStruct((), jnp.float32,
+                                  sharding=NamedSharding(mesh, P()))
+    compiled = ps.multi_step_pallas_sharded.lower(
+        field, field, (scalar,) * 5, n=10, mesh=mesh,
+        axis="ranks").compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3       # T = 4, 4, 2
+    assert "collective-permute" in text
+    assert device_bytes(compiled) < CHIP_BYTES
+    # u and v in, u and v out, and no more than one more state of temps
+    assert device_bytes(compiled) < 3.5 * 2 * 4 * np.prod(shard)
+
+
+def test_the_1024_cube_step_program_fits_a_chip(topo, monkeypatch):
+    """gs1024-4rank's step program (march + fold + column exchange + sort
+    + composite + slot exchange at 1280 x 1280, K = 16) for the 2x2 as a
+    TPU builds it: every kernel serves the new widths — nothing on the
+    fallback ledger — and the program stays under 16 GB a device, beside
+    the state and the sim program's output."""
+    from scenery_insitu_tpu import obs
+    from scenery_insitu_tpu.config import FrameworkConfig
+    from scenery_insitu_tpu.parallel import pipeline
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    obs.clear_ledger()
+    cfg = FrameworkConfig().with_overrides(*GS_OVERRIDES)
+    mesh, tf, spec, args, seeded, thr = four_rank_step(topo, cfg,
+                                                       "gray_scott")
+    on = lambda spec: NamedSharding(mesh, spec)
+    assert (spec.ni, spec.nj) == (1280, 1280)
+    compiled = pipeline.distributed_vdi_step_mxu_temporal(
+        mesh, tf, spec, cfg.vdi, cfg.composite,
+        reuse_tol=cfg.delta.range_tol).lower(*args, thr).compile()
+    text = compiled.as_text()
+    assert "sitpu_fold_seg_compact" in text
+    assert "sitpu_resegment_sorted" in text
+    assert obs.ledger() == []
+    state = 2 * 4 * np.prod(GS_GRID) // RANKS          # u and v, a rank
+    sim_out_and_temps = 2 * state + 0.1e9
+    for program in (seeded, compiled):
+        # its own arguments hold the field (half the state) already
+        assert (device_bytes(program) + state / 2 + sim_out_and_temps
+                < CHIP_BYTES)
+    (vdi, _), _ = compiled.output_shardings
+    for leaf in (vdi.color, vdi.depth):
+        assert leaf.is_equivalent_to(on(P("ranks", None, None, None)), 4)
+    assert "f32[4,4,1280,1280]" in text

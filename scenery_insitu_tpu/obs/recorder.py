@@ -303,6 +303,17 @@ _COUNTER_REGISTRY: Dict[str, str] = {
                               "there (InSituSession._to_host)",
     "head_degraded_frames": "the head composited a frame with >= 1 rank "
                             "missing (degraded flag set)",
+    "host_minor_faults": "minor page faults of the whole process over "
+                         "the frame loop's iterations (getrusage; bumped "
+                         "once a frame by a RECORDED run only, with the "
+                         "second `upkeep` span's `minflt_frame`, and only "
+                         "where the kernel counts faults: obs/hostmem.py)",
+    "host_pages_touched": "pages the process's resident set grew by over "
+                          "the frame loop's iterations (/proc/self/statm, "
+                          "growth summed over the reading intervals; "
+                          "bumped once a frame by a RECORDED run only, "
+                          "with the second `upkeep` span's "
+                          "`touched_frame`)",
     "head_ranks_down": "head liveness marked a render rank silent",
     "head_ranks_readmitted": "a silent render rank resumed and was "
                              "readmitted to the composite",

@@ -36,6 +36,7 @@ from scenery_insitu_tpu.config import FrameworkConfig
 from scenery_insitu_tpu.core.camera import Camera, orbit
 from scenery_insitu_tpu.core.transfer import TransferFunction, for_dataset
 from scenery_insitu_tpu.core.vdi import VDI
+from scenery_insitu_tpu.obs.hostmem import PAGE, host_pages
 from scenery_insitu_tpu.obs.profiler import scoped_step
 from scenery_insitu_tpu.parallel.topology import (make_topology_mesh,
                                                   resolve_mesh_topology)
@@ -101,10 +102,11 @@ def camera_regime(sess, site: str):
         return sess._slicer.choose_axis(sess.camera)
 
 
-def drain_steering(sess) -> None:
+def drain_steering(sess, launch=None) -> None:
     """Apply all pending steering messages to ``sess``. Shared by
     InSituSession and SceneSession so the steering protocol has ONE
-    consumer (`steer_session`).
+    consumer (`steer_session`). ``launch``: a recorded caller's
+    ``prev_ready`` reader, asked as the ``steer`` span opens.
 
     A camera message is the one request a viewer sends, so each one
     applied takes the next number (``sess._steer_seq``): the frames
@@ -117,6 +119,8 @@ def drain_steering(sess) -> None:
         return
     rec = sess.obs.enabled
     with sess.obs.span("steer", frame=sess.frame_index) as span:
+        if launch is not None:
+            span.note(prev_ready=launch())
         msgs, seq0, t_drain = 0, sess._steer_seq, None
         for msg in sess.steering.drain():
             if rec and t_drain is None:
@@ -876,10 +880,15 @@ class InSituSession:
     def render_frame(self):
         """Advance the sim and dispatch one render step (device arrays)."""
         rec = self.obs.enabled
-        drain_steering(self)
+        # each span a recorded launch can be held in says whether the
+        # device had finished the newest frame in flight as it opened
+        # (`_prev_ready`: no read in a run that records nothing)
+        drain_steering(self, self._prev_ready if rec else None)
         self._maybe_replan()
         with self.obs.span("sim", frame=self.frame_index,
-                           kind=self.sim.kind):
+                           kind=self.sim.kind) as sim:
+            if rec:
+                sim.note(prev_ready=self._prev_ready())
             self.sim.advance(self.cfg.sim.steps_per_frame)
         # what a recorded launch says of itself: the camera message it
         # renders from, and what was in flight when it was made (two
@@ -911,16 +920,24 @@ class InSituSession:
             self._frame_dispatched(meta)
         return out
 
+    @staticmethod
+    def _ready(out) -> bool:
+        """Every leaf of ``out`` answers ``is_ready()``; nothing waits."""
+        return all(leaf.is_ready() for leaf in
+                   jax.tree_util.tree_leaves(out))
+
     def _prev_ready(self) -> bool:
         """Whether the device has finished the newest frame in flight
-        (every leaf answers ``is_ready()``; nothing waits): its
-        device->host copy, started at its dispatch, is then under way or
-        done, and the launch that reads this found the device idle. False
-        when nothing is in flight."""
-        if not self._pending:
-            return False
-        return all(leaf.is_ready() for leaf in
-                   jax.tree_util.tree_leaves(self._pending[-1][1]))
+        (`_ready`): its device->host copy, started at its dispatch, is
+        then under way or done, and the launch that reads this found the
+        device idle. False when nothing is in flight."""
+        return bool(self._pending) and self._ready(self._pending[-1][1])
+
+    def _beside(self) -> bool:
+        """Whether a frame NEWER than the one being fetched still has
+        programs in flight (the retired frame has left ``_pending``): a
+        transfer that runs now runs beside them."""
+        return bool(self._pending) and not self._ready(self._pending[-1][1])
 
     def _frame_dispatched(self, meta) -> None:
         """The loop's bookkeeping after a dispatch (a recorded run's
@@ -968,6 +985,7 @@ class InSituSession:
                 # body: it would cover every idle gap of a trace), in any
                 # other run under none
                 rec, span = self.obs.enabled, self.obs.span
+                pages = host_pages() if rec else None
 
                 def retire() -> None:
                     """Retire the oldest frame in flight. Where that
@@ -975,7 +993,10 @@ class InSituSession:
                     first, by name: a rebind would free it (157 MB at
                     512^3 where no sink kept it) between two spans. A
                     recorded run also lets the retired frame's device
-                    arrays go by name (on four chips 1.1 ms a frame)."""
+                    arrays go by name (on four chips 1.1 ms a frame), and
+                    both ``release`` spans say what the process's memory
+                    did meanwhile (``rss_pages``, ``minflt``:
+                    obs/hostmem.py)."""
                     nonlocal payload
                     index, consume = pending[0][0], pending[0][2]
                     if not rec:
@@ -987,13 +1008,20 @@ class InSituSession:
                     if consume:
                         with span("release", frame=index, bytes=sum(
                                 v.nbytes for v in payload.values()
-                                if isinstance(v, np.ndarray))):
+                                if isinstance(v, np.ndarray))) as gone:
+                            before = pages.read()
                             payload = None
+                            gone.note(**pages.since(before))
                     entry = pending.popleft()
                     payload = self._retire(entry, fetch, payload)
-                    with span("release", frame=index, device=True):
+                    with span("release", frame=index, device=True) as gone:
+                        before = pages.read()
                         del entry
+                        gone.note(**pages.since(before))
 
+                if rec:
+                    at_start = pages.read()
+                    pages.take_grown()
                 for i in range(frames):
                     t_f = time.perf_counter()
                     out = self.render_frame()
@@ -1017,8 +1045,22 @@ class InSituSession:
                     while len(pending) > depth:
                         retire()
                     if rec:
-                        with span("upkeep", frame=index):
+                        # the pages the iteration touched for the first
+                        # time, whoever touched them (the runtime's
+                        # transfer threads do under no span of the loop):
+                        # from the end of the iteration before to the end
+                        # of this one
+                        with span("upkeep", frame=index) as kept:
                             self._upkeep(t_f)
+                            now = pages.read()
+                            found = pages.delta(at_start, now, "_frame")
+                            at_start, touched = now, pages.take_grown()
+                            kept.note(touched_frame=touched, page=PAGE,
+                                      **found)
+                            self.obs.count("host_pages_touched", touched)
+                            if "minflt_frame" in found:
+                                self.obs.count("host_minor_faults",
+                                               found["minflt_frame"])
                     else:
                         self._upkeep(t_f)
                 while pending:
@@ -1119,41 +1161,63 @@ class InSituSession:
         ``obs.enabled`` only decides whether spans open: ``fetch.ready``
         (``jax.block_until_ready``: the frame's device programs; the one
         extra device wait of a recorded run), ``fetch.copy`` (attr
-        ``bytes``; one per shard on a mesh, attr ``shard``), and
-        ``fetch.concat`` around the host assembly of each sharded leaf
-        (attrs ``bytes``, ``fresh``: the pool had to allocate, and
-        ``kmajor``: every shard's ``index`` leaves all axes but the
-        first whole, so the assembly is contiguous copies). Counters,
-        recorded or not: ``frames_fetched_sharded``, and
-        ``frames_fetched_kmajor`` for a frame whose every sharded leaf
-        came that way."""
+        ``bytes``; one per shard on a mesh, attr ``shard``; and the
+        transfer's own account: ``waited``, the frame's programs were
+        still running when ``fetch.ready`` was entered, so its end IS the
+        transfer's start and the end of the frame's last ``fetch.copy``
+        its end; ``beside0`` / ``beside1``, a newer frame's programs
+        were in flight when this copy's wait began / ended: `_beside`),
+        and ``fetch.concat`` around the host assembly of each sharded
+        leaf (attrs ``bytes``, ``rss_pages`` / ``minflt``, ``fresh``: the
+        pool had to allocate, and ``kmajor``: every shard's ``index``
+        leaves all axes but the first whole, so the assembly is contiguous
+        copies). Counters, recorded or not: ``frames_fetched_sharded``,
+        and ``frames_fetched_kmajor`` for a frame whose every sharded
+        leaf came that way."""
         if self.obs.enabled:
             span = self.obs.span
+            pages = host_pages()
+            waited = not self._ready(out)
             with span("fetch.ready", frame=index):
                 jax.block_until_ready(out)
+            beside = self._beside()
+
+            def copied(copy) -> None:
+                """The transfer's own account on a ``fetch.copy`` span
+                that has its bytes."""
+                nonlocal beside
+                before, beside = beside, self._beside()
+                copy.note(waited=waited, beside0=before, beside1=beside)
         else:
-            span = _no_span
+            span, copied, pages = _no_span, None, None
         leaves, treedef = jax.tree_util.tree_flatten(out)
         sharded = [isinstance(leaf, jax.Array) and leaf.is_fully_addressable
                    and not leaf.is_fully_replicated for leaf in leaves]
         if not any(sharded):
             with span("fetch.copy", frame=index,
-                      bytes=sum(leaf.nbytes for leaf in leaves)):
+                      bytes=sum(leaf.nbytes for leaf in leaves)) as copy:
                 host = [np.asarray(leaf) for leaf in leaves]
+                if copy is not None:        # a recorded run
+                    copied(copy)
             return jax.tree_util.tree_unflatten(treedef, host)
         self.obs.count("frames_fetched_sharded")
         host, kmajor = [], True
         for leaf, split in zip(leaves, sharded):
             if not split:
-                with span("fetch.copy", frame=index, bytes=leaf.nbytes):
+                with span("fetch.copy", frame=index,
+                          bytes=leaf.nbytes) as copy:
                     host.append(np.asarray(leaf))
+                    if copy is not None:
+                        copied(copy)
                 continue
             parts = []
             for sh in leaf.addressable_shards:
                 if sh.replica_id == 0:
-                    with span("fetch.copy", frame=index,
-                              shard=sh.device.id, bytes=sh.data.nbytes):
+                    with span("fetch.copy", frame=index, shard=sh.device.id,
+                              bytes=sh.data.nbytes) as copy:
                         parts.append((sh.index, np.asarray(sh.data)))
+                        if copy is not None:
+                            copied(copy)
             # blocks cut along the leading axis alone: each one is a
             # contiguous piece of the whole, not rows of it
             whole = all(cut.indices(size) == (0, size, 1)
@@ -1162,11 +1226,12 @@ class InSituSession:
             kmajor = kmajor and whole
             with span("fetch.concat", frame=index,
                       bytes=leaf.nbytes) as concat:
+                before = pages.read() if pages is not None else None
                 host.append(self._host_frames.assemble(
                     leaf.shape, leaf.dtype, parts))
                 if concat is not None:      # a recorded run
                     concat.note(fresh=self._host_frames.last_fresh,
-                                kmajor=whole)
+                                kmajor=whole, **pages.since(before))
         if kmajor:
             self.obs.count("frames_fetched_kmajor")
         return jax.tree_util.tree_unflatten(treedef, host)
@@ -1179,8 +1244,11 @@ class InSituSession:
         tiles = ()
         tiled = bool(self.tile_sinks) \
             and self.cfg.composite.schedule == "waves"
-        with self.obs.span("fetch", frame=index, steer_seq=steer_seq):
-            if self._n_ranks > 1 or self.obs.enabled:
+        rec = self.obs.enabled
+        with self.obs.span("fetch", frame=index,
+                           steer_seq=steer_seq) as fetched:
+            before = host_pages().read() if rec else None
+            if self._n_ranks > 1 or rec:
                 # a frame on a mesh, or a recorded run (the copy, timed)
                 out = self._to_host(index, out)
             if isinstance(out, VDI):
@@ -1211,6 +1279,8 @@ class InSituSession:
                 payload = {"image": np.asarray(out)}
             payload["frame"] = index
             payload["meta"] = meta
+            if rec:
+                fetched.note(**host_pages().since(before))
         if self._delivery is not None:
             # off the critical path: the worker runs the tile sinks then
             # the frame sinks behind the shared SinkGuard; the loop only

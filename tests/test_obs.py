@@ -644,7 +644,7 @@ def test_to_host_is_bitwise_np_asarray(ranks, enabled):
         assert names.count("fetch.concat") == 2
 
 
-@pytest.mark.parametrize("ranks", [1, 2])
+@pytest.mark.parametrize("ranks", [1, 2, 4])
 def test_obs_enabled_payload_is_bitwise_the_disabled_one(ranks):
     payloads = []
     for enabled in (False, True):
@@ -774,7 +774,9 @@ def test_loop_thread_spans_cover_the_run():
     assert len(per_frame("upkeep")) == 12 and len(per_frame("fetch")) == 6
     nbytes = per_frame("host_copy.start")[0]["attrs"]["bytes"]
     assert nbytes > 0
-    assert [e["attrs"] for e in per_frame("release")] == [
+    named = lambda attrs: {k: v for k, v in attrs.items()
+                           if k not in ("rss_pages", "minflt", "majflt")}
+    assert [named(e["attrs"]) for e in per_frame("release")] == [
         a for n in [0] + [nbytes] * 5 for a in ({"bytes": n},
                                                 {"device": True})]
     assert all(isinstance(e["thread"], str) for e in spans)
@@ -928,3 +930,239 @@ def test_chrome_trace_gives_each_thread_a_row():
     assert {e["tid"] for e in evs if e.get("ph") == "C"} == {0}
     assert {e["thread"] for e in rec.events if e["type"] == "span"} == \
         set(rows)
+
+
+# ------------------------------------------- the transfers' account (PR 43)
+
+@pytest.fixture(scope="module")
+def transfer_spans():
+    """{ranks: (spans, counters)} of a recorded, steered run of four frames
+    after a frame that compiled: on one device and on a 4-device mesh."""
+    found = {}
+    prev = obs.get_recorder()
+    for ranks in (1, 4):
+        sess = _steered_session(True, ranks=ranks)
+        sess.run(1)
+        n0, c0 = len(sess.obs.events), dict(sess.obs.counters)
+        sess.steering.msgs = [{"type": "camera", "eye": [0.2, 0.6, 3.0]}]
+        sess.run(4)
+        found[ranks] = (
+            [e for e in sess.obs.events[n0:] if e["type"] == "span"],
+            {k: v - c0.get(k, 0) for k, v in sess.obs.counters.items()})
+    obs.set_recorder(prev)
+    return found
+
+
+def _account_fetch_copy(spans, counters, ranks):
+    copies = [e for e in spans if e["name"] == "fetch.copy"]
+    assert len(copies) == 4 * (1 if ranks == 1 else 2 * ranks)
+    for e in copies:
+        assert {type(e["attrs"][k]) for k in ("waited", "beside0",
+                                              "beside1")} == {bool}
+    for frame in {e["frame"] for e in copies}:      # one `waited` a frame
+        assert len({e["attrs"]["waited"] for e in copies
+                    if e["frame"] == frame}) == 1
+    # the run's last frame is fetched with nothing newer in flight
+    last = [e["attrs"] for e in copies if e["frame"] == 4]
+    assert not any(a["beside0"] or a["beside1"] for a in last)
+
+
+def _account_minflt(spans, counters, ranks):
+    from scenery_insitu_tpu.obs.hostmem import host_pages
+
+    counted = host_pages().counts_faults        # gVisor's kernel: none
+    for name in ("fetch", "release") + (("fetch.concat",) if ranks > 1
+                                        else ()):
+        named = [e for e in spans if e["name"] == name]
+        assert named, name
+        for e in named:
+            assert isinstance(e["attrs"]["rss_pages"], int), name
+            assert ("minflt" in e["attrs"]) == counted
+            assert e["attrs"].get("minflt", 0) >= 0
+            assert e["attrs"].get("majflt", 1) > 0      # only where non-zero
+    assert len([e for e in spans if e["name"] == "release"]) == 8
+    concat = [e for e in spans if e["name"] == "fetch.concat"]
+    assert len(concat) == (0 if ranks == 1 else 8)
+    for e in concat:                    # beside what it already said
+        assert {"bytes", "fresh", "kmajor", "rss_pages"} <= set(e["attrs"])
+
+
+def _account_minflt_frame(spans, counters, ranks):
+    from scenery_insitu_tpu.obs.hostmem import PAGE, host_pages
+
+    upkeep = sorted((e for e in spans if e["name"] == "upkeep"),
+                    key=lambda e: e["ts"])
+    assert len(upkeep) == 8
+    first, second = upkeep[0::2], upkeep[1::2]
+    assert not any("touched_frame" in (e.get("attrs") or {}) for e in first)
+    touched = [e["attrs"]["touched_frame"] for e in second]
+    assert all(isinstance(n, int) and n >= 0 for n in touched)
+    assert {e["attrs"]["page"] for e in second} == {PAGE}
+    assert counters["host_pages_touched"] == sum(touched)
+    # the growth of the reading intervals that grew is never under the
+    # iteration's net growth, nor under that of a span inside it
+    for e, n in zip(second, touched):
+        assert n >= e["attrs"]["rss_pages_frame"]
+        assert n >= max((s["attrs"]["rss_pages"] for s in spans
+                         if s["name"] in ("fetch", "release")
+                         and s["frame"] == e["frame"] - 1), default=0)
+    if host_pages().counts_faults:
+        faults = [e["attrs"]["minflt_frame"] for e in second]
+        assert counters["host_minor_faults"] == sum(faults)
+        for e, total in zip(second, faults):
+            assert total >= sum(
+                s["attrs"]["minflt"] for s in spans
+                if s["name"] in ("fetch", "release")
+                and s["frame"] == e["frame"] - 1)
+    else:
+        assert "host_minor_faults" not in counters
+        assert not any("minflt_frame" in e["attrs"] for e in second)
+
+
+def _account_prev_ready(spans, counters, ranks):
+    for name in ("steer", "sim", "dispatch"):
+        by_frame = {}
+        for e in spans:
+            if e["name"] == name:
+                by_frame.setdefault(e["frame"], []).append(
+                    e["attrs"]["prev_ready"])
+        assert sorted(by_frame) == [1, 2, 3, 4], name
+        assert all(len(v) == 1 and type(v[0]) is bool
+                   for v in by_frame.values()), name
+    # a run's first launch has nothing in flight before it
+    assert not any(e["attrs"]["prev_ready"] for e in spans
+                   if e["frame"] == 1 and e["name"] in ("steer", "sim",
+                                                        "dispatch"))
+
+
+@pytest.mark.parametrize("ranks", [1, 4])
+@pytest.mark.parametrize("what", ["fetch_copy", "minflt", "minflt_frame",
+                                  "prev_ready"])
+def test_the_transfers_account_is_on_the_spans_that_exist(
+        transfer_spans, what, ranks):
+    """A recorded `run`: `waited` / `beside0` / `beside1` on every
+    `fetch.copy`; `rss_pages` (and `minflt` where the kernel counts
+    faults) on `fetch`, `fetch.concat` and both `release` spans;
+    `touched_frame` with the page size (counter `host_pages_touched`) and
+    `minflt_frame` (counter `host_minor_faults`) on the second `upkeep`;
+    `prev_ready` on `steer`, `sim` and `dispatch`, one value a frame."""
+    globals()["_account_" + what](*transfer_spans[ranks], ranks)
+
+
+@pytest.mark.parametrize("ranks", [1, 4])
+def test_fetch_copy_says_whether_it_waited_and_beside_what(ranks):
+    """Whatever the device answers is what the spans say: a frame (and
+    every newer one) the loop has waited for was not `waited` for and had
+    nothing `beside` it; one that never answers ready was, beside the
+    newer frame in flight for all but the run's last fetch."""
+    import jax
+
+    sess = _steered_session(True, ranks=ranks)
+    sess.run(1)
+    fetch = sess._fetch
+
+    def all_done(index, out):
+        jax.block_until_ready((out, [p[1] for p in sess._pending]))
+        return fetch(index, out)
+
+    copies = lambda n0: [e["attrs"] for e in sess.obs.events[n0:]
+                         if e.get("name") == "fetch.copy"]
+    n0, sess._fetch = len(sess.obs.events), all_done
+    sess.run(3)
+    assert copies(n0) and not any(
+        a["waited"] or a["beside0"] or a["beside1"] for a in copies(n0))
+    n0, sess._fetch, sess._ready = len(sess.obs.events), fetch, \
+        lambda out: False
+    sess.run(3)
+    per_frame = len(copies(n0)) // 3
+    assert all(a["waited"] for a in copies(n0))
+    assert [a["beside0"] and a["beside1"] for a in copies(n0)] == \
+        [True] * 2 * per_frame + [False] * per_frame
+    launches = [e["attrs"]["prev_ready"] for e in sess.obs.events[n0:]
+                if e.get("name") in ("steer", "sim", "dispatch")]
+    assert len(launches) == 9 and not any(launches)
+
+
+@pytest.mark.parametrize("ranks", [1, 4])
+def test_unrecorded_run_reads_no_fault_count_and_sweeps_nothing(
+        ranks, monkeypatch):
+    """With the recorder off the process's memory is never read
+    (`HostPages.read`: `/proc/self/statm` and `resource.getrusage`) and the
+    `is_ready()` sweep (`_ready`: `waited`, `beside`, `prev_ready`) is
+    never made; a recorded run does both every frame; and what the sinks
+    get is the same bytes either way."""
+    import resource
+
+    from scenery_insitu_tpu.obs import hostmem
+
+    hostmem.host_pages()            # its own first readings are not a run's
+    calls = {"pages": 0, "getrusage": 0, "ready": 0}
+    wrapped = {"pages": hostmem.HostPages.read,
+               "getrusage": resource.getrusage,
+               "ready": InSituSession._ready}
+
+    def counted(name):
+        def call(*args):
+            calls[name] += 1
+            return wrapped[name](*args)
+        return call
+
+    payloads = {}
+    for enabled in (False, True):
+        sess = _steered_session(enabled, ranks=ranks)
+        sess.run(1)
+        monkeypatch.setattr(hostmem.HostPages, "read", counted("pages"))
+        monkeypatch.setattr(hostmem.resource, "getrusage",
+                            counted("getrusage"))
+        monkeypatch.setattr(InSituSession, "_ready",
+                            staticmethod(counted("ready")))
+        calls.update(pages=0, getrusage=0, ready=0)
+        payloads[enabled] = sess.run(3)
+        monkeypatch.undo()
+        if enabled:
+            # a frame: the iteration's reading, two a span on `fetch` and
+            # both `release` (and each `fetch.concat`); a sweep for
+            # `waited`, and with a frame in flight those of the three
+            # launch spans and of the copies' `beside`
+            assert calls["pages"] >= 3 * 7 and calls["ready"] >= 3 * 3
+            assert calls["getrusage"] == (
+                calls["pages"] if hostmem.host_pages().counts_faults else 0)
+        else:
+            assert calls == {"pages": 0, "getrusage": 0, "ready": 0}
+            assert sess.obs.events == []
+            assert not {"host_minor_faults", "host_pages_touched"} & set(
+                sess.obs.counters)
+    for key in ("vdi_color", "vdi_depth"):
+        assert payloads[False][key].tobytes() == payloads[True][key].tobytes()
+
+
+def test_host_pages_reads_what_a_first_touch_grows():
+    """`HostPages`: a fresh 8 MB mapping, touched, grows the resident set
+    by its pages (net of nothing: `rss_pages` and the summed growth
+    agree), unmapping it shrinks it and grows nothing; a kernel that
+    counts no fault (asked once) is never asked again and leaves `minflt`
+    out."""
+    import mmap
+
+    import numpy as np
+
+    from scenery_insitu_tpu.obs.hostmem import PAGE, HostPages
+
+    pages = HostPages()
+    n = 8 << 20
+    before = pages.read()
+    pages.take_grown()
+    fresh = mmap.mmap(-1, n)
+    np.frombuffer(fresh, np.uint8).fill(1)
+    found = pages.since(before)
+    assert n // PAGE <= found["rss_pages"] <= n // PAGE + 512
+    assert found["rss_pages"] <= pages.take_grown() <= n // PAGE + 512
+    assert ("minflt" in found) == pages.counts_faults
+    before = pages.read()
+    fresh.close()
+    assert pages.since(before)["rss_pages"] <= -(n // PAGE) + 512
+    assert pages.take_grown() <= 512
+    blind = HostPages()
+    blind.counts_faults = False
+    assert blind.read()[1:] == (None, None)
+    assert set(blind.since(blind.read())) == {"rss_pages"}

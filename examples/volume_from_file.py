@@ -1,11 +1,17 @@
 """Offline dataset rendering / VDI generation — the counterpart of the
 reference's VolumeFromFileExample (VolumeFromFileExample.kt:69-1116):
-load a raw volume (or a procedural one), render a view sweep, optionally
-generate + store VDIs and publish them over ZMQ.
+a raw volume file goes through `InSituSession` as a simulation's field
+does — loaded in z-slabs at the file's dtype and held resident
+(`runtime/session.DatasetVolumeAdapter`), the camera swept over a few
+views, every frame's VDI decoded to a PNG and optionally stored and
+published over ZMQ.
 
     python examples/volume_from_file.py --out out/                # procedural
     python examples/volume_from_file.py --dataset Kingsnake \
         --data-dir /data --out out/ --store-vdis
+
+`--dataset procedural` writes a 96^3 procedural volume as a u8 raw file
+into --out first, so it takes the same path as a scan.
 """
 
 import argparse
@@ -22,55 +28,70 @@ def main():
                          "'procedural'")
     ap.add_argument("--data-dir", default=".")
     ap.add_argument("--out", default="out")
-    ap.add_argument("--width", type=int, default=512)
-    ap.add_argument("--height", type=int, default=512)
+    ap.add_argument("--width", type=int, default=0,
+                    help="accepted for older command lines; the PNG is "
+                         "the VDI decoded on the march's own grid")
+    ap.add_argument("--height", type=int, default=0, help="as --width")
     ap.add_argument("--views", type=int, default=5)
     ap.add_argument("--store-vdis", action="store_true")
     ap.add_argument("--publish", default="",
                     help="ZMQ bind address to stream generated VDIs")
-    ap.add_argument("--k", type=int, default=16, help="max supersegments")
+    ap.add_argument("--k", type=int, default=20, help="max supersegments")
     args = ap.parse_args()
 
     import numpy as np
 
-    from scenery_insitu_tpu.config import SliceMarchConfig, VDIConfig
+    from scenery_insitu_tpu.config import FrameworkConfig
+    import jax.numpy as jnp
+
     from scenery_insitu_tpu.core.camera import Camera, orbit
-    from scenery_insitu_tpu.core.transfer import for_dataset
-    from scenery_insitu_tpu.core.volume import load_dataset, procedural_volume
-    from scenery_insitu_tpu.ops import slicer
+    from scenery_insitu_tpu.core.vdi import VDI, render_vdi_same_view
+    from scenery_insitu_tpu.core.volume import procedural_volume
+    from scenery_insitu_tpu.io.vdi_io import save_vdi
+    from scenery_insitu_tpu.runtime.session import (DatasetVolumeAdapter,
+                                                    InSituSession)
     from scenery_insitu_tpu.utils.image import save_png
 
-    if args.dataset == "procedural":
-        vol = procedural_volume(96, kind="blobs", seed=1)
-    else:
-        vol = load_dataset(args.dataset, args.data_dir)
-    tf = for_dataset(args.dataset)
     os.makedirs(args.out, exist_ok=True)
+    overrides = ["slicer.engine=mxu", "vdi.adaptive_mode=temporal",
+                 f"vdi.max_supersegments={args.k}",
+                 f"composite.max_output_supersegments={args.k}",
+                 f"runtime.dataset={args.dataset}"]
+    sim = None
+    if args.dataset == "procedural":
+        raw = np.asarray(procedural_volume(96, kind="blobs", seed=1).data)
+        np.round(raw * 255).astype(np.uint8).tofile(
+            os.path.join(args.out, "procedural.raw"))
+        cfg = FrameworkConfig().with_overrides(
+            *overrides, f"runtime.data_dir={args.out}")
+        sim = DatasetVolumeAdapter(cfg, dims_xyz=(96, 96, 96),
+                                   dtype=np.uint8)
+    else:       # by the dims and dtype tables, built by the session
+        cfg = FrameworkConfig().with_overrides(
+            *overrides, f"runtime.data_dir={args.data_dir}")
+
+    def views(index: int, payload: dict) -> None:
+        vdi = VDI(jnp.asarray(payload["vdi_color"]),
+                  jnp.asarray(payload["vdi_depth"]))
+        save_png(os.path.join(args.out, f"view{index:03d}.png"),
+                 np.asarray(render_vdi_same_view(vdi)))
+        if args.store_vdis:
+            save_vdi(os.path.join(args.out, f"vdi{index:03d}.npz"), vdi,
+                     payload["meta"])
+
+    sinks = [views]
+    if args.publish:
+        from scenery_insitu_tpu.runtime.streaming import (VDIPublisher,
+                                                          stream_sink)
+        sinks.append(stream_sink(VDIPublisher(args.publish)))
 
     cam0 = Camera.create((0.0, 0.5, 3.0), fov_y_deg=50.0, near=0.3, far=20.0)
-    pub = None
-    if args.publish:
-        from scenery_insitu_tpu.runtime.streaming import VDIPublisher
-        pub = VDIPublisher(args.publish)
-
+    sess = InSituSession(cfg, sim=sim, camera=cam0, sinks=sinks)
     for i in range(args.views):
-        cam = orbit(cam0, 2.0 * np.pi * i / max(args.views, 1) * 0.25)
-        spec = slicer.make_spec(cam, vol.data.shape, SliceMarchConfig())
-        out = slicer.raycast_mxu(vol, tf, cam, args.width, args.height, spec)
-        save_png(os.path.join(args.out, f"view{i:03d}.png"),
-                 np.asarray(out.image))
-        if args.store_vdis or pub is not None:
-            vdi, meta, _ = slicer.generate_vdi_mxu(
-                vol, tf, cam, spec,
-                VDIConfig(max_supersegments=args.k, adaptive_iters=4),
-                frame_index=i)
-            if args.store_vdis:
-                from scenery_insitu_tpu.io.vdi_io import save_vdi
-                save_vdi(os.path.join(args.out, f"vdi{i:03d}.npz"),
-                         vdi, meta)
-            if pub is not None:
-                pub.publish(vdi, meta)
+        sess.camera = orbit(cam0, 2.0 * np.pi * i / max(args.views, 1) * 0.25)
+        sess.run(1)
         print(f"view {i + 1}/{args.views} done")
+    sess.close()
     print(f"wrote {args.views} views to {args.out}/")
 
 

@@ -540,7 +540,13 @@ class RuntimeConfig:
     benchmark: bool = False
     benchmark_frames: int = 100
     stats_window: int = 100         # frames between timer-stat dumps
+    # Names the transfer function (core/transfer.for_dataset). A name of
+    # the raw-file table (core/volume.DATASET_DIMS_XYZ, e.g. "kingsnake")
+    # also makes the session render that file instead of a simulation:
+    # <data_dir>/<dataset>.raw, loaded in z-slabs at the file's dtype and
+    # held resident (runtime/session.DatasetVolumeAdapter)
     dataset: str = "procedural"
+    data_dir: str = "."
     # Device->host pipeline depth of the frame loop (docs/PERF.md "Async
     # delivery"): how many dispatched frames may have their host copies
     # in flight before the loop blocks on the oldest. 1 = the historical

@@ -199,6 +199,44 @@ def parse_hlo_scopes(hlo_text: str):
     return module, ops, inherited
 
 
+_HLO_RESULT_RE = re.compile(
+    r"^\s+(?:ROOT )?%?[\w\.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(")
+_HLO_FUSED_RE = re.compile(r" fusion\(.*calls=%?([\w\.\-]+)")
+# results that are another buffer's bytes under a new name or shape
+_HLO_NO_WRITE = ("parameter", "get-tuple-element", "bitcast", "constant",
+                 "while", "conditional", "call", "iota")
+
+
+def hlo_large_writes(hlo_text: str, shape) -> list:
+    """The opcodes of the compiled program's instructions that WRITE an
+    array of the rank of ``shape`` and no smaller than it in any dim
+    (dims compared sorted, so a transposed or a padded copy counts),
+    outside fused computations (a fusion's own result counts, what it
+    computes inside does not exist in memory). With ``shape`` = the
+    field a step takes, this lists the per-frame copies of the whole
+    volume: a transpose into the march layout, a cast, a pad, a halo
+    concatenate. An instruction inside a loop's body counts once,
+    however often it runs."""
+    want = sorted(shape)
+    fused = set(_HLO_FUSED_RE.findall(hlo_text))
+    out, comp = [], None
+    for line in hlo_text.splitlines():
+        mr = _HLO_RESULT_RE.match(line)
+        if mr is None:
+            mc = _HLO_COMP_RE.match(line)
+            if mc is not None:
+                comp = mc.group(1)
+            continue
+        dims, opcode = mr.groups()
+        if comp in fused or opcode in _HLO_NO_WRITE:
+            continue
+        have = sorted(int(d) for d in dims.split(",") if d)
+        if len(have) == len(want) and all(h >= w
+                                          for h, w in zip(have, want)):
+            out.append(opcode)
+    return out
+
+
 def scoped_step(fn, rec):
     """``fn`` (a jitted step) where ``rec`` is disabled. Where it is
     enabled, a wrapper that after its FIRST call reads the executable's
@@ -208,25 +246,37 @@ def scoped_step(fn, rec):
     device ops to ``sitpu_*`` scopes with. The call has just compiled the
     program, so ``lower().compile()`` is answered from jit's own cache
     and issues no compile request; nothing is read on later calls.
-    ``lower`` stays reachable (obs/device.cost_snapshot)."""
+    ``lower`` stays reachable (obs/device.cost_snapshot).
+
+    From the same text, for a step whose first argument is a field
+    (ndim 3): how many of its instructions write an array as large
+    as that field (`hlo_large_writes`), added to the counter
+    ``volume_copies_per_frame`` on EVERY call — 0 where the march reads
+    the field where it lives."""
     if not rec.enabled:
         return fn
-    noted = []
+    noted = {}      # after the first call: the step's volume-sized writes
 
     def call(*args, **kwargs):
         out = fn(*args, **kwargs)
         if not noted:
-            noted.append(True)
+            noted["copies"] = None
             try:
-                module, ops, inherited = parse_hlo_scopes(
-                    fn.lower(*args, **kwargs).compile().as_text())
+                text = fn.lower(*args, **kwargs).compile().as_text()
+                module, ops, inherited = parse_hlo_scopes(text)
                 rec.hlo_scopes.setdefault(module, {}).update(ops)
                 rec.hlo_inherited.setdefault(module, set()).update(
                     inherited)
+                if args and getattr(args[0], "ndim", 0) == 3:
+                    # a rank's share of a field sharded over the mesh
+                    noted["copies"] = len(hlo_large_writes(
+                        text, args[0].addressable_shards[0].data.shape))
             except Exception as e:      # noqa: BLE001 — observability
                 # must never take the frame down
                 _rec.degrade("obs.profiler", "hlo_scopes", "none",
                              f"scope table unavailable: {e}", warn=False)
+        if noted["copies"] is not None:
+            rec.count("volume_copies_per_frame", noted["copies"])
         return out
 
     call.lower = fn.lower

@@ -410,6 +410,18 @@ _COUNTER_REGISTRY: Dict[str, str] = {
                        "compiled steps",
     "tf_updates": "a steered transfer-function update was applied",
     "tiles_delivered": "the assembler delivered one complete tile",
+    "volume_copies_per_frame": "instructions of a frame's step program "
+                               "that write an array as large as the "
+                               "field it takes: per-frame copies of the "
+                               "whole volume, read from the compiled "
+                               "HLO (count = such instructions, added "
+                               "every frame; 0 where the march reads "
+                               "the field where it lives; recorded "
+                               "runs only)",
+    "volume_resident_bytes": "bytes of every copy of a dataset volume "
+                             "the session holds on its fullest device, "
+                             "at the dtype each is held at, under the "
+                             "`dataset.load` span (count = bytes)",
     "wave_schedule_builds": "a tile-wave overlap schedule was built",
     "wave_steps_built": "a tile-wave render step was compiled",
     "wire_encode_builds": "a wire encode executable was built",

@@ -31,8 +31,7 @@ Three ideas, layered:
    near-free. `pyramid_from_ranges` maps those DATA-layout brick ranges
    onto the MARCH-layout (chunk × v-tile) cells of any `AxisSpec`
    conservatively (outward-rounded brick intervals, apron rows included,
-   zero admitted for padded chunks, a bf16 widening when the march reads
-   a bf16 copy), so a frame can skip empty space without ever re-reading
+   a bf16 widening when the march reads a bf16 copy), so a frame can skip empty space without ever re-reading
    the volume. When the Pallas path degrades, `field_ranges` is the lax
    fallback reduction (one sweep of the field in data layout — still
    cheaper than permute + reduce, and routed through ``obs.degrade``).
@@ -239,41 +238,75 @@ def _gates(tf, lo, hi, pre_shaded: bool, alpha_eps: float):
     return chunks, tiles
 
 
+def volume_ranges(vol, spec, volp: Optional[jnp.ndarray] = None,
+                  ntiles: Optional[int] = None):
+    """(lo, hi) f32[nchunks, nt]: the exact per-(chunk x v-tile) value
+    ranges of the march layout, in MARCH order and normalized — ONE pass
+    over the layout, which holds everything of the pyramid that depends
+    on the field and the spec alone (the gates also take the TF:
+    `pyramid_from_volume`). ``volp`` (the `slicer.permute_volume`
+    output, storage order) lets the caller share the frame's layout;
+    chunk boundaries are `slicer.march_chunks`' (full chunks counted
+    from the march's front, the remainder last, no pad), so the pyramid
+    and the march can never disagree on slab layout. A field that never
+    changes has them computed once (`parallel/pipeline
+    .distributed_volume_ranges_mxu`)."""
+    from scenery_insitu_tpu.core.volume import value_scale
+    from scenery_insitu_tpu.ops import slicer
+
+    if volp is None:
+        volp = slicer.permute_volume(vol, spec)
+    if vol.data.ndim == 4:
+        volp = volp[:, 3]                                  # alpha plane
+    c = spec.chunk
+    s_total, nv = volp.shape[:2]
+    nfull, rem = divmod(s_total, c)
+    fwd = spec.sign > 0
+    # storage slices of the full chunks and of the remainder
+    full = volp[:nfull * c] if fwd else volp[rem:]
+    rest = volp[nfull * c:] if fwd else volp[:rem]
+    nt = resolved_tiles(spec, nv) if ntiles is None else max(1, ntiles)
+
+    def cells(x, n):
+        # reduce in storage dtype (bf16 march copies, raw integers)
+        out = [[red(x[:, r0:r1].reshape(n, -1), axis=1)
+                for r0, r1 in _tile_bands(nv, nt)]
+               for red in (jnp.min, jnp.max)]
+        return [jnp.stack(o, axis=1).astype(jnp.float32) for o in out]
+
+    parts = []
+    if nfull:
+        parts.append(cells(full, nfull) if fwd
+                     else [x[::-1] for x in cells(full, nfull)])
+    if rem:
+        parts.append(cells(rest, 1))
+    lo, hi = (jnp.concatenate(x) for x in zip(*parts))
+    scale = value_scale(volp.dtype)
+    if scale != 1.0:
+        lo, hi = lo * jnp.float32(scale), hi * jnp.float32(scale)
+    return lo, hi
+
+
 def pyramid_from_volume(vol, tf, spec, volp: Optional[jnp.ndarray] = None,
                         alpha_eps: float = 1e-5,
-                        ntiles: Optional[int] = None) -> OccupancyPyramid:
-    """Build the pyramid from the volume itself — ONE pass over the
-    march-layout copy, exact ranges. ``volp`` (the UNPADDED
-    `slicer.permute_volume` output) lets the caller share the single
-    per-frame permuted copy between this pass and the marches; chunk
-    boundaries come from the shared `slicer._pad_to_chunks`, so the
-    pyramid and the march can never disagree on slab layout.
+                        ntiles: Optional[int] = None,
+                        ranges=None) -> OccupancyPyramid:
+    """Build the pyramid from the volume itself — exact ranges
+    (`volume_ranges`: one pass over the march layout, or ``ranges``,
+    the same two arrays computed before for a field that has not
+    changed since) pushed through the TF's conservative alpha bound.
 
     ``ntiles`` overrides the spec-derived tile count (used by the legacy
     `slicer.chunk_occupancy` wrapper, which is the nt=1 level alone)."""
-    from scenery_insitu_tpu.ops import slicer
-
     rec = obs.get_recorder()
-    if volp is None:
-        volp = slicer.permute_volume(vol, spec)
-    pre_shaded = vol.data.ndim == 4
-    if pre_shaded:
-        volp = volp[:, 3]                                  # alpha plane
-    volp, nchunks = slicer._pad_to_chunks(volp, spec.chunk)
-    nv = volp.shape[1]
-    nt = resolved_tiles(spec, nv) if ntiles is None else max(1, ntiles)
-    los, his = [], []
-    for r0, r1 in _tile_bands(nv, nt):
-        band = volp[:, r0:r1].reshape(nchunks, -1)
-        # reduce in storage dtype (bf16 march copies), gate in f32
-        los.append(jnp.min(band, axis=1).astype(jnp.float32))
-        his.append(jnp.max(band, axis=1).astype(jnp.float32))
-    lo = jnp.stack(los, axis=1)                            # [nchunks, nt]
-    hi = jnp.stack(his, axis=1)
-    chunks, tiles = _gates(tf, lo, hi, pre_shaded, alpha_eps)
-    rec.count("occupancy_pyramid_builds")
-    rec.event("occupancy_build", source="volume", nchunks=int(nchunks),
-              ntiles=int(nt))
+    kept = ranges is not None
+    if not kept:
+        ranges = volume_ranges(vol, spec, volp, ntiles)
+        rec.count("occupancy_pyramid_builds")
+    lo, hi = ranges
+    chunks, tiles = _gates(tf, lo, hi, vol.data.ndim == 4, alpha_eps)
+    rec.event("occupancy_build", source="kept_ranges" if kept else "volume",
+              nchunks=int(lo.shape[0]), ntiles=int(lo.shape[1]))
     return OccupancyPyramid(lo, hi, chunks, tiles)
 
 
@@ -286,9 +319,8 @@ def pyramid_from_ranges(ranges: FieldRanges, vol, tf, spec,
     instead).
 
     Conservative by construction: each (chunk × v-tile) cell takes the
-    union range of every brick its region (apron rows included, padded
-    slices admitting zero) can touch, with brick intervals rounded
-    outward; a bf16 march copy (``spec.render_dtype``) additionally
+    union range of every brick its region (apron rows included) can
+    touch, with brick intervals rounded outward; a bf16 march copy (``spec.render_dtype``) additionally
     widens the range by one storage rounding. Cells this pyramid gates
     off are a SUBSET of what `pyramid_from_volume` gates off — coarser
     skipping, identical output (the march's skip path is exact)."""
@@ -339,11 +371,6 @@ def pyramid_from_ranges(ranges: FieldRanges, vol, tf, spec,
         b0, b1 = d0 // sb, -(-d1 // sb)
         lo_c = jnp.min(band_lo[b0:b1], axis=0)
         hi_c = jnp.max(band_hi[b0:b1], axis=0)
-        if (ci + 1) * c > s_total:
-            # the shared _pad_to_chunks zero-pads the last chunk: zero
-            # enters its value range
-            lo_c = jnp.minimum(lo_c, 0.0)
-            hi_c = jnp.maximum(hi_c, 0.0)
         los.append(lo_c)
         his.append(hi_c)
     lo = jnp.stack(los)                                    # [nchunks, nt]

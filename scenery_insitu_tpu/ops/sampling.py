@@ -6,14 +6,16 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from scenery_insitu_tpu.core.volume import Volume
+from scenery_insitu_tpu.core.volume import Volume, value_scale
 
 
 def sample_trilinear(data: jnp.ndarray, pos_xyz: jnp.ndarray) -> jnp.ndarray:
     """Trilinearly sample ``data f32[D, H, W]`` at continuous voxel
     coordinates ``pos_xyz f32[..., 3]`` (x, y, z; voxel centers at
     integer + 0.5). Coordinates are clamped to the border (GL
-    CLAMP_TO_EDGE semantics, matching the reference's samplers)."""
+    CLAMP_TO_EDGE semantics, matching the reference's samplers). A field
+    at a raw file's integer dtype is sampled as stored and the result
+    scaled to [0, 1] (`core.volume.value_scale`)."""
     d, h, w = data.shape
     p = pos_xyz - 0.5
     x = jnp.clip(p[..., 0], 0.0, w - 1.0)
@@ -52,7 +54,9 @@ def sample_trilinear(data: jnp.ndarray, pos_xyz: jnp.ndarray) -> jnp.ndarray:
     c11 = c110 * (1 - fx) + c111 * fx
     c0 = c00 * (1 - fy) + c01 * fy
     c1 = c10 * (1 - fy) + c11 * fy
-    return c0 * (1 - fz) + c1 * fz
+    out = c0 * (1 - fz) + c1 * fz
+    scale = value_scale(data.dtype)
+    return out if scale == 1.0 else out * jnp.float32(scale)
 
 
 def sample_volume_world(vol: Volume, world_pos: jnp.ndarray) -> jnp.ndarray:

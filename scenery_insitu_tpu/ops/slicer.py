@@ -57,7 +57,7 @@ from scenery_insitu_tpu.config import SliceMarchConfig, VDIConfig
 from scenery_insitu_tpu.core.camera import Camera, frustum, look_at
 from scenery_insitu_tpu.core.transfer import TransferFunction
 from scenery_insitu_tpu.core.vdi import VDI, VDIMetadata
-from scenery_insitu_tpu.core.volume import Volume
+from scenery_insitu_tpu.core.volume import Volume, value_scale
 from scenery_insitu_tpu.obs.profiler import in_phase as _in_phase
 from scenery_insitu_tpu.obs.profiler import phase as _phase
 from scenery_insitu_tpu.ops import pallas_march as pm
@@ -252,25 +252,62 @@ class AxisCamera(NamedTuple):
 
 def permute_volume(vol: Volume, spec: AxisSpec) -> jnp.ndarray:
     """Volume data -> march layout ``[S, (ch,) Nv, Nu]`` (slice, optional
-    channels, in-plane v, u), flipped so marched slice index ascends
-    front-to-back. A leading channel dim of pre-shaded RGBA volumes moves
+    channels, in-plane v, u) in STORAGE order along the slice dim: the
+    array itself for a z march, one transpose for an x or y march. It is
+    never flipped and never padded: `slice_march` and the occupancy pass
+    walk it front to back by ``spec.sign`` (`march_chunks`), so a z march
+    reads the field where it lives and a march toward -axis costs no
+    reversed copy. A leading channel dim of pre-shaded RGBA volumes moves
     BEHIND the slice dim so the march can slab-slice on dim 0.
 
     ``spec.render_dtype == "bf16"`` emits the march layout in bf16 — the
     copy every march reads halves in HBM (XLA CSEs the one cast+transpose
     across the occupancy pass and the marches of a frame); accumulation
-    downstream stays f32."""
+    downstream stays f32. An integer field (a raw file's dtype,
+    `core.volume.value_scale`) stays as it is."""
     data = vol.data
     if spec.render_dtype == "bf16" and data.dtype == jnp.float32:
         data = data.astype(jnp.bfloat16)
     nd = data.ndim
     perm3 = {2: (0, 1, 2), 1: (1, 0, 2), 0: (2, 0, 1)}[spec.axis]
+    if nd == 3 and perm3 == (0, 1, 2):
+        return data
     dims = [nd - 3 + p for p in perm3]
-    volp = jnp.transpose(data,
-                         [dims[0]] + list(range(nd - 3)) + dims[1:])
-    if spec.sign < 0:
-        volp = jnp.flip(volp, axis=0)
-    return volp
+    return jnp.transpose(data, [dims[0]] + list(range(nd - 3)) + dims[1:])
+
+
+def march_chunks(volp: jnp.ndarray, spec: AxisSpec):
+    """How a march walks the storage-order layout ``volp`` in chunks of
+    ``spec.chunk`` slices, front to back: ``(nchunks, fetch)``, where
+    ``fetch(ci)`` is marched chunk ``ci`` (``ci`` may be traced): storage
+    slices ``[ci*c, (ci+1)*c)`` for sign > 0, and for sign < 0
+    ``[S-(ci+1)*c, S-ci*c)`` reversed — a reverse of one chunk, fused
+    into its consumer, where a flipped layout was a copy of the volume.
+    Where ``c`` does not divide the slice count S, the last chunk is the
+    remaining ``S % c`` marched slices zero-padded to ``c``: one
+    chunk-sized array made beside the loop and selected in for that one
+    ``ci`` (the select reads it beside every chunk's window: a chunk's
+    bytes again, never the volume's), so a depth that is no chunk
+    multiple pads no volume. One implementation for the march and every
+    occupancy pass, so chunk boundaries can never disagree."""
+    c = spec.chunk
+    s_total = volp.shape[0]
+    nfull, rem = divmod(s_total, c)
+    fwd = spec.sign > 0
+
+    def window(ci):
+        start = ci * c if fwd else s_total - (ci + 1) * c
+        sl = jax.lax.dynamic_slice_in_dim(volp, start, c, 0)
+        return sl if fwd else jnp.flip(sl, axis=0)
+
+    if not rem:
+        return nfull, window
+    last = volp[s_total - rem:] if fwd else jnp.flip(volp[:rem], axis=0)
+    tail = jnp.concatenate(
+        [last, jnp.zeros((c - rem,) + volp.shape[1:], volp.dtype)], axis=0)
+    if not nfull:
+        return 1, lambda ci: tail
+    return nfull + 1, lambda ci: jnp.where(ci == nfull, tail, window(ci))
 
 
 def make_axis_camera(vol: Volume, cam: Camera, spec: AxisSpec,
@@ -478,19 +515,6 @@ def chunk_occupancy(vol: Volume, tf: TransferFunction, spec: AxisSpec,
                                     alpha_eps=alpha_eps, ntiles=1).chunks
 
 
-def _pad_to_chunks(volp: jnp.ndarray, c: int) -> Tuple[jnp.ndarray, int]:
-    """Zero-pad the march-layout volume along slices to a chunk multiple;
-    returns (padded, nchunks). One implementation for the march and every
-    occupancy pass, so slab boundaries can never disagree."""
-    s_total = volp.shape[0]
-    nchunks = -(-s_total // c)
-    if nchunks * c != s_total:
-        volp = jnp.concatenate(
-            [volp, jnp.zeros((nchunks * c - s_total,) + volp.shape[1:],
-                             volp.dtype)], axis=0)
-    return volp, nchunks
-
-
 def chunk_occupancy_vtiles(vol: Volume, tf: TransferFunction,
                            spec: AxisSpec, alpha_eps: float = 1e-5,
                            volp: Optional[jnp.ndarray] = None
@@ -688,16 +712,31 @@ def slice_march(vol: Volume, tf: TransferFunction, axcam: AxisCamera,
     occ_tiles = None
     if isinstance(occupancy, tuple):
         occupancy, occ_tiles = occupancy
-    # ``volp`` shares the frame's one permuted copy (occupancy pass +
-    # every march of the frame read the same layout; XLA CSEs the
-    # transpose either way inside one jit, but the explicit handoff also
-    # serves eager callers and keeps the structure visible)
-    volp0 = permute_volume(vol, spec) if volp is None else volp
-    s_total = volp0.shape[0]
+    # ``volp`` shares the frame's one march layout (occupancy pass +
+    # every march of the frame read the same array; XLA CSEs an x/y
+    # march's transpose either way inside one jit, but the explicit
+    # handoff also serves eager callers and keeps the structure visible)
+    if volp is None:
+        volp = permute_volume(vol, spec)
+    s_total = volp.shape[0]
     c = spec.chunk
-    volp, nchunks = _pad_to_chunks(volp0, c)
+    mm = jnp.bfloat16 if spec.matmul_dtype == "bf16" else jnp.float32
+    # an integer field's stored value v stands for v * vscale: the scale
+    # goes on the matmul's f32 result, so the volume operand is the
+    # file's own values (u8 is exact in bf16; wider integers take f32
+    # operands, which hold them exactly)
+    vscale = value_scale(volp.dtype)
+    if vscale != 1.0 and volp.dtype.itemsize > 1:
+        mm = jnp.float32
+    if jnp.issubdtype(volp.dtype, jnp.floating) \
+            and volp.dtype.itemsize > jnp.dtype(mm).itemsize:
+        # a float layout wider than the matmul operand: the compiler
+        # lifts the chunks' cast out of the loop anyway (one convert of
+        # the layout a frame); written here it carries the march's scope
+        volp = volp.astype(mm)
+    nchunks, fetch = march_chunks(volp, spec)
     if occupancy is not None and occupancy.shape[0] != nchunks:
-        # both sides chunk through the shared _pad_to_chunks, so a
+        # both sides chunk through the shared march_chunks, so a
         # mismatch means the occupancy was built for a DIFFERENT volume
         # or chunk size — skipping with it would be silently wrong
         raise ValueError(
@@ -706,7 +745,6 @@ def slice_march(vol: Volume, tf: TransferFunction, axcam: AxisCamera,
 
     ou, su, nu, ov, sv, nv = _axis_params(vol, spec)
     eu, ev, ew = axcam.eye_u, axcam.eye_v, axcam.eye_w
-    mm = jnp.bfloat16 if spec.matmul_dtype == "bf16" else jnp.float32
 
     # per-ray geometry (constant over the march)
     length = axcam.ray_lengths()                           # [Nj, Ni]
@@ -730,7 +768,7 @@ def slice_march(vol: Volume, tf: TransferFunction, axcam: AxisCamera,
         if w_bounds is not None:
             live &= (wk > w_bounds[0]) & (wk < w_bounds[1])
 
-        slices = jax.lax.dynamic_slice_in_dim(volp, ci * c, c, 0)
+        slices = fetch(ci)
 
         pos_u = eu + (axcam.u_grid[None, :] - eu) * sk[:, None]    # [C, Ni]
         pos_v = ev + (axcam.v_grid[None, :] - ev) * sk[:, None]    # [C, Nj]
@@ -746,6 +784,8 @@ def slice_march(vol: Volume, tf: TransferFunction, axcam: AxisCamera,
                              wv_r.astype(mm), slices.astype(mm),
                              wu.astype(mm),
                              preferred_element_type=jnp.float32)
+            if vscale != 1.0:
+                val = val * jnp.float32(vscale)
             # clip BEFORE the sentinel so a genuine value <= -0.5 (un-
             # normalized field) can't be conflated with a dead sample;
             # exact — every shading path clips to [0,1] anyway
@@ -771,6 +811,8 @@ def slice_march(vol: Volume, tf: TransferFunction, axcam: AxisCamera,
                              wv_r.astype(mm), slices.astype(mm),
                              wu.astype(mm),
                              preferred_element_type=jnp.float32)
+            if vscale != 1.0:
+                val = val * jnp.float32(vscale)
             val = jnp.clip(val, 0.0, 1.0)
 
             rgb, alpha = tf(val)                   # [C,B,Ni,3], [C,B,Ni]

@@ -1397,7 +1397,7 @@ def _planned_slab(local_data, origin, spacing, spec, axis, n,
 
 def _rank_frame_state(local_data, origin, spacing, spec, tf, vdi_cfg,
                       axis, n, comp_cfg, plan=None,
-                      need_pyramid: bool = False):
+                      need_pyramid: bool = False, ranges=None):
     """Per-frame, per-rank shared state of an MXU generation: the
     halo-exact slab (or planned render band, ``plan``), the frame's ONE
     occupancy pyramid, and (when ``comp_cfg.k_budget == "occupancy"``)
@@ -1405,7 +1405,10 @@ def _rank_frame_state(local_data, origin, spacing, spec, tf, vdi_cfg,
     generation (`_mxu_rank_generate`) and the tile-wave path
     (`_mxu_rank_generate_waves`) — T waves must not pay T pyramids or T
     psums. ``need_pyramid`` forces the pyramid even with skipping off —
-    the temporal-reuse dirty detector reads its ranges every frame."""
+    the temporal-reuse dirty detector reads its ranges every frame.
+    ``ranges`` (`distributed_volume_ranges_mxu`'s two rank-stacked
+    arrays, of a field that never changes) stand in for the pyramid's
+    sweep of the volume."""
     vol, gmax, v_bounds, w_bounds, dims = _rank_slab(
         local_data, origin, spacing, spec, axis, n, plan=plan)
     occ_pyr = None
@@ -1423,8 +1426,12 @@ def _rank_frame_state(local_data, origin, spacing, spec, tf, vdi_cfg,
     if spec.skip_empty or budgeted or need_pyramid:
         from scenery_insitu_tpu.ops import occupancy as _occ
 
+        if ranges is not None:      # this rank's of the rank-stacked two
+            ranges = tuple(jnp.asarray(x)[jax.lax.axis_index(axis)]
+                           for x in ranges)
         with _phase("march"):
-            occ_pyr = _occ.pyramid_from_volume(vol, tf, spec)
+            occ_pyr = _occ.pyramid_from_volume(vol, tf, spec,
+                                               ranges=ranges)
     if budgeted:
         from scenery_insitu_tpu import obs as _obs
         from scenery_insitu_tpu.ops import occupancy as _occ
@@ -1444,7 +1451,7 @@ def _rank_frame_state(local_data, origin, spacing, spec, tf, vdi_cfg,
 def _mxu_rank_generate(local_data, origin, spacing, cam, slicer, spec,
                        tf, vdi_cfg, axis, n, threshold=None,
                        comp_cfg=None, plan=None, reuse=None,
-                       reuse_tol: float = 0.0):
+                       reuse_tol: float = 0.0, ranges=None):
     """Per-rank slice-march VDI generation on a z-slab (shared by the
     distributed VDI and hybrid steps). Returns (vdi, meta, axcam,
     next_threshold, next_reuse) — the last two are None unless carried
@@ -1470,7 +1477,7 @@ def _mxu_rank_generate(local_data, origin, spacing, cam, slicer, spec,
     vol, gmax, v_bounds, w_bounds, dims, occ_pyr, k_target = \
         _rank_frame_state(local_data, origin, spacing, spec, tf, vdi_cfg,
                           axis, n, comp_cfg, plan=plan,
-                          need_pyramid=reuse is not None)
+                          need_pyramid=reuse is not None, ranges=ranges)
     if reuse is None:
         with _phase("march"):
             if threshold is None:
@@ -1664,7 +1671,7 @@ def distributed_vdi_step_mxu(mesh: Mesh, tf: TransferFunction,
                              axis_name: Optional[str] = None,
                              plan=None, bricks=None,
                              reuse_tol: float = 0.0,
-                             topology=None):
+                             topology=None, ranges=None):
     """Distributed sort-last VDI pipeline on the MXU slice-march engine
     (ops/slicer.py) — generation runs as banded-matmul slice resampling
     instead of per-ray gathers; the rest of the chain (width-axis column
@@ -1687,15 +1694,21 @@ def distributed_vdi_step_mxu(mesh: Mesh, tf: TransferFunction,
     ``f(vol_data, origin, spacing, cam, reuse) -> ((VDI, meta),
     reuse')`` — seed ``reuse`` with `distributed_initial_reuse_mxu`;
     ``reuse_tol`` is the dirty tolerance (cfg.delta.range_tol).
+
+    ``ranges``: what `distributed_volume_ranges_mxu` gave for a field
+    that never changes (a dataset): the step then sweeps no volume for
+    its occupancy pyramid. A planned band, a brick map and the
+    tile-wave schedule sweep as before.
     """
     return _build_mxu_step(mesh, tf, spec, vdi_cfg, comp_cfg, axis_name,
                            temporal=False, plan=plan, bricks=bricks,
-                           reuse_tol=reuse_tol, topology=topology)
+                           reuse_tol=reuse_tol, topology=topology,
+                           ranges=ranges)
 
 
 def _build_mxu_step(mesh, tf, spec, vdi_cfg, comp_cfg, axis_name,
                     temporal: bool, plan=None, bricks=None,
-                    reuse_tol: float = 0.0, topology=None):
+                    reuse_tol: float = 0.0, topology=None, ranges=None):
     """Shared builder of the MXU sort-last step (generate → column
     exchange under ``comp_cfg.exchange`` → composite), with or without
     carried temporal threshold state threaded through.
@@ -1753,7 +1766,8 @@ def _build_mxu_step(mesh, tf, spec, vdi_cfg, comp_cfg, axis_name,
         vdi, meta, _, thr2, ru2 = _mxu_rank_generate(
             local_data, origin, spacing, cam, slicer, spec, tf, vdi_cfg,
             axis, n, threshold=thr, comp_cfg=comp_cfg, plan=plan,
-            reuse=ru, reuse_tol=reuse_tol)
+            reuse=ru, reuse_tol=reuse_tol,
+            ranges=ranges if plan is None else None)
         return (_composite_exchanged(vdi.color, vdi.depth, n, axis,
                                      comp_cfg, topo=topo), meta, thr2,
                 ru2)
@@ -1816,6 +1830,28 @@ def _build_mxu_step(mesh, tf, spec, vdi_cfg, comp_cfg, axis_name,
                       in_specs=(spec_vol, P(), P(), P()),
                       out_specs=(out_vdi, out_meta), check_vma=False)
     return jax.jit(f)
+
+
+def distributed_volume_ranges_mxu(mesh: Mesh, spec,
+                                  axis_name: Optional[str] = None):
+    """Jitted ``f(vol_data (z-sharded), origin, spacing) -> (lo, hi)``:
+    every rank's occupancy ranges of its own even slab for ``spec``'s
+    march (`occupancy.volume_ranges`), rank-stacked f32[n, nchunks, nt].
+    They depend on the field and the spec alone, so a session whose
+    field never changes computes them once per march regime and hands
+    them to the step builder (``ranges``), as the reference builds its
+    octree once per dataset (VolumeFromFileExample.kt:226-327)."""
+    from scenery_insitu_tpu.ops import occupancy as _occ
+
+    axis, n, _ = resolve_mesh_topology(mesh, axis_name)
+
+    def ranges(local_data, origin, spacing):
+        vol = _rank_slab(local_data, origin, spacing, spec, axis, n)[0]
+        return tuple(x[None] for x in _occ.volume_ranges(vol, spec))
+
+    return jax.jit(shard_map(
+        ranges, mesh=mesh, in_specs=(P(axis, None, None), P(), P()),
+        out_specs=(P(axis, None, None),) * 2, check_vma=False))
 
 
 def _thr_state_spec(axis):
@@ -1885,7 +1921,7 @@ def distributed_vdi_step_mxu_temporal(mesh: Mesh, tf: TransferFunction,
                                       axis_name: Optional[str] = None,
                                       plan=None, bricks=None,
                                       reuse_tol: float = 0.0,
-                                      topology=None):
+                                      topology=None, ranges=None):
     """`distributed_vdi_step_mxu` with carried per-rank temporal threshold
     state (adaptive_mode="temporal": ONE march per rank per frame instead
     of counting + write — see slicer.generate_vdi_mxu_temporal).
@@ -1900,7 +1936,8 @@ def distributed_vdi_step_mxu_temporal(mesh: Mesh, tf: TransferFunction,
     """
     return _build_mxu_step(mesh, tf, spec, vdi_cfg, comp_cfg, axis_name,
                            temporal=True, plan=plan, bricks=bricks,
-                           reuse_tol=reuse_tol, topology=topology)
+                           reuse_tol=reuse_tol, topology=topology,
+                           ranges=ranges)
 
 
 def distributed_hybrid_step_mxu(mesh: Mesh, tf: TransferFunction,

@@ -36,6 +36,7 @@ from scenery_insitu_tpu.config import FrameworkConfig
 from scenery_insitu_tpu.core.camera import Camera, orbit
 from scenery_insitu_tpu.core.transfer import TransferFunction, for_dataset
 from scenery_insitu_tpu.core.vdi import VDI
+from scenery_insitu_tpu.core.volume import DATASET_DIMS_XYZ
 from scenery_insitu_tpu.obs.hostmem import PAGE, host_pages
 from scenery_insitu_tpu.obs.profiler import scoped_step
 from scenery_insitu_tpu.parallel.topology import (make_topology_mesh,
@@ -386,6 +387,71 @@ class VolumeSimAdapter:
         return self._field
 
 
+class DatasetVolumeAdapter:
+    """A volume source with no simulation behind it: a raw dataset file
+    (≅ VolumeFromFileExample, `fromPathRaw` x num_parts) loaded in
+    z-slabs straight to the device at the FILE'S dtype and held
+    resident, unchanged, for the session's life. ``advance`` does
+    nothing and ``field`` is that array: u8 (or u16) in HBM, normalized
+    where the march reads it (`core.volume.value_scale`), never widened.
+
+    By default the file is ``<runtime.data_dir>/<runtime.dataset>.raw``
+    with the dims and dtype the tables of `core.volume` give that name;
+    ``dims_xyz`` / ``dtype`` say otherwise (a small file of a test);
+    ``parts`` is the number of z-slabs it is read and put in.
+    ``static`` tells the session that what depends on the field alone
+    (the occupancy ranges of a march regime) is computed once. The load
+    happens at the first use of ``field`` — for a session, inside its
+    constructor, under its recorder: span ``dataset.load`` (attrs
+    ``name``, ``dims``, ``dtype``, ``parts``, ``bytes``, ``read_s``,
+    ``put_s``), counter ``volume_resident_bytes``."""
+
+    kind = "dataset"
+    static = True
+
+    def __init__(self, cfg: FrameworkConfig, mesh=None, axis=None,
+                 dims_xyz=None, dtype=None, parts: int = 8):
+        from scenery_insitu_tpu.core import volume as _vol
+
+        rt = cfg.runtime
+        self.name = rt.dataset
+        self.path = os.path.join(rt.data_dir, f"{rt.dataset}.raw")
+        self.dims_xyz = tuple(dims_xyz
+                              or _vol.DATASET_DIMS_XYZ[rt.dataset.lower()])
+        self.dtype = np.dtype(dtype
+                              or _vol.DATASET_DTYPES[rt.dataset.lower()])
+        self._parts = parts
+        self._place = (_field_sharding(mesh, axis) if _sharded_sim(mesh)
+                       else None)
+        self._field = None
+
+    def advance(self, n: int) -> None:
+        """No simulation: the field is the file's, whatever ``n``."""
+
+    @property
+    def field(self) -> jnp.ndarray:
+        if self._field is None:
+            from scenery_insitu_tpu.core.volume import load_raw_parts
+
+            rec = _obs.get_recorder()
+            with rec.span("dataset.load", frame=0) as span:
+                took = {}
+                field = load_raw_parts(self.path, self.dims_xyz,
+                                       self.dtype, self._parts,
+                                       timings=took)
+                if self._place is not None:
+                    field = jax.device_put(field, self._place)
+                held = max(sh.data.nbytes
+                           for sh in field.addressable_shards)
+                span.note(name=self.name, dims=list(self.dims_xyz),
+                          dtype=self.dtype.name, parts=took["parts"],
+                          bytes=field.nbytes, read_s=took["read"],
+                          put_s=took["put"])
+                rec.count("volume_resident_bytes", held)
+            self._field = field
+        return self._field
+
+
 class ParticleSimAdapter:
     """Session facade over the built-in particle sims (lennard_jones | sho;
     ≅ the reference's MD-driven InVisRenderer path and the SHO workload of
@@ -628,6 +694,10 @@ class InSituSession:
         elif self.cfg.sim.kind == "hybrid":
             self.sim = HybridSimAdapter(self.cfg, mesh=self.mesh,
                                         axis=self._flat_axis)
+        elif self.cfg.runtime.dataset.lower() in DATASET_DIMS_XYZ:
+            # a name of the raw-file table: no simulation, the file
+            self.sim = DatasetVolumeAdapter(self.cfg, mesh=self.mesh,
+                                            axis=self._flat_axis)
         else:
             self.sim = VolumeSimAdapter(self.cfg, mesh=self.mesh,
                                         axis=self._flat_axis, obs=self.obs)
@@ -704,8 +774,10 @@ class InSituSession:
             self._origin = jnp.zeros((3,), jnp.float32)
             self._spacing = jnp.ones((3,), jnp.float32)
         else:
-            d, h, w = (tuple(self.cfg.sim.grid) if sim is None
-                       else np.asarray(self.sim.field.shape))
+            d, h, w = (np.asarray(self.sim.field.shape)
+                       if sim is not None
+                       or isinstance(self.sim, DatasetVolumeAdapter)
+                       else tuple(self.cfg.sim.grid))
             vox = 2.0 / max(d, h, w)
             self._origin = jnp.asarray(
                 [-w * vox / 2, -h * vox / 2, -d * vox / 2], jnp.float32)
@@ -1634,9 +1706,18 @@ class InSituSession:
         c = self.cfg
         build = (pipeline.distributed_vdi_step_mxu_temporal
                  if self._temporal else pipeline.distributed_vdi_step_mxu)
+        ranges = None
+        if (getattr(self.sim, "static", False) and spec.skip_empty
+                and self._plan is None and self._bricks is None):
+            # the field never changes: its occupancy ranges for this
+            # regime are computed here, once, and not by every frame
+            ranges = tuple(np.asarray(x) for x in
+                           pipeline.distributed_volume_ranges_mxu(
+                               self.mesh, spec)(*self._field_args()[:3]))
         return StepEntry(
             build(self.mesh, self.tf, spec, c.vdi, c.composite,
-                  reuse_tol=c.delta.range_tol, **self._decomp()),
+                  reuse_tol=c.delta.range_tol, ranges=ranges,
+                  **self._decomp()),
             seed_thr=(pipeline.distributed_initial_threshold_mxu(
                 self.mesh, self.tf, spec, c.vdi, plan=self._plan,
                 bricks=self._bricks) if self._temporal else None),

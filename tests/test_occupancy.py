@@ -82,7 +82,8 @@ def test_pyramid_volume_conservative(tf_fn):
     pyr = occ.pyramid_from_volume(vol, tf, spec)
     tiles = np.asarray(pyr.tiles)
     assert tiles.sum() < tiles.size          # something is skippable
-    volp = np.asarray(slicer.permute_volume(vol, spec))
+    # the layout is in storage order: walk it front to back by the sign
+    volp = np.asarray(slicer.permute_volume(vol, spec))[::spec.sign]
     c = spec.chunk
     nv = volp.shape[1]
     nt = tiles.shape[1]
@@ -104,10 +105,12 @@ def test_pyramid_volume_conservative(tf_fn):
     assert (np.asarray(pyr.chunks) >= tiles.any(axis=1)).all()
 
 
-def test_pyramid_padded_last_chunk_admits_zero():
-    """_pad_to_chunks zero-pads the last chunk, so with a TF whose alpha
-    band sits at LOW values a high-valued field must keep its padded
-    chunk live (the pad zeros can shade) — in both construction paths."""
+def test_pyramid_partial_last_chunk_takes_no_pad():
+    """A depth that is no chunk multiple is marched as full chunks and a
+    remainder, never padded (`slicer.march_chunks`): with a TF whose
+    alpha band sits at LOW values a high-valued field keeps its last,
+    partial chunk dead like the others, where the zero-padded layout had
+    to admit its own zeros — in both construction paths."""
     data = jnp.full((40, 16, 16), 0.9, jnp.float32)   # 40 = 2*16 + 8 pad
     vol = Volume.centered(data, extent=2.0)
     tf = TransferFunction.points(
@@ -118,8 +121,8 @@ def test_pyramid_padded_last_chunk_admits_zero():
     pyr_r = occ.pyramid_from_ranges(rng, vol, tf, spec)
     for name, pyr in (("volume", pyr_v), ("ranges", pyr_r)):
         chunks = np.asarray(pyr.chunks)
-        assert not chunks[:2].any(), (name, chunks)   # pure 0.9 -> no alpha
-        assert chunks[2], (name, chunks)              # padded chunk: zeros
+        assert chunks.shape == (3,), (name, chunks)   # 16 + 16 + 8 slices
+        assert not chunks.any(), (name, chunks)       # pure 0.9 -> no alpha
 
 
 def test_pyramid_preshaded_alpha_ranges():

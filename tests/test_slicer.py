@@ -204,8 +204,9 @@ def test_occupancy_skip_is_exact(vol, tf):
 
 def test_occupancy_flags_conservative(tf):
     """Every chunk flagged empty must truly contribute zero alpha — checked
-    in MARCH order (chunk_occupancy chunks the permuted+flipped volume), on
-    an asymmetric band so a flip-indexing regression cannot pass."""
+    in MARCH order (chunk_occupancy walks the storage-order layout front
+    to back by the sign), on an asymmetric band so a flip-indexing
+    regression cannot pass."""
     data = jnp.zeros((64, 16, 16), jnp.float32)
     data = data.at[8:24].set(0.9)          # asymmetric occupied band
     v = Volume.centered(data, extent=2.0)
@@ -214,7 +215,7 @@ def test_occupancy_flags_conservative(tf):
     assert spec.axis == 2                  # the camera this test assumes
     occ = np.asarray(slicer.chunk_occupancy(v, tf, spec))
     assert occ.sum() < occ.size            # something was skippable
-    volp = np.asarray(slicer.permute_volume(v, spec))   # march layout
+    volp = np.asarray(slicer.permute_volume(v, spec))[::spec.sign]
     c = spec.chunk
     for ci in range(occ.size):
         band = volp[ci * c:(ci + 1) * c]

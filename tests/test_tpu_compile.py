@@ -271,3 +271,80 @@ def test_the_1024_cube_step_program_fits_a_chip(topo, monkeypatch):
     for leaf in (vdi.color, vdi.depth):
         assert leaf.is_equivalent_to(on(P("ranks", None, None, None)), 4)
     assert "f32[4,4,1280,1280]" in text
+
+
+# kingsnake-u8-1chip (PR 44): 795 x 1024 x 1024 voxels of u8 on ONE chip,
+# a 1280 x 1280 intermediate grid, K = 20
+KS_GRID, KS_OVERRIDES = (795, 1024, 1024), (
+    "slicer.engine=mxu", "vdi.adaptive_mode=temporal",
+    "vdi.max_supersegments=20", "composite.max_output_supersegments=20",
+    "runtime.dataset=kingsnake", "mesh.num_devices=1")
+
+
+def test_the_kingsnake_step_compiles_at_its_native_dtype(topo, monkeypatch):
+    """The file-dataset cell's programs for one v5e as a TPU builds them:
+    the occupancy ranges, the threshold seeder and the step at (795, 1024,
+    1024) u8, K = 20 (not a multiple of 8), a depth of 49 chunks and 11
+    planes. Mosaic takes the fold kernel at K = 20, nothing lands on the
+    fallback ledger, no instruction of the step or the seeder writes an
+    array as large as the volume (no widened, flipped or padded copy),
+    and the step's temporaries stay under the volume's own size."""
+    from scenery_insitu_tpu import obs
+    from scenery_insitu_tpu.config import FrameworkConfig
+    from scenery_insitu_tpu.core.camera import Camera
+    from scenery_insitu_tpu.core.transfer import for_dataset
+    from scenery_insitu_tpu.obs.profiler import hlo_large_writes
+    from scenery_insitu_tpu.ops import slicer
+    from scenery_insitu_tpu.parallel import pipeline
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    obs.clear_ledger()
+    cfg = FrameworkConfig().with_overrides(*KS_OVERRIDES)
+    mesh = Mesh(np.array(topo.devices[:1]), ("ranks",))
+    on = lambda spec: NamedSharding(mesh, spec)
+    like = lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.asarray(x).dtype,
+                                          sharding=on(P()))
+    cam = Camera.create((0.0, 0.6, 3.0), fov_y_deg=50.0, near=0.3, far=20.0)
+    spec = slicer.make_spec(cam, KS_GRID, cfg.slicer,
+                            axis_sign=slicer.choose_axis(cam))
+    assert (spec.axis, spec.sign, spec.ni, spec.nj, spec.fold) == \
+        (2, -1, 1280, 1280, "pallas_seg")
+    args = (jax.ShapeDtypeStruct(KS_GRID, jnp.uint8,
+                                 sharding=on(P("ranks", None, None))),
+            like(np.zeros(3, np.float32)),
+            like(np.full(3, 2.0 / max(KS_GRID), np.float32)),
+            jax.tree_util.tree_map(like, cam))
+    tf = for_dataset("kingsnake")
+    ranges = pipeline.distributed_volume_ranges_mxu(mesh, spec)
+    ranges.lower(*args[:3]).compile()
+    kept = tuple(np.zeros(s.shape, np.float32)
+                 for s in jax.eval_shape(ranges, *args[:3]))
+    assert kept[0].shape == (1, 50, spec.vtiles)
+    seed = pipeline.distributed_initial_threshold_mxu(mesh, tf, spec,
+                                                      cfg.vdi)
+    seeded = seed.lower(*args).compile()
+    thr = jax.tree_util.tree_map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(seed, *args), seeded.output_shardings)
+    compiled = pipeline.distributed_vdi_step_mxu_temporal(
+        mesh, tf, spec, cfg.vdi, cfg.composite,
+        reuse_tol=cfg.delta.range_tol, ranges=kept).lower(
+            *args, thr).compile()
+    text = compiled.as_text()
+    assert "sitpu_fold_seg_compact" in text
+    assert "f32[20,4,1280,1280]" in text
+    assert obs.ledger() == []
+    for program in (seeded, compiled):
+        assert hlo_large_writes(program.as_text(), KS_GRID) == []
+    volume = int(np.prod(KS_GRID))
+    assert compiled.memory_analysis().temp_size_in_bytes < volume
+    # the widened f32 field of the same shape: no flip and no pad either,
+    # but the compiler hoists the operand's bf16 cast out of the chunk
+    # loop, one `convert` of the whole volume a frame (the u8 operand's
+    # cast stays inside the loop: it would only make the array larger)
+    wide = (jax.ShapeDtypeStruct(KS_GRID, jnp.float32,
+                                 sharding=args[0].sharding),) + args[1:]
+    assert set(hlo_large_writes(
+        pipeline.distributed_vdi_step_mxu_temporal(
+            mesh, tf, spec, cfg.vdi, cfg.composite).lower(
+                *wide, thr).compile().as_text(), KS_GRID)) <= {"convert"}

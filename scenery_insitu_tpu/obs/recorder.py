@@ -134,6 +134,10 @@ _LEDGER_REGISTRY: Dict[str, str] = {
     "head.rank_down": "head node: a render rank went silent past "
                       "stale_frames; frames composite without it "
                       "(degraded flag) until it returns",
+    "host.heap": "a session fetches frames of 32 MB or more and glibc's "
+                 "mallopt is not there to keep the heap's large blocks: "
+                 "each frame's device-to-host copy lands on freshly "
+                 "mapped pages (runtime/hostheap.py)",
     "ingest.stall": "shm ingest: no strictly-newer producer frame past "
                     "frame_timeout_ms; the session keeps rendering the "
                     "last-good frame until frames resume",
@@ -303,6 +307,15 @@ _COUNTER_REGISTRY: Dict[str, str] = {
                               "there (InSituSession._to_host)",
     "head_degraded_frames": "the head composited a frame with >= 1 rank "
                             "missing (degraded flag set)",
+    "host_heap_frame_bytes": "the host bytes of the first frame a session "
+                             "fetched, per process: what decided "
+                             "host_heap_kept (recorded or not)",
+    "host_heap_kept": "1 where the session told glibc to keep the "
+                      "process's large heap blocks because the frame it "
+                      "fetches reaches glibc's 32 MB ceiling for heap "
+                      "blocks, 0 where the frame is smaller or there is "
+                      "no glibc (runtime/hostheap.py; recorded or not; "
+                      "absent from a session that fetched nothing)",
     "host_minor_faults": "minor page faults of the whole process over "
                          "the frame loop's iterations (getrusage; bumped "
                          "once a frame by a RECORDED run only, with the "

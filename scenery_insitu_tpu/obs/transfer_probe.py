@@ -43,6 +43,12 @@ The rows (medians over ``--repeats`` trials; every trial is in the JSON):
   in which glibc keeps large blocks (``M_MMAP_MAX`` 0, ``M_TRIM_THRESHOLD``
   and ``M_ARENA_MAX`` set through ``ctypes`` before the backend starts: a
   freed host buffer stays mapped, the next one reuses its pages), and
+  ``late``, a child that starts the backend and brings one frame to the
+  host on glibc's defaults (its ``before`` row) and only THEN tells glibc
+  the same on the calling thread, which is all a session can do
+  (`runtime/hostheap.py`): on the process's first thread, and as
+  ``late-thread`` all of it on a thread that is not (a frame loop that
+  runs beside a host application's own); and
   ``pinned_host``, ``jax.device_put`` of the ready frame to the same
   sharding with ``memory_kind="pinned_host"`` (``ready_ms``: until it is
   there; ``ms``: until it is numpy arrays) or ``unsupported`` with the
@@ -58,7 +64,6 @@ far under ``fresh_MB``).
 from __future__ import annotations
 
 import argparse
-import ctypes
 import json
 import math
 import os
@@ -69,11 +74,13 @@ import threading
 import time
 
 from scenery_insitu_tpu.obs.hostmem import PAGE, host_pages
+from scenery_insitu_tpu.runtime.hostheap import keep_large_blocks
 
 K = 16                  # slots
 CHANNELS = (4, 2)       # colour, depth: f32, 24 B a slot and pixel
 PIECES = (1, 4, 16)
 FILLERS = ("matmul", "stream")
+LATE = ("late", "late-thread")      # the children that call mallopt late
 LANDED_MS = 0.3
 _THP = "/sys/kernel/mm/transparent_hugepage/enabled"
 
@@ -83,17 +90,6 @@ def frame_side(nbytes: int) -> int:
     of 16 (so that 16 pieces cut its rows evenly)."""
     side = math.isqrt(nbytes // (K * 4 * sum(CHANNELS)))
     return max(16, side - side % 16)
-
-
-def keep_large_blocks() -> None:
-    """Tell glibc to serve every block from the heap and never give it
-    back: ``M_MMAP_MAX`` 0, ``M_TRIM_THRESHOLD`` as high as it goes, one
-    arena."""
-    libc = ctypes.CDLL("libc.so.6")
-    m_trim_threshold, m_mmap_max, m_arena_max = -1, -4, -8
-    libc.mallopt(m_mmap_max, 0)
-    libc.mallopt(m_trim_threshold, 2 ** 31 - 1)
-    libc.mallopt(m_arena_max, 1)
 
 
 def _touched(pages, before: tuple, prefix: str = "") -> dict:
@@ -342,6 +338,20 @@ def measure(args) -> dict:
     states = ("",) + FILLERS
     pieces = PIECES if args.child == "default" else (1,)
     probe.transfer(1, "")                               # first-use costs
+    if args.child in LATE:
+        def late() -> None:
+            out["before"] = [repeat(probe.transfer, 1, "")]
+            out["kept"] = keep_large_blocks()
+            probe.transfer(1, "")           # the heap grows, once
+            out["transfer"] = [repeat(probe.transfer, 1, b) for b in states]
+
+        if args.child == "late":
+            late()
+        else:
+            other = threading.Thread(target=late, name="probe-late")
+            other.start()
+            other.join()
+        return out
     out["transfer"] = [repeat(probe.transfer, p, b)
                        for p in pieces for b in states]
     if args.child != "default":
@@ -393,7 +403,10 @@ def report(res: dict) -> str:
     parts = [head, table(
         "transfer: copy_to_host_async -> last np.asarray",
         _COLUMNS["transfer"], dest(res["transfer"], "default")
-        + dest(res["mallopt"], "mallopt"))]
+        + dest(res["mallopt"], "mallopt") + [
+            row for name in LATE
+            for row in dest(res[name + ":before"], name + ":before")
+            + dest(res[name], name)])]
     parts.append(table("held: a launch made with the transfer in flight",
                        _COLUMNS["held"], res["held"]))
     if res.get("shard_ends"):
@@ -435,7 +448,8 @@ def main(argv=None) -> int:
     ap.add_argument("--filler-ms", type=float, default=100.0)
     ap.add_argument("--json", action="store_true",
                     help="the whole result as one JSON line, last")
-    ap.add_argument("--child", choices=("default", "mallopt"), default="",
+    ap.add_argument("--child", choices=("default", "mallopt") + LATE,
+                    default="",
                     help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
     if args.child:
@@ -443,6 +457,9 @@ def main(argv=None) -> int:
         return 0
     res = _child(args, "default")
     res["mallopt"] = _child(args, "mallopt")["transfer"]
+    for name in LATE:
+        child = _child(args, name)
+        res[name], res[name + ":before"] = child["transfer"], child["before"]
     res["thp"] = _thp()
     print(report(res), flush=True)
     if args.json:

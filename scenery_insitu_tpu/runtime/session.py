@@ -42,6 +42,7 @@ from scenery_insitu_tpu.obs.profiler import scoped_step
 from scenery_insitu_tpu.parallel.topology import (make_topology_mesh,
                                                   resolve_mesh_topology)
 from scenery_insitu_tpu.parallel.pipeline import shard_volume
+from scenery_insitu_tpu.runtime import hostheap
 from scenery_insitu_tpu.runtime.failsafe import SinkGuard
 from scenery_insitu_tpu.runtime.steps import StepEntry, StepTable
 from scenery_insitu_tpu.sim import grayscott as gs
@@ -562,6 +563,14 @@ class HostFrames:
     ``base`` to the owner). A frame that a sink does keep costs one fresh
     array, which the threads keep inside a four-rank frame's time.
 
+    The SHARDS it copies from are the runtime's own host buffers, and
+    since PR 45 those and this pool's own ``np.empty`` come from a heap
+    that keeps its large blocks wherever the frame weighs 32 MB or more
+    (`InSituSession._keep_host_heap`, runtime/hostheap.py), so neither
+    side of the copy meets an untouched page after the first frames.
+    Whether ``take`` still needs its pool then is a question for a
+    `simplicity` PR; it is kept as it was.
+
     What it copies depends on how the frame left the mesh. A VDI frame
     leaves sharded over its LEADING (slot) axis wherever the step can
     re-shard it (parallel/pipeline.py ``_frame_out``): each shard is one
@@ -758,6 +767,7 @@ class InSituSession:
         self._steer_seq = 0     # camera messages applied (drain_steering)
         self._pending = deque()     # run()'s in-flight frames, newest last
         self._host_frames = HostFrames()    # see _to_host
+        self._heap_kept = None      # decided by the first frame fetched
 
         from scenery_insitu_tpu.ops import slicer as _slicer
         self._slicer = _slicer
@@ -1202,9 +1212,38 @@ class InSituSession:
         """Kick off the device->host transfer of every buffer in ``out``
         without blocking (``copy_to_host_async``): by the time the
         depth-k pipeline retires this frame, the bytes are already on
-        the host and ``np.asarray`` is a cheap wrap, not a sync."""
-        for leaf in jax.tree_util.tree_leaves(out):
+        the host and ``np.asarray`` is a cheap wrap, not a sync.
+
+        The runtime allocates each buffer's host destination here, on
+        the calling thread, and its own threads write it later. Before
+        the first frame it fetches, the session therefore looks at what
+        that frame weighs on this process's host (`_keep_host_heap`)."""
+        leaves = jax.tree_util.tree_leaves(out)
+        if self._heap_kept is None:
+            self._keep_host_heap(leaves)
+        for leaf in leaves:
             leaf.copy_to_host_async()
+
+    def _keep_host_heap(self, leaves) -> None:
+        """Decide, once a session, whether this process's heap has to keep
+        its large blocks (runtime/hostheap.py) — from the host bytes of
+        the frame about to be fetched and from nothing a user sets. A
+        frame that reaches glibc's ceiling for heap blocks lands on
+        freshly mapped pages every time otherwise (its buffers are over
+        the ceiling, or the heap's top is trimmed between frames); a
+        smaller one is reused by glibc as it is, and a session that
+        fetches nothing never comes here. Engaging is for the whole
+        process and for good: one line through ``log``, counters
+        ``host_heap_kept`` (1 where engaged, 0 where not) and
+        ``host_heap_frame_bytes`` (what decided), recorded or not."""
+        nbytes = sum(
+            leaf.nbytes if leaf.is_fully_addressable else
+            sum(sh.data.nbytes for sh in leaf.addressable_shards)
+            for leaf in leaves)
+        self._heap_kept = nbytes >= hostheap.DEFAULT_MMAP_THRESHOLD_MAX \
+            and hostheap.keep_large_blocks(self.log)
+        self.obs.count("host_heap_kept", int(self._heap_kept))
+        self.obs.count("host_heap_frame_bytes", nbytes)
 
     def _sync_nofetch(self, index: int, out) -> None:
         """Retire a pipelined frame nobody consumes: drop its metadata

@@ -1,7 +1,8 @@
-"""`python -m scenery_insitu_tpu.obs.transfer_probe` (PR 43) at a megabyte
-on the CPU and on the 4-device CPU mesh: the command as the chip runs it,
-children and all; every row of every table is there with a number in it.
-What the numbers are is the chip's to say, not this file's."""
+"""`python -m scenery_insitu_tpu.obs.transfer_probe` (PR 43; the `late`
+children PR 45) at a megabyte on the CPU and on the 4-device CPU mesh: the
+command as the chip runs it, children and all; every row of every table is
+there with a number in it. What the numbers are is the chip's to say, not
+this file's."""
 
 import json
 import subprocess
@@ -48,6 +49,24 @@ def _rows_transfer(text, res):
     assert "mallopt" in text and "free_host_ms" in text
 
 
+def _rows_late(text, res):
+    """The children that tell glibc late (runtime/hostheap.py): one
+    default row before the call, the whole-frame rows after it, on the
+    first thread and on another."""
+    assert tp.LATE == ("late", "late-thread")
+    for name in tp.LATE:
+        assert [(r["pieces"], r["beside"])
+                for r in res[name + ":before"]] == [(1, "idle")]
+        assert [(r["pieces"], r["beside"]) for r in res[name]] == \
+            [(1, b) for b in STATES]
+        for r in res[name + ":before"] + res[name]:
+            assert r["ms"] > 0 and r["GB/s"] > 0 and len(r["trials"]) == 2
+            assert isinstance(r["fresh_MB"], float)
+        firsts = [line.split()[:1] for line in text.splitlines()]
+        assert firsts.count([name + ":before"]) == 1
+        assert firsts.count([name]) == len(STATES)
+
+
 def _rows_held(text, res):
     assert [(r["pieces"], r["beside"], r["held"]) for r in res["held"]] == [
         (p, b, h) for p in tp.PIECES for b in ("idle", "stream")
@@ -82,9 +101,15 @@ def _rows_destinations(text, res):
 
 @pytest.mark.parametrize("devices", [1, 4])
 @pytest.mark.parametrize("rows", ["transfer", "held", "shard_ends",
-                                  "destinations"])
+                                  "destinations", "late"])
 def test_the_probes_table_has_every_row(probed, rows, devices):
     globals()["_rows_" + rows](*probed[devices])
+
+
+def test_the_probe_tells_glibc_as_the_session_does():
+    """One definition: the probe's children call the program's own."""
+    from scenery_insitu_tpu.runtime import hostheap
+    assert tp.keep_large_blocks is hostheap.keep_large_blocks
 
 
 @pytest.mark.parametrize("nbytes,side", [(157286400, 640),

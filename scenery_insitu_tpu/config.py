@@ -143,12 +143,20 @@ class SliceMarchConfig:
     #   "pallas_fused" shade-in-kernel: the TF + opacity correction +
     #                depth streams move into the fold kernel (≅ the
     #                reference's single-kernel generation,
-    #                VDIGenerator.comp + AccumulateVDI.comp);
+    #                VDIGenerator.comp + AccumulateVDI.comp); the march
+    #                hands over its one-channel value plane and the
+    #                shaded rgba chunk never crosses HBM;
     #   "fused_stream" the whole-march fused fold: chunk loop inside the
     #                kernel grid, [K] state VMEM-resident per pixel strip
     #                (one HBM round trip per march; costs a f32[S,Nj,Ni]
     #                stream buffer);
-    #   "auto"       pallas_seg on TPU, xla elsewhere.
+    #   "auto"       pallas_fused on TPU (since PR 46), xla elsewhere.
+    # The two shade-in-kernel schedules bake the transfer function's
+    # knots into the kernel, so per march they need a scalar volume and
+    # a concrete TF: a pre-shaded RGBA volume (the novel-view proxy) and
+    # a TF that is traced get pallas_seg's shaded feed of the same
+    # kernel instead (ops/slicer.fold_schedule — chosen from what the
+    # march is given, whether the value came from "auto" or was named).
     fold: str = "auto"
 
     def __post_init__(self):

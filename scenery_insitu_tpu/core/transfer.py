@@ -24,6 +24,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -107,7 +108,13 @@ class TransferFunction(NamedTuple):
         a = self.alpha_b + jnp.sum(
             self.alpha_m * jnp.maximum(x - self.alpha_x, 0.0), axis=-1)
         tc = jnp.maximum(x - self.color_x, 0.0)           # [..., K]
-        rgb = self.color_b + jnp.tensordot(tc, self.color_m, axes=([-1], [0]))
+        # an f32 sum like alpha's: at a TPU's default precision the MXU
+        # rounds both operands to bf16 (colours 3.6e-3 off the polyline,
+        # measured on a v5e), and the fold kernel that shades in VMEM
+        # (ops/pallas_seg._shade_plane) evaluates these knots in f32
+        rgb = self.color_b + jnp.tensordot(
+            tc, self.color_m, axes=([-1], [0]),
+            precision=jax.lax.Precision.HIGHEST)
         return rgb, a
 
     # ------------------------------------------------ dense LUT views (host)

@@ -65,6 +65,7 @@ import glob
 import os
 import re
 import tempfile
+import threading
 import time
 from typing import Any, Callable, Dict, Optional
 
@@ -237,6 +238,21 @@ def hlo_large_writes(hlo_text: str, shape) -> list:
     return out
 
 
+_FOLD_NOTES = threading.local()     # .open: [chunks, fused] while a
+#                                     recorded step's first call traces
+
+
+def note_fold_chunks(chunks: int, fused: bool) -> None:
+    """A VDI generator says, while it is traced, that its write march
+    folds ``chunks`` chunks and whether the fold kernel shades them
+    itself. Kept only while a recorded step makes its first call
+    (`scoped_step`); said to nobody otherwise."""
+    notes = getattr(_FOLD_NOTES, "open", None)
+    if notes is not None:
+        notes[0] += chunks
+        notes[1] += chunks if fused else 0
+
+
 def scoped_step(fn, rec):
     """``fn`` (a jitted step) where ``rec`` is disabled. Where it is
     enabled, a wrapper that after its FIRST call reads the executable's
@@ -252,15 +268,26 @@ def scoped_step(fn, rec):
     (ndim 3): how many of its instructions write an array as large
     as that field (`hlo_large_writes`), added to the counter
     ``volume_copies_per_frame`` on EVERY call — 0 where the march reads
-    the field where it lives."""
+    the field where it lives. And from what the VDI generators said
+    while that first call traced them (`note_fold_chunks`): the chunks
+    the step's write marches fold, and those of them the fold kernel
+    shades itself, added to ``fold_chunks`` / ``fold_chunks_fused`` on
+    every call of a step that folds."""
     if not rec.enabled:
         return fn
-    noted = {}      # after the first call: the step's volume-sized writes
+    noted = {}      # after the first call: the step's volume-sized
+    #                 writes and its write marches' [chunks, fused]
 
     def call(*args, **kwargs):
-        out = fn(*args, **kwargs)
-        if not noted:
+        if noted:
+            out = fn(*args, **kwargs)
+        else:
             noted["copies"] = None
+            _FOLD_NOTES.open = noted["folds"] = [0, 0]
+            try:
+                out = fn(*args, **kwargs)
+            finally:
+                _FOLD_NOTES.open = None
             try:
                 text = fn.lower(*args, **kwargs).compile().as_text()
                 module, ops, inherited = parse_hlo_scopes(text)
@@ -277,6 +304,9 @@ def scoped_step(fn, rec):
                              f"scope table unavailable: {e}", warn=False)
         if noted["copies"] is not None:
             rec.count("volume_copies_per_frame", noted["copies"])
+        if noted["folds"][0]:
+            rec.count("fold_chunks", noted["folds"][0])
+            rec.count("fold_chunks_fused", noted["folds"][1])
         return out
 
     call.lower = fn.lower

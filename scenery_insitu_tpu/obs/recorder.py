@@ -296,6 +296,17 @@ _COUNTER_REGISTRY: Dict[str, str] = {
     "delta_tiles_skipped": "an unchanged tile shipped as a SKIP record",
     "flight_dumps": "the flight recorder dumped the last obs window "
                     "after an unhandled frame-loop exception",
+    "fold_chunks": "chunks a frame's step program folds in its write "
+                   "marches, on one rank: a march's depth over "
+                   "`slicer.chunk`, noted while the step's first call "
+                   "traced it, added every frame (recorded runs only)",
+    "fold_chunks_fused": "those of `fold_chunks` the fold kernel shades "
+                         "itself from the march's one-channel value "
+                         "plane (`pallas_fused` / `fused_stream`: what "
+                         "`slicer.fold=auto` takes on a TPU for a "
+                         "scalar volume with a concrete transfer "
+                         "function); the others cross HBM as shaded "
+                         "rgba (recorded runs only)",
     "frames_abandoned": "the tile assembler abandoned a frame that "
                         "stayed incomplete past its window",
     "frames_fetched_kmajor": "a frame fetched from the mesh whose every "

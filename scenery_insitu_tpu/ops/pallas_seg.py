@@ -105,10 +105,11 @@ def _phase_b(ev_slot, ev_rgba, t0_of, t1_of, ci_, di_, co, do_,
     jax.lax.fori_loop(0, max_k, slot_body, 0)
 
 
-def _phase_a(rgba_ref, thr, smi_, smo, ev_ref, kf):
-    """Per-slice (slot, v) records from the shaded rgba stream; 4 small
-    live carries. Shared by the plane-depth and compact-depth kernels."""
-    nc = rgba_ref.shape[0]
+def _phase_a(nc: int, rgba_of, thr, smi_, smo, ev_ref, kf):
+    """Per-slice (slot, v) records from the shaded rgba stream
+    (``rgba_of(s)`` f32[4, TH, WB]: read from the chunk's ref, or shaded
+    here from the value plane); 4 small live carries. Shared by every
+    kernel of this module."""
     sm = smi_[...]
     run_cnt = sm[_CNT]
     pr = sm[_PREV_RGB]
@@ -116,7 +117,7 @@ def _phase_a(rgba_ref, thr, smi_, smo, ev_ref, kf):
 
     t_run = jnp.ones_like(thr)
     for s in range(nc):
-        rgba = rgba_ref[s]
+        rgba = rgba_of(s)
         emp = rgba[3] < ss.EMPTY_ALPHA
         d = rgba[:3] - pr
         diff = jnp.sqrt(jnp.sum(d * d, axis=0))
@@ -136,10 +137,25 @@ def _phase_a(rgba_ref, thr, smi_, smo, ev_ref, kf):
         run_cnt[None], pr, pe.astype(jnp.float32)[None]])
 
 
+def _phase_b_compact(ev_ref, len_ref, sk0_ref, sk1_ref, ci_, di_, co,
+                     do_, max_k: int):
+    """`_phase_b` with the depth candidates formed here from the
+    per-slice ratios and the per-pixel ray length (t = sk * length —
+    exactly what the march's outer product materialized)."""
+    ev = ev_ref[...]                                       # [C, 5, TH, WB]
+    ln = len_ref[...]                                      # [TH, WB]
+    t0a = sk0_ref[...] * ln[None]                          # [C, TH, WB]
+    t1a = sk1_ref[...] * ln[None]
+    _phase_b(ev[:, 0], ev[:, 1:5],
+             lambda m: jnp.where(m, t0a, jnp.inf),
+             lambda m: jnp.where(m, t1a, -jnp.inf),
+             ci_, di_, co, do_, max_k)
+
+
 def _seg_kernel(rgba_ref, td_ref, thr_ref, ci_, di_, smi_,
                 co, do_, smo, ev_ref, *, max_k: int):
-    thr = thr_ref[...]
-    _phase_a(rgba_ref, thr, smi_, smo, ev_ref, jnp.float32(max_k - 1))
+    _phase_a(rgba_ref.shape[0], lambda s: rgba_ref[s], thr_ref[...],
+             smi_, smo, ev_ref, jnp.float32(max_k - 1))
 
     # ---- phase B: rolled K loop, state touched once per chunk
     ev = ev_ref[...]                                       # [C, 5, TH, WB]
@@ -152,22 +168,14 @@ def _seg_kernel(rgba_ref, td_ref, thr_ref, ci_, di_, smi_,
 def _seg_kernel_compact(rgba_ref, len_ref, thr_ref, sk0_ref, sk1_ref,
                         ci_, di_, smi_, co, do_, smo, ev_ref, *,
                         max_k: int):
-    """_seg_kernel with the depth planes computed IN-KERNEL from the
-    per-slice ratios and the per-pixel ray length (t = sk * length —
-    exactly what the march's outer product materialized): the [C,2,H,W]
-    td stream never exists in HBM, the march's biggest remaining stream
-    term after rgba (~3.4 GB/march at the 512³ flagship)."""
-    thr = thr_ref[...]
-    _phase_a(rgba_ref, thr, smi_, smo, ev_ref, jnp.float32(max_k - 1))
-
-    ev = ev_ref[...]                                       # [C, 5, TH, WB]
-    ln = len_ref[...]                                      # [TH, WB]
-    t0a = sk0_ref[...] * ln[None]                          # [C, TH, WB]
-    t1a = sk1_ref[...] * ln[None]
-    _phase_b(ev[:, 0], ev[:, 1:5],
-             lambda m: jnp.where(m, t0a, jnp.inf),
-             lambda m: jnp.where(m, t1a, -jnp.inf),
-             ci_, di_, co, do_, max_k)
+    """_seg_kernel with the depth planes computed IN-KERNEL
+    (`_phase_b_compact`): the [C,2,H,W] td stream never exists in HBM,
+    the march's biggest remaining stream term after rgba (~3.4 GB/march
+    at the 512³ flagship)."""
+    _phase_a(rgba_ref.shape[0], lambda s: rgba_ref[s], thr_ref[...],
+             smi_, smo, ev_ref, jnp.float32(max_k - 1))
+    _phase_b_compact(ev_ref, len_ref, sk0_ref, sk1_ref, ci_, di_, co, do_,
+                     max_k)
 
 
 def fold_chunk_packed(packed, rgba: jnp.ndarray, t0=None, t1=None,
@@ -274,31 +282,59 @@ def seg_fold_chunk(st: sf.SegFoldState, rgba: jnp.ndarray, t0: jnp.ndarray,
 # ----------------------------------------------- fused shade+fold kernel
 
 
+def tf_is_concrete(tf) -> bool:
+    """Can the transfer function's knots be read as numbers here? Not
+    where a caller jits over the TF: its leaves are tracers then."""
+    return not any(isinstance(leaf, jax.core.Tracer)
+                   for leaf in jax.tree_util.tree_leaves(tf))
+
+
 def _tf_consts(tf) -> tuple:
     """The transfer function's knots as PYTHON floats, baked into the
     kernel as compile-time constants (zero-slope padded knots are skipped
     at kernel-build time — free TF trimming). Raises if the TF is traced:
     every production path closes over a concrete TF (the session rebuilds
     its compiled steps on a runtime TF swap), and a traced TF would need
-    the knots as kernel operands — use fold="pallas_seg" there."""
-    # only the tracer-leak family is "the TF is traced"; anything else
-    # (renamed field, numpy failure) is a genuine bug and must propagate
-    try:
-        ax = np.asarray(tf.alpha_x).tolist()
-        am = np.asarray(tf.alpha_m).tolist()
-        ab = float(np.asarray(tf.alpha_b))
-        cx = np.asarray(tf.color_x).tolist()
-        cm = np.asarray(tf.color_m).tolist()
-        cb = np.asarray(tf.color_b).tolist()
-    except (jax.errors.TracerArrayConversionError,
-            jax.errors.ConcretizationTypeError) as e:
+    the knots as kernel operands — the generators give such a march the
+    shaded feed instead (ops/slicer.fold_schedule)."""
+    if not tf_is_concrete(tf):
         raise ValueError(
-            "the fused fold schedules (pallas_fused / fused_stream) bake "
-            "the transfer function into the kernel and need a CONCRETE "
-            f"TransferFunction (got traced values: {e}); pass the TF as "
-            "a closure constant or use fold='pallas_seg'") from None
+            "the fused fold kernels (pallas_fused / fused_stream) bake "
+            "the transfer function in and need a CONCRETE "
+            "TransferFunction, not traced values; pass the TF as a "
+            "closure constant or fold the shaded chunk "
+            "(fold_chunk_packed)")
+    ax = np.asarray(tf.alpha_x).tolist()
+    am = np.asarray(tf.alpha_m).tolist()
+    ab = float(np.asarray(tf.alpha_b))
+    cx = np.asarray(tf.color_x).tolist()
+    cm = np.asarray(tf.color_m).tolist()
+    cb = np.asarray(tf.color_b).tolist()
     return (tuple(ax), tuple(am), ab, tuple(cx),
             tuple(tuple(r) for r in cm), tuple(cb))
+
+
+def _shade_plane(v_raw, ratio, tfc: tuple):
+    """One value plane f32[TH, WB] (``-1`` = dead sample) -> premultiplied,
+    opacity-corrected rgba f32[4, TH, WB]: `TransferFunction.__call__` in
+    knot form with the knots as immediates (zero-slope padding knots
+    compile to nothing), then `adjust_opacity`, formula-exact."""
+    ax, am, ab, cx, cm, cb = tfc
+    x = jnp.clip(v_raw, 0.0, 1.0)
+    a = ab
+    for xi, mi in zip(ax, am):
+        if mi != 0.0:
+            a = a + mi * jnp.maximum(x - xi, 0.0)
+    chans = []
+    for ch in range(3):
+        cch = cb[ch]
+        for xi, row in zip(cx, cm):
+            if row[ch] != 0.0:
+                cch = cch + row[ch] * jnp.maximum(x - xi, 0.0)
+        chans.append(cch)
+    a = jnp.where(v_raw < -0.5, 0.0, a)                    # dead sample
+    a = 1.0 - jnp.power(jnp.clip(1.0 - a, 1e-7, 1.0), ratio)
+    return jnp.stack([c * a for c in chans] + [a])
 
 
 def _fused_kernel(val_ref, len_ref, ratio_ref, thr_ref, sk0_ref, sk1_ref,
@@ -311,78 +347,26 @@ def _fused_kernel(val_ref, len_ref, ratio_ref, thr_ref, sk0_ref, sk1_ref,
     (sentinel -1 marks outside-volume/dead samples) instead of the
     4-channel post-TF rgba stream: 4x less HBM into the kernel, and the
     TF's relu-sum runs on VMEM-resident data with its knots baked in as
-    immediates (`_tf_consts`)."""
-    ax, am, ab, cx, cm, cb = tfc
-    nc = val_ref.shape[0]
-    thr = thr_ref[...]
-    length = len_ref[...]
+    immediates (`_tf_consts`). Past the shading it IS the compact seg
+    kernel: the same `_phase_a` records, the same `_phase_b_compact`."""
     ratio = ratio_ref[...]
-    t0_all = sk0_ref[...] * length[None]                   # [C, TH, WB]
-    t1_all = sk1_ref[...] * length[None]
 
-    sm = smi_[...]
-    run_cnt = sm[_CNT]
-    pr = sm[_PREV_RGB]
-    pe = sm[_PREV_EMPTY] > 0.5
-    kf = jnp.float32(max_k - 1)
+    def shade(s):
+        return _shade_plane(val_ref[s], ratio, tfc)
 
-    t_run = jnp.ones_like(thr)
-    for s in range(nc):
-        v_raw = val_ref[s]
-        outside = v_raw < -0.5
-        x = jnp.clip(v_raw, 0.0, 1.0)
-        # knot-form TF with baked immediates; zero-slope (padding) knots
-        # compile to nothing
-        a = ab
-        for xi, mi in zip(ax, am):
-            if mi != 0.0:
-                a = a + mi * jnp.maximum(x - xi, 0.0)
-        chans = []
-        for ch in range(3):
-            cch = cb[ch]
-            for xi, row in zip(cx, cm):
-                if row[ch] != 0.0:
-                    cch = cch + row[ch] * jnp.maximum(x - xi, 0.0)
-            chans.append(cch)
-        a = jnp.where(outside, 0.0, a)
-        # adjust_opacity(a, ratio), formula-exact
-        a = 1.0 - jnp.power(jnp.clip(1.0 - a, 1e-7, 1.0), ratio)
-
-        emp = a < ss.EMPTY_ALPHA
-        r3 = jnp.stack([c * a for c in chans])             # premult [3,..]
-        d = r3 - pr
-        diff = jnp.sqrt(jnp.sum(d * d, axis=0))
-        start = ~emp & (pe | (diff > thr))
-        run_cnt = run_cnt + start.astype(jnp.float32)
-        sid = run_cnt - 1.0
-        reset = start & (sid <= kf)
-        t_here = jnp.where(reset, 1.0, t_run)
-        t_run = t_here * (1.0 - jnp.where(emp, 0.0, a))
-        slotf = jnp.where(emp, -1.0, jnp.minimum(sid, kf))
-        live = t_here * (~emp).astype(jnp.float32)
-        ev_ref[s] = jnp.concatenate([
-            slotf[None], r3 * live[None], (a * live)[None],
-            t0_all[s][None], t1_all[s][None]])
-        pr = jnp.where(emp[None], pr, r3)
-        pe = emp
-
-    smo[...] = jnp.concatenate([
-        run_cnt[None], pr, pe.astype(jnp.float32)[None]])
-
-    ev = ev_ref[...]                                       # [C, 7, TH, WB]
-    _phase_b(ev[:, 0], ev[:, 1:5],
-             lambda m: jnp.where(m, ev[:, 5], jnp.inf),
-             lambda m: jnp.where(m, ev[:, 6], -jnp.inf),
-             ci_, di_, co, do_, max_k)
+    _phase_a(val_ref.shape[0], shade, thr_ref[...], smi_, smo, ev_ref,
+             jnp.float32(max_k - 1))
+    _phase_b_compact(ev_ref, len_ref, sk0_ref, sk1_ref, ci_, di_, co, do_,
+                     max_k)
 
 
 def _fused_fpp(c: int, k: int) -> int:
     """Fused-kernel strip budget via the shared formula: 1-channel value
     stream (vs 6C rgba+depth), 2 extra per-pixel planes (length, ratio),
-    and 9 per-slice record floats (7 scratch + the t0/t1 temporaries the
-    kernel broadcasts itself)."""
+    and 7 per-slice record floats (5 scratch + the t0/t1 temporaries
+    phase B broadcasts itself, as the compact seg kernel's)."""
     return strip_fpp(c, k, small_rows=_NSMALL, count_plane=False,
-                     per_slice_records=9, stream_per_slice=1,
+                     per_slice_records=7, stream_per_slice=1,
                      extra_planes=2)
 
 
@@ -424,7 +408,7 @@ def fused_fold_chunk(packed, val: jnp.ndarray, length: jnp.ndarray,
         + state_specs,
         out_specs=state_specs,
         out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in packed],
-        scratch_shapes=[pltpu.VMEM((c, 7, TILE_H, wb), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((c, 5, TILE_H, wb), jnp.float32)],
         input_output_aliases={6: 0, 7: 1, 8: 2},
         interpret=interpret,
         name="sitpu_fold_fused",
@@ -513,7 +497,7 @@ def fused_stream_fold(packed, val: jnp.ndarray, length: jnp.ndarray,
         + state_specs,
         out_specs=state_specs,
         out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in packed],
-        scratch_shapes=[pltpu.VMEM((c, 7, TILE_H, wb), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((c, 5, TILE_H, wb), jnp.float32)],
         input_output_aliases={6: 0, 7: 1, 8: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),

@@ -59,6 +59,8 @@ from scenery_insitu_tpu.core.transfer import TransferFunction
 from scenery_insitu_tpu.core.vdi import VDI, VDIMetadata
 from scenery_insitu_tpu.core.volume import Volume, value_scale
 from scenery_insitu_tpu.obs.profiler import in_phase as _in_phase
+from scenery_insitu_tpu.obs.profiler import \
+    note_fold_chunks as _note_fold_chunks
 from scenery_insitu_tpu.obs.profiler import phase as _phase
 from scenery_insitu_tpu.ops import pallas_march as pm
 from scenery_insitu_tpu.ops import pallas_seg as psg
@@ -90,6 +92,8 @@ class AxisSpec:
     # supersegment-fold schedule: "xla" (sequential machine, lax.scan) |
     # "pallas" (round-3 two-phase machine kernel) | "seg" (round-4
     # segmented-scan fold, ops/seg_fold.py) | "pallas_seg" (its VMEM twin)
+    # | "pallas_fused" / "fused_stream" (the twin shading the march's
+    # value plane itself; per march `fold_schedule` says which feed runs)
     fold: str = "xla"
     # storage dtype of the marched volume copy: "bf16" makes
     # `permute_volume` emit a bf16 march layout — volume bytes halve for
@@ -167,14 +171,18 @@ def make_spec(cam: Camera, vol_shape: Tuple[int, int, int],
     nj = rnd(dims_xyz[v_axis])
     fold = cfg.fold
     if fold == "auto":
-        # On TPU the default is the round-4 segmented-scan fold's Pallas
-        # VMEM twin (its counting march runs pm.count_multi_chunk); what
+        # On TPU the default is the segmented-scan fold's Pallas VMEM
+        # twin fed the march's one-channel VALUE plane, which it shades
+        # itself (the shaded rgba chunk never crosses HBM); a march that
+        # has no scalar volume or no concrete transfer function takes
+        # the same kernel's shaded feed instead (`fold_schedule`, per
+        # march). Its counting march runs pm.count_multi_chunk; what
         # Mosaic says about either kernel reaches the caller. On CPU the
         # sequential machine wins (state lives in cache, and seg's
         # K-masked reductions are real extra compute on a scalar core —
         # measured 3x slower at 64x96^2), so tests and the virtual mesh
         # keep "xla".
-        fold = "pallas_seg" if jax.default_backend() == "tpu" else "xla"
+        fold = "pallas_fused" if jax.default_backend() == "tpu" else "xla"
     if fold not in ("xla", "pallas", "seg", "pallas_seg", "pallas_fused",
                     "fused_stream"):
         raise ValueError(f"unknown fold schedule {fold!r} (expected 'auto', "
@@ -546,6 +554,34 @@ def chunk_occupancy_vtiles(vol: Volume, tf: TransferFunction,
     pyr = _occ.pyramid_from_volume(vol, tf, spec, volp=volp,
                                    alpha_eps=alpha_eps)
     return pyr.chunks, pyr.tiles
+
+
+# the schedules whose kernel shades the march's value plane itself
+_SHADE_IN_KERNEL = ("pallas_fused", "fused_stream")
+
+
+def fold_schedule(spec: AxisSpec, vol: Volume, tf) -> str:
+    """The fold schedule THIS march runs. The shade-in-kernel schedules
+    (``pallas_fused`` / ``fused_stream``; the former is what ``auto``
+    means on a TPU) hand the kernel the resampled value plane and bake
+    the transfer function's knots in, so they need a scalar volume and a
+    concrete TF. A pre-shaded volume (``vol.data.ndim == 4``: it has no
+    TF) and a TF that is traced (a caller that jits over it) take the
+    same kernel's shaded feed, ``pallas_seg``: one algorithm, its input
+    form chosen from what the march is given. Every other schedule runs
+    as configured."""
+    if spec.fold in _SHADE_IN_KERNEL and (
+            vol.data.ndim == 4 or not psg.tf_is_concrete(tf)):
+        return "pallas_seg"
+    return spec.fold
+
+
+def _note_write_fold(volp: jnp.ndarray, spec: AxisSpec, fold: str) -> None:
+    """Tell a recorded step how many chunks its write march folds and
+    whether the kernel shades them (counters ``fold_chunks`` /
+    ``fold_chunks_fused``, obs/profiler.scoped_step)."""
+    _note_fold_chunks(-(-volp.shape[0] // spec.chunk),
+                      fold in _SHADE_IN_KERNEL)
 
 
 def _fused_vdi_march(vol, tf, axcam, spec, threshold, k, occ,
@@ -1183,14 +1219,16 @@ def generate_vdi_mxu(vol: Volume, tf: TransferFunction, cam: Camera,
     else:
         threshold = jnp.full((nj, ni), cfg.threshold, jnp.float32)
 
-    if spec.fold == "pallas":
+    fold = fold_schedule(spec, vol, tf)
+    _note_write_fold(volp, spec, fold)
+    if fold == "pallas":
         def consume(packed, rgba, t0, t1):
             return pm.fold_chunk(packed, rgba, t0, t1, threshold, max_k=k)
 
         packed = march(consume, pm.init_packed(k, nj, ni))
         with _phase("fold"):
             color, depth = ss.finalize(pm.unpack_state(packed))
-    elif spec.fold == "pallas_seg":
+    elif fold == "pallas_seg":
         # packed-carry: the [K,...] state keeps one layout across the
         # whole scan so the kernel's input_output_aliases update it in
         # place (a NamedTuple carry would pay a stack/slice copy of the
@@ -1211,21 +1249,21 @@ def generate_vdi_mxu(vol: Volume, tf: TransferFunction, cam: Camera,
                              w_bounds=w_bounds)
         with _phase("fold"):
             color, depth = sf.seg_finalize(psg.unpack_seg_state(packed))
-    elif spec.fold in ("pallas_fused", "fused_stream"):
+    elif fold in _SHADE_IN_KERNEL:
         # shade-in-kernel: the march feeds the raw resampled value plane
         # and the kernel applies TF + opacity correction + depths itself
         # (≅ the reference's one-kernel generation) — the 4-channel rgba
         # and two depth streams never exist in HBM. fused_stream further
         # moves the chunk loop inside the kernel grid (state resident in
         # VMEM per strip, one HBM round trip per march).
-        marcher = (_fused_stream_vdi_march if spec.fold == "fused_stream"
+        marcher = (_fused_stream_vdi_march if fold == "fused_stream"
                    else _fused_vdi_march)
         state = marcher(vol, tf, axcam, spec, threshold, k, occ,
                         u_bounds, v_bounds, step_scale=step_scale,
                         volp=volp, w_bounds=w_bounds)
         with _phase("fold"):
             color, depth = sf.seg_finalize(state)
-    elif spec.fold == "seg":
+    elif fold == "seg":
         def consume(st, rgba, t0, t1):
             return sf.seg_fold_chunk(st, rgba, t0, t1, threshold, max_k=k)
 
@@ -1363,8 +1401,10 @@ def generate_vdi_mxu_temporal(vol: Volume, tf: TransferFunction,
     if volp is None:
         volp = permute_volume(vol, spec)
     occ = _resolve_occupancy(vol, tf, spec, occupancy, volp)
+    fold = fold_schedule(spec, vol, tf)
+    _note_write_fold(volp, spec, fold)
 
-    if spec.fold == "pallas":
+    if fold == "pallas":
         # fused write+count: ONE kernel per chunk, the count rides the
         # writer's own prev-item stream (≅ the reference's single-kernel
         # generate+accumulate, VDIGenerator.comp + AccumulateVDI.comp)
@@ -1380,19 +1420,18 @@ def generate_vdi_mxu_temporal(vol: Volume, tf: TransferFunction,
             volp=volp, w_bounds=w_bounds)
         with _phase("fold"):
             color, depth = ss.finalize(pm.unpack_state(packed))
-    elif spec.fold in ("seg", "pallas_seg", "pallas_fused",
-                       "fused_stream"):
+    elif fold in ("seg", "pallas_seg", "pallas_fused", "fused_stream"):
         # the segmented-scan fold's own running start count IS the true
         # per-pixel segment count — the temporal controller's feedback
         # signal comes out of the write fold for free
-        if spec.fold in ("pallas_fused", "fused_stream"):
+        if fold in _SHADE_IN_KERNEL:
             marcher = (_fused_stream_vdi_march
-                       if spec.fold == "fused_stream"
+                       if fold == "fused_stream"
                        else _fused_vdi_march)
             state = marcher(vol, tf, axcam, spec, thr, k, occ,
                             u_bounds, v_bounds, step_scale=step_scale,
                             volp=volp, w_bounds=w_bounds)
-        elif spec.fold == "pallas_seg":
+        elif fold == "pallas_seg":
             length = axcam.ray_lengths()
 
             def consume(packed, rgba, sk0, sk1):

@@ -124,6 +124,7 @@ def test_the_session_builds_the_adapter_from_the_config(data_dir,
     raw = seeded(np.uint8)
     cfg = FrameworkConfig().with_overrides(
         *overrides(data_dir(raw)), "obs.enabled=true")
+    obs.clear_ledger()      # whatever this worker's earlier tests left
     sess, got = frames(cfg, None, 3)
     assert isinstance(sess.sim, DatasetVolumeAdapter)
     field = sess.sim.field

@@ -1166,3 +1166,57 @@ def test_host_pages_reads_what_a_first_touch_grows():
     blind.counts_faults = False
     assert blind.read()[1:] == (None, None)
     assert set(blind.since(blind.read())) == {"rss_pages"}
+
+
+@pytest.mark.parametrize("fold,share", [("pallas_fused", 1.0),
+                                        ("pallas_seg", 0.0)])
+def test_fold_chunk_counters_say_how_often_the_kernel_shades(fold, share):
+    """PR 46: a recorded step notes, while its first call traces it, how
+    many chunks its write march folds and how many of them the fold
+    kernel shades itself, and adds both on every call:
+    `fold_chunks_fused / fold_chunks` reads 1.0 where the march hands the
+    kernel its value plane, 0.0 where it hands over shaded rgba. An
+    unrecorded step is the jitted function itself and counts nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    from scenery_insitu_tpu.config import SliceMarchConfig, VDIConfig
+    from scenery_insitu_tpu.core.camera import Camera
+    from scenery_insitu_tpu.core.transfer import for_dataset
+    from scenery_insitu_tpu.core.volume import procedural_volume
+    from scenery_insitu_tpu.obs.profiler import scoped_step
+    from scenery_insitu_tpu.ops import slicer
+
+    vol = procedural_volume(24, kind="blobs", seed=3)
+    tf = for_dataset("procedural")
+    cam = Camera.create((0.2, 0.4, 3.0), fov_y_deg=45.0, near=0.3, far=10.0)
+    spec = slicer.make_spec(cam, vol.data.shape, SliceMarchConfig(
+        matmul_dtype="f32", scale=1.0, fold=fold, chunk=8))
+    cfg = VDIConfig(max_supersegments=4, adaptive_mode="temporal")
+    thr = slicer.initial_threshold(vol, tf, cam, spec, cfg)
+
+    def build():
+        @jax.jit
+        def step(data, thr):
+            v = vol._replace(data=data)
+            vdi, _, _, nxt = slicer.generate_vdi_mxu_temporal(
+                v, tf, cam, spec, thr, cfg)
+            return vdi.color, nxt
+        return step
+
+    rec = Recorder(enabled=True)
+    step = scoped_step(build(), rec)
+    for _ in range(3):
+        color, thr = step(vol.data, thr)
+    assert float(jnp.max(color[:, 3])) > 0.0
+    assert rec.counters["fold_chunks"] == 3 * 3         # 24 planes, chunk 8
+    assert rec.counters["fold_chunks_fused"] \
+        / rec.counters["fold_chunks"] == share
+
+    off = Recorder(enabled=False)
+    plain = build()
+    assert scoped_step(plain, off) is plain
+    plain(vol.data, thr)
+    assert "fold_chunks" not in off.counters
+    assert "fold_chunks_fused" not in off.counters
+    assert rec.counters["fold_chunks"] == 9             # nobody listening

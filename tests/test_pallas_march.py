@@ -193,9 +193,11 @@ def test_fold_parity_under_jit(vol, tf):
 def test_auto_fold_resolution(monkeypatch):
     """"auto" resolves by backend NAME — the XLA fold off-TPU
     (interpret-mode pallas is slow; conftest pins the cpu backend), the
-    pallas_seg kernel on TPU with no compile probe in between (a Mosaic
-    refusal raises at compile time) — and an explicit fold choice is
-    always honored."""
+    seg kernel that shades the march's value plane itself on TPU (since
+    PR 46; `slicer.fold_schedule` gives a march without a scalar volume
+    or a concrete TF its shaded feed) with no compile probe in between
+    (a Mosaic refusal raises at compile time) — and an explicit fold
+    choice is always honored."""
     assert jax.default_backend() == "cpu"        # conftest invariant
     cam = Camera.create((0.0, 0.4, 2.8))
     spec = slicer.make_spec(cam, (16, 16, 16), SliceMarchConfig())
@@ -204,7 +206,7 @@ def test_auto_fold_resolution(monkeypatch):
     assert spec_p.fold == "pallas"
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     spec_t = slicer.make_spec(cam, (16, 16, 16), SliceMarchConfig())
-    assert spec_t.fold == "pallas_seg"
+    assert spec_t.fold == "pallas_fused"
 
 
 def test_skip_chunks_execute_through_pallas_fold(tf):

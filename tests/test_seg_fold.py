@@ -299,3 +299,158 @@ def test_compact_depth_equals_td_planes():
     for a, b, name in zip(ref, got, ("color", "depth", "small")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-6, atol=1e-6, err_msg=name)
+
+
+# ---------------------------------------------------------------------------
+# PR 46: what `slicer.fold=auto` takes on a TPU
+
+
+def _tpu_auto_fold(monkeypatch, cam, shape):
+    """The fold `auto` resolves to on a TPU: the backend's NAME is
+    patched for `make_spec`'s resolution only and put back, so the march
+    itself runs as on any CPU (interpret mode, f32 operands)."""
+    from scenery_insitu_tpu.config import SliceMarchConfig
+    from scenery_insitu_tpu.ops import slicer
+
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        return slicer.make_spec(cam, shape, SliceMarchConfig()).fold
+
+
+def _blob_field(shape, seed):
+    """Smooth blobs in [0, 1] on a (D, H, W) grid, empty toward one
+    corner so that occupancy gates have something to skip."""
+    rng = np.random.default_rng(seed)
+    z, y, x = np.meshgrid(*(np.linspace(-1, 1, n) for n in shape),
+                          indexing="ij")
+    out = np.zeros(shape, np.float32)
+    for _ in range(5):
+        c = rng.uniform(-0.1, 0.7, 3)
+        out += rng.uniform(0.4, 0.9) * np.exp(
+            -((z - c[0]) ** 2 + (y - c[1]) ** 2 + (x - c[2]) ** 2)
+            / rng.uniform(0.02, 0.08))
+    return np.clip(out, 0.0, 1.0)
+
+
+# what the cells hold: the dataset cell's u8 operand at K = 20 on a depth
+# that is no chunk multiple; in-plane occupancy gates; a planned band's
+# ownership interval on the march axis; a march toward -axis
+AUTO_TPU_CASES = {
+    "u8_k20_depth27_chunk8": dict(
+        shape=(27, 40, 48), dtype=np.uint8, k=20, chunk=8, eye_z=3.0),
+    "vtiles4": dict(
+        shape=(32, 32, 32), dtype=np.float32, k=6, chunk=8, eye_z=3.0,
+        vtiles=4),
+    "w_bounds": dict(
+        shape=(32, 32, 32), dtype=np.float32, k=6, chunk=8, eye_z=3.0,
+        w_bounds=(-0.55, 0.3)),
+    "sign_minus_remainder": dict(
+        shape=(27, 32, 32), dtype=np.float32, k=6, chunk=8, eye_z=3.0),
+    "sign_plus_remainder_vtiles": dict(
+        shape=(27, 32, 32), dtype=np.uint8, k=6, chunk=8, eye_z=-3.0,
+        vtiles=4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(AUTO_TPU_CASES))
+def test_tpu_auto_path_matches_xla_fold(case, monkeypatch):
+    """The schedule `auto` takes on a TPU (the march hands the fold its
+    value plane, the kernel shades it) against the sequential XLA fold of
+    the shaded chunk, through the generator the cells run
+    (`generate_vdi_mxu_temporal`, two frames, so the controller's
+    feedback is compared too): same supersegments, same counts."""
+    from scenery_insitu_tpu.config import SliceMarchConfig, VDIConfig
+    from scenery_insitu_tpu.core.camera import Camera
+    from scenery_insitu_tpu.core.transfer import for_dataset
+    from scenery_insitu_tpu.core.volume import Volume
+    from scenery_insitu_tpu.ops import slicer
+
+    p = AUTO_TPU_CASES[case]
+    field = _blob_field(p["shape"], seed=len(case))
+    if p["dtype"] == np.uint8:
+        field = np.round(field * 255).astype(np.uint8)
+    vol = Volume.centered(jnp.asarray(field), extent=2.0)
+    assert vol.data.dtype == p["dtype"]
+    tf = for_dataset("procedural")
+    cam = Camera.create((0.2, 0.4, p["eye_z"]), fov_y_deg=45.0, near=0.3,
+                        far=10.0)
+    fold = _tpu_auto_fold(monkeypatch, cam, vol.data.shape)
+    cfg = VDIConfig(max_supersegments=p["k"], adaptive_mode="temporal")
+    out = {}
+    for name in ("xla", fold):
+        spec = slicer.make_spec(
+            cam, vol.data.shape,
+            SliceMarchConfig(matmul_dtype="f32", scale=1.0, fold=name,
+                             chunk=p["chunk"],
+                             occupancy_vtiles=p.get("vtiles", 0)))
+        assert spec.sign == (-1 if p["eye_z"] > 0 else 1)
+        assert slicer.fold_schedule(spec, vol, tf) == name
+        kw = dict(w_bounds=p.get("w_bounds"))
+        thr = slicer.initial_threshold(vol, tf, cam, spec, cfg, **kw)
+        frames = []
+        for _ in range(2):
+            vdi, _, _, thr = slicer.generate_vdi_mxu_temporal(
+                vol, tf, cam, spec, thr, cfg, **kw)
+            frames.append((np.asarray(vdi.color), np.asarray(vdi.depth),
+                           np.asarray(thr.thr)))
+        out[name] = frames
+    assert fold != "xla"
+    assert np.asarray(out["xla"][-1][0])[..., 3, :, :].max() > 0.05
+    for (c_x, d_x, t_x), (c_f, d_f, t_f) in zip(out["xla"], out[fold]):
+        np.testing.assert_allclose(c_f, c_x, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(d_f, d_x, rtol=1e-5, atol=1e-5)
+        # thresholds bisect from identical integer counts -> exact
+        np.testing.assert_allclose(t_f, t_x, rtol=1e-6, atol=1e-6)
+
+
+def test_tpu_auto_selects_by_volume_rank_and_tf(monkeypatch):
+    """On a TPU `auto` is the shade-in-kernel fold; per march the
+    generators keep it for a scalar volume with a concrete transfer
+    function and take `pallas_seg` (the same kernel, shaded feed) for a
+    pre-shaded volume and for a transfer function that is traced. Off a
+    TPU `auto` stays `xla`; an explicit schedule is never re-chosen."""
+    from scenery_insitu_tpu.config import SliceMarchConfig
+    from scenery_insitu_tpu.core.camera import Camera
+    from scenery_insitu_tpu.core.transfer import for_dataset
+    from scenery_insitu_tpu.core.volume import Volume
+    from scenery_insitu_tpu.obs import Recorder, profiler
+    from scenery_insitu_tpu.ops import slicer
+
+    cam = Camera.create((0.2, 0.4, 3.0), fov_y_deg=45.0, near=0.3, far=10.0)
+    shape = (16, 16, 16)
+    assert slicer.make_spec(cam, shape, SliceMarchConfig()).fold == "xla"
+    assert _tpu_auto_fold(monkeypatch, cam, shape) == "pallas_fused"
+    tf = for_dataset("procedural")
+    scalar = Volume.centered(jnp.asarray(_blob_field(shape, 1)), extent=2.0)
+    shaded = Volume(jnp.zeros((4,) + shape, jnp.float32), scalar.origin,
+                    scalar.spacing)
+    auto = slicer.make_spec(cam, shape, SliceMarchConfig(
+        matmul_dtype="f32", fold="pallas_fused"))
+    assert slicer.fold_schedule(auto, scalar, tf) == "pallas_fused"
+    assert slicer.fold_schedule(auto, shaded, None) == "pallas_seg"
+    for name in ("xla", "seg", "pallas", "pallas_seg"):
+        spec = slicer.make_spec(cam, shape, SliceMarchConfig(
+            matmul_dtype="f32", fold=name))
+        assert slicer.fold_schedule(spec, scalar, tf) == name
+        assert slicer.fold_schedule(spec, shaded, None) == name
+
+    # what the generators run, by what they tell a recorded step: a
+    # caller that jits over the TF gets the shaded feed, not an error
+    from scenery_insitu_tpu.config import VDIConfig
+
+    cfg = VDIConfig(max_supersegments=4, adaptive=False, threshold=0.3)
+
+    def gen(vol, tf):
+        return slicer.generate_vdi_mxu(vol, tf, cam, auto, cfg)[0].color
+
+    def noted(fn, *args):
+        rec = Recorder(enabled=True)
+        color = profiler.scoped_step(fn, rec)(*args)
+        return [rec.counters.get("fold_chunks", 0),
+                rec.counters.get("fold_chunks_fused", 0)], np.asarray(color)
+
+    n_closed, c_closed = noted(jax.jit(lambda v: gen(v, tf)), scalar)
+    n_traced, c_traced = noted(jax.jit(gen), scalar, tf)
+    n_shaded, _ = noted(jax.jit(lambda v: gen(v, None)), shaded)
+    assert n_closed == [1, 1] and n_traced == [1, 0] and n_shaded == [1, 0]
+    np.testing.assert_allclose(c_traced, c_closed, rtol=1e-5, atol=1e-5)

@@ -258,7 +258,7 @@ def test_the_1024_cube_step_program_fits_a_chip(topo, monkeypatch):
         mesh, tf, spec, cfg.vdi, cfg.composite,
         reuse_tol=cfg.delta.range_tol).lower(*args, thr).compile()
     text = compiled.as_text()
-    assert "sitpu_fold_seg_compact" in text
+    assert "sitpu_fold_fused" in text
     assert "sitpu_resegment_sorted" in text
     assert obs.ledger() == []
     state = 2 * 4 * np.prod(GS_GRID) // RANKS          # u and v, a rank
@@ -308,7 +308,7 @@ def test_the_kingsnake_step_compiles_at_its_native_dtype(topo, monkeypatch):
     spec = slicer.make_spec(cam, KS_GRID, cfg.slicer,
                             axis_sign=slicer.choose_axis(cam))
     assert (spec.axis, spec.sign, spec.ni, spec.nj, spec.fold) == \
-        (2, -1, 1280, 1280, "pallas_seg")
+        (2, -1, 1280, 1280, "pallas_fused")
     args = (jax.ShapeDtypeStruct(KS_GRID, jnp.uint8,
                                  sharding=on(P("ranks", None, None))),
             like(np.zeros(3, np.float32)),
@@ -331,7 +331,11 @@ def test_the_kingsnake_step_compiles_at_its_native_dtype(topo, monkeypatch):
         reuse_tol=cfg.delta.range_tol, ranges=kept).lower(
             *args, thr).compile()
     text = compiled.as_text()
-    assert "sitpu_fold_seg_compact" in text
+    # since PR 46 the march hands the fold its value plane: no shaded
+    # f32[16,4,1280,1280] chunk is written between them
+    assert "sitpu_fold_fused" in text
+    assert "sitpu_fold_seg_compact" not in text
+    assert "f32[16,4,1280,1280]" not in text
     assert "f32[20,4,1280,1280]" in text
     assert obs.ledger() == []
     for program in (seeded, compiled):

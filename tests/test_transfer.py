@@ -40,3 +40,24 @@ def test_batched_sampling():
     tf = TransferFunction.ramp(0.0, 1.0)
     rgb, a = tf(jnp.linspace(0, 1, 7).reshape(7, 1) * jnp.ones((7, 3)))
     assert rgb.shape == (7, 3, 3) and a.shape == (7, 3)
+
+
+def test_colour_sum_is_lowered_at_f32_precision():
+    """PR 46: a TPU's MXU rounds an f32 dot's operands to bf16 at the
+    default precision (colours 3.6e-3 off the polyline on a v5e); the
+    fold kernel that shades in VMEM sums the same knots in f32, and the
+    chip benchmark holds the two paths to 120 dB of each other. So the
+    colour sum asks for the highest precision, in every lowering."""
+    import jax
+
+    tf = for_dataset("gray_scott")
+    text = jax.jit(lambda x: tf(x)[0]).lower(jnp.zeros((8, 128))).as_text()
+    dots = [ln for ln in text.splitlines() if "dot_general" in ln]
+    assert len(dots) == 1 and "HIGHEST" in dots[0]
+    # and what it computes is the polyline, to f32 rounding
+    x = np.linspace(0.0, 1.0, 1001)
+    rgb, _ = tf(jnp.asarray(x, jnp.float32))
+    want = np.asarray(tf.color_b, np.float64) + np.maximum(
+        x[:, None] - np.asarray(tf.color_x, np.float64), 0.0) \
+        @ np.asarray(tf.color_m, np.float64)
+    np.testing.assert_allclose(np.asarray(rgb), want, atol=2e-6)

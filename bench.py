@@ -23,11 +23,11 @@ control flow and measures nothing):
   SITPU_BENCH_STEPS=256 SITPU_BENCH_K=16 SITPU_BENCH_FRAMES=25|5
   SITPU_BENCH_SIM_STEPS=10 SITPU_BENCH_ADAPTIVE_ITERS=2
   SITPU_BENCH_ENGINE=mxu|gather
-  SITPU_BENCH_FOLD=auto|pallas_seg|seg|pallas|xla  (auto = pallas_seg on
-    TPU; see config.SliceMarchConfig.fold for the schedules)
+  SITPU_BENCH_FOLD=auto|pallas_fused|pallas_seg|xla  (auto = pallas_fused
+    on TPU; see config.SliceMarchConfig.fold for the schedules)
   SITPU_BENCH_AUTOTUNE=1|0  (default ON for TPU temporal runs at
     grid<=512 with no explicit FOLD: times 2 frames each of
-    auto/fused_stream/xla at warmup and benches the winner — set 0, or
+    auto/xla at warmup and benches the winner — set 0, or
     set SITPU_BENCH_FOLD, for fixed-fold A/B captures)
   SITPU_BENCH_SCAN_FRAMES=1  (whole frame loop in ONE lax.scan launch)
   SITPU_BENCH_SIM_STEPS=0    (render-only: static field, moving camera)
@@ -277,9 +277,9 @@ def main():
     # explicit SITPU_BENCH_FOLD disables): the fold-schedule ranking has
     # disagreed with the synthetic microbench across rounds — so measure
     # 2 frames per candidate and bench the winner. Candidates:
-    # the platform default, the whole-march stream fold, and the
-    # fuses-into-the-march XLA fold (the round-2 256^3 frame-context
-    # winner). Per-candidate guarded; compile cache makes repeats cheap.
+    # the platform default and the fuses-into-the-march XLA fold (the
+    # round-2 256^3 frame-context winner). Per-candidate guarded; compile
+    # cache makes repeats cheap.
     # gated to <=512 grids: the tuning jits are NOT donated (each timed
     # call holds input + output sim copies), which is fine at 512^3
     # (~1 GB extra) but would OOM the 1024^3 memory plan before the
@@ -293,7 +293,7 @@ def main():
         st0 = gs.GrayScott.init((grid, grid, grid))
         autotune_ms = {}
         thr0 = None
-        for fname in ("auto", "fused_stream", "xla"):
+        for fname in ("auto", "xla"):
             try:
                 _, fs = make_step(fname)
                 fr = jax.jit(lambda u_, v_, yaw, th, fs=fs:

@@ -91,7 +91,7 @@ KNOB_MATRIX = {
     "slicer.engine": "mxu is the only coarse consumer (gather ledgers "
                      "lod.engine); render_bench.py A/Bs the engines",
     "slicer.scale": "virtual-grid multiplier; render_bench.py sweeps it",
-    "slicer.chunk": "fold chunking; benchmarks/fold_microbench.py",
+    "slicer.chunk": "fold chunking; chipbench's fold_device_ms",
     "slicer.matmul_dtype": "bf16/f32 operand A/B in render_bench.py",
     "slicer.render_dtype": "marched-copy storage dtype; hbm_bench.py",
     "slicer.s_floor": "near-plane clip; fixed across rungs (geometry, "
@@ -100,7 +100,8 @@ KNOB_MATRIX = {
                          "(composes with LOD: a coarse brick still "
                          "chunk-skips)",
     "slicer.occupancy_vtiles": "in-plane skip tiles; occupancy_bench.py",
-    "slicer.fold": "supersegment fold schedule; fold_microbench.py",
+    "slicer.fold": "supersegment fold schedule; chipbench's "
+                   "fold_device_ms",
 }
 
 

@@ -106,10 +106,10 @@ def main():
         return ss.finalize(stf)
     timeit(jax.jit(write_pass), vol.data, label="one writing march")
 
-    # the round-4 fold schedules head to head: ONE write march each
+    # the fold schedules head to head: ONE write march each
     # (adaptive off -> fixed threshold, no counting pass), guarded per
     # variant so a Mosaic rejection can't kill the rest of the profile
-    folds = ["xla", "seg"]
+    folds = ["xla"]
     if jax.default_backend() == "tpu":
         folds += ["pallas_seg", "pallas_fused"]
     for fname in folds:

@@ -131,32 +131,27 @@ class SliceMarchConfig:
     # A request larger than the geometry supports is clamped and the
     # reduction recorded on the fallback ledger (occupancy.vtiles_clamp).
     occupancy_vtiles: int = -1
-    # Supersegment-fold schedule for the VDI marches:
+    # Supersegment-fold schedule for the VDI marches (docs/SEG_FOLD.md):
     #   "xla"        sequential ss.push machine in a lax.scan (every slice
     #                round-trips the [K] state through HBM — the portable
-    #                reference schedule, fastest on CPU);
-    #   "pallas"     round-3 two-phase machine kernel (ops/pallas_march.py);
-    #   "seg"        round-4 segmented-scan fold (ops/seg_fold.py): start
-    #                flags / segment ids / transmittance all data-parallel,
-    #                [K] state touched once per chunk;
-    #   "pallas_seg" the seg fold's VMEM pixel-strip twin (ops/pallas_seg.py);
-    #   "pallas_fused" shade-in-kernel: the TF + opacity correction +
-    #                depth streams move into the fold kernel (≅ the
+    #                reference schedule, fastest on CPU, and what every
+    #                chipbench cell is compared against);
+    #   "pallas_fused" the segmented-scan fold on VMEM pixel strips
+    #                (ops/pallas_seg.py), shading in the kernel: TF +
+    #                opacity correction + depths move into the fold (≅ the
     #                reference's single-kernel generation,
     #                VDIGenerator.comp + AccumulateVDI.comp); the march
     #                hands over its one-channel value plane and the
     #                shaded rgba chunk never crosses HBM;
-    #   "fused_stream" the whole-march fused fold: chunk loop inside the
-    #                kernel grid, [K] state VMEM-resident per pixel strip
-    #                (one HBM round trip per march; costs a f32[S,Nj,Ni]
-    #                stream buffer);
+    #   "pallas_seg" the same kernel fed the shaded rgba chunk;
     #   "auto"       pallas_fused on TPU (since PR 46), xla elsewhere.
-    # The two shade-in-kernel schedules bake the transfer function's
-    # knots into the kernel, so per march they need a scalar volume and
-    # a concrete TF: a pre-shaded RGBA volume (the novel-view proxy) and
-    # a TF that is traced get pallas_seg's shaded feed of the same
-    # kernel instead (ops/slicer.fold_schedule — chosen from what the
-    # march is given, whether the value came from "auto" or was named).
+    # pallas_fused bakes the transfer function's knots into the kernel,
+    # so per march it needs a scalar volume and a concrete TF: a
+    # pre-shaded RGBA volume (the novel-view proxy) and a TF that is
+    # traced get pallas_seg's shaded feed of the same kernel instead
+    # (ops/slicer.fold_schedule — chosen from what the march is given,
+    # whether the value came from "auto" or was named). Any other name
+    # is refused by slicer.make_spec.
     fold: str = "auto"
 
     def __post_init__(self):

@@ -201,8 +201,8 @@ _LEDGER_REGISTRY: Dict[str, str] = {
                             "lax field_ranges recompute runs",
     "occupancy.vtiles_clamp": "requested in-plane occupancy tiles exceed "
                               "the geometry; clamped",
-    "ops.pallas_march.block_width": "kernel block width clamped below "
-                                    "the VMEM-budget request",
+    "ops.fold.block_width": "kernel block width clamped below the "
+                            "VMEM-budget request",
     "phase_bench.sim_fused": "phase_bench: --sim-fused needs a 1-rank "
                              "mesh; xla_roll runs",
     "scenario.tf_update": "a steered transfer function not seen before "
@@ -302,7 +302,7 @@ _COUNTER_REGISTRY: Dict[str, str] = {
                    "traced it, added every frame (recorded runs only)",
     "fold_chunks_fused": "those of `fold_chunks` the fold kernel shades "
                          "itself from the march's one-channel value "
-                         "plane (`pallas_fused` / `fused_stream`: what "
+                         "plane (`pallas_fused`: what "
                          "`slicer.fold=auto` takes on a TPU for a "
                          "scalar volume with a concrete transfer "
                          "function); the others cross HBM as shaded "

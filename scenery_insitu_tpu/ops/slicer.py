@@ -47,6 +47,7 @@ regime, cached by jit.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -62,7 +63,6 @@ from scenery_insitu_tpu.obs.profiler import in_phase as _in_phase
 from scenery_insitu_tpu.obs.profiler import \
     note_fold_chunks as _note_fold_chunks
 from scenery_insitu_tpu.obs.profiler import phase as _phase
-from scenery_insitu_tpu.ops import pallas_march as pm
 from scenery_insitu_tpu.ops import pallas_seg as psg
 from scenery_insitu_tpu.ops import seg_fold as sf
 from scenery_insitu_tpu.ops import supersegments as ss
@@ -89,11 +89,11 @@ class AxisSpec:
     matmul_dtype: str = "bf16"   # resampling matmul operand dtype
     s_floor: float = 1e-3     # min depth ratio: slices closer are dropped
     skip_empty: bool = True   # chunk_occupancy-based empty-space skipping
-    # supersegment-fold schedule: "xla" (sequential machine, lax.scan) |
-    # "pallas" (round-3 two-phase machine kernel) | "seg" (round-4
-    # segmented-scan fold, ops/seg_fold.py) | "pallas_seg" (its VMEM twin)
-    # | "pallas_fused" / "fused_stream" (the twin shading the march's
-    # value plane itself; per march `fold_schedule` says which feed runs)
+    # supersegment-fold schedule: "xla" (sequential machine, lax.scan:
+    # the reference) | "pallas_fused" (the segmented-scan fold's VMEM
+    # kernel shading the march's value plane itself) | "pallas_seg" (the
+    # same kernel fed the shaded chunk); per march `fold_schedule` says
+    # which feed runs
     fold: str = "xla"
     # storage dtype of the marched volume copy: "bf16" makes
     # `permute_volume` emit a bf16 march layout — volume bytes halve for
@@ -176,18 +176,16 @@ def make_spec(cam: Camera, vol_shape: Tuple[int, int, int],
         # itself (the shaded rgba chunk never crosses HBM); a march that
         # has no scalar volume or no concrete transfer function takes
         # the same kernel's shaded feed instead (`fold_schedule`, per
-        # march). Its counting march runs pm.count_multi_chunk; what
+        # march). Its counting march runs psg.count_multi_chunk; what
         # Mosaic says about either kernel reaches the caller. On CPU the
         # sequential machine wins (state lives in cache, and seg's
         # K-masked reductions are real extra compute on a scalar core —
         # measured 3x slower at 64x96^2), so tests and the virtual mesh
         # keep "xla".
         fold = "pallas_fused" if jax.default_backend() == "tpu" else "xla"
-    if fold not in ("xla", "pallas", "seg", "pallas_seg", "pallas_fused",
-                    "fused_stream"):
+    if fold not in ("xla", "pallas_seg", "pallas_fused"):
         raise ValueError(f"unknown fold schedule {fold!r} (expected 'auto', "
-                         "'xla', 'pallas', 'seg', 'pallas_seg', "
-                         "'pallas_fused' or 'fused_stream')")
+                         "'xla', 'pallas_seg' or 'pallas_fused')")
     # resolve the benched auto default (-1): in-plane tiling pays on the
     # TPU march (the A/B in benchmarks/occupancy_bench.py — sparse
     # fields skip most cells) but adds nt lax.cond branches per chunk,
@@ -556,21 +554,16 @@ def chunk_occupancy_vtiles(vol: Volume, tf: TransferFunction,
     return pyr.chunks, pyr.tiles
 
 
-# the schedules whose kernel shades the march's value plane itself
-_SHADE_IN_KERNEL = ("pallas_fused", "fused_stream")
-
-
 def fold_schedule(spec: AxisSpec, vol: Volume, tf) -> str:
-    """The fold schedule THIS march runs. The shade-in-kernel schedules
-    (``pallas_fused`` / ``fused_stream``; the former is what ``auto``
-    means on a TPU) hand the kernel the resampled value plane and bake
-    the transfer function's knots in, so they need a scalar volume and a
+    """The fold schedule THIS march runs. ``pallas_fused`` (what ``auto``
+    means on a TPU) hands the kernel the resampled value plane and bakes
+    the transfer function's knots in, so it needs a scalar volume and a
     concrete TF. A pre-shaded volume (``vol.data.ndim == 4``: it has no
     TF) and a TF that is traced (a caller that jits over it) take the
     same kernel's shaded feed, ``pallas_seg``: one algorithm, its input
     form chosen from what the march is given. Every other schedule runs
     as configured."""
-    if spec.fold in _SHADE_IN_KERNEL and (
+    if spec.fold == "pallas_fused" and (
             vol.data.ndim == 4 or not psg.tf_is_concrete(tf)):
         return "pallas_seg"
     return spec.fold
@@ -581,70 +574,7 @@ def _note_write_fold(volp: jnp.ndarray, spec: AxisSpec, fold: str) -> None:
     whether the kernel shades them (counters ``fold_chunks`` /
     ``fold_chunks_fused``, obs/profiler.scoped_step)."""
     _note_fold_chunks(-(-volp.shape[0] // spec.chunk),
-                      fold in _SHADE_IN_KERNEL)
-
-
-def _fused_vdi_march(vol, tf, axcam, spec, threshold, k, occ,
-                     u_bounds, v_bounds, step_scale: float = 1.0,
-                     volp=None, w_bounds=None):
-    """One write march through the fused shade+fold kernel (raw mode).
-    The length/ds/ratio geometry matches slice_march's own shading
-    formula INCLUDING step_scale — one implementation for both the plain
-    and temporal generators."""
-    length = axcam.ray_lengths()
-    ds = jnp.abs(axcam.dwm) / axcam.zp
-    ratio = ds * length / nominal_step(vol, step_scale)
-
-    def consume(packed, val, sk):
-        return psg.fused_fold_chunk(packed, val, length, ratio, sk,
-                                    sk + ds, threshold, max_k=k, tf=tf)
-
-    packed = slice_march(vol, tf, axcam, spec, consume,
-                         psg.init_seg_packed(k, spec.nj, spec.ni),
-                         u_bounds, v_bounds, step_scale=step_scale,
-                         occupancy=occ, raw=True, volp=volp,
-                         w_bounds=w_bounds)
-    return psg.unpack_seg_state(packed)
-
-
-def _fused_stream_vdi_march(vol, tf, axcam, spec, threshold, k, occ,
-                            u_bounds, v_bounds, step_scale: float = 1.0,
-                            volp=None, w_bounds=None):
-    """Two-phase whole-march fused fold: phase M materializes the raw
-    value stream (the matmul phase, chunk-skipping intact — skipped
-    chunks write -1 planes), then ONE pallas_call folds the entire
-    stream with the [K] state VMEM-resident per strip
-    (ops/pallas_seg.fused_stream_fold). Costs a f32[S,Nj,Ni] stream
-    buffer (~840 MB at the 512^3 flagship scale: 512 x 640^2 x 4 B) — the chunked
-    fold="pallas_fused" is the memory-constrained alternative
-    (e.g. 1024^3, where this buffer would be 6.7 GB)."""
-    length = axcam.ray_lengths()
-    ds = jnp.abs(axcam.dwm) / axcam.zp
-    ratio = ds * length / nominal_step(vol, step_scale)
-    c = spec.chunk
-    # static slice count straight from the shape — permute_volume here
-    # would materialize a full transposed copy in eager execution
-    s_total = vol.data.shape[_DATA_DIM[spec.axis]]
-    s_pad = -(-s_total // c) * c
-
-    def consume(carry, val, sk):
-        buf, skb, idx = carry
-        buf = jax.lax.dynamic_update_slice(buf, val, (idx * c, 0, 0))
-        skb = jax.lax.dynamic_update_slice(skb, sk, (idx * c,))
-        return buf, skb, idx + 1
-
-    buf0 = jnp.zeros((s_pad, spec.nj, spec.ni), jnp.float32)
-    sk0 = jnp.zeros((s_pad,), jnp.float32)
-    buf, skb, _ = slice_march(vol, tf, axcam, spec, consume,
-                              (buf0, sk0, jnp.int32(0)), u_bounds,
-                              v_bounds, step_scale=step_scale,
-                              occupancy=occ, raw=True, raw_full_skip=True,
-                              volp=volp, w_bounds=w_bounds)
-    with _phase("fold"):
-        packed = psg.fused_stream_fold(
-            psg.init_seg_packed(k, spec.nj, spec.ni), buf, length, ratio,
-            skb, skb + ds, threshold, max_k=k, chunk=c, tf=tf)
-    return psg.unpack_seg_state(packed)
+                      fold == "pallas_fused")
 
 
 def occupancy_for(vol: Volume, tf: TransferFunction, spec: AxisSpec,
@@ -684,13 +614,19 @@ def slice_march(vol: Volume, tf: TransferFunction, axcam: AxisCamera,
                 u_bounds=None, v_bounds=None, step_scale: float = 1.0,
                 occupancy: Optional[jnp.ndarray] = None,
                 early_stop: Optional[Callable] = None, raw: bool = False,
-                raw_full_skip: bool = False,
                 shaded_compact: bool = False,
                 volp: Optional[jnp.ndarray] = None,
                 w_bounds=None):
-    """The chunked slice march. Calls ``consume(carry, rgba [C,4,Nj,Ni],
-    t0 [C,Nj,Ni], t1 [C,Nj,Ni]) -> carry`` for each chunk of slices, front
-    to back, and returns the final carry.
+    """The chunked slice march. Calls ``consume`` for each chunk of
+    slices, front to back, and returns the final carry. It speaks three
+    ``consume`` contracts:
+
+    - planes (the default): ``consume(carry, rgba [C,4,Nj,Ni],
+      t0 [C,Nj,Ni], t1 [C,Nj,Ni]) -> carry`` — the plain render
+      (`render_slices`), every counting march (`_histogram_threshold`,
+      "search" mode) and the XLA reference fold of `write_march`;
+    - ``shaded_compact=True``: `write_march`'s ``pallas_seg`` feed;
+    - ``raw=True``: `write_march`'s ``pallas_fused`` feed.
 
     rgba is premultiplied, already opacity-corrected for the per-ray
     inter-slice path length, and zero outside the volume/ownership bounds.
@@ -718,7 +654,8 @@ def slice_march(vol: Volume, tf: TransferFunction, axcam: AxisCamera,
     slices) and the per-slice eye-depth ratios — no transfer function,
     no opacity correction, no t0/t1 streams. This is the fused-kernel
     feed (ops/pallas_seg.fused_fold_chunk shades in-kernel); scalar
-    volumes only.
+    volumes only. Occupancy-skipped iterations feed a C=1 plane of
+    sentinels.
 
     ``w_bounds`` (an open world interval ``(w_lo, w_hi)`` on the march
     axis) additionally drops slices whose plane lies outside it — the
@@ -926,13 +863,6 @@ def slice_march(vol: Volume, tf: TransferFunction, axcam: AxisCamera,
         s0 = jnp.float32(spec.sign) * (local_w0 + ci * c * axcam.dwm - ew) \
             / axcam.zp
         if raw:
-            if raw_full_skip:
-                # stream builders need every chunk at full C rows: emit
-                # the whole chunk of -1 sentinels + its true depth ratios
-                sk_c = s0 + jnp.arange(c, dtype=jnp.float32) * ds
-                return consume(carry,
-                               jnp.full((c, spec.nj, spec.ni), -1.0,
-                                        jnp.float32), sk_c)
             return consume(carry,
                            jnp.full((1, spec.nj, spec.ni), -1.0,
                                     jnp.float32), s0[None])
@@ -1141,6 +1071,73 @@ def raycast_mxu(vol: Volume, tf: TransferFunction, cam: Camera,
 # ----------------------------------------------------------- VDI generation
 
 
+def write_march(vol: Volume, tf, axcam: AxisCamera, spec: AxisSpec,
+                threshold: jnp.ndarray, k: int, *, volp: jnp.ndarray,
+                step_scale: float = 1.0, count: bool = False, **bounds):
+    """THE write march: one `slice_march` (``volp``, ``step_scale`` and
+    ``bounds`` — its ``u_bounds`` / ``v_bounds`` / ``w_bounds`` /
+    ``occupancy`` — go to it as given; here it is only told which
+    ``consume`` contract to feed) folded into K supersegments at the
+    ``threshold`` map -> ``(color [K,4,Nj,Ni], depth [K,2,Nj,Ni],
+    count i32[Nj,Ni])``. ``count`` is the TRUE per-pixel segment starts
+    at this threshold (the temporal controller's feedback): the kernel
+    folds carry it for free, the XLA machine folds ``ss.push_count``
+    beside ``ss.push`` only where ``count=True`` and returns None
+    otherwise. The only place a fold schedule is chosen
+    (`fold_schedule`) and noted."""
+    nj, ni = spec.nj, spec.ni
+    march = functools.partial(slice_march, vol, tf, axcam, spec, volp=volp,
+                              step_scale=step_scale, **bounds)
+    fold = fold_schedule(spec, vol, tf)
+    _note_write_fold(volp, spec, fold)
+    if fold == "xla":
+        def consume(carry, rgba, t0, t1):
+            st, cst = carry
+            for i in range(rgba.shape[0]):
+                st = ss.push(st, k, threshold, rgba[i], t0[i], t1[i])
+                if count:
+                    cst = ss.push_count(cst, threshold, rgba[i])
+            return st, cst
+
+        state, cstate = march(
+            consume, (ss.init_state(k, nj, ni),
+                      ss.init_count(nj, ni) if count else None))
+        with _phase("fold"):
+            color, depth = ss.finalize(state)
+        return color, depth, (cstate.count if count else None)
+
+    # the kernel folds carry the packed triple: the [K,...] state keeps
+    # one layout across the whole scan so `input_output_aliases` update
+    # it in place, and the kernel forms t = sk*length itself — the
+    # [C,2,Nj,Ni] depth planes never hit HBM
+    length = axcam.ray_lengths()
+    if fold == "pallas_fused":
+        # the march feeds the raw resampled value plane and the kernel
+        # applies TF + opacity correction itself (≅ the reference's
+        # one-kernel generation): the shaded chunk never exists in HBM.
+        # ds/ratio match slice_march's own shading formula INCLUDING
+        # step_scale
+        ds = jnp.abs(axcam.dwm) / axcam.zp
+        ratio = ds * length / nominal_step(vol, step_scale)
+
+        def consume(packed, val, sk):
+            return psg.fused_fold_chunk(packed, val, length, ratio, sk,
+                                        sk + ds, threshold, max_k=k, tf=tf)
+
+        packed = march(consume, psg.init_seg_packed(k, nj, ni), raw=True)
+    else:
+        def consume(packed, rgba, sk0, sk1):
+            return psg.fold_chunk_packed(packed, rgba, threshold, max_k=k,
+                                         sk0=sk0, sk1=sk1, length=length)
+
+        packed = march(consume, psg.init_seg_packed(k, nj, ni),
+                       shaded_compact=True)
+    state = psg.unpack_seg_state(packed)
+    with _phase("fold"):
+        color, depth = sf.seg_finalize(state)
+    return color, depth, state.cnt
+
+
 @_in_phase("march")
 def generate_vdi_mxu(vol: Volume, tf: TransferFunction, cam: Camera,
                      spec: AxisSpec, cfg: Optional[VDIConfig] = None,
@@ -1191,11 +1188,11 @@ def generate_vdi_mxu(vol: Volume, tf: TransferFunction, cam: Camera,
     # counting + writing march of this generation
     if volp is None:
         volp = permute_volume(vol, spec)
-    occ = _resolve_occupancy(vol, tf, spec, occupancy, volp)
-    march = lambda consume, carry0: slice_march(
-        vol, tf, axcam, spec, consume, carry0, u_bounds, v_bounds,
-        step_scale=step_scale, occupancy=occ, volp=volp,
-        w_bounds=w_bounds)
+    bound = dict(u_bounds=u_bounds, v_bounds=v_bounds, w_bounds=w_bounds,
+                 step_scale=step_scale, volp=volp,
+                 occupancy=_resolve_occupancy(vol, tf, spec, occupancy,
+                                              volp))
+    march = functools.partial(slice_march, vol, tf, axcam, spec, **bound)
 
     if cfg.adaptive and cfg.adaptive_mode == "temporal":
         raise ValueError(
@@ -1219,67 +1216,8 @@ def generate_vdi_mxu(vol: Volume, tf: TransferFunction, cam: Camera,
     else:
         threshold = jnp.full((nj, ni), cfg.threshold, jnp.float32)
 
-    fold = fold_schedule(spec, vol, tf)
-    _note_write_fold(volp, spec, fold)
-    if fold == "pallas":
-        def consume(packed, rgba, t0, t1):
-            return pm.fold_chunk(packed, rgba, t0, t1, threshold, max_k=k)
-
-        packed = march(consume, pm.init_packed(k, nj, ni))
-        with _phase("fold"):
-            color, depth = ss.finalize(pm.unpack_state(packed))
-    elif fold == "pallas_seg":
-        # packed-carry: the [K,...] state keeps one layout across the
-        # whole scan so the kernel's input_output_aliases update it in
-        # place (a NamedTuple carry would pay a stack/slice copy of the
-        # depth plane per chunk). Compact depth: the kernel computes
-        # t = sk*length itself — the [C,2,Nj,Ni] planes never hit HBM.
-        length = axcam.ray_lengths()
-
-        def consume(packed, rgba, sk0, sk1):
-            return psg.fold_chunk_packed(packed, rgba, threshold=threshold,
-                                         max_k=k, sk0=sk0, sk1=sk1,
-                                         length=length)
-
-        packed = slice_march(vol, tf, axcam, spec, consume,
-                             psg.init_seg_packed(k, nj, ni),
-                             u_bounds, v_bounds, step_scale=step_scale,
-                             occupancy=occ,
-                             shaded_compact=True, volp=volp,
-                             w_bounds=w_bounds)
-        with _phase("fold"):
-            color, depth = sf.seg_finalize(psg.unpack_seg_state(packed))
-    elif fold in _SHADE_IN_KERNEL:
-        # shade-in-kernel: the march feeds the raw resampled value plane
-        # and the kernel applies TF + opacity correction + depths itself
-        # (≅ the reference's one-kernel generation) — the 4-channel rgba
-        # and two depth streams never exist in HBM. fused_stream further
-        # moves the chunk loop inside the kernel grid (state resident in
-        # VMEM per strip, one HBM round trip per march).
-        marcher = (_fused_stream_vdi_march if fold == "fused_stream"
-                   else _fused_vdi_march)
-        state = marcher(vol, tf, axcam, spec, threshold, k, occ,
-                        u_bounds, v_bounds, step_scale=step_scale,
-                        volp=volp, w_bounds=w_bounds)
-        with _phase("fold"):
-            color, depth = sf.seg_finalize(state)
-    elif fold == "seg":
-        def consume(st, rgba, t0, t1):
-            return sf.seg_fold_chunk(st, rgba, t0, t1, threshold, max_k=k)
-
-        state = march(consume, sf.init_seg_state(k, nj, ni))
-        with _phase("fold"):
-            color, depth = sf.seg_finalize(state)
-    else:
-        def consume(st, rgba, t0, t1):
-            for i in range(rgba.shape[0]):
-                st = ss.push(st, k, threshold, rgba[i], t0[i], t1[i])
-            return st
-
-        state = march(consume, ss.init_state(k, nj, ni))
-        with _phase("fold"):
-            color, depth = ss.finalize(state)
-
+    color, depth, _ = write_march(vol, tf, axcam, spec, threshold, k,
+                                  **bound)
     meta = _vdi_meta(vol, axcam, ni, nj, frame_index, step_scale)
     return VDI(color, depth), meta, axcam
 
@@ -1304,13 +1242,13 @@ def _histogram_threshold(march, cfg: VDIConfig, k: int, nj: int, ni: int,
     """One counting march for ALL candidate thresholds at once."""
     tvec = ss.threshold_candidates(cfg.histogram_bins, cfg.thr_max)
 
-    # any pallas fold implies a TPU backend where the VMEM counting
-    # kernel is also the right schedule for the histogram march
-    if fold.startswith("pallas") or fold == "fused_stream":
+    # a kernel fold implies a TPU backend where the VMEM counting kernel
+    # is also the right schedule for the histogram march
+    if fold != "xla":
         def consume_multi(carry, rgba, t0, t1):
-            return pm.count_multi_chunk(carry, rgba, tvec)
+            return psg.count_multi_chunk(carry, rgba, tvec)
 
-        counts = march(consume_multi, pm.init_count_multi_packed(
+        counts = march(consume_multi, psg.init_count_multi_packed(
             cfg.histogram_bins, nj, ni))[0]
     else:
         def consume_multi(st, rgba, t0, t1):
@@ -1345,9 +1283,9 @@ def initial_threshold(vol: Volume, tf: TransferFunction, cam: Camera,
         axcam = make_axis_camera(vol, cam, spec, box_min, box_max)
     volp = permute_volume(vol, spec)
     occ = _resolve_occupancy(vol, tf, spec, occupancy, volp)
-    march = lambda consume, carry0: slice_march(
-        vol, tf, axcam, spec, consume, carry0, u_bounds, v_bounds,
-        step_scale=step_scale, occupancy=occ, volp=volp,
+    march = functools.partial(
+        slice_march, vol, tf, axcam, spec, u_bounds=u_bounds,
+        v_bounds=v_bounds, step_scale=step_scale, occupancy=occ, volp=volp,
         w_bounds=w_bounds)
     kt = cfg.max_supersegments if k_target is None else k_target
     thr = _histogram_threshold(march, cfg, kt,
@@ -1400,80 +1338,11 @@ def generate_vdi_mxu_temporal(vol: Volume, tf: TransferFunction,
         axcam = make_axis_camera(vol, cam, spec, box_min, box_max)
     if volp is None:
         volp = permute_volume(vol, spec)
-    occ = _resolve_occupancy(vol, tf, spec, occupancy, volp)
-    fold = fold_schedule(spec, vol, tf)
-    _note_write_fold(volp, spec, fold)
-
-    if fold == "pallas":
-        # fused write+count: ONE kernel per chunk, the count rides the
-        # writer's own prev-item stream (≅ the reference's single-kernel
-        # generate+accumulate, VDIGenerator.comp + AccumulateVDI.comp)
-        def consume(carry, rgba, t0, t1):
-            packed, count = carry
-            return pm.fold_chunk(packed, rgba, t0, t1, thr, max_k=k,
-                                 count=count)
-
-        packed, count = slice_march(
-            vol, tf, axcam, spec, consume,
-            (pm.init_packed(k, nj, ni), jnp.zeros((nj, ni), jnp.int32)),
-            u_bounds, v_bounds, step_scale=step_scale, occupancy=occ,
-            volp=volp, w_bounds=w_bounds)
-        with _phase("fold"):
-            color, depth = ss.finalize(pm.unpack_state(packed))
-    elif fold in ("seg", "pallas_seg", "pallas_fused", "fused_stream"):
-        # the segmented-scan fold's own running start count IS the true
-        # per-pixel segment count — the temporal controller's feedback
-        # signal comes out of the write fold for free
-        if fold in _SHADE_IN_KERNEL:
-            marcher = (_fused_stream_vdi_march
-                       if fold == "fused_stream"
-                       else _fused_vdi_march)
-            state = marcher(vol, tf, axcam, spec, thr, k, occ,
-                            u_bounds, v_bounds, step_scale=step_scale,
-                            volp=volp, w_bounds=w_bounds)
-        elif fold == "pallas_seg":
-            length = axcam.ray_lengths()
-
-            def consume(packed, rgba, sk0, sk1):
-                return psg.fold_chunk_packed(packed, rgba, threshold=thr,
-                                             max_k=k, sk0=sk0, sk1=sk1,
-                                             length=length)
-
-            packed = slice_march(vol, tf, axcam, spec, consume,
-                                 psg.init_seg_packed(k, nj, ni),
-                                 u_bounds, v_bounds,
-                                 step_scale=step_scale, occupancy=occ,
-                                 shaded_compact=True, volp=volp,
-                                 w_bounds=w_bounds)
-            state = psg.unpack_seg_state(packed)
-        else:
-            def consume(st, rgba, t0, t1):
-                return sf.seg_fold_chunk(st, rgba, t0, t1, thr, max_k=k)
-
-            state = slice_march(vol, tf, axcam, spec, consume,
-                                sf.init_seg_state(k, nj, ni),
-                                u_bounds, v_bounds,
-                                step_scale=step_scale, occupancy=occ,
-                                volp=volp, w_bounds=w_bounds)
-        with _phase("fold"):
-            color, depth = sf.seg_finalize(state)
-        count = state.cnt
-    else:
-        def consume(carry, rgba, t0, t1):
-            st, cst = carry
-            for i in range(rgba.shape[0]):
-                st = ss.push(st, k, thr, rgba[i], t0[i], t1[i])
-                cst = ss.push_count(cst, thr, rgba[i])
-            return st, cst
-
-        state, cstate = slice_march(
-            vol, tf, axcam, spec, consume,
-            (ss.init_state(k, nj, ni), ss.init_count(nj, ni)),
-            u_bounds, v_bounds, step_scale=step_scale, occupancy=occ,
-            volp=volp, w_bounds=w_bounds)
-        with _phase("fold"):
-            color, depth = ss.finalize(state)
-        count = cstate.count
+    color, depth, count = write_march(
+        vol, tf, axcam, spec, thr, k, count=True, u_bounds=u_bounds,
+        v_bounds=v_bounds, w_bounds=w_bounds, step_scale=step_scale,
+        volp=volp,
+        occupancy=_resolve_occupancy(vol, tf, spec, occupancy, volp))
     with _phase("fold"):        # the controller reads the fold's count
         next_thr = ss.update_threshold(threshold, count, kt,
                                        cfg.adaptive_delta, cfg.thr_min,

@@ -80,7 +80,7 @@ def test_pallas_fold_matches_golden():
     Shares make_golden.build_vdi so the configs cannot drift apart."""
     from tests.golden.make_golden import build_vdi
 
-    comp, _, _ = build_vdi(fold="pallas")
+    comp, _, _ = build_vdi(fold="pallas_fused")
     with np.load(os.path.join(GOLDEN_DIR, "golden_vdi.npz")) as z:
         np.testing.assert_allclose(np.asarray(comp.color), z["color"],
                                    rtol=2e-4, atol=2e-5)

@@ -228,8 +228,7 @@ class TestPallas:
 
     def test_real_kernels_clean(self):
         """Every production pallas_call declares its tile-divisibility
-        handling and keeps its SMEM operands whole (the fold_microbench
-        experiment kernels are baselined, not clean)."""
+        handling and keeps its SMEM operands whole."""
         pkg = os.path.join(ROOT, "scenery_insitu_tpu")
         paths = []
         for dirpath, _, files in os.walk(pkg):

@@ -420,7 +420,7 @@ def test_scope_of_reads_a_kernel_name_as_its_phase(op_name, want):
     assert scope_of(op_name) == want
 
 
-@pytest.mark.parametrize("fold", ["xla", "seg", "pallas_seg"])
+@pytest.mark.parametrize("fold", ["xla", "pallas_seg", "pallas_fused"])
 def test_scopes_temporal_mxu_step_divides_march_and_fold(fold):
     """The slicer's own scopes: resampling and shading stay `march`, what
     the consumer does with a chunk (and the finish of its state) is

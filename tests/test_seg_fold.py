@@ -123,22 +123,37 @@ def test_gap_splits_segment_across_chunk_boundary():
                                rtol=1e-6, atol=1e-6)
 
 
+def _ratios(rng, n, h, w):
+    """Per-slice depth ratios, their step and a per-pixel ray length: the
+    compact depth form; its planes are ``sk[:, None, None] * length``."""
+    sk = jnp.asarray(np.sort(rng.random(n).astype(np.float32)) + 0.5)
+    ds = jnp.float32(0.03)
+    length = jnp.asarray(1.0 + rng.random((h, w), dtype=np.float32))
+    return sk, sk + ds, length
+
+
 def test_pallas_seg_matches_xla_seg():
-    """The VMEM twin (ops/pallas_seg.py, interpret mode off-TPU) must
-    reproduce the XLA seg fold including carried state across chunks."""
+    """The VMEM kernel's shaded feed (ops/pallas_seg.py, interpret mode
+    off-TPU) must reproduce the XLA seg fold on the planes
+    ``sk * length``, including carried state across chunks."""
     from scenery_insitu_tpu.ops import pallas_seg as psg
 
     h, w = 16, 40                          # w deliberately NOT 128-aligned
     max_k = 5
-    rgba, t0, t1 = _stream(jax.random.PRNGKey(4), 12, h, w)
+    rgba, _, _ = _stream(jax.random.PRNGKey(4), 12, h, w)
+    sk0, sk1, length = _ratios(np.random.default_rng(4), 12, h, w)
+    t0 = sk0[:, None, None] * length[None]
+    t1 = sk1[:, None, None] * length[None]
     thr = jnp.full((h, w), 0.35, jnp.float32)
     st_x = sf.init_seg_state(max_k, h, w)
-    st_p = sf.init_seg_state(max_k, h, w)
+    packed = psg.init_seg_packed(max_k, h, w)
     for lo, n in ((0, 7), (7, 5)):
         st_x = sf.seg_fold_chunk(st_x, rgba[lo:lo + n], t0[lo:lo + n],
                                  t1[lo:lo + n], thr, max_k=max_k)
-        st_p = psg.seg_fold_chunk(st_p, rgba[lo:lo + n], t0[lo:lo + n],
-                                  t1[lo:lo + n], thr, max_k=max_k)
+        packed = psg.fold_chunk_packed(
+            packed, rgba[lo:lo + n], thr, max_k=max_k, sk0=sk0[lo:lo + n],
+            sk1=sk1[lo:lo + n], length=length)
+    st_p = psg.unpack_seg_state(packed)
     np.testing.assert_array_equal(np.asarray(st_p.cnt), np.asarray(st_x.cnt))
     for a, b, name in zip(sf.seg_finalize(st_x), sf.seg_finalize(st_p),
                           ("color", "depth")):
@@ -146,10 +161,9 @@ def test_pallas_seg_matches_xla_seg():
                                    rtol=1e-6, atol=1e-6, err_msg=name)
 
 
-@pytest.mark.parametrize("fold", ["seg", "pallas_seg", "pallas_fused",
-                                  "fused_stream"])
+@pytest.mark.parametrize("fold", ["pallas_seg", "pallas_fused"])
 def test_whole_march_parity(fold):
-    """generate_vdi_mxu + temporal: the seg folds must reproduce the
+    """generate_vdi_mxu + temporal: the kernel folds must reproduce the
     sequential-machine fold end to end, including the temporal threshold
     controller's feedback (integer counts must agree exactly)."""
     from scenery_insitu_tpu.config import SliceMarchConfig, VDIConfig
@@ -267,12 +281,11 @@ def test_k1_everything_merges():
 
 
 def test_compact_depth_equals_td_planes():
-    """fold_chunk_packed's compact form (sk ratios + length, depths
-    computed in-kernel) must equal the td-plane form when the planes are
-    the same outer product the march materializes (t = sk * length) —
-    the production path's 3.4 GB/march stream delete must be a pure
-    traffic change, bit-for-bit."""
-    import numpy as np
+    """fold_chunk_packed's compact depth form (sk ratios + length, depths
+    computed in-kernel) must equal the XLA fold of the td planes when the
+    planes are the same outer product the march would materialize
+    (t = sk * length): the depth stream that never reaches HBM is a pure
+    traffic change — whole packed state, 128-aligned strip."""
     from scenery_insitu_tpu.ops import pallas_seg as psg
 
     rng = np.random.default_rng(11)
@@ -282,19 +295,14 @@ def test_compact_depth_equals_td_planes():
     rgba = rgba.at[:, 3].set(
         jnp.where(jnp.asarray(rng.random((c, h, w))) < 0.3, 0.0,
                   rgba[:, 3]))
-    sk = jnp.asarray(np.sort(rng.random(c).astype(np.float32)) + 0.5)
-    ds = jnp.float32(0.03)
-    length = jnp.asarray(1.0 + rng.random((h, w), dtype=np.float32))
+    sk0, sk1, length = _ratios(rng, c, h, w)
     thr = jnp.full((h, w), 0.15, jnp.float32)
 
-    t0 = sk[:, None, None] * length[None]
-    t1 = (sk + ds)[:, None, None] * length[None]
-
-    pk0 = psg.init_seg_packed(k, h, w)
-    ref = psg.fold_chunk_packed(pk0, rgba, t0, t1, thr, max_k=k,
-                                interpret=True)
-    got = psg.fold_chunk_packed(pk0, rgba, threshold=thr, max_k=k,
-                                sk0=sk, sk1=sk + ds, length=length,
+    ref = psg.pack_seg_state(sf.seg_fold_chunk(
+        sf.init_seg_state(k, h, w), rgba, sk0[:, None, None] * length[None],
+        sk1[:, None, None] * length[None], thr, max_k=k))
+    got = psg.fold_chunk_packed(psg.init_seg_packed(k, h, w), rgba, thr,
+                                max_k=k, sk0=sk0, sk1=sk1, length=length,
                                 interpret=True)
     for a, b, name in zip(ref, got, ("color", "depth", "small")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -428,7 +436,7 @@ def test_tpu_auto_selects_by_volume_rank_and_tf(monkeypatch):
         matmul_dtype="f32", fold="pallas_fused"))
     assert slicer.fold_schedule(auto, scalar, tf) == "pallas_fused"
     assert slicer.fold_schedule(auto, shaded, None) == "pallas_seg"
-    for name in ("xla", "seg", "pallas", "pallas_seg"):
+    for name in ("xla", "pallas_seg"):
         spec = slicer.make_spec(cam, shape, SliceMarchConfig(
             matmul_dtype="f32", fold=name))
         assert slicer.fold_schedule(spec, scalar, tf) == name
@@ -454,3 +462,318 @@ def test_tpu_auto_selects_by_volume_rank_and_tf(monkeypatch):
     n_shaded, _ = noted(jax.jit(lambda v: gen(v, None)), shaded)
     assert n_closed == [1, 1] and n_traced == [1, 0] and n_shaded == [1, 0]
     np.testing.assert_allclose(c_traced, c_closed, rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The chip's schedule (`pallas_fused`) against the reference (`xla`) through
+# the generators, the count kernel, and what `slicer.fold` accepts (PR 47)
+
+XLA = dict(matmul_dtype="f32", scale=1.5, fold="xla")
+FUSED = dict(matmul_dtype="f32", scale=1.5, fold="pallas_fused")
+TOL = dict(rtol=1e-5, atol=1e-5)       # test_whole_march_parity's
+
+
+@pytest.fixture(scope="module")
+def blobs():
+    from scenery_insitu_tpu.core.volume import procedural_volume
+
+    return procedural_volume(40, kind="blobs", seed=7)
+
+
+@pytest.fixture(scope="module")
+def tf():
+    from scenery_insitu_tpu.core.transfer import for_dataset
+
+    return for_dataset("procedural")
+
+
+def _specs(cam, shape, **over):
+    from scenery_insitu_tpu.config import SliceMarchConfig
+    from scenery_insitu_tpu.ops import slicer
+
+    return (slicer.make_spec(cam, shape, SliceMarchConfig(**{**XLA, **over})),
+            slicer.make_spec(cam, shape,
+                             SliceMarchConfig(**{**FUSED, **over})))
+
+
+def _camera(eye):
+    from scenery_insitu_tpu.core.camera import Camera
+
+    return Camera.create(eye, fov_y_deg=45.0, near=0.3, far=10.0)
+
+
+def test_count_multi_matches_push_count():
+    from scenery_insitu_tpu.ops import pallas_seg as psg
+
+    h, w = 16, 24
+    bins = 6
+    rgba, _, _ = _stream(jax.random.PRNGKey(5), 10, h, w)
+    tvec = ss.threshold_candidates(bins, 2.0)
+
+    st = ss.init_count_multi(bins, h, w)
+    for i in range(rgba.shape[0]):
+        st = ss.push_count(st, tvec[:, None, None], rgba[i])
+
+    carry = psg.init_count_multi_packed(bins, h, w)
+    carry = psg.count_multi_chunk(carry, rgba[:4], np.asarray(tvec))
+    carry = psg.count_multi_chunk(carry, rgba[4:], np.asarray(tvec))
+    np.testing.assert_array_equal(np.asarray(carry[0]),
+                                  np.asarray(st.count))
+
+
+def test_generate_vdi_mxu_fold_parity(blobs, tf):
+    """Whole-march parity: fold='pallas_fused' must reproduce fold='xla'
+    (histogram adaptive mode — the counting march through the count
+    kernel, the write march through the fused kernel), on an x march:
+    the one layout `permute_volume` transposes."""
+    from scenery_insitu_tpu.config import VDIConfig
+    from scenery_insitu_tpu.ops import slicer
+
+    cam = _camera((2.6, 0.5, 0.25))
+    cfg = VDIConfig(max_supersegments=6, adaptive_mode="histogram",
+                    histogram_bins=8)
+    spec_x, spec_p = _specs(cam, blobs.data.shape)
+    assert spec_p.fold == "pallas_fused" and spec_x.fold == "xla"
+    assert (spec_p.axis, spec_p.sign) == (0, -1)
+
+    vdi_x, _, _ = slicer.generate_vdi_mxu(blobs, tf, cam, spec_x, cfg)
+    vdi_p, _, _ = slicer.generate_vdi_mxu(blobs, tf, cam, spec_p, cfg)
+    np.testing.assert_allclose(np.asarray(vdi_p.color),
+                               np.asarray(vdi_x.color), **TOL)
+    np.testing.assert_allclose(np.asarray(vdi_p.depth),
+                               np.asarray(vdi_x.depth), **TOL)
+
+
+def test_temporal_fold_parity(blobs, tf):
+    """Temporal mode: the kernel's own running start count must produce
+    the same VDI AND the same next-frame threshold state as the XLA
+    side-by-side fold, across several carried frames."""
+    from scenery_insitu_tpu.config import VDIConfig
+    from scenery_insitu_tpu.ops import slicer
+
+    cam = _camera((0.0, 0.4, 2.8))
+    cfg = VDIConfig(max_supersegments=6, adaptive_mode="temporal")
+    spec_x, spec_p = _specs(cam, blobs.data.shape)
+
+    thr_x = slicer.initial_threshold(blobs, tf, cam, spec_x, cfg)
+    thr_p = slicer.initial_threshold(blobs, tf, cam, spec_p, cfg)
+    np.testing.assert_allclose(np.asarray(thr_p.thr),
+                               np.asarray(thr_x.thr), rtol=2e-6, atol=1e-6)
+
+    for _ in range(3):
+        vdi_x, _, _, thr_x = slicer.generate_vdi_mxu_temporal(
+            blobs, tf, cam, spec_x, thr_x, cfg)
+        vdi_p, _, _, thr_p = slicer.generate_vdi_mxu_temporal(
+            blobs, tf, cam, spec_p, thr_p, cfg)
+        np.testing.assert_allclose(np.asarray(vdi_p.color),
+                                   np.asarray(vdi_x.color), **TOL)
+        np.testing.assert_allclose(np.asarray(vdi_p.depth),
+                                   np.asarray(vdi_x.depth), **TOL)
+        # thresholds bisect from identical integer counts -> exact
+        np.testing.assert_allclose(np.asarray(thr_p.thr),
+                                   np.asarray(thr_x.thr),
+                                   rtol=1e-6, atol=1e-6)
+
+
+def test_fold_parity_under_jit(blobs, tf):
+    """The production call shape: the whole generate step jitted, the
+    kernel fold inside — must still match and must be jit-stable."""
+    from scenery_insitu_tpu.config import VDIConfig
+    from scenery_insitu_tpu.ops import slicer
+
+    cam = _camera((0.1, 0.5, 2.7))
+    cfg = VDIConfig(max_supersegments=5, adaptive_mode="histogram",
+                    histogram_bins=8)
+    spec_x, spec_p = _specs(cam, blobs.data.shape)
+
+    def gen(spec):
+        @jax.jit
+        def run(data):
+            v = type(blobs)(data, blobs.origin, blobs.spacing)
+            vdi, _, _ = slicer.generate_vdi_mxu(v, tf, cam, spec, cfg)
+            return vdi.color, vdi.depth
+        return run
+
+    cp, dp = gen(spec_p)(blobs.data)
+    cx, dx = gen(spec_x)(blobs.data)
+    np.testing.assert_allclose(np.asarray(cp), np.asarray(cx), **TOL)
+    np.testing.assert_allclose(np.asarray(dp), np.asarray(dx), **TOL)
+
+
+def test_auto_fold_resolution(monkeypatch):
+    """"auto" resolves by backend NAME — the XLA fold off-TPU
+    (interpret-mode pallas is slow; conftest pins the cpu backend), the
+    seg kernel that shades the march's value plane itself on TPU (since
+    PR 46; `slicer.fold_schedule` gives a march without a scalar volume
+    or a concrete TF its shaded feed) with no compile probe in between
+    (a Mosaic refusal raises at compile time) — and an explicit fold
+    choice is always honored."""
+    from scenery_insitu_tpu.config import SliceMarchConfig
+    from scenery_insitu_tpu.ops import slicer
+
+    assert jax.default_backend() == "cpu"        # conftest invariant
+    cam = _camera((0.0, 0.4, 2.8))
+    spec = slicer.make_spec(cam, (16, 16, 16), SliceMarchConfig())
+    assert spec.fold == "xla"
+    spec_p = slicer.make_spec(cam, (16, 16, 16),
+                              SliceMarchConfig(fold="pallas_seg"))
+    assert spec_p.fold == "pallas_seg"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec_t = slicer.make_spec(cam, (16, 16, 16), SliceMarchConfig())
+    assert spec_t.fold == "pallas_fused"
+
+
+@pytest.mark.parametrize("name", ["pallas", "seg", "fused_stream"])
+def test_make_spec_rejects_a_removed_schedule(name):
+    """A configuration file or `--set` from outside that still names a
+    schedule PR 47 removed is refused where the spec is made, by name."""
+    from scenery_insitu_tpu.config import SliceMarchConfig
+    from scenery_insitu_tpu.ops import slicer
+
+    with pytest.raises(ValueError, match=f"unknown fold schedule '{name}'"):
+        slicer.make_spec(_camera((0.0, 0.4, 2.8)), (16, 16, 16),
+                         SliceMarchConfig(fold=name))
+
+
+def test_skip_chunks_execute_through_pallas_fold(tf):
+    """Occupancy skipping EXECUTES the C=1 dead-sample branch through the
+    fused fold (the blob fixture above rarely leaves a whole chunk empty,
+    so the lax.cond skip branch only gets traced there, not run): a
+    corner blob leaves most chunks provably empty, occupancy must skip
+    them, and the kernel fold must still match the xla fold and the
+    skip_empty=False reference."""
+    from scenery_insitu_tpu.config import VDIConfig
+    from scenery_insitu_tpu.core.volume import Volume
+    from scenery_insitu_tpu.ops import slicer
+
+    size = 40
+    z, y, x = np.meshgrid(*(np.linspace(-1, 1, size, dtype=np.float32),)
+                          * 3, indexing="ij")
+    field = np.exp(-(((x - 0.7) ** 2 + (y - 0.7) ** 2 + (z - 0.7) ** 2)
+                     / 0.02)).astype(np.float32)
+    vol = Volume.centered(jnp.asarray(field), extent=2.0)
+
+    cam = _camera((0.3, 0.5, 2.8))
+    spec_x, spec_p = _specs(cam, vol.data.shape)
+    occ = np.asarray(slicer.chunk_occupancy(vol, tf, spec_p))
+    assert (~occ).sum() >= 1, "fixture must leave at least one empty chunk"
+
+    cfg = VDIConfig(max_supersegments=6, adaptive_mode="histogram",
+                    histogram_bins=8)
+    vdi_p, _, _ = slicer.generate_vdi_mxu(vol, tf, cam, spec_p, cfg)
+    vdi_x, _, _ = slicer.generate_vdi_mxu(vol, tf, cam, spec_x, cfg)
+    _, spec_off = _specs(cam, vol.data.shape, skip_empty=False)
+    vdi_off, _, _ = slicer.generate_vdi_mxu(vol, tf, cam, spec_off, cfg)
+
+    np.testing.assert_allclose(np.asarray(vdi_p.color),
+                               np.asarray(vdi_x.color), **TOL)
+    np.testing.assert_allclose(np.asarray(vdi_p.color),
+                               np.asarray(vdi_off.color), **TOL)
+    dp = np.nan_to_num(np.asarray(vdi_p.depth), posinf=1e9)
+    dx = np.nan_to_num(np.asarray(vdi_x.depth), posinf=1e9)
+    doff = np.nan_to_num(np.asarray(vdi_off.depth), posinf=1e9)
+    np.testing.assert_allclose(dp, dx, **TOL)
+    np.testing.assert_allclose(dp, doff, **TOL)
+
+
+@pytest.mark.parametrize("kernel", ["fused", "compact", "count"])
+def test_width_tiled_strips_match_the_xla_reference(kernel, tf, monkeypatch):
+    """Multi-block width tiling (wb < w: 2D grid, masked partial last
+    block) of each kernel against its XLA reference. The cells' 640- and
+    1280-wide frames tile the fused kernel at 256 / 384; no test-sized
+    frame exceeds the strip budget, so the geometry is forced:
+    320 = 128 + 128 + 64 masked."""
+    from scenery_insitu_tpu.ops import pallas_seg as psg
+    from scenery_insitu_tpu.ops import pallas_util
+    from scenery_insitu_tpu.ops.sampling import adjust_opacity
+
+    h, w = 16, 320
+    k, c = 6, 5
+    monkeypatch.setattr(pallas_util, "_FORCE_BLOCK_W", 128)
+    assert pallas_util.pick_block_w(w, 1) == 128
+    rgba, _, _ = _stream(jax.random.PRNGKey(11), c, h, w)
+
+    if kernel == "count":
+        tvec = jnp.asarray([0.1, 0.25, 0.6])
+        carry = psg.count_multi_chunk(psg.init_count_multi_packed(3, h, w),
+                                      rgba, tvec, interpret=True)
+        cm = ss.init_count_multi(3, h, w)
+        for i in range(c):
+            cm = ss.push_count(cm, tvec[:, None, None], rgba[i])
+        np.testing.assert_array_equal(np.asarray(carry[0]),
+                                      np.asarray(cm.count))
+        return
+
+    rng = np.random.default_rng(11)
+    sk0, sk1, length = _ratios(rng, c, h, w)
+    thr = jnp.full((h, w), 0.25, jnp.float32)
+    packed = psg.init_seg_packed(k, h, w)
+    if kernel == "fused":
+        # a value plane with dead samples; the reference shades it as
+        # slice_march does (TF, dead -> transparent, opacity correction)
+        val = jnp.asarray(rng.random((c, h, w), dtype=np.float32))
+        val = jnp.where(jnp.asarray(rng.random((c, h, w))) < 0.3, -1.0, val)
+        ratio = jnp.asarray(0.5 + rng.random((h, w), dtype=np.float32))
+        rgb, alpha = tf(val)
+        alpha = adjust_opacity(jnp.where(val < -0.5, 0.0, alpha),
+                               ratio[None])
+        rgba = jnp.concatenate([jnp.moveaxis(rgb, -1, 1) * alpha[:, None],
+                                alpha[:, None]], axis=1)
+        got = psg.fused_fold_chunk(packed, val, length, ratio, sk0, sk1,
+                                   thr, max_k=k, tf=tf, interpret=True)
+    else:
+        got = psg.fold_chunk_packed(packed, rgba, thr, max_k=k, sk0=sk0,
+                                    sk1=sk1, length=length, interpret=True)
+    ref = psg.pack_seg_state(sf.seg_fold_chunk(
+        sf.init_seg_state(k, h, w), rgba, sk0[:, None, None] * length[None],
+        sk1[:, None, None] * length[None], thr, max_k=k))
+    assert float(np.asarray(ref[2][0]).max()) >= 2     # segments were cut
+    for a, b, name in zip(ref, got, ("color", "depth", "small")):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   err_msg=name, **TOL)
+
+
+@pytest.mark.parametrize("fold", ["xla", "pallas_seg", "pallas_fused"])
+def test_one_dispatch_serves_both_generators(fold, tf):
+    """`slicer.write_march` is the one write march: the plain generator
+    at a fixed threshold and the temporal generator seeded with the same
+    map write the SAME VDI, and the count the temporal generator's
+    controller read is `ss.push_count`'s at that threshold."""
+    from scenery_insitu_tpu.config import SliceMarchConfig, VDIConfig
+    from scenery_insitu_tpu.core.volume import Volume
+    from scenery_insitu_tpu.ops import slicer
+
+    vol = Volume.centered(jnp.asarray(_blob_field((24, 24, 24), 3)),
+                          extent=2.0)
+    cam = _camera((0.2, 0.4, 3.0))
+    spec = slicer.make_spec(cam, vol.data.shape, SliceMarchConfig(
+        matmul_dtype="f32", scale=1.0, fold=fold, chunk=8))
+    assert slicer.fold_schedule(spec, vol, tf) == fold
+    fixed = VDIConfig(max_supersegments=4, adaptive=False, threshold=0.12)
+    temporal = VDIConfig(max_supersegments=4, adaptive_mode="temporal")
+    thr0 = ss.init_threshold_state(
+        jnp.full((spec.nj, spec.ni), fixed.threshold, jnp.float32),
+        temporal.thr_min, temporal.thr_max)
+
+    vdi_f, _, axcam = slicer.generate_vdi_mxu(vol, tf, cam, spec, fixed)
+    vdi_t, _, _, thr1 = slicer.generate_vdi_mxu_temporal(
+        vol, tf, cam, spec, thr0, temporal)
+    np.testing.assert_array_equal(np.asarray(vdi_t.color),
+                                  np.asarray(vdi_f.color))
+    np.testing.assert_array_equal(np.asarray(vdi_t.depth),
+                                  np.asarray(vdi_f.depth))
+
+    def consume(st, rgba, t0, t1):
+        for i in range(rgba.shape[0]):
+            st = ss.push_count(st, thr0.thr, rgba[i])
+        return st
+
+    count = slicer.slice_march(vol, tf, axcam, spec, consume,
+                               ss.init_count(spec.nj, spec.ni)).count
+    assert int(np.asarray(count).max()) > temporal.max_supersegments
+    want = ss.update_threshold(thr0, count, temporal.max_supersegments,
+                               temporal.adaptive_delta, temporal.thr_min,
+                               temporal.thr_max, temporal.temporal_track)
+    for a, b, name in zip(want, thr1, ("thr", "lo", "hi")):
+        np.testing.assert_array_equal(np.asarray(b), np.asarray(a),
+                                      err_msg=name)

@@ -145,33 +145,3 @@ def test_bf16_distributed_matches_f32():
         vdi, _ = step(shard_volume(st.field, mesh), origin, spacing, cam)
         outs[rdt] = np.asarray(vdi.color)
     np.testing.assert_allclose(outs["bf16"], outs["f32"], atol=0.05)
-
-
-def test_fold_chunk_packed_rejects_mixed_depth_forms():
-    from scenery_insitu_tpu.ops import pallas_seg as psg
-
-    k, h, w = 4, 8, 16
-    packed = psg.init_seg_packed(k, h, w)
-    rgba = jnp.zeros((2, 4, h, w), jnp.float32)
-    t = jnp.zeros((2, h, w), jnp.float32)
-    sk = jnp.zeros((2,), jnp.float32)
-    ln = jnp.ones((h, w), jnp.float32)
-    thr = jnp.float32(0.1)
-    with pytest.raises(ValueError, match="cannot be mixed"):
-        psg.fold_chunk_packed(packed, rgba, t0=t, t1=t, threshold=thr,
-                              max_k=k, sk0=sk)
-    with pytest.raises(ValueError, match="cannot be mixed"):
-        psg.fold_chunk_packed(packed, rgba, t0=t, threshold=thr,
-                              max_k=k, sk0=sk, sk1=sk, length=ln)
-    with pytest.raises(ValueError, match="COMPLETE depth form"):
-        psg.fold_chunk_packed(packed, rgba, threshold=thr, max_k=k,
-                              sk0=sk, sk1=sk)
-    with pytest.raises(ValueError, match="COMPLETE depth form"):
-        psg.fold_chunk_packed(packed, rgba, t0=t, threshold=thr, max_k=k)
-    # both complete forms still work (interpret mode)
-    out = psg.fold_chunk_packed(packed, rgba, t0=t, t1=t, threshold=thr,
-                                max_k=k, interpret=True)
-    assert out[0].shape == (k, 4, h, w)
-    out = psg.fold_chunk_packed(packed, rgba, threshold=thr, max_k=k,
-                                sk0=sk, sk1=sk, length=ln, interpret=True)
-    assert out[0].shape == (k, 4, h, w)

@@ -9,6 +9,8 @@ published over ZMQ.
     python examples/volume_from_file.py --out out/                # procedural
     python examples/volume_from_file.py --dataset Kingsnake \
         --data-dir /data --out out/ --store-vdis
+    python examples/volume_from_file.py --dataset beechnut \
+        --data-dir /data --out out/     # the same session at u16 (PR 49)
 
 `--dataset procedural` writes a 96^3 procedural volume as a u8 raw file
 into --out first, so it takes the same path as a scan.

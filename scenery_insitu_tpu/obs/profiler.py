@@ -238,19 +238,22 @@ def hlo_large_writes(hlo_text: str, shape) -> list:
     return out
 
 
-_FOLD_NOTES = threading.local()     # .open: [chunks, fused] while a
-#                                     recorded step's first call traces
+_FOLD_NOTES = threading.local()     # .open: [chunks, fused, planes] while
+#                                     a recorded step's first call traces
 
 
-def note_fold_chunks(chunks: int, fused: bool) -> None:
+def note_fold_chunks(chunks: int, fused: bool, planes: int = 1) -> None:
     """A VDI generator says, while it is traced, that its write march
-    folds ``chunks`` chunks and whether the fold kernel shades them
-    itself. Kept only while a recorded step makes its first call
-    (`scoped_step`); said to nobody otherwise."""
+    folds ``chunks`` chunks, whether the fold kernel shades them itself,
+    and as how many operand ``planes`` a chunk meets the resampling
+    matmuls (2: a u16 field's two bytes). Kept only while a recorded
+    step makes its first call (`scoped_step`); said to nobody
+    otherwise."""
     notes = getattr(_FOLD_NOTES, "open", None)
     if notes is not None:
         notes[0] += chunks
         notes[1] += chunks if fused else 0
+        notes[2] = max(notes[2], planes)
 
 
 def scoped_step(fn, rec):
@@ -272,18 +275,19 @@ def scoped_step(fn, rec):
     while that first call traced them (`note_fold_chunks`): the chunks
     the step's write marches fold, and those of them the fold kernel
     shades itself, added to ``fold_chunks`` / ``fold_chunks_fused`` on
-    every call of a step that folds."""
+    every call of a step that folds, and the operand planes of a
+    marched chunk, added to ``march_operand_planes``."""
     if not rec.enabled:
         return fn
     noted = {}      # after the first call: the step's volume-sized
-    #                 writes and its write marches' [chunks, fused]
+    #                 writes and its write marches' [chunks, fused, planes]
 
     def call(*args, **kwargs):
         if noted:
             out = fn(*args, **kwargs)
         else:
             noted["copies"] = None
-            _FOLD_NOTES.open = noted["folds"] = [0, 0]
+            _FOLD_NOTES.open = noted["folds"] = [0, 0, 0]
             try:
                 out = fn(*args, **kwargs)
             finally:
@@ -307,6 +311,7 @@ def scoped_step(fn, rec):
         if noted["folds"][0]:
             rec.count("fold_chunks", noted["folds"][0])
             rec.count("fold_chunks_fused", noted["folds"][1])
+            rec.count("march_operand_planes", noted["folds"][2])
         return out
 
     call.lower = fn.lower

@@ -358,6 +358,18 @@ _COUNTER_REGISTRY: Dict[str, str] = {
                                "frame again after a stall",
     "ingest_stalls": "shm ingest found no strictly-newer producer frame "
                      "past frame_timeout_ms",
+    "march_operand_planes": "matmul operands a marched chunk of the "
+                            "field is resampled as: 1 (f32, bf16, u8: "
+                            "the chunk itself; u16 as one f32 operand "
+                            "at Precision.HIGHEST where slicer."
+                            "matmul_dtype=f32) or 2 (u16 into bf16 "
+                            "matmuls: its two byte planes, each exact "
+                            "in bf16, recombined on the f32 "
+                            "accumulator, `slicer.resample_wide`); "
+                            "noted while the step's "
+                            "first call traced it, added every frame, "
+                            "so over the frames it reads 1 or 2 "
+                            "(recorded runs only)",
     "obs_batch_drops": "a fleet-telemetry batch was dropped because the "
                        "collector socket would have blocked",
     "obs_batches_published": "a fleet-telemetry batch was handed to the "

@@ -479,6 +479,76 @@ def _axis_params(vol: Volume, spec: AxisSpec):
             vol.origin[va], vol.spacing[va], nv)
 
 
+def byte_planes(x: jnp.ndarray):
+    """An unsigned-integer array as its bytes, most significant first:
+    each plane holds 0..255 at ``x``'s dtype, so it is exact in bfloat16
+    (8 bits of mantissa) where the value itself is not, and
+    ``sum(plane_k * 256 ** (n - 1 - k))`` is ``x``."""
+    return [(x >> (8 * k)) & 0xFF for k in reversed(range(x.dtype.itemsize))]
+
+
+def operand_planes(dtype, matmul_dtype: str = "bf16") -> int:
+    """As how many matmul operands a chunk of a field of ``dtype`` is
+    resampled: its bytes for an integer wider than one that meets bf16
+    matmuls (`resample_wide`), else 1 (the chunk itself; with
+    ``slicer.matmul_dtype=f32`` a wide integer is one f32 operand at
+    `Precision.HIGHEST`, `slice_march`)."""
+    dtype = jnp.dtype(dtype)
+    wide = value_scale(dtype) != 1.0 and matmul_dtype == "bf16"
+    return dtype.itemsize if wide else 1
+
+
+def rounded_row_sums(w: jnp.ndarray) -> jnp.ndarray:
+    """Row sums ``[C, M]`` of an interpolation matrix ``[C, M, n]`` as a
+    bf16 matmul sees it: 1 within 2^-9 where the f32 rows sum to 1 (0
+    outside the volume)."""
+    return w.astype(jnp.bfloat16).astype(jnp.float32).sum(-1)
+
+
+def resample_wide(wv: jnp.ndarray, slices: jnp.ndarray, wu: jnp.ndarray,
+                  wu_sums: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """``einsum("cjy,cyx,cix->cji", wv, slices, wu)`` in f32 for a chunk
+    of integers wider than a byte (u16), in the file's own units, with
+    bf16 matmul operands and no precision asked above the default.
+
+    One bf16 operand keeps 8 of the 16 bits (and an f32 operand goes
+    through a TPU's MXU as ONE bf16 pass at the default precision, so it
+    keeps no more), so the chunk is contracted with ``wv`` byte plane by
+    byte plane (`byte_planes`: each exact in bf16) and the planes are
+    recombined, 256 * hi + lo, on the f32 accumulator. The f32
+    intermediate then meets ``wu`` as TWO bf16 terms, ``t1 = bf16(t)``
+    and ``t2 = bf16(t - t1)`` (16 bits of mantissa between them: one
+    rounded term would lose the low byte again, one matmul later).
+    ``t1`` is taken with `lax.reduce_precision`, not with a cast there
+    and back: XLA may drop such a cast pair as excess precision, and on
+    a TPU it does (``t - t1`` was 0 there and the frame read as with one
+    rounded term: PERF.md, PR 49). The
+    result is divided by the row sums of the weights AS ROUNDED:
+    a bf16 weight pair sums to 1 only within 2^-9, an error
+    proportional to the VALUE (57 counts of 65,535 at mid range, as
+    much as the operand's own rounding), where renormalised it is one
+    of the sample's position alone (``wu_sums``: `rounded_row_sums` of
+    ``wu`` where the caller has them: `slice_march` takes them once a
+    chunk, not once a row block)."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    wv_m, wu_m = wv.astype(bf16), wu.astype(bf16)
+    t = None
+    for plane in byte_planes(slices):
+        tp = jnp.einsum("cjy,cyx->cjx", wv_m, plane.astype(bf16),
+                        preferred_element_type=f32)
+        t = tp if t is None else t * 256.0 + tp
+    t1 = jax.lax.reduce_precision(t, exponent_bits=8, mantissa_bits=7)
+    val = sum(jnp.einsum("cjx,cix->cji", part.astype(bf16), wu_m,
+                         preferred_element_type=f32)
+              for part in (t1, t - t1))
+    if wu_sums is None:
+        wu_sums = rounded_row_sums(wu)
+    # a row outside the volume sums to 0, and so does its result
+    inv = lambda sums: 1.0 / jnp.maximum(sums, 1e-6)
+    return val * inv(rounded_row_sums(wv))[:, :, None] \
+        * inv(wu_sums)[:, None, :]
+
+
 def _interp_matrix(pos: jnp.ndarray, origin, spacing, n: int,
                    bounds: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None
                    ) -> jnp.ndarray:
@@ -570,11 +640,13 @@ def fold_schedule(spec: AxisSpec, vol: Volume, tf) -> str:
 
 
 def _note_write_fold(volp: jnp.ndarray, spec: AxisSpec, fold: str) -> None:
-    """Tell a recorded step how many chunks its write march folds and
-    whether the kernel shades them (counters ``fold_chunks`` /
-    ``fold_chunks_fused``, obs/profiler.scoped_step)."""
+    """Tell a recorded step how many chunks its write march folds,
+    whether the kernel shades them, and as how many operand planes a
+    chunk is resampled (counters ``fold_chunks`` / ``fold_chunks_fused``
+    / ``march_operand_planes``, obs/profiler.scoped_step)."""
     _note_fold_chunks(-(-volp.shape[0] // spec.chunk),
-                      fold == "pallas_fused")
+                      fold == "pallas_fused",
+                      operand_planes(volp.dtype, spec.matmul_dtype))
 
 
 def occupancy_for(vol: Volume, tf: TransferFunction, spec: AxisSpec,
@@ -696,11 +768,15 @@ def slice_march(vol: Volume, tf: TransferFunction, axcam: AxisCamera,
     mm = jnp.bfloat16 if spec.matmul_dtype == "bf16" else jnp.float32
     # an integer field's stored value v stands for v * vscale: the scale
     # goes on the matmul's f32 result, so the volume operand is the
-    # file's own values (u8 is exact in bf16; wider integers take f32
-    # operands, which hold them exactly)
+    # file's own values. u8 is exact in bf16; a wider integer is not,
+    # nor as an f32 operand on a TPU (one bf16 pass at the default
+    # precision), so its chunks are contracted byte plane by byte plane
+    # (`resample_wide`) or, where f32 operands are asked for, at
+    # Precision.HIGHEST (f32 arithmetic on every backend)
     vscale = value_scale(volp.dtype)
-    if vscale != 1.0 and volp.dtype.itemsize > 1:
-        mm = jnp.float32
+    wide = operand_planes(volp.dtype, spec.matmul_dtype) > 1
+    exact = ({"precision": jax.lax.Precision.HIGHEST}
+             if operand_planes(volp.dtype) > 1 and not wide else {})
     if jnp.issubdtype(volp.dtype, jnp.floating) \
             and volp.dtype.itemsize > jnp.dtype(mm).itemsize:
         # a float layout wider than the matmul operand: the compiler
@@ -751,14 +827,26 @@ def slice_march(vol: Volume, tf: TransferFunction, axcam: AxisCamera,
         inside = (wv.sum(-1) > 0.0)[:, :, None] & (wu.sum(-1) > 0.0)[:, None, :]
         keep = inside & live[:, None, None]
 
-        def rows_val(wv_r, keep_r):
-            """Raw-mode block: resampled values, -1 where dead."""
-            val = jnp.einsum("cjy,cyx,cix->cji",
-                             wv_r.astype(mm), slices.astype(mm),
-                             wu.astype(mm),
-                             preferred_element_type=jnp.float32)
+        wu_sums = rounded_row_sums(wu) if wide else None
+
+        def resample(wv_r):
+            """A block of output rows of the chunk resampled onto the
+            grid, normalised: ``[C, B, Ni]`` f32 (scalar volumes)."""
+            if wide:
+                val = resample_wide(wv_r, slices, wu, wu_sums)
+            else:
+                val = jnp.einsum("cjy,cyx,cix->cji",
+                                 wv_r.astype(mm), slices.astype(mm),
+                                 wu.astype(mm),
+                                 preferred_element_type=jnp.float32,
+                                 **exact)
             if vscale != 1.0:
                 val = val * jnp.float32(vscale)
+            return val
+
+        def rows_val(wv_r, keep_r):
+            """Raw-mode block: resampled values, -1 where dead."""
+            val = resample(wv_r)
             # clip BEFORE the sentinel so a genuine value <= -0.5 (un-
             # normalized field) can't be conflated with a dead sample;
             # exact — every shading path clips to [0,1] anyway
@@ -780,12 +868,7 @@ def slice_march(vol: Volume, tf: TransferFunction, axcam: AxisCamera,
                 return jnp.concatenate(
                     [jnp.clip(val[:, :3], 0.0, 1.0) * scale[:, None],
                      alpha[:, None]], axis=1)
-            val = jnp.einsum("cjy,cyx,cix->cji",
-                             wv_r.astype(mm), slices.astype(mm),
-                             wu.astype(mm),
-                             preferred_element_type=jnp.float32)
-            if vscale != 1.0:
-                val = val * jnp.float32(vscale)
+            val = resample(wv_r)
             val = jnp.clip(val, 0.0, 1.0)
 
             rgb, alpha = tf(val)                   # [C,B,Ni,3], [C,B,Ni]

@@ -357,6 +357,12 @@ AUTO_TPU_CASES = {
     "sign_plus_remainder_vtiles": dict(
         shape=(27, 32, 32), dtype=np.uint8, k=6, chunk=8, eye_z=-3.0,
         vtiles=4),
+    # the 16-bit dataset cell (PR 49): two byte planes into the march,
+    # a depth of 16 + 10, Beechnut's five-point tent (not monotone) as
+    # the kernel's immediates, the blobs squeezed into the tent's band
+    "u16_k20_depth26_tent": dict(
+        shape=(26, 40, 48), dtype=np.uint16, k=20, chunk=16, eye_z=3.0,
+        tf="beechnut"),
 }
 
 
@@ -377,9 +383,11 @@ def test_tpu_auto_path_matches_xla_fold(case, monkeypatch):
     field = _blob_field(p["shape"], seed=len(case))
     if p["dtype"] == np.uint8:
         field = np.round(field * 255).astype(np.uint8)
+    if p["dtype"] == np.uint16:
+        field = np.round((0.40 + 0.12 * field) * 65535).astype(np.uint16)
     vol = Volume.centered(jnp.asarray(field), extent=2.0)
     assert vol.data.dtype == p["dtype"]
-    tf = for_dataset("procedural")
+    tf = for_dataset(p.get("tf", "procedural"))
     cam = Camera.create((0.2, 0.4, p["eye_z"]), fov_y_deg=45.0, near=0.3,
                         far=10.0)
     fold = _tpu_auto_fold(monkeypatch, cam, vol.data.shape)

@@ -281,6 +281,58 @@ KS_GRID, KS_OVERRIDES = (795, 1024, 1024), (
     "runtime.dataset=kingsnake", "mesh.num_devices=1")
 
 
+def _dataset_programs(topo, monkeypatch, grid, dtype, name):
+    """A file-dataset cell's programs for one v5e as a TPU builds them:
+    the occupancy ranges, the threshold seeder and the step of a session
+    with `runtime.dataset=<name>` at ``grid`` / ``dtype``, K = 20.
+    Returns (seeded, compiled, build, args, thr): the last three to
+    compile the step of another field (`build(ranges=None)`)."""
+    from scenery_insitu_tpu import obs
+    from scenery_insitu_tpu.config import FrameworkConfig
+    from scenery_insitu_tpu.core.camera import Camera
+    from scenery_insitu_tpu.core.transfer import for_dataset
+    from scenery_insitu_tpu.ops import slicer
+    from scenery_insitu_tpu.parallel import pipeline
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    obs.clear_ledger()
+    cfg = FrameworkConfig().with_overrides(*KS_OVERRIDES,
+                                           f"runtime.dataset={name}")
+    mesh = Mesh(np.array(topo.devices[:1]), ("ranks",))
+    on = lambda spec: NamedSharding(mesh, spec)
+    like = lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.asarray(x).dtype,
+                                          sharding=on(P()))
+    cam = Camera.create((0.0, 0.6, 3.0), fov_y_deg=50.0, near=0.3, far=20.0)
+    spec = slicer.make_spec(cam, grid, cfg.slicer,
+                            axis_sign=slicer.choose_axis(cam))
+    assert (spec.axis, spec.sign, spec.ni, spec.nj, spec.fold) == \
+        (2, -1, 1280, 1280, "pallas_fused")
+    args = (jax.ShapeDtypeStruct(grid, dtype,
+                                 sharding=on(P("ranks", None, None))),
+            like(np.zeros(3, np.float32)),
+            like(np.full(3, 2.0 / max(grid), np.float32)),
+            jax.tree_util.tree_map(like, cam))
+    tf = for_dataset(name)
+    ranges = pipeline.distributed_volume_ranges_mxu(mesh, spec)
+    ranges.lower(*args[:3]).compile()
+    kept = tuple(np.zeros(s.shape, np.float32)
+                 for s in jax.eval_shape(ranges, *args[:3]))
+    assert kept[0].shape == (1, -(-grid[0] // 16), spec.vtiles)
+    seed = pipeline.distributed_initial_threshold_mxu(mesh, tf, spec,
+                                                      cfg.vdi)
+    seeded = seed.lower(*args).compile()
+    thr = jax.tree_util.tree_map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(seed, *args), seeded.output_shardings)
+
+    def build(**kw):
+        return pipeline.distributed_vdi_step_mxu_temporal(
+            mesh, tf, spec, cfg.vdi, cfg.composite, **kw)
+    compiled = build(reuse_tol=cfg.delta.range_tol, ranges=kept).lower(
+        *args, thr).compile()
+    return seeded, compiled, build, args, thr
+
+
 def test_the_kingsnake_step_compiles_at_its_native_dtype(topo, monkeypatch):
     """The file-dataset cell's programs for one v5e as a TPU builds them:
     the occupancy ranges, the threshold seeder and the step at (795, 1024,
@@ -290,46 +342,10 @@ def test_the_kingsnake_step_compiles_at_its_native_dtype(topo, monkeypatch):
     array as large as the volume (no widened, flipped or padded copy),
     and the step's temporaries stay under the volume's own size."""
     from scenery_insitu_tpu import obs
-    from scenery_insitu_tpu.config import FrameworkConfig
-    from scenery_insitu_tpu.core.camera import Camera
-    from scenery_insitu_tpu.core.transfer import for_dataset
     from scenery_insitu_tpu.obs.profiler import hlo_large_writes
-    from scenery_insitu_tpu.ops import slicer
-    from scenery_insitu_tpu.parallel import pipeline
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    obs.clear_ledger()
-    cfg = FrameworkConfig().with_overrides(*KS_OVERRIDES)
-    mesh = Mesh(np.array(topo.devices[:1]), ("ranks",))
-    on = lambda spec: NamedSharding(mesh, spec)
-    like = lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.asarray(x).dtype,
-                                          sharding=on(P()))
-    cam = Camera.create((0.0, 0.6, 3.0), fov_y_deg=50.0, near=0.3, far=20.0)
-    spec = slicer.make_spec(cam, KS_GRID, cfg.slicer,
-                            axis_sign=slicer.choose_axis(cam))
-    assert (spec.axis, spec.sign, spec.ni, spec.nj, spec.fold) == \
-        (2, -1, 1280, 1280, "pallas_fused")
-    args = (jax.ShapeDtypeStruct(KS_GRID, jnp.uint8,
-                                 sharding=on(P("ranks", None, None))),
-            like(np.zeros(3, np.float32)),
-            like(np.full(3, 2.0 / max(KS_GRID), np.float32)),
-            jax.tree_util.tree_map(like, cam))
-    tf = for_dataset("kingsnake")
-    ranges = pipeline.distributed_volume_ranges_mxu(mesh, spec)
-    ranges.lower(*args[:3]).compile()
-    kept = tuple(np.zeros(s.shape, np.float32)
-                 for s in jax.eval_shape(ranges, *args[:3]))
-    assert kept[0].shape == (1, 50, spec.vtiles)
-    seed = pipeline.distributed_initial_threshold_mxu(mesh, tf, spec,
-                                                      cfg.vdi)
-    seeded = seed.lower(*args).compile()
-    thr = jax.tree_util.tree_map(
-        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-        jax.eval_shape(seed, *args), seeded.output_shardings)
-    compiled = pipeline.distributed_vdi_step_mxu_temporal(
-        mesh, tf, spec, cfg.vdi, cfg.composite,
-        reuse_tol=cfg.delta.range_tol, ranges=kept).lower(
-            *args, thr).compile()
+    seeded, compiled, build, args, thr = _dataset_programs(
+        topo, monkeypatch, KS_GRID, jnp.uint8, "kingsnake")
     text = compiled.as_text()
     # since PR 46 the march hands the fold its value plane: no shaded
     # f32[16,4,1280,1280] chunk is written between them
@@ -349,6 +365,48 @@ def test_the_kingsnake_step_compiles_at_its_native_dtype(topo, monkeypatch):
     wide = (jax.ShapeDtypeStruct(KS_GRID, jnp.float32,
                                  sharding=args[0].sharding),) + args[1:]
     assert set(hlo_large_writes(
-        pipeline.distributed_vdi_step_mxu_temporal(
-            mesh, tf, spec, cfg.vdi, cfg.composite).lower(
-                *wide, thr).compile().as_text(), KS_GRID)) <= {"convert"}
+        build().lower(*wide, thr).compile().as_text(),
+        KS_GRID)) <= {"convert"}
+
+
+BN_GRID = (1546, 1024, 1024)
+
+
+def test_the_beechnut_step_keeps_sixteen_bits_in_bf16_operands(topo,
+                                                               monkeypatch):
+    """`beechnut-u16-1chip` (PR 49): the same programs at (1546, 1024,
+    1024) u16, a depth of 96 chunks and 10 planes, the `beechnut` tent
+    as the fold kernel's immediates. EVERY convolution of the compiled
+    step (the resampling matmuls: four per output row block, two byte
+    planes into the first contraction and the f32 intermediate's two
+    bf16 terms into the second) takes two bf16 operands, none asks for
+    more than the default precision, and next to the 3.24 GB volume the
+    step holds well under a tenth of it in temporaries: no plane, no
+    widened copy."""
+    import re
+
+    from scenery_insitu_tpu import obs
+    from scenery_insitu_tpu.obs.profiler import hlo_large_writes
+
+    seeded, compiled, *_ = _dataset_programs(
+        topo, monkeypatch, BN_GRID, jnp.uint16, "beechnut")
+    text = compiled.as_text()
+    assert "sitpu_fold_fused" in text and obs.ledger() == []
+    for program in (seeded, compiled):
+        assert hlo_large_writes(program.as_text(), BN_GRID) == []
+    dtype_of = dict(re.findall(
+        r"^\s*(?:ROOT )?(%[\w.-]+) = (\w+)\[", text, re.M))
+    convs = re.findall(r"= f32\[[\d,]+\]\S* convolution\((%[\w.-]+), "
+                       r"(%[\w.-]+)\)(.*)$", text, re.M)
+    assert len(convs) >= 4 and len(convs) % 4 == 0
+    for lhs, rhs, rest in convs:
+        assert (dtype_of[lhs], dtype_of[rhs]) == ("bf16", "bf16")
+        assert "operand_precision" not in rest
+    assert " dot(" not in text
+    # the intermediate's first term is taken by reduce-precision: a cast
+    # to bf16 and back is excess precision to XLA, which drops it on a
+    # TPU, and the second term is then 0 (read on the chip, PR 49)
+    assert text.count(" reduce-precision(") >= len(convs) // 4
+    volume = 2 * int(np.prod(BN_GRID))
+    assert compiled.memory_analysis().argument_size_in_bytes >= volume
+    assert compiled.memory_analysis().temp_size_in_bytes < volume // 8

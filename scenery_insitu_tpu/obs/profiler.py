@@ -60,6 +60,7 @@ benchmarks/divergence.py.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import glob
 import os
@@ -239,7 +240,8 @@ def hlo_large_writes(hlo_text: str, shape) -> list:
 
 
 _FOLD_NOTES = threading.local()     # .open: [chunks, fused, planes] while
-#                                     a recorded step's first call traces
+#                                     a recorded step's first call traces;
+#                                     .slots: see `fold_slot_account`
 
 
 def note_fold_chunks(chunks: int, fused: bool, planes: int = 1) -> None:
@@ -254,6 +256,33 @@ def note_fold_chunks(chunks: int, fused: bool, planes: int = 1) -> None:
         notes[0] += chunks
         notes[1] += chunks if fused else 0
         notes[2] = max(notes[2], planes)
+
+
+@contextlib.contextmanager
+def fold_slot_account(collect: bool):
+    """While a step that hands its folds' slot account on is traced
+    (``collect``), the list `note_fold_slots` fills: one traced i32[2] a
+    write march, ``(slot rows merged, slot rows visited)``. None where
+    the step was not built to (an unrecorded step: the account is then
+    dead code on the device). The marches have to be traced at the
+    opener's own level — a march inside a ``cond`` or a ``scan`` would
+    hand out a tracer of that inner trace — so a step opens this only
+    around marches it calls directly."""
+    before = getattr(_FOLD_NOTES, "slots", None)
+    _FOLD_NOTES.slots = noted = [] if collect else None
+    try:
+        yield noted
+    finally:
+        _FOLD_NOTES.slots = before
+
+
+def note_fold_slots(counts) -> None:
+    """A write march that folded through the kernel hands on what the
+    kernel counted (ops/pallas_seg.fold_slot_counts, a traced i32[2]);
+    kept only inside a `fold_slot_account` that collects."""
+    noted = getattr(_FOLD_NOTES, "slots", None)
+    if noted is not None:
+        noted.append(counts)
 
 
 def scoped_step(fn, rec):

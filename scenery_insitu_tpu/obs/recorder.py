@@ -307,6 +307,18 @@ _COUNTER_REGISTRY: Dict[str, str] = {
                          "scalar volume with a concrete transfer "
                          "function); the others cross HBM as shaded "
                          "rgba (recorded runs only)",
+    "fold_slot_rows": "slot rows the fold kernel's K-loops visited: K "
+                      "per 8 x 128 tile of the image and per chunk the "
+                      "kernel ran on (the one-sample chunk of a skipped "
+                      "iteration too), each rank's own, summed; the "
+                      "kernel's count, added where a recorded session "
+                      "fetches the frame (the MXU VDI step; recorded "
+                      "runs only)",
+    "fold_slot_rows_merged": "those of `fold_slot_rows` the kernel "
+                             "merged records into: per tile and chunk "
+                             "the hull of the slots its live samples "
+                             "landed in; the others were copied "
+                             "(recorded runs only)",
     "frames_abandoned": "the tile assembler abandoned a frame that "
                         "stayed incomplete past its window",
     "frames_fetched_kmajor": "a frame fetched from the mesh whose every "

@@ -14,6 +14,19 @@ running transmittance, prev rgb, prev empty) and writes each slice's
 ref, so no live range spans the loop; phase B re-reads the scratch per
 slot row — VMEM-to-register traffic, not HBM.
 
+Phase B's trip count follows the data. Per pixel the slots a chunk
+touches are one interval; phase A keeps its two ends as planes, and per
+8 x 128 tile of the strip phase B merges only the hull ``[lo, hi)`` of
+the tile's intervals (`tile_slot_bounds`) and COPIES the rows outside
+it: the state's outputs are aliased to its inputs in HBM but are their
+own VMEM buffers, so an untouched row must still be written. A tile
+with no live sample merges nothing. Every row is visited once, merged
+or copied, so a dense tile costs what a plain ``fori_loop(0, K)`` does
+plus two reductions, and the results are the same numbers (the copied
+rows merged zeros before). The kernel counts the rows it merged and
+visited per tile in a small SMEM output carried with the state
+(`init_slot_counts`, `fold_slot_counts`).
+
 One kernel body, two feeds, chosen per march by `slicer.fold_schedule`:
 `fused_fold_chunk` (kernel ``sitpu_fold_fused``) takes the march's
 one-channel VALUE plane and shades it in VMEM; `fold_chunk_packed`
@@ -26,9 +39,10 @@ interpret-mode equality) and therefore to C sequential ``ss.push`` calls
 up to fp association (≅ the reference's fused single-kernel generation,
 VDIGenerator.comp:380-529 + AccumulateVDI.comp:69-98).
 
-State layout (3 aliased arrays): ``color f32[K,4,H,W]``, ``depth
+State layout (4 aliased arrays): ``color f32[K,4,H,W]``, ``depth
 f32[K,2,H,W]`` (start/end; start init +inf, end init -inf), ``small
-f32[5,H,W]`` = cnt[0] (f32-encoded), prev_rgb[1:4], prev_empty[4].
+f32[5,H,W]`` = cnt[0] (f32-encoded), prev_rgb[1:4], prev_empty[4], and
+the kernel's account ``slots i32[2, tiles]`` (rows merged, rows visited).
 Helpers convert to/from ``seg_fold.SegFoldState`` so the march code
 handles ONE state type. On CPU (tests, the virtual mesh) the kernels run
 in interpret mode.
@@ -47,16 +61,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 from scenery_insitu_tpu.ops import seg_fold as sf
 from scenery_insitu_tpu.ops import supersegments as ss
-from scenery_insitu_tpu.ops.pallas_util import (TILE_H, pick_block_w,
+from scenery_insitu_tpu.ops.pallas_util import (TILE_H, TILE_W,
+                                                pick_block_w,
                                                 should_interpret, strip_fpp)
 
 _CNT, _PREV_RGB, _PREV_EMPTY = 0, slice(1, 4), 4
 _NSMALL = 5
+# the slot-row account lives whole in SMEM: a few scalars a grid step
+_SLOTS_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def init_seg_packed(k: int, height: int, width: int):
     """Packed fold state ≅ seg_fold.init_seg_state — built directly in
-    packed layout so a march can carry the triple through its scan with
+    packed layout so a march can carry the tuple through its scan with
     no per-chunk stack/concat traffic (the depth plane alone is
     [K,2,H,W]; re-materializing it every chunk would cost more HBM than
     the kernel's own state pass)."""
@@ -66,7 +83,20 @@ def init_seg_packed(k: int, height: int, width: int):
         jnp.full((k, height, width), -jnp.inf, jnp.float32)], axis=1)
     small = jnp.zeros((_NSMALL, height, width), jnp.float32)
     small = small.at[_PREV_EMPTY].set(1.0)
-    return (color, depth, small)
+    return (color, depth, small, init_slot_counts(height, width))
+
+
+def init_slot_counts(height: int, width: int):
+    """The fold's own account, i32[2, tiles]: per 8 x 128 tile the slot
+    rows its K-loops merged and the rows they visited, added up by the
+    kernel over the chunks it ran on (`fold_slot_counts`)."""
+    return jnp.zeros((2, slot_tiles(height, width)), jnp.int32)
+
+
+def fold_slot_counts(packed) -> jnp.ndarray:
+    """i32[2]: the slot rows the folds of this state merged, and the
+    rows they visited (K x tiles x chunks the kernel ran on)."""
+    return packed[3].sum(axis=1)
 
 
 def pack_seg_state(st: sf.SegFoldState):
@@ -76,11 +106,11 @@ def pack_seg_state(st: sf.SegFoldState):
         st.prev_empty.astype(jnp.float32)[None]])
     return (st.out_color,
             jnp.stack([st.out_start, st.out_end], axis=1),
-            small)
+            small, init_slot_counts(*st.cnt.shape))
 
 
 def unpack_seg_state(packed) -> sf.SegFoldState:
-    color, depth, small = packed
+    color, depth, small = packed[:3]
     return sf.SegFoldState(
         out_color=color, out_start=depth[:, 0], out_end=depth[:, 1],
         cnt=small[_CNT].astype(jnp.int32),
@@ -91,14 +121,19 @@ def unpack_seg_state(packed) -> sf.SegFoldState:
 def _phase_a(nc: int, rgba_of, thr, smi_, smo, ev_ref, kf):
     """Per-slice (slot, v) records from the shaded rgba stream
     (``rgba_of(s)`` f32[4, TH, WB]: read from the chunk's ref, or shaded
-    here from the value plane); 4 small live carries. Shared by both
-    write kernels."""
+    here from the value plane); 4 small live carries and the two planes
+    of the pixel's slot interval. Shared by both write kernels. Returns
+    ``(first, last)`` f32[TH, WB]: the smallest and the largest slot a
+    sample of this chunk landed in (``kf + 1`` and ``-1`` on a pixel
+    with no live sample) — what `_phase_b_compact` bounds its loop by."""
     sm = smi_[...]
     run_cnt = sm[_CNT]
     pr = sm[_PREV_RGB]
     pe = sm[_PREV_EMPTY] > 0.5
 
     t_run = jnp.ones_like(thr)
+    first = jnp.full_like(thr, kf + 1.0)
+    last = jnp.full_like(thr, -1.0)
     for s in range(nc):
         rgba = rgba_of(s)
         emp = rgba[3] < ss.EMPTY_ALPHA
@@ -111,6 +146,8 @@ def _phase_a(nc: int, rgba_of, thr, smi_, smo, ev_ref, kf):
         t_here = jnp.where(reset, 1.0, t_run)
         t_run = t_here * (1.0 - jnp.where(emp, 0.0, rgba[3]))
         slotf = jnp.where(emp, -1.0, jnp.minimum(sid, kf))
+        first = jnp.minimum(first, jnp.where(emp, kf + 1.0, slotf))
+        last = jnp.maximum(last, slotf)
         v = rgba * (t_here * (~emp).astype(jnp.float32))[None]
         ev_ref[s] = jnp.concatenate([slotf[None], v])
         pr = jnp.where(emp[None], pr, rgba[:3])
@@ -118,46 +155,112 @@ def _phase_a(nc: int, rgba_of, thr, smi_, smo, ev_ref, kf):
 
     smo[...] = jnp.concatenate([
         run_cnt[None], pr, pe.astype(jnp.float32)[None]])
+    return first, last
+
+
+def tile_slot_bounds(first, last, col0, width: int, max_k: int):
+    """The slot rows ``[lo, hi)`` a tile's samples landed in, as two i32
+    scalars with ``0 <= lo <= hi <= max_k`` whatever the planes hold.
+    ``first`` / ``last`` f32[TH, BW] are `_phase_a`'s interval planes of
+    the tile, whose lane 0 is the image's column ``col0``; lanes at or
+    beyond ``width`` are a last block's padding (whatever was in VMEM,
+    NaN included) and are left out, as is anything that is not a slot
+    (negative, NaN). A tile with no live sample gives ``(0, 0)``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, first.shape, first.ndim - 1)
+    inside = lane < width - col0
+    lo = jnp.min(jnp.where(inside & (first >= 0.0), first, float(max_k)))
+    hi = jnp.max(jnp.where(inside & (last >= 0.0), last, -1.0)) + 1.0
+    hi = jnp.clip(hi, 0.0, float(max_k)).astype(jnp.int32)
+    lo = jnp.clip(lo, 0.0, float(max_k)).astype(jnp.int32)
+    return jnp.minimum(lo, hi), hi
+
+
+def slot_tiles(height: int, width: int) -> int:
+    """How many 8 x 128 tiles the fold counts slot rows by: a tile is a
+    128-lane block inside the image's width (the last may be narrower)."""
+    return (height // TILE_H) * pl.cdiv(width, TILE_W)
 
 
 def _phase_b_compact(ev_ref, len_ref, sk0_ref, sk1_ref, ci_, di_, co,
-                     do_, max_k: int):
-    """Rolled K-loop merge shared by both feeds: per slot row, masked-sum
-    the per-slice records and under-merge into the aliased [K,...] state
-    (touched once per chunk). The depth candidates are formed here from
+                     do_, si_, so, first, last, max_k: int, width: int):
+    """The K-loop merge shared by both feeds, its trip count following
+    the data: per 8 x 128 tile of the strip, only the slot rows
+    ``[lo, hi)`` some sample of the tile landed in (`tile_slot_bounds`,
+    the hull of its pixels' intervals) masked-sum the per-slice records
+    and under-merge into the aliased [K,...] state; the rows outside are
+    copied, since the outputs are aliased but are their own VMEM buffers
+    and an untouched row must still be written. Every row is visited
+    once, so a dense tile costs what the plain ``fori_loop(0, K)`` did
+    plus two reductions, and a tile with no live sample only copies. A
+    128-lane block wholly beyond the image's width does nothing (its
+    output lanes are dropped). The depth candidates are formed here from
     the per-slice ratios and the per-pixel ray length (t = sk * length —
-    exactly the outer product a plane feed would materialize)."""
-    ev = ev_ref[...]                                       # [C, 5, TH, WB]
-    ln = len_ref[...]                                      # [TH, WB]
-    t0a = sk0_ref[...] * ln[None]                          # [C, TH, WB]
-    t1a = sk1_ref[...] * ln[None]
-    ev_slot, ev_rgba = ev[:, 0], ev[:, 1:5]
+    exactly the outer product a plane feed would materialize).
 
-    def slot_body(kk, _):
-        m = ev_slot == kk.astype(jnp.float32)
-        mf = m.astype(jnp.float32)
-        contrib = jnp.sum(ev_rgba * mf[:, None], axis=0)
-        d0 = jnp.min(jnp.where(m, t0a, jnp.inf), axis=0)
-        d1 = jnp.max(jnp.where(m, t1a, -jnp.inf), axis=0)
-        oc = ci_[pl.dslice(kk, 1)]
-        co[pl.dslice(kk, 1)] = oc + (1.0 - oc[:, 3:4]) * contrib[None]
-        dr = di_[pl.dslice(kk, 1)]
-        do_[pl.dslice(kk, 1)] = jnp.stack(
-            [jnp.minimum(dr[0, 0], d0), jnp.maximum(dr[0, 1], d1)])[None]
-        return 0
+    ``si_`` / ``so`` i32[2, tiles] (SMEM, aliased): per tile of the
+    image, the rows merged (``hi - lo``) and the rows visited
+    (``max_k``), added up over the chunks the kernel ran on."""
+    wb = len_ref.shape[-1]
+    j, i = pl.program_id(0), pl.program_id(1)
+    blocks = [(b0, min(TILE_W, wb - b0)) for b0 in range(0, wb, TILE_W)]
+    # every tile's two vector-to-scalar reductions before the first loop
+    # that waits on one
+    bounds = [tile_slot_bounds(first[:, b0:b0 + bw], last[:, b0:b0 + bw],
+                               i * wb + b0, width, max_k)
+              for b0, bw in blocks]
+    sk0, sk1 = sk0_ref[...], sk1_ref[...]
 
-    jax.lax.fori_loop(0, max_k, slot_body, 0)
+    for (b0, bw), (lo, hi) in zip(blocks, bounds):
+        lanes = slice(b0, b0 + bw)
+
+        @pl.when(i * wb + b0 < width)       # traced here, in the loop
+        def _():
+            ev = ev_ref[:, :, :, lanes]                    # [C, 5, TH, BW]
+            ln = len_ref[:, lanes]                         # [TH, BW]
+            t0a = sk0 * ln[None]                           # [C, TH, BW]
+            t1a = sk1 * ln[None]
+            ev_slot, ev_rgba = ev[:, 0], ev[:, 1:5]
+
+            def slot_body(kk, _):
+                m = ev_slot == kk.astype(jnp.float32)
+                mf = m.astype(jnp.float32)
+                contrib = jnp.sum(ev_rgba * mf[:, None], axis=0)
+                d0 = jnp.min(jnp.where(m, t0a, jnp.inf), axis=0)
+                d1 = jnp.max(jnp.where(m, t1a, -jnp.inf), axis=0)
+                row = (pl.dslice(kk, 1), slice(None), slice(None), lanes)
+                oc = ci_[row]
+                co[row] = oc + (1.0 - oc[:, 3:4]) * contrib[None]
+                dr = di_[row]
+                do_[row] = jnp.stack(
+                    [jnp.minimum(dr[0, 0], d0),
+                     jnp.maximum(dr[0, 1], d1)])[None]
+                return 0
+
+            def copy_body(kk, _):
+                row = (pl.dslice(kk, 1), slice(None), slice(None), lanes)
+                co[row] = ci_[row]
+                do_[row] = di_[row]
+                return 0
+
+            jax.lax.fori_loop(0, lo, copy_body, 0)
+            jax.lax.fori_loop(lo, hi, slot_body, 0)
+            jax.lax.fori_loop(hi, max_k, copy_body, 0)
+            tile = (j * pl.cdiv(width, TILE_W) + i * (wb // TILE_W)
+                    + b0 // TILE_W)
+            so[0, tile] = si_[0, tile] + (hi - lo)
+            so[1, tile] = si_[1, tile] + max_k
 
 
 def _seg_kernel_compact(rgba_ref, len_ref, thr_ref, sk0_ref, sk1_ref,
-                        ci_, di_, smi_, co, do_, smo, ev_ref, *,
-                        max_k: int):
+                        ci_, di_, smi_, si_, co, do_, smo, so, ev_ref, *,
+                        max_k: int, width: int):
     """The shaded feed: `_phase_a` reads the chunk's rgba as it came;
     the [C,2,H,W] depth planes never exist in HBM (`_phase_b_compact`)."""
-    _phase_a(rgba_ref.shape[0], lambda s: rgba_ref[s], thr_ref[...],
-             smi_, smo, ev_ref, jnp.float32(max_k - 1))
+    first, last = _phase_a(rgba_ref.shape[0], lambda s: rgba_ref[s],
+                           thr_ref[...], smi_, smo, ev_ref,
+                           jnp.float32(max_k - 1))
     _phase_b_compact(ev_ref, len_ref, sk0_ref, sk1_ref, ci_, di_, co, do_,
-                     max_k)
+                     si_, so, first, last, max_k, width)
 
 
 def fold_chunk_packed(packed, rgba: jnp.ndarray, threshold: jnp.ndarray, *,
@@ -166,7 +269,7 @@ def fold_chunk_packed(packed, rgba: jnp.ndarray, threshold: jnp.ndarray, *,
                       interpret: Optional[bool] = None):
     """Fold one SHADED chunk on VMEM pixel strips, packed-state in/out.
 
-    ``packed`` is the `init_seg_packed` triple; carrying it through the
+    ``packed`` is the `init_seg_packed` tuple; carrying it through the
     march's scan keeps the [K,...] state layout stable across chunks so
     ``input_output_aliases`` updates it in place — no per-chunk
     stack/slice re-materialization. rgba f32[C,4,H,W] premultiplied;
@@ -177,7 +280,7 @@ def fold_chunk_packed(packed, rgba: jnp.ndarray, threshold: jnp.ndarray, *,
     """
     if interpret is None:
         interpret = should_interpret()
-    color, depth, small = packed
+    color = packed[0]
     kk = color.shape[0]
     _, _, h, w = color.shape
     c = rgba.shape[0]
@@ -197,16 +300,16 @@ def fold_chunk_packed(packed, rgba: jnp.ndarray, threshold: jnp.ndarray, *,
     grid = (h // TILE_H, pl.cdiv(w, wb))
     row = lambda *lead: pl.BlockSpec(lead + (TILE_H, wb),
                                      lambda j, i: (0,) * len(lead) + (j, i))
-    state_specs = [row(kk, 4), row(kk, 2), row(_NSMALL)]
+    state_specs = [row(kk, 4), row(kk, 2), row(_NSMALL), _SLOTS_SPEC]
     sk_spec = pl.BlockSpec((c, 1, 1), lambda j, i: (0, 0, 0))
     out = pl.pallas_call(
-        functools.partial(_seg_kernel_compact, max_k=max_k),
+        functools.partial(_seg_kernel_compact, max_k=max_k, width=w),
         grid=grid,
         in_specs=[row(c, 4), row(), row(), sk_spec, sk_spec] + state_specs,
         out_specs=state_specs,
         out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in packed],
         scratch_shapes=[pltpu.VMEM((c, 5, TILE_H, wb), jnp.float32)],
-        input_output_aliases={5: 0, 6: 1, 7: 2},
+        input_output_aliases={5: 0, 6: 1, 7: 2, 8: 3},
         interpret=interpret,
         name="sitpu_fold_seg_compact",
     )(rgba, length, threshold, sk0, sk1, *packed)
@@ -272,8 +375,8 @@ def _shade_plane(v_raw, ratio, tfc: tuple):
 
 
 def _fused_kernel(val_ref, len_ref, ratio_ref, thr_ref, sk0_ref, sk1_ref,
-                  ci_, di_, smi_, co, do_, smo, ev_ref, *,
-                  max_k: int, tfc: tuple):
+                  ci_, di_, smi_, si_, co, do_, smo, so, ev_ref, *,
+                  max_k: int, width: int, tfc: tuple):
     """Shade (TF + opacity correction) + segmented fold in ONE kernel —
     the TPU counterpart of the reference's fused generation kernel
     (VDIGenerator.comp:380-529 shades and accumulates per ray without
@@ -288,10 +391,10 @@ def _fused_kernel(val_ref, len_ref, ratio_ref, thr_ref, sk0_ref, sk1_ref,
     def shade(s):
         return _shade_plane(val_ref[s], ratio, tfc)
 
-    _phase_a(val_ref.shape[0], shade, thr_ref[...], smi_, smo, ev_ref,
-             jnp.float32(max_k - 1))
+    first, last = _phase_a(val_ref.shape[0], shade, thr_ref[...], smi_,
+                           smo, ev_ref, jnp.float32(max_k - 1))
     _phase_b_compact(ev_ref, len_ref, sk0_ref, sk1_ref, ci_, di_, co, do_,
-                     max_k)
+                     si_, so, first, last, max_k, width)
 
 
 def _fused_fpp(c: int, k: int) -> int:
@@ -316,7 +419,7 @@ def fused_fold_chunk(packed, val: jnp.ndarray, length: jnp.ndarray,
     if interpret is None:
         interpret = should_interpret()
     tfc = _tf_consts(tf)
-    color, depth, small = packed
+    color = packed[0]
     kk = color.shape[0]
     _, _, h, w = color.shape
     c = val.shape[0]
@@ -332,17 +435,17 @@ def fused_fold_chunk(packed, val: jnp.ndarray, length: jnp.ndarray,
     grid = (h // TILE_H, pl.cdiv(w, wb))
     row = lambda *lead: pl.BlockSpec(lead + (TILE_H, wb),
                                      lambda j, i: (0,) * len(lead) + (j, i))
-    state_specs = [row(kk, 4), row(kk, 2), row(_NSMALL)]
+    state_specs = [row(kk, 4), row(kk, 2), row(_NSMALL), _SLOTS_SPEC]
     sk_spec = pl.BlockSpec((c, 1, 1), lambda j, i: (0, 0, 0))
     out = pl.pallas_call(
-        functools.partial(_fused_kernel, max_k=max_k, tfc=tfc),
+        functools.partial(_fused_kernel, max_k=max_k, width=w, tfc=tfc),
         grid=grid,
         in_specs=[row(c), row(), row(), row(), sk_spec, sk_spec]
         + state_specs,
         out_specs=state_specs,
         out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in packed],
         scratch_shapes=[pltpu.VMEM((c, 5, TILE_H, wb), jnp.float32)],
-        input_output_aliases={6: 0, 7: 1, 8: 2},
+        input_output_aliases={6: 0, 7: 1, 8: 2, 9: 3},
         interpret=interpret,
         name="sitpu_fold_fused",
     )(val, length, ratio, threshold, sk0, sk1, *packed)

@@ -62,6 +62,8 @@ from scenery_insitu_tpu.core.volume import Volume, value_scale
 from scenery_insitu_tpu.obs.profiler import in_phase as _in_phase
 from scenery_insitu_tpu.obs.profiler import \
     note_fold_chunks as _note_fold_chunks
+from scenery_insitu_tpu.obs.profiler import \
+    note_fold_slots as _note_fold_slots
 from scenery_insitu_tpu.obs.profiler import phase as _phase
 from scenery_insitu_tpu.ops import pallas_seg as psg
 from scenery_insitu_tpu.ops import seg_fold as sf
@@ -1189,7 +1191,7 @@ def write_march(vol: Volume, tf, axcam: AxisCamera, spec: AxisSpec,
             color, depth = ss.finalize(state)
         return color, depth, (cstate.count if count else None)
 
-    # the kernel folds carry the packed triple: the [K,...] state keeps
+    # the kernel folds carry the packed tuple: the [K,...] state keeps
     # one layout across the whole scan so `input_output_aliases` update
     # it in place, and the kernel forms t = sk*length itself — the
     # [C,2,Nj,Ni] depth planes never hit HBM
@@ -1217,6 +1219,9 @@ def write_march(vol: Volume, tf, axcam: AxisCamera, spec: AxisSpec,
                        shaded_compact=True)
     state = psg.unpack_seg_state(packed)
     with _phase("fold"):
+        # the kernel's own account of its K-loops, summed once a march:
+        # kept by a step that was built to hand it on, dead code otherwise
+        _note_fold_slots(psg.fold_slot_counts(packed))
         color, depth = sf.seg_finalize(state)
     return color, depth, state.cnt
 

@@ -1671,7 +1671,8 @@ def distributed_vdi_step_mxu(mesh: Mesh, tf: TransferFunction,
                              axis_name: Optional[str] = None,
                              plan=None, bricks=None,
                              reuse_tol: float = 0.0,
-                             topology=None, ranges=None):
+                             topology=None, ranges=None,
+                             slot_counts: bool = False):
     """Distributed sort-last VDI pipeline on the MXU slice-march engine
     (ops/slicer.py) — generation runs as banded-matmul slice resampling
     instead of per-ray gathers; the rest of the chain (width-axis column
@@ -1699,16 +1700,20 @@ def distributed_vdi_step_mxu(mesh: Mesh, tf: TransferFunction,
     that never changes (a dataset): the step then sweeps no volume for
     its occupancy pyramid. A planned band, a brick map and the
     tile-wave schedule sweep as before.
+
+    ``slot_counts``: the frame comes as ``(VDI, meta, slots)``, the fold
+    kernel's slot-row account a rank (see `_build_mxu_step`).
     """
     return _build_mxu_step(mesh, tf, spec, vdi_cfg, comp_cfg, axis_name,
                            temporal=False, plan=plan, bricks=bricks,
                            reuse_tol=reuse_tol, topology=topology,
-                           ranges=ranges)
+                           ranges=ranges, slot_counts=slot_counts)
 
 
 def _build_mxu_step(mesh, tf, spec, vdi_cfg, comp_cfg, axis_name,
                     temporal: bool, plan=None, bricks=None,
-                    reuse_tol: float = 0.0, topology=None, ranges=None):
+                    reuse_tol: float = 0.0, topology=None, ranges=None,
+                    slot_counts: bool = False):
     """Shared builder of the MXU sort-last step (generate → column
     exchange under ``comp_cfg.exchange`` → composite), with or without
     carried temporal threshold state threaded through.
@@ -1719,8 +1724,18 @@ def _build_mxu_step(mesh, tf, spec, vdi_cfg, comp_cfg, axis_name,
     `distributed_initial_reuse_mxu`) and the return gains ``reuse'`` —
     ranks whose occupancy-range signature moved at most ``reuse_tol``
     (``FrameworkConfig.delta.range_tol``) skip their march and feed the
-    carried fragment to the exchange."""
+    carried fragment to the exchange.
+
+    ``slot_counts`` (a recorded session's step) makes the frame a triple
+    ``(VDI, meta, slots)``: ``slots`` i32[ranks, 2], each rank's own
+    ``(slot rows merged, slot rows visited)`` of its write march's fold
+    kernel (ops/pallas_seg.fold_slot_counts), for the host to add up
+    where it fetches the frame. Zeros where no kernel folds
+    (``slicer.fold=xla``) and under the schedules that march inside a
+    ``scan`` or a ``cond`` (tile waves, temporal reuse) or brick by
+    brick; without it the account is dead code on the device."""
     from scenery_insitu_tpu.core.vdi import VDIMetadata
+    from scenery_insitu_tpu.obs.profiler import fold_slot_account
     from scenery_insitu_tpu.ops import slicer
 
     vdi_cfg = vdi_cfg or VDIConfig()
@@ -1773,62 +1788,65 @@ def _build_mxu_step(mesh, tf, spec, vdi_cfg, comp_cfg, axis_name,
                 ru2)
 
     def body(local_data, origin, spacing, cam, thr, ru):
-        out, meta, thr2, ru2 = composited(local_data, origin, spacing, cam,
-                                          thr, ru)
-        return leave(out), meta, thr2, ru2
+        with fold_slot_account(slot_counts and bricks is None
+                               and not waves and not reuse) as noted:
+            out, meta, thr2, ru2 = composited(local_data, origin, spacing,
+                                              cam, thr, ru)
+        frame = (leave(out), meta)
+        if slot_counts:
+            frame += (sum(noted or (), jnp.zeros((2,), jnp.int32))[None],)
+        return frame, thr2, ru2
 
     spec_vol = P(axis, None, None)
     out_vdi, leave = _frame_out(mesh, axis, n, topo,
                                 comp_cfg.max_output_supersegments)
     out_meta = VDIMetadata(*(P() for _ in VDIMetadata._fields))
+    out_frame = (out_vdi, out_meta) + ((P(axis, None),) if slot_counts
+                                       else ())
 
     if temporal and reuse:
         thr_spec = _thr_state_spec(axis)
         ru_spec = _reuse_state_spec(axis)
 
         def step(local_data, origin, spacing, cam: Camera, thr, ru):
-            out, meta, thr2, ru2 = body(local_data, origin, spacing,
-                                        cam, thr, ru)
-            return (out, meta), thr2, ru2
+            return body(local_data, origin, spacing, cam, thr, ru)
 
         f = shard_map(step, mesh=mesh,
                       in_specs=(spec_vol, P(), P(), P(), thr_spec,
                                 ru_spec),
-                      out_specs=((out_vdi, out_meta), thr_spec, ru_spec),
+                      out_specs=(out_frame, thr_spec, ru_spec),
                       check_vma=False)
     elif temporal:
         thr_spec = _thr_state_spec(axis)
 
         def step(local_data, origin, spacing, cam: Camera, thr):
-            out, meta, thr2, _ = body(local_data, origin, spacing, cam,
-                                      thr, None)
-            return (out, meta), thr2
+            frame, thr2, _ = body(local_data, origin, spacing, cam, thr,
+                                  None)
+            return frame, thr2
 
         f = shard_map(step, mesh=mesh,
                       in_specs=(spec_vol, P(), P(), P(), thr_spec),
-                      out_specs=((out_vdi, out_meta), thr_spec),
+                      out_specs=(out_frame, thr_spec),
                       check_vma=False)
     elif reuse:
         ru_spec = _reuse_state_spec(axis)
 
         def step(local_data, origin, spacing, cam: Camera, ru):
-            out, meta, _, ru2 = body(local_data, origin, spacing, cam,
-                                     None, ru)
-            return (out, meta), ru2
+            frame, _, ru2 = body(local_data, origin, spacing, cam, None,
+                                 ru)
+            return frame, ru2
 
         f = shard_map(step, mesh=mesh,
                       in_specs=(spec_vol, P(), P(), P(), ru_spec),
-                      out_specs=((out_vdi, out_meta), ru_spec),
+                      out_specs=(out_frame, ru_spec),
                       check_vma=False)
     else:
         def step(local_data, origin, spacing, cam: Camera):
-            out, meta, _, _ = body(local_data, origin, spacing, cam,
-                                   None, None)
-            return out, meta
+            return body(local_data, origin, spacing, cam, None, None)[0]
 
         f = shard_map(step, mesh=mesh,
                       in_specs=(spec_vol, P(), P(), P()),
-                      out_specs=(out_vdi, out_meta), check_vma=False)
+                      out_specs=out_frame, check_vma=False)
     return jax.jit(f)
 
 
@@ -1921,7 +1939,8 @@ def distributed_vdi_step_mxu_temporal(mesh: Mesh, tf: TransferFunction,
                                       axis_name: Optional[str] = None,
                                       plan=None, bricks=None,
                                       reuse_tol: float = 0.0,
-                                      topology=None, ranges=None):
+                                      topology=None, ranges=None,
+                                      slot_counts: bool = False):
     """`distributed_vdi_step_mxu` with carried per-rank temporal threshold
     state (adaptive_mode="temporal": ONE march per rank per frame instead
     of counting + write — see slicer.generate_vdi_mxu_temporal).
@@ -1937,7 +1956,7 @@ def distributed_vdi_step_mxu_temporal(mesh: Mesh, tf: TransferFunction,
     return _build_mxu_step(mesh, tf, spec, vdi_cfg, comp_cfg, axis_name,
                            temporal=True, plan=plan, bricks=bricks,
                            reuse_tol=reuse_tol, topology=topology,
-                           ranges=ranges)
+                           ranges=ranges, slot_counts=slot_counts)
 
 
 def distributed_hybrid_step_mxu(mesh: Mesh, tf: TransferFunction,

@@ -762,8 +762,10 @@ class InSituSession:
         self.steering = None   # optional streaming.SteeringEndpoint
         self.on_steer: List[Callable[[dict], None]] = []  # non-camera msgs
         # frame index -> (VDIMetadata at dispatch, the number of the
-        # newest camera message that frame was rendered from)
+        # newest camera message that frame was rendered from, a recorded
+        # VDI step's slot-row account: `_count_fold_slots`)
         self._pending_meta = {}
+        self._fold_slots = None     # the step just dispatched said this
         self._steer_seq = 0     # camera messages applied (drain_steering)
         self._pending = deque()     # run()'s in-flight frames, newest last
         self._host_frames = HostFrames()    # see _to_host
@@ -1026,7 +1028,9 @@ class InSituSession:
         ``upkeep`` span, with `_upkeep`)."""
         # metadata snapshot BEFORE the camera advances (fetch is pipelined
         # one frame behind, so it must not see the next frame's pose)
-        self._pending_meta[self.frame_index] = (meta, self._steer_seq)
+        self._pending_meta[self.frame_index] = (meta, self._steer_seq,
+                                                self._fold_slots)
+        self._fold_slots = None
         # bound the dict: the fetch runs at most pipeline_depth frames
         # behind, so any older entry is unreachable — without this, a
         # headless run(fetch=False) loop (which never pops) grows it
@@ -1245,15 +1249,27 @@ class InSituSession:
         self.obs.count("host_heap_kept", int(self._heap_kept))
         self.obs.count("host_heap_frame_bytes", nbytes)
 
+    def _count_fold_slots(self, slots) -> None:
+        """Add a recorded frame's slot-row account (i32[ranks, 2] of the
+        step that rendered it, `_vdi_frame`) to ``fold_slot_rows_merged``
+        / ``fold_slot_rows``. Called once the frame's programs are done,
+        so the read waits for nothing; None (an unrecorded run, a mode
+        that folds no VDI) counts nothing."""
+        if slots is not None:
+            merged, rows = np.asarray(slots).sum(axis=0)
+            self.obs.count("fold_slot_rows_merged", int(merged))
+            self.obs.count("fold_slot_rows", int(rows))
+
     def _sync_nofetch(self, index: int, out) -> None:
         """Retire a pipelined frame nobody consumes: drop its metadata
         snapshot and pace on device completion WITHOUT the device->host
         copy the historical path paid here (``fetch=True`` with no
         sinks used to ``np.asarray`` every frame just to throw the
         bytes away)."""
-        self._pending_meta.pop(index, None)
+        _, _, slots = self._pending_meta.pop(index, (None,) * 3)
         with self.obs.span("fetch", frame=index, host_copy=False):
             jax.block_until_ready(out)
+            self._count_fold_slots(slots)
 
     def _to_host(self, index: int, out):
         """``out`` with every leaf a read-only numpy array holding the
@@ -1349,7 +1365,7 @@ class InSituSession:
 
     def _fetch(self, index: int, out) -> dict:
         from scenery_insitu_tpu.ops.splat import SplatOutput
-        meta, steer_seq = self._pending_meta.pop(index, (None, None))
+        meta, steer_seq, slots = self._pending_meta.pop(index, (None,) * 3)
         if meta is None:
             meta = self.frame_metadata(index)
         tiles = ()
@@ -1362,6 +1378,7 @@ class InSituSession:
             if self._n_ranks > 1 or rec:
                 # a frame on a mesh, or a recorded run (the copy, timed)
                 out = self._to_host(index, out)
+            self._count_fold_slots(slots)
             if isinstance(out, VDI):
                 # ONE device->host transfer; the tile delivery below and
                 # the frame payload share these buffers (a no-op wrap
@@ -1756,7 +1773,7 @@ class InSituSession:
         return StepEntry(
             build(self.mesh, self.tf, spec, c.vdi, c.composite,
                   reuse_tol=c.delta.range_tol, ranges=ranges,
-                  **self._decomp()),
+                  slot_counts=self.obs.enabled, **self._decomp()),
             seed_thr=(pipeline.distributed_initial_threshold_mxu(
                 self.mesh, self.tf, spec, c.vdi, plan=self._plan,
                 bricks=self._bricks) if self._temporal else None),
@@ -1769,7 +1786,13 @@ class InSituSession:
             ru = self._steps.reuse.get(key)
             if ru is not None:
                 self._note_dirty(ru)
-        return self._steps.run(key, entry, self._field_args())
+        out = self._steps.run(key, entry, self._field_args())
+        if self.obs.enabled:
+            # a recorded step hands its fold kernel's slot-row account
+            # on, still on the device: read where the frame is fetched
+            vdi, meta, self._fold_slots = out
+            return vdi, meta
+        return out
 
     def _hybrid_entry(self, spec) -> StepEntry:
         """Distributed hybrid frame: volume VDI + tracers, merged on the

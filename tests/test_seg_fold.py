@@ -785,3 +785,359 @@ def test_one_dispatch_serves_both_generators(fold, tf):
     for a, b, name in zip(want, thr1, ("thr", "lo", "hi")):
         np.testing.assert_array_equal(np.asarray(b), np.asarray(a),
                                       err_msg=name)
+
+
+# ---------------------------------------------------------------------------
+# PR 50: phase B's trip count follows the data (the hull of each 8 x 128
+# tile's slot intervals is merged, the rows outside it are copied)
+
+
+def _dense_phase_b(ev_ref, len_ref, sk0_ref, sk1_ref, ci_, di_, co, do_,
+                   si_, so, first, last, max_k, width):
+    """The reference: PR 49's phase B, every one of the K slot rows of
+    the whole strip merged whatever the records hold (it counts
+    nothing)."""
+    from jax.experimental import pallas as pl
+
+    ev = ev_ref[...]
+    ln = len_ref[...]
+    t0a = sk0_ref[...] * ln[None]
+    t1a = sk1_ref[...] * ln[None]
+    ev_slot, ev_rgba = ev[:, 0], ev[:, 1:5]
+
+    def slot_body(kk, _):
+        m = ev_slot == kk.astype(jnp.float32)
+        mf = m.astype(jnp.float32)
+        contrib = jnp.sum(ev_rgba * mf[:, None], axis=0)
+        d0 = jnp.min(jnp.where(m, t0a, jnp.inf), axis=0)
+        d1 = jnp.max(jnp.where(m, t1a, -jnp.inf), axis=0)
+        oc = ci_[pl.dslice(kk, 1)]
+        co[pl.dslice(kk, 1)] = oc + (1.0 - oc[:, 3:4]) * contrib[None]
+        dr = di_[pl.dslice(kk, 1)]
+        do_[pl.dslice(kk, 1)] = jnp.stack(
+            [jnp.minimum(dr[0, 0], d0), jnp.maximum(dr[0, 1], d1)])[None]
+        return 0
+
+    jax.lax.fori_loop(0, max_k, slot_body, 0)
+    so[...] = si_[...]
+
+
+def _shade(tf, val, ratio):
+    """A value chunk (-1 = dead) shaded as `slice_march` shades it."""
+    from scenery_insitu_tpu.ops.sampling import adjust_opacity
+
+    rgb, alpha = tf(val)
+    alpha = adjust_opacity(jnp.where(val < -0.5, 0.0, alpha), ratio[None])
+    return jnp.concatenate([jnp.moveaxis(rgb, -1, 1) * alpha[:, None],
+                            alpha[:, None]], axis=1)
+
+
+# name -> (K, chunk sizes); every stream is 16 x 300 pixels (tiles of
+# 128, 128 and 44 lanes), threshold 0: a sample starts a segment iff its
+# colour differs at all from the one before, or follows a gap, so the
+# in-kernel and the XLA shading cut the same segments
+SLOT_STREAMS = {
+    "dead_tile": (6, (5, 5, 5)),
+    "leading_empty": (6, (5, 5, 5)),
+    "disjoint": (8, (5, 2)),
+    "overflow": (4, (5, 5, 5)),
+    "k20": (20, (5, 5, 5)),
+    "remainder": (6, (5, 5, 2)),
+}
+
+
+def _slot_stream(case):
+    """The chunks of ``case`` as value planes f32[C, 16, 300] with -1 for
+    a dead sample: ~30 % dead, ~30 % repeats of the sample before (they
+    accumulate into its segment), and what the case is named for."""
+    k, sizes = SLOT_STREAMS[case]
+    h, w, n = 16, 300, sum(sizes)
+    rng = np.random.default_rng(sorted(SLOT_STREAMS).index(case))
+    val = (0.2 + 0.8 * rng.random((n, h, w))).astype(np.float32)
+    for s in range(1, n):
+        val[s] = np.where(rng.random((h, w)) < 0.3, val[s - 1], val[s])
+    val = np.where(rng.random((n, h, w)) < 0.3, -1.0, val)
+    if case == "dead_tile":
+        val[:, :, 128:256] = -1.0           # no live sample, any chunk
+        val[5:10, :8, :128] = -1.0          # and one dead for a chunk
+    elif case == "leading_empty":
+        val[:5] = -1.0
+    elif case == "disjoint":
+        # the left half of every tile sleeps through chunk 0 while the
+        # right half opens five segments; in chunk 1 the halves land in
+        # slots 0-1 and 5-6: the hull holds rows nobody merges into
+        val[:] = (0.2 + 0.8 * rng.random((n, h, w))).astype(np.float32)
+        lanes = (np.arange(w) % 128) < 64
+        val[:5, :, lanes] = -1.0
+    return [jnp.asarray(val[lo:lo + c].astype(np.float32))
+            for lo, c in zip(np.cumsum((0,) + sizes[:-1]), sizes)], k
+
+
+def _fold_stream(feed, tf, chunks, k, geo, interpret=True):
+    """The stream through one of the kernel's feeds -> the packed
+    quadruple as numpy."""
+    from scenery_insitu_tpu.ops import pallas_seg as psg
+
+    sk0, sk1, length, ratio, thr = geo
+    packed = psg.init_seg_packed(k, *length.shape)
+    lo = 0
+    for val in chunks:
+        a, b = sk0[lo:lo + val.shape[0]], sk1[lo:lo + val.shape[0]]
+        if feed == "fused":
+            packed = psg.fused_fold_chunk(packed, val, length, ratio, a, b,
+                                          thr, max_k=k, tf=tf,
+                                          interpret=interpret)
+        else:
+            packed = psg.fold_chunk_packed(packed, _shade(tf, val, ratio),
+                                           thr, max_k=k, sk0=a, sk1=b,
+                                           length=length,
+                                           interpret=interpret)
+        lo += val.shape[0]
+    return [np.asarray(x) for x in packed]
+
+
+def _geometry(n, h, w, seed=50):
+    rng = np.random.default_rng(seed)
+    sk0, sk1, length = _ratios(rng, n, h, w)
+    ratio = jnp.asarray(0.5 + rng.random((h, w), dtype=np.float32))
+    return sk0, sk1, length, ratio, jnp.zeros((h, w), jnp.float32)
+
+
+def _numpy_slot_rows(tf, chunks, k, geo):
+    """(rows merged, rows visited) as the kernel defines them, from
+    `seg_fold.chunk_flags` on the same stream: per chunk and 8 x 128
+    tile, the hull of the slots its live samples land in."""
+    sk0, sk1, length, ratio, thr = geo
+    h, w = length.shape
+    st = sf.init_seg_state(k, h, w)
+    merged = rows = lo = 0
+    for val in chunks:
+        c = val.shape[0]
+        rgba = _shade(tf, val, ratio)
+        emp, starts = sf.chunk_flags(rgba, st.prev_rgb, st.prev_empty, thr)
+        sid = np.asarray(st.cnt)[None] + np.cumsum(
+            np.asarray(starts), axis=0) - 1
+        slot = np.where(np.asarray(emp), -1, np.minimum(sid, k - 1))
+        for j in range(0, h, 8):
+            for i in range(0, w, 128):
+                tile = slot[:, j:j + 8, i:i + 128]
+                rows += k
+                if (tile >= 0).any():
+                    merged += tile.max() + 1 - tile[tile >= 0].min()
+        st = sf.seg_fold_chunk(
+            st, rgba, sk0[lo:lo + c, None, None] * length[None],
+            sk1[lo:lo + c, None, None] * length[None], thr, max_k=k)
+        lo += c
+    return int(merged), int(rows)
+
+
+@pytest.mark.parametrize("feed", ["fused", "compact"])
+@pytest.mark.parametrize("case", sorted(SLOT_STREAMS))
+def test_bounded_slot_loop_is_the_dense_loop_bit_for_bit(case, feed, tf,
+                                                         monkeypatch):
+    """Merging only a tile's hull of slot rows and copying the others
+    gives the packed state the dense loop gives, to the bit, under both
+    feeds; and the kernel's count of the rows it merged is the one NumPy
+    makes from `seg_fold.chunk_flags` on the same stream."""
+    from scenery_insitu_tpu.ops import pallas_seg as psg
+
+    chunks, k = _slot_stream(case)
+    geo = _geometry(sum(c.shape[0] for c in chunks), 16, 300)
+    got = _fold_stream(feed, tf, chunks, k, geo)
+    with monkeypatch.context() as m:
+        m.setattr(psg, "_phase_b_compact", _dense_phase_b)
+        ref = _fold_stream(feed, tf, chunks, k, geo)
+    cnt = got[2][0]
+    assert cnt.max() >= (k if case in ("overflow",) else 2)
+    for a, b, name in zip(got, ref, ("color", "depth", "small")):
+        assert np.array_equal(a, b), name
+    assert not ref[3].any()
+    merged, rows = _numpy_slot_rows(tf, chunks, k, geo)
+    assert tuple(got[3].sum(axis=1)) == (merged, rows)
+    assert 0 < merged < rows
+    if case == "dead_tile":     # tiles 0-2 of the two row bands
+        assert not got[3][0, [1, 4]].any() and got[3][0, [0, 3]].all()
+
+
+def test_slot_hull_holds_rows_nobody_merges_into(tf):
+    """The `disjoint` stream's second chunk: every pixel lands in two
+    slots, the tile's hull is seven rows wide (0-1 and 5-6 with 2-4
+    between), and the rows between come out as they went in."""
+    chunks, k = _slot_stream("disjoint")
+    geo = _geometry(7, 16, 300)
+    before = _fold_stream("fused", tf, chunks[:1], k, geo)
+    after = _fold_stream("fused", tf, chunks, k, geo)
+    # (the 44-lane tile holds left halves only: slots 0-1)
+    np.testing.assert_array_equal(after[3][0] - before[3][0],
+                                  [7, 7, 2, 7, 7, 2])
+    lanes = (np.arange(300) % 128) < 64
+    # rows 2-4: empty on the left half, untouched on the right
+    assert not after[0][2:5][..., lanes].any()
+    assert np.array_equal(after[0][2:5][..., ~lanes],
+                          before[0][2:5][..., ~lanes])
+    assert after[0][:2][..., lanes].any() and after[0][5:7].any()
+
+
+@pytest.mark.parametrize("feed", ["fused", "compact"])
+def test_bounded_slot_loop_on_width_tiled_strips(feed, tf, monkeypatch):
+    """The width-tiled grid (300 = 256 + 44: the last block is narrower
+    than the block AND than 128 lanes, its second 128-lane group lies
+    wholly outside the image and its padding holds whatever interpret
+    mode pads with): the same bits as the dense loop on the full row,
+    and the same count."""
+    from scenery_insitu_tpu.ops import pallas_seg as psg
+    from scenery_insitu_tpu.ops import pallas_util
+
+    chunks, k = _slot_stream("dead_tile")
+    geo = _geometry(15, 16, 300)
+    with monkeypatch.context() as m:
+        m.setattr(psg, "_phase_b_compact", _dense_phase_b)
+        ref = _fold_stream(feed, tf, chunks, k, geo)
+    full = _fold_stream(feed, tf, chunks, k, geo)
+    monkeypatch.setattr(pallas_util, "_FORCE_BLOCK_W", 256)
+    assert pallas_util.pick_block_w(300, 1) == 256
+    got = _fold_stream(feed, tf, chunks, k, geo)
+    for a, b, name in zip(got, ref, ("color", "depth", "small")):
+        assert np.array_equal(a, b), name
+    assert np.array_equal(got[3], full[3]) and got[3][0].any()
+
+
+@pytest.mark.parametrize("junk", [np.nan, np.inf, -np.inf, -7.0, 1e30])
+def test_slot_bounds_ignore_what_the_padding_holds(junk):
+    """`tile_slot_bounds` alone: whatever the lanes at or beyond the
+    image's width hold, the bounds are those of the lanes inside, in
+    [0, K] with lo <= hi; and junk INSIDE the image cannot take them out
+    of that range either."""
+    from scenery_insitu_tpu.ops import pallas_seg as psg
+
+    k, width, col0 = 20, 300, 256           # 44 lanes of the tile inside
+    rng = np.random.default_rng(3)
+    first = rng.integers(3, 9, (8, 128)).astype(np.float32)
+    last = first + rng.integers(0, 5, (8, 128)).astype(np.float32)
+    first[2:4], last[2:4] = k, -1.0         # pixels with no live sample
+    inside = np.arange(128) < width - col0
+    want = (int(first[:, inside].min()), int(last[:, inside].max()) + 1)
+    first[:, ~inside], last[:, ~inside] = junk, junk
+    lo, hi = psg.tile_slot_bounds(jnp.asarray(first), jnp.asarray(last),
+                                  col0, width, k)
+    assert (int(lo), int(hi)) == want
+    # no live sample inside: nothing to merge, whatever is outside
+    first[:, inside], last[:, inside] = k, -1.0
+    lo, hi = psg.tile_slot_bounds(jnp.asarray(first), jnp.asarray(last),
+                                  col0, width, k)
+    assert (int(lo), int(hi)) == (0, 0)
+    # junk everywhere (it cannot happen inside the image: a guard)
+    lo, hi = psg.tile_slot_bounds(jnp.full((8, 128), junk, jnp.float32),
+                                  jnp.full((8, 128), junk, jnp.float32),
+                                  0, width, k)
+    assert 0 <= int(lo) <= int(hi) <= k
+
+
+def test_slot_rows_of_a_dense_chunk_and_of_a_dead_march(tf):
+    """`fold_slot_rows_merged` is `fold_slot_rows` where every tile's
+    samples span all K slots, and 0 for a march with no live sample (a
+    field the transfer function leaves transparent)."""
+    from scenery_insitu_tpu.config import SliceMarchConfig, VDIConfig
+    from scenery_insitu_tpu.core.volume import Volume
+    from scenery_insitu_tpu.obs.profiler import fold_slot_account
+    from scenery_insitu_tpu.ops import pallas_seg as psg
+    from scenery_insitu_tpu.ops import slicer
+
+    rng = np.random.default_rng(5)
+    val = jnp.asarray((0.2 + 0.8 * rng.random((5, 16, 300)))
+                      .astype(np.float32))
+    dense = _fold_stream("fused", tf, [val], 4, _geometry(5, 16, 300))
+    assert dense[2][0].min() == 5                  # five starts a pixel
+    assert np.array_equal(dense[3][0], dense[3][1])
+    assert dense[3][1].sum() == 4 * 2 * 3
+
+    vol = Volume.centered(jnp.zeros((24, 24, 24), jnp.float32), extent=2.0)
+    cam = _camera((0.2, 0.4, 3.0))
+    spec = slicer.make_spec(cam, vol.data.shape, SliceMarchConfig(
+        matmul_dtype="f32", scale=1.0, fold="pallas_fused", chunk=8,
+        skip_empty=False))
+    cfg = VDIConfig(max_supersegments=4, adaptive=False, threshold=0.1)
+    with fold_slot_account(True) as noted:
+        vdi, _, _ = slicer.generate_vdi_mxu(vol, tf, cam, spec, cfg)
+    (slots,) = noted
+    tiles = psg.slot_tiles(spec.nj, spec.ni)
+    assert tuple(np.asarray(slots)) == (0, 4 * tiles * 3)
+    assert not np.asarray(vdi.color).any()
+    # nobody listening: the march says it to nobody
+    with fold_slot_account(False) as noted:
+        slicer.generate_vdi_mxu(vol, tf, cam, spec, cfg)
+    assert noted is None
+
+
+def test_four_rank_step_hands_each_ranks_slot_rows_on(tf):
+    """A four-rank march under `shard_map` (`distributed_vdi_step_mxu`
+    built with ``slot_counts``): the frame comes with i32[4, 2], each
+    rank's own (merged, visited) of its slab's march; built without, the
+    frame is the pair it always was, bit for bit."""
+    from scenery_insitu_tpu.config import (CompositeConfig, SliceMarchConfig,
+                                           VDIConfig)
+    from scenery_insitu_tpu.ops import pallas_seg as psg
+    from scenery_insitu_tpu.ops import slicer
+    from scenery_insitu_tpu.parallel import pipeline
+    from scenery_insitu_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(4)
+    field = jnp.asarray(_blob_field((32, 32, 32), 4))
+    cam = _camera((0.2, 0.4, 3.0))
+    spec = slicer.make_spec(cam, field.shape, SliceMarchConfig(
+        matmul_dtype="f32", scale=1.0, fold="pallas_fused", chunk=8),
+        multiple_of=4)
+    cfg = VDIConfig(max_supersegments=4, adaptive=False, threshold=0.1)
+    comp = CompositeConfig(max_output_supersegments=4)
+    args = (pipeline.shard_volume(field, mesh),
+            jnp.full((3,), -1.0, jnp.float32),
+            jnp.full((3,), 2.0 / 32, jnp.float32), cam)
+    vdi, _, slots = pipeline.distributed_vdi_step_mxu(
+        mesh, tf, spec, cfg, comp, slot_counts=True)(*args)
+    slots = np.asarray(slots)
+    tiles = psg.slot_tiles(spec.nj, spec.ni)
+    assert slots.shape == (4, 2)
+    # a rank's slab is 8 planes deep: one chunk a rank
+    assert (slots[:, 1] == 4 * tiles).all()
+    assert (slots[:, 0] <= slots[:, 1]).all() and slots[:, 0].sum() > 0
+    assert len(set(slots[:, 0])) > 1       # each rank's own, not a copy
+    plain, _ = pipeline.distributed_vdi_step_mxu(
+        mesh, tf, spec, cfg, comp)(*args)
+    assert np.array_equal(np.asarray(vdi.color), np.asarray(plain.color))
+    assert np.array_equal(np.asarray(vdi.depth), np.asarray(plain.depth))
+
+
+@pytest.mark.parametrize("ranks", [1, 4])
+def test_session_counts_the_slot_rows_where_it_fetches(ranks):
+    """A recorded session adds every fetched frame's account to
+    `fold_slot_rows_merged` / `fold_slot_rows` (on a mesh each rank's
+    own, summed); a session that records nothing has neither counter
+    and its steps hand nothing on."""
+    from scenery_insitu_tpu.config import FrameworkConfig
+    from scenery_insitu_tpu.runtime.session import InSituSession
+
+    def run(recorded):
+        sess = InSituSession(FrameworkConfig().with_overrides(
+            "sim.grid=[32,32,32]", "sim.steps_per_frame=2",
+            "slicer.engine=mxu", "slicer.fold=pallas_fused",
+            "slicer.chunk=8", "vdi.adaptive_mode=temporal",
+            "vdi.max_supersegments=6",
+            "composite.max_output_supersegments=6",
+            "runtime.dataset=gray_scott", f"mesh.num_devices={ranks}",
+            f"obs.enabled={str(recorded).lower()}"))
+        frames = []
+        sess.sinks.append(lambda i, p: frames.append(p["vdi_color"].copy()))
+        sess.run(3)
+        sess.close()
+        assert all(v[2] is None for v in sess._pending_meta.values())
+        return dict(sess.obs.counters), frames
+
+    counters, frames = run(True)
+    # 40 x 40 pixels: five row bands of one tile; 32 planes in chunks
+    # of 8, over the ranks or in a row: four kernel calls a frame
+    assert counters["fold_slot_rows"] == 3 * 4 * 6 * 5
+    assert 0 < counters["fold_slot_rows_merged"] < counters["fold_slot_rows"]
+    off, frames_off = run(False)
+    assert "fold_slot_rows" not in off and "fold_slot_rows_merged" not in off
+    for a, b in zip(frames, frames_off):
+        assert np.array_equal(a, b)

@@ -410,3 +410,43 @@ def test_the_beechnut_step_keeps_sixteen_bits_in_bf16_operands(topo,
     volume = 2 * int(np.prod(BN_GRID))
     assert compiled.memory_analysis().argument_size_in_bytes >= volume
     assert compiled.memory_analysis().temp_size_in_bytes < volume // 8
+
+
+# (K, chunk, Nj, Ni): gs512's and the dataset cells' frames (strips of
+# 384 lanes: 640 = 384 + 256, 1280 = 3 x 384 + 128), gs128's and
+# vortex256's (one full-row strip whose last tile is 32 / 64 lanes), and
+# the one-sample chunk an occupancy-skipped iteration folds
+FOLD_SHAPES = [(16, 16, 640, 640), (20, 16, 1280, 1280), (16, 16, 160, 160),
+               (16, 16, 320, 320), (16, 1, 640, 640)]
+
+
+@pytest.mark.parametrize("k,c,nj,ni", FOLD_SHAPES)
+def test_the_fold_kernel_compiles_with_its_data_bounded_loops(topo, k, c,
+                                                              nj, ni):
+    """`sitpu_fold_fused` for one v5e at the cells' widths: what
+    interpret mode cannot show of PR 50's phase B — Mosaic's view of
+    `fori_loop`s whose bounds are scalars reduced from vectors, of the
+    128-lane slices of the state (the last one narrower where the row is
+    one strip), of the SMEM account, and the VMEM budget with the two
+    interval planes live across phase A."""
+    from scenery_insitu_tpu.core.transfer import for_dataset
+    from scenery_insitu_tpu.ops import pallas_seg as psg
+
+    one = SingleDeviceSharding(topo.devices[0])
+    shape = lambda s, dt=jnp.float32: jax.ShapeDtypeStruct(s, dt,
+                                                           sharding=one)
+    tf = for_dataset("gray_scott")
+    packed = (shape((k, 4, nj, ni)), shape((k, 2, nj, ni)),
+              shape((5, nj, ni)),
+              shape((2, psg.slot_tiles(nj, ni)), jnp.int32))
+
+    def fold(packed, val, length, ratio, sk0, sk1, thr):
+        return psg.fused_fold_chunk(packed, val, length, ratio, sk0, sk1,
+                                    thr, max_k=k, tf=tf, interpret=False)
+
+    plane = shape((nj, ni))
+    text = jax.jit(fold, donate_argnums=0).lower(
+        packed, shape((c, nj, ni)), plane, plane, shape((c,)), shape((c,)),
+        plane).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "sitpu_fold_fused" in text
